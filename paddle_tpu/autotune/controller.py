@@ -29,7 +29,7 @@ Safety rails (all contract-tested):
 
 The controller owns NO measurement code: ``measure(config) -> row`` is
 injected (bench.py's ``--autotune`` mode wraps ``bench_train`` +
-``_retry_transient`` + BENCH_RUN-keyed resume; tests inject synthetic
+BENCH_RUN-keyed resume; tests inject synthetic
 objective surfaces).  The row must carry the objective under
 ``objective_key``; ``doctor`` (ranked verdicts) and
 ``xla_compiles_measured`` ride along when available.
@@ -64,8 +64,8 @@ class AutotuneController:
     measure:
         ``measure(config: dict) -> row: dict``.  Must return the
         objective under ``objective_key``; may raise (the trial is then
-        rolled back).  Resume/retry belong INSIDE measure (bench.py
-        wraps ``_retry_transient`` + persisted-row lookup).
+        rolled back).  Resume belongs INSIDE measure (bench.py wraps a
+        persisted-row lookup).
     kind:
         'train' | 'serve' — restricts both the doctor rule table and
         the eligible knob axes.

@@ -175,14 +175,14 @@ class LiveRetuner:
                     row = np.zeros(eng.blocks_per_slot, np.int32)
                     row[:n] = blocks
                     try:
-                        logits, cache = eng._prefill_paged_cold_jit(
+                        logits, cache, _ = eng._prefill_paged_cold_jit(
                             eng.params, eng.cache, ids,
                             jnp.asarray(row), np.int32(1))
                         eng.cache = cache
                     finally:
                         eng._alloc.decref(blocks)
                 else:
-                    logits, cache = eng._prefill_jit(
+                    logits, cache, _ = eng._prefill_jit(
                         eng.params, eng.cache, ids, np.int32(0),
                         np.int32(1))
                     eng.cache = cache

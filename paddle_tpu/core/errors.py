@@ -4,7 +4,7 @@ TPU-native equivalent of the reference's PADDLE_ENFORCE machinery
 (/root/reference/paddle/fluid/platform/enforce.h:440,505 and errors.h /
 error_codes.proto). The reference formats typed error codes with stack
 traces from C++ macros; here errors are Python exception classes with the
-same taxonomy so user-facing behavior matches, and `enforce*` helpers give
+same hierarchy so user-facing behavior matches, and `enforce*` helpers give
 call sites the same one-liner ergonomics.
 """
 from __future__ import annotations

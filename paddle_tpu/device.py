@@ -27,8 +27,16 @@ class Place:
         return hash((self.device_type, self.device_id))
 
     def jax_device(self):
-        devs = jax.devices() if self.device_type != "cpu" else jax.devices("cpu")
-        return devs[min(self.device_id, len(devs) - 1)]
+        """The jax device this place names.  A place that names a device
+        the process does not have is an error: an id past the last
+        device is not clamped to it, and an accelerator place in a
+        CPU-only process does not quietly become a CPU device."""
+        devs = jax.devices(self.device_type)
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: this process has {len(devs)} "
+                f"{self.device_type} device(s)")
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):

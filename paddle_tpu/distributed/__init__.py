@@ -43,4 +43,4 @@ from .resilience import CheckpointManager, PreemptionGuard  # noqa: F401
 from . import launch as launch_mod  # noqa: F401
 from .spawn import spawn  # noqa: F401
 from . import overlap  # noqa: F401
-from .overlap import overlap_enabled, ensure_xla_overlap_flags  # noqa: F401
+from .overlap import overlap_enabled  # noqa: F401

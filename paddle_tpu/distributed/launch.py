@@ -131,12 +131,6 @@ def _trainer_env(rank: int, world: int, endpoints: List[str],
                  coordinator: str) -> dict:
     env = dict(os.environ)
     env.update(trainer_env_vars(rank, world, endpoints, coordinator))
-    # children get the async-collective / latency-hiding XLA flags
-    # (PADDLE_TPU_OVERLAP): their jax has not initialized yet, so this
-    # is the one place the env knob can still take effect on real
-    # accelerator backends (no-op on host platforms)
-    from .overlap import ensure_xla_overlap_flags
-    ensure_xla_overlap_flags(env=env)
     return env
 
 

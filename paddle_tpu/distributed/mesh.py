@@ -29,46 +29,17 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# shard_map moved across jax versions (jax.experimental.shard_map ->
-# top-level jax.shard_map) and renamed its replication-check kwarg
-# (check_rep -> check_vma); resolve once here so every consumer gets a
-# callable with the NEW spelling regardless of the installed version.
-try:
-    from jax import shard_map as _sm
-    _shard_map = _sm if callable(_sm) else _sm.shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map  # noqa: F401  (re-exported to every schedule)
 
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    import functools as _functools
-
-    @_functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis inside shard_map/pmap bodies.
-    jax.lax.axis_size only exists from jax 0.5; psum of a Python
-    constant is the portable spelling (folded to a static int)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a bound mesh axis inside shard_map/pmap bodies."""
+    return jax.lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
-# Version-compat collective helpers (used inside shard_map bodies).
-#
-# spmd/pipeline/moe/ring_attention each used to spell these against
-# jax.lax directly; the names and kwargs moved across jax versions
-# (psum_scatter's `scatter_dimension`, all_gather's `tiled` default), so
-# one shim here keeps every schedule on the same spelling.  All three
-# return the TILED layout: gather concatenates shards on `axis`,
+# Collective helpers (used inside shard_map bodies): one spelling for
+# spmd/pipeline/moe/ring_attention.  All three return the TILED layout: gather concatenates shards on `axis`,
 # reduce_scatter leaves each rank its `axis` slice of the sum.
 # ---------------------------------------------------------------------------
 def all_gather(x, axis_name: str, *, axis: int = 0):

@@ -195,7 +195,7 @@ class SpmdTrainer:
         self._donate = donate
         self._step_count = 0
 
-        # persistent XLA compile cache (PADDLE_TPU_COMPILE_CACHE): warm
+        # persistent XLA compile cache (utils.compile_cache): warm
         # restarts skip the multi-minute recompile of identical steps
         from ..utils.compile_cache import ensure_compile_cache
         ensure_compile_cache()
@@ -696,7 +696,16 @@ class SpmdTrainer:
                     donate_argnums=(0, 1) if fam != "eval" else (),
                     meta={"mesh_axes": dict(self.mesh.shape),
                           "zero_stage": self.zero_stage,
-                          "amp": self.amp_enabled})
+                          "amp": self.amp_enabled,
+                          # lets analyze() re-lower on THIS mesh: the
+                          # uncommitted operands (lr, step number) are
+                          # replicated there, as at runtime
+                          "submesh": {
+                              "shape": {ax: int(n) for ax, n in
+                                        self.mesh.shape.items()},
+                              "devices": [
+                                  int(d.id) for d in
+                                  np.asarray(self.mesh.devices).flat]}})
         t0 = time.perf_counter()
         res = self._compiled[key](*args)
         dt = (time.perf_counter() - t0) * 1e3

@@ -3,7 +3,7 @@
 The GSPMD ZeRO-3 path (`spmd.zero_sharding_spec` with stage>=3) leaves
 the gather placement to XLA: params live dp-sharded and the partitioner
 inserts an all-gather at each use site.  That is correct but gives the
-scheduler no structure to hide the gathers behind — on jaxlib 0.4.x the
+scheduler no structure to hide the gathers behind: the
 partitioned module typically gathers a layer's weights right before its
 matmuls need them, serializing ICI transfer and MXU work.
 

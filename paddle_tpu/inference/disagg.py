@@ -401,8 +401,8 @@ class DisaggServingEngine:
             mesh=self.worker.mesh)
         # the group boundary: recommit each gathered stack to the
         # decode group's pool sharding (this is the actual D2D copy)
-        dims = [(None, None, None, "tp", None)] * 2 + \
-            [(None, None, None, "tp")] * (len(rows) - 2)
+        dims = [(None, None, "tp", None, None)] * 2 + \
+            [(None, None, "tp", None)] * (len(rows) - 2)
         moved = tuple(eng._put(eng.mesh, r, d)
                       for r, d in zip(rows, dims))
         eng.cache = eng._timed_exec(

@@ -38,12 +38,9 @@ by distributed.async_dispatch's host-sync counter, same as training).
 
 Both executables go through the persistent XLA compile cache
 (utils.compile_cache), so a server restart deserializes instead of
-recompiling.  On the CPU backend the engine does NOT donate its cache
-operands: jaxlib 0.4.x mis-aliases donated buffers in executables
-deserialized from the persistent cache (the same hazard PR 2 hit with
-rollback) — the compile-cache guard plus no-donation keeps the test
-suite's warm cache safe.  On TPU, donation is on and the cache updates
-are true in-place writes.
+recompiling.  The cache operands are donated on every backend, so the
+cache updates are true in-place writes (``donate=False`` /
+``PADDLE_TPU_INFER_DONATE=0`` turns it off).
 
 Chunked prefill (ISSUE 20, Agrawal et al., *Sarathi-Serve*): the
 monolithic bucketed prefill above runs BETWEEN decode ticks, so one
@@ -75,6 +72,7 @@ half the HBM bytes per step; default full precision).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
@@ -97,6 +95,7 @@ from ..observability import flightrec as _flightrec
 from ..observability import metrics as _metrics
 from ..observability import spans as _spans
 from ..observability import watchdog as _watchdog
+from ..ops import kernel_paths as _kernel_paths
 from ..utils import compile_cache, compile_counter
 from .paged_kv import (BlockAllocator, blocks_for, blocks_to_extend,
                        init_paged_cache)
@@ -300,33 +299,29 @@ class InferenceEngine:
         self.ep_degree = int(mesh.shape["ep"]) \
             if mesh is not None and "ep" in mesh.axis_names else 1
         self._shard_warned = False
-        if self.kv_layout == "paged":
-            self._init_paged(cache_dtype, kv_block_size, kv_num_blocks,
-                             prefix_cache)
-        else:
-            self.cache = model.init_kv_cache(self.batch_slots,
-                                             self.max_seq_len, cache_dtype,
-                                             kv_dtype=self.kv_dtype)
-            self._alloc = None
-            self._prefix = None
+        # a mesh engine's cache is born on the HOST and goes straight to
+        # its shards: built on the default device first, the whole
+        # unsharded cache would have to fit one chip beside the weights
+        # (gpt3-1.3b float32 at tp=4: 6 GiB beside 5 GiB — it did not,
+        # PR 21's four-chip run)
+        born_on = jax.default_device(jax.devices("cpu")[0]) \
+            if mesh is not None else contextlib.nullcontext()
+        with born_on:
+            if self.kv_layout == "paged":
+                self._init_paged(cache_dtype, kv_block_size,
+                                 kv_num_blocks, prefix_cache)
+            else:
+                self.cache = model.init_kv_cache(
+                    self.batch_slots, self.max_seq_len, cache_dtype,
+                    kv_dtype=self.kv_dtype)
+                self._alloc = None
+                self._prefix = None
         if mesh is not None:
             self._shard_over_mesh(mesh)
 
-        # CPU + persistent cache + donation = the PR 2 mis-alias hazard
-        # (deserialized executables alias donated buffers wrongly on
-        # jaxlib 0.4.x CPU); see module docstring
         if donate is None:
-            env = os.environ.get("PADDLE_TPU_INFER_DONATE")
-            if env is not None:
-                donate = env != "0"
-            else:
-                donate = jax.default_backend() not in ("cpu",)
+            donate = os.environ.get("PADDLE_TPU_INFER_DONATE", "1") != "0"
         self._donate = bool(donate)
-        # donation + CPU + persistent cache: never DESERIALIZE these
-        # executables (compile fresh; entries still written) — see
-        # compile_cache.suspend_cpu_cache_hits
-        self._suspend_cache_hits = (self._donate and
-                                    jax.default_backend() == "cpu")
         dargs = (1,) if self._donate else ()
         self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=dargs)
         self._decode_jit = jax.jit(self._decode_fn, donate_argnums=dargs)
@@ -429,6 +424,9 @@ class InferenceEngine:
         self._guard_timeout: Optional[float] = None
         self.undelivered: List[Request] = []
         self._first_call_keys: set = set()
+        # executable key -> {kernel entry point: {"kernel": n,
+        # "composite": m}} as traced for that executable (ops.kernel_paths)
+        self.kernel_paths: dict = {}
         self._counters0 = compile_counter.snapshot()
 
         # unified telemetry (observability/): registry children bound
@@ -626,19 +624,19 @@ class InferenceEngine:
             *scales)
 
     def _shard_paged_cache_arrays(self, mesh, cache):
-        """Paged pool layout on the mesh: k/v [L, NB, bs, Hkv, D] —
+        """Paged pool layout on the mesh: k/v [L, NB, Hkv, bs, D] —
         KV heads over 'tp', block/position dims REPLICATED so host-side
         allocation, the radix prefix cache and zero-recompile slot
         churn never see the mesh (block tables stay plain host int32)."""
         scales = ()
         if cache.quantized:
             scales = (self._put(mesh, cache.k_scale,
-                                (None, None, None, "tp")),
+                                (None, None, "tp", None)),
                       self._put(mesh, cache.v_scale,
-                                (None, None, None, "tp")))
+                                (None, None, "tp", None)))
         return type(cache)(
-            self._put(mesh, cache.k, (None, None, None, "tp", None)),
-            self._put(mesh, cache.v, (None, None, None, "tp", None)),
+            self._put(mesh, cache.k, (None, None, "tp", None, None)),
+            self._put(mesh, cache.v, (None, None, "tp", None, None)),
             *scales)
 
     def _shard_over_mesh(self, mesh):
@@ -870,11 +868,15 @@ class InferenceEngine:
             # first call per executable = trace + compile/deserialize
             self._first_call_keys.add(key)
             with self._trace_lock:
-                if self._suspend_cache_hits:
-                    with compile_cache.suspend_cpu_cache_hits():
-                        out = fn()
-                else:
-                    out = fn()
+                before = _kernel_paths.counts()
+                out = fn()
+                # which kernel entry points traced their Pallas kernel
+                # and which their composite, for THIS executable
+                self.kernel_paths[key] = {
+                    op: {path: n - before.get(op, {}).get(path, 0)
+                         for path, n in per_op.items()}
+                    for op, per_op in _kernel_paths.counts().items()
+                    if per_op != before.get(op)}
             dt = (time.perf_counter() - t0) * 1e3
             self._timings["compile_ms_cold"] += dt
             _exec_registry.registry().note_compile(
@@ -2085,9 +2087,12 @@ class InferenceEngine:
                 self.params, self.cache, ids, np.int32(0), np.int32(1))
             self.cache = cache
         self._key, sub = jax.random.split(self._key)
+        # numpy operands, exactly as _record_admission passes them: jit's
+        # fast path keys on the operand kind, so a jax.Array here would
+        # leave the tick's first sample call to re-trace
         self._timed_exec("prefill_ms", ("sample", 1), self._sample_jit,
-                         logits, sub, jnp.zeros((1,), jnp.float32),
-                         jnp.ones((1,), jnp.float32))
+                         logits, sub, np.zeros((1,), np.float32),
+                         np.ones((1,), np.float32))
         nxt, self._key, cache, _ = self._timed_exec(
             "decode_ms", ("decode", 0), self._decode_jit,
             self.params, self.cache,
@@ -2139,10 +2144,11 @@ class InferenceEngine:
             self._alloc.decref(blocks)
         if logits is not None:
             self._key, sub = jax.random.split(self._key)
+            # numpy operands, as _record_admission passes them
             self._timed_exec("prefill_ms", ("sample", 1),
                              self._sample_jit, logits, sub,
-                             jnp.zeros((1,), jnp.float32),
-                             jnp.ones((1,), jnp.float32))
+                             np.zeros((1,), np.float32),
+                             np.ones((1,), np.float32))
         # decode over all-null tables: every write lands in the null
         # block, every slot length is 0 — pure compile fodder
         nxt, self._key, cache, _ = self._timed_exec(
@@ -2349,9 +2355,20 @@ class InferenceEngine:
         else:
             s.pop("moe_assigned_tokens", None)
             s.pop("moe_dropped_tokens", None)
+        # what the decode executable COMPILED, not the knob: an armed
+        # megakernel that traced its composite (VMEM gate, backend,
+        # shape) or stood down under tp reads False, with the reason
         from ..ops.decode_megakernel import megakernel_enabled
-        s["decode_megakernel"] = (megakernel_enabled(self.model.cfg)
-                                  and self.tp_degree == 1)
+        mk = self.kernel_paths.get(("decode", 0), {}).get(
+            "decode_megakernel", {})
+        s["decode_megakernel"] = mk.get("kernel", 0) > 0
+        s["decode_megakernel_refusal"] = None
+        if megakernel_enabled(self.model.cfg) and \
+                not s["decode_megakernel"]:
+            s["decode_megakernel_refusal"] = \
+                "stands down under tp>1" if self.tp_degree > 1 else \
+                _kernel_paths.last_reason("decode_megakernel") or \
+                "decode executable not compiled yet"
         s["decode_hbm_bytes_per_tok"] = self._decode_hbm_bytes_per_tok()
         if self._spec is not None:
             s["spec_k"] = self._spec.k

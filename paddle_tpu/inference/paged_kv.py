@@ -8,9 +8,12 @@ every live request is short.  This module is the vLLM-style fix
 (Kwon et al., *Efficient Memory Management for Large Language Model
 Serving with PagedAttention*): K/V live in a pool of fixed-size blocks
 
-    ``[layers, num_blocks, block_size, kv_heads, head_dim]``
+    ``[layers, num_blocks, kv_heads, block_size, head_dim]``
 
-and each slot holds a small BLOCK TABLE of pool indices.  A slot
+(heads AHEAD of the block dimension: the paged kernels' per-head
+``[block_size, head_dim]`` strip is then the two minor dimensions of
+the pool, which is the only block shape the TPU lowering accepts for
+every block size) and each slot holds a small BLOCK TABLE of pool indices.  A slot
 consumes exactly ``ceil(len/block_size)`` blocks, so concurrency is
 bounded by total memory, not by the worst-case sequence length — and
 blocks can be SHARED between slots (refcounts), which is what makes
@@ -48,7 +51,8 @@ import jax.numpy as jnp
 from ..observability import metrics as _metrics
 
 __all__ = ["PagedKVCache", "BlockAllocator", "init_paged_cache",
-           "blocks_for", "blocks_to_extend"]
+           "blocks_for", "blocks_to_extend", "blocks_to_rows",
+           "rows_to_blocks"]
 
 
 def blocks_for(n_tokens: int, block_size: int) -> int:
@@ -66,15 +70,30 @@ def blocks_to_extend(have_blocks: int, new_len: int,
     return max(blocks_for(new_len, block_size) - int(have_blocks), 0)
 
 
+def blocks_to_rows(blocks):
+    """Pool blocks ``[n, Hkv, bs, ...]`` -> position-major rows
+    ``[n·bs, Hkv, ...]`` (the dense cache's per-slot layout).  Works for
+    value blocks (trailing D) and scale blocks (no trailing dim)."""
+    rows = jnp.swapaxes(blocks, 1, 2)
+    return rows.reshape((-1,) + rows.shape[2:])
+
+
+def rows_to_blocks(rows, block_size: int):
+    """Inverse of :func:`blocks_to_rows`: ``[n·bs, Hkv, ...]`` ->
+    ``[n, Hkv, bs, ...]``."""
+    return jnp.swapaxes(
+        rows.reshape((-1, int(block_size)) + rows.shape[1:]), 1, 2)
+
+
 class PagedKVCache:
     """Device half of the paged cache: ``k``/``v`` are
-    ``[layers, num_blocks, block_size, kv_heads, head_dim]`` block
+    ``[layers, num_blocks, kv_heads, block_size, head_dim]`` block
     pools.  Which blocks belong to which slot is the host allocator's
     business; the executables receive block tables as operands.
 
     Quantized form (``kv_dtype='int8'``/``'fp8'``): the value pools
     hold 8-bit values and ``k_scale``/``v_scale`` the per-(position,
-    head) f32 scale pools ``[layers, num_blocks, block_size, kv_heads]``
+    head) f32 scale pools ``[layers, num_blocks, kv_heads, block_size]``
     — the paged decode kernel streams both and dequantizes in VMEM.
     Full-precision pools (``k_scale is None``) stay the default and the
     parity oracle."""
@@ -95,7 +114,7 @@ class PagedKVCache:
 
     @property
     def block_size(self):
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def quantized(self) -> bool:
@@ -103,8 +122,8 @@ class PagedKVCache:
 
     def __repr__(self):
         return (f"PagedKVCache(layers={self.k.shape[0]}, "
-                f"blocks={self.k.shape[1]}, block_size={self.k.shape[2]}, "
-                f"kv_heads={self.k.shape[3]}, dtype={self.k.dtype}"
+                f"blocks={self.k.shape[1]}, block_size={self.k.shape[3]}, "
+                f"kv_heads={self.k.shape[2]}, dtype={self.k.dtype}"
                 f"{', quantized' if self.quantized else ''})")
 
 
@@ -127,8 +146,8 @@ def init_paged_cache(model, num_blocks: int, block_size: int,
     mode = resolve_kv_quant(kv_dtype)
     dt = kv_storage_dtype(mode) if mode else \
         (dtype or gpt.wte.weight.dtype)
-    shape = (cfg.num_layers, int(num_blocks), int(block_size),
-             cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, int(num_blocks), cfg.num_kv_heads,
+             int(block_size), cfg.head_dim)
     scales = (jnp.zeros(shape[:-1], jnp.float32),
               jnp.zeros(shape[:-1], jnp.float32)) if mode else (None, None)
     return PagedKVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),

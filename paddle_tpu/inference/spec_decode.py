@@ -450,16 +450,20 @@ class SpecDecoder:
         # engine._warmup_dense: an uncommitted zeros operand is a
         # different jit cache key than the committed one the warmup
         # trace used, and the first real prefill would recompile
-        zeros = jnp.zeros((eng.batch_slots,), jnp.int32)
-        if eng.mesh is not None:
-            try:
-                zeros = eng._put(eng.mesh, zeros, ("dp",))
-            except Exception as e:
-                eng._shard_failed("spec_warmup_lengths", e)
+        def zeros():
+            # one buffer per cache: both caches are donated, and a
+            # shared lengths buffer would die with whichever went first
+            z = jnp.zeros((eng.batch_slots,), jnp.int32)
+            if eng.mesh is not None:
+                try:
+                    z = eng._put(eng.mesh, z, ("dp",))
+                except Exception as e:
+                    eng._shard_failed("spec_warmup_lengths", e)
+            return z
         self.draft_cache = StaticKVCache(
-            self.draft_cache.k, self.draft_cache.v, zeros,
+            self.draft_cache.k, self.draft_cache.v, zeros(),
             self.draft_cache.k_scale, self.draft_cache.v_scale)
         if eng.kv_layout != "paged":
             eng.cache = StaticKVCache(
-                eng.cache.k, eng.cache.v, zeros,
+                eng.cache.k, eng.cache.v, zeros(),
                 eng.cache.k_scale, eng.cache.v_scale)

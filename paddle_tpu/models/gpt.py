@@ -499,7 +499,7 @@ class GPTAttention(Layer):
         [B, W, hidden]; tables [B, MB] int32; lengths [B] int32
         EXCLUDING the window."""
         b, w = x.shape[0], x.shape[1]
-        bs = k_pool.shape[1]
+        bs = k_pool.shape[2]
         mb = tables.shape[1]
         q, k, v = self._qkv_arrays(x)
         lens = lengths.astype(jnp.int32)
@@ -514,17 +514,17 @@ class GPTAttention(Layer):
             mode = kv_quant_mode(k_pool.dtype)
             kq, ks = quantize_kv(k, mode)
             vq, vs = quantize_kv(v, mode)
-            k_pool = k_pool.at[blk, off].set(kq)
-            v_pool = v_pool.at[blk, off].set(vq)
-            k_scale = k_scale.at[blk, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, off].set(vs.astype(v_scale.dtype))
+            k_pool = k_pool.at[blk, :, off].set(kq)
+            v_pool = v_pool.at[blk, :, off].set(vq)
+            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
+            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
             out = _ops.paged_decode_attention_window(
                 q, k_pool, v_pool, tables, lens, k_scale, v_scale)
             out = out.astype(q.dtype)
             return (self._proj_out(out, b, w), k_pool, v_pool,
                     k_scale, v_scale)
-        k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype))
+        k_pool = k_pool.at[blk, :, off].set(k.astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(v.astype(v_pool.dtype))
         out = _ops.paged_decode_attention_window(
             q.astype(k_pool.dtype), k_pool, v_pool, tables, lens)
         out = out.astype(q.dtype)
@@ -579,18 +579,18 @@ class GPTAttention(Layer):
         new k/v at pool position ``(tables[b, lengths[b]//bs],
         lengths[b]%bs)`` (scatter), then run the paged fused attention
         streaming the slot's blocks through its table.  x [B, 1, H];
-        k_pool/v_pool [num_blocks, bs, Hkv, D]; tables [B, MB] int32;
+        k_pool/v_pool [num_blocks, Hkv, bs, D]; tables [B, MB] int32;
         lengths [B] int32 EXCLUDING the new token.  Inactive slots write
         into the reserved null block (their table rows are all-zero) —
         masked garbage by construction.  Returns
         ``(out, k_pool, v_pool)``.
 
-        Quantized pools: ``k_scale``/``v_scale`` [num_blocks, bs, Hkv]
+        Quantized pools: ``k_scale``/``v_scale`` [num_blocks, Hkv, bs]
         f32 — new k/v quantized per head on write, scales streamed and
         dequantized inside the paged kernel; returns
         ``(out, k_pool, v_pool, k_scale, v_scale)``."""
         b = x.shape[0]
-        bs = k_pool.shape[1]
+        bs = k_pool.shape[2]
         mb = tables.shape[1]
         q, k, v = self._qkv_arrays(x)
         lens = lengths.astype(jnp.int32)
@@ -604,18 +604,18 @@ class GPTAttention(Layer):
             mode = kv_quant_mode(k_pool.dtype)
             kq, ks = quantize_kv(k[:, 0], mode)         # [b,Hkv,D],[b,Hkv]
             vq, vs = quantize_kv(v[:, 0], mode)
-            k_pool = k_pool.at[blk, off].set(kq)
-            v_pool = v_pool.at[blk, off].set(vq)
-            k_scale = k_scale.at[blk, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, off].set(vs.astype(v_scale.dtype))
+            k_pool = k_pool.at[blk, :, off].set(kq)
+            v_pool = v_pool.at[blk, :, off].set(vq)
+            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
+            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
             out = _ops.paged_decode_attention(
                 q[:, 0], k_pool, v_pool, tables, lens + 1,
                 k_scale, v_scale)
             out = out[:, None].astype(q.dtype)           # [b, 1, H, D]
             return (self._proj_out(out, b, 1), k_pool, v_pool,
                     k_scale, v_scale)
-        k_pool = k_pool.at[blk, off].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, off].set(v[:, 0].astype(v_pool.dtype))
+        k_pool = k_pool.at[blk, :, off].set(k[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(v[:, 0].astype(v_pool.dtype))
         out = _ops.paged_decode_attention(
             q[:, 0].astype(k_pool.dtype), k_pool, v_pool, tables,
             lens + 1)
@@ -866,7 +866,7 @@ class GPTBlock(Layer):
         from ..ops import decode_megakernel as _mk
         arr = x.data if isinstance(x, Tensor) else x      # [B, 1, H]
         b = arr.shape[0]
-        bs = k_pool.shape[1]
+        bs = k_pool.shape[2]
         mb = tables.shape[1]
         xo, k_new, v_new = _mk.decode_layer_step_paged(
             arr[:, 0], self._megakernel_weights(), k_pool, v_pool,
@@ -883,14 +883,14 @@ class GPTBlock(Layer):
             mode = kv_quant_mode(k_pool.dtype)
             kq, ks = quantize_kv(k_new, mode)
             vq, vs = quantize_kv(v_new, mode)
-            k_pool = k_pool.at[blk, off].set(kq)
-            v_pool = v_pool.at[blk, off].set(vq)
-            k_scale = k_scale.at[blk, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, off].set(vs.astype(v_scale.dtype))
+            k_pool = k_pool.at[blk, :, off].set(kq)
+            v_pool = v_pool.at[blk, :, off].set(vq)
+            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
+            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
             return (Tensor(xo[:, None]), k_pool, v_pool, k_scale,
                     v_scale)
-        k_pool = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))
+        k_pool = k_pool.at[blk, :, off].set(k_new.astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(v_new.astype(v_pool.dtype))
         return Tensor(xo[:, None]), k_pool, v_pool
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
@@ -1387,9 +1387,8 @@ class GPTModel(Layer):
             else jnp.asarray(input_ids)
         cfg = self.cfg
         s = ids.shape[1]
-        mb = table_row.shape[0]
+        from ..inference.paged_kv import blocks_to_rows, rows_to_blocks
         bs = cache.block_size
-        hkv, dh = cfg.num_kv_heads, cfg.head_dim
         off = jnp.asarray(prefix_len, jnp.int32)
         pos = jnp.minimum(off + jnp.arange(s, dtype=jnp.int32),
                           cfg.max_seq_len - 1)
@@ -1416,14 +1415,14 @@ class GPTModel(Layer):
                 # radix-cache hit.  Attention dtype is unaffected: the
                 # block casts the buffer to q.dtype before attending.
                 k_buf = dequantize_kv(
-                    cache_k[i][table_row], k_sc[i][table_row],
-                    jnp.float32).reshape(mb * bs, hkv, dh)
+                    blocks_to_rows(cache_k[i][table_row]),
+                    blocks_to_rows(k_sc[i][table_row]), jnp.float32)
                 v_buf = dequantize_kv(
-                    cache_v[i][table_row], v_sc[i][table_row],
-                    jnp.float32).reshape(mb * bs, hkv, dh)
+                    blocks_to_rows(cache_v[i][table_row]),
+                    blocks_to_rows(v_sc[i][table_row]), jnp.float32)
             else:
-                k_buf = cache_k[i][table_row].reshape(mb * bs, hkv, dh)
-                v_buf = cache_v[i][table_row].reshape(mb * bs, hkv, dh)
+                k_buf = blocks_to_rows(cache_k[i][table_row])
+                v_buf = blocks_to_rows(cache_v[i][table_row])
             x, k_buf, v_buf = blk.forward_prefill_paged(
                 x, k_buf, v_buf, prefix_len)
             # duplicate table entries (trailing null-block slots) scatter
@@ -1432,18 +1431,18 @@ class GPTModel(Layer):
                 kq, ks = quantize_kv(k_buf, mode)
                 vq, vs = quantize_kv(v_buf, mode)
                 cache_k = cache_k.at[i, table_row].set(
-                    kq.reshape(mb, bs, hkv, dh))
+                    rows_to_blocks(kq, bs))
                 cache_v = cache_v.at[i, table_row].set(
-                    vq.reshape(mb, bs, hkv, dh))
+                    rows_to_blocks(vq, bs))
                 k_sc = k_sc.at[i, table_row].set(
-                    ks.reshape(mb, bs, hkv).astype(k_sc.dtype))
+                    rows_to_blocks(ks, bs).astype(k_sc.dtype))
                 v_sc = v_sc.at[i, table_row].set(
-                    vs.reshape(mb, bs, hkv).astype(v_sc.dtype))
+                    rows_to_blocks(vs, bs).astype(v_sc.dtype))
             else:
                 cache_k = cache_k.at[i, table_row].set(
-                    k_buf.reshape(mb, bs, hkv, dh))
+                    rows_to_blocks(k_buf, bs))
                 cache_v = cache_v.at[i, table_row].set(
-                    v_buf.reshape(mb, bs, hkv, dh))
+                    rows_to_blocks(v_buf, bs))
         return self.ln_f(x), type(cache)(cache_k, cache_v, k_sc, v_sc)
 
     def forward_decode_paged(self, tokens, cache, tables, lengths):

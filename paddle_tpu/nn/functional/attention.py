@@ -89,6 +89,10 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
 
     # composite fallback: expand GQA heads (the kernel handles them
     # natively; the composite needs full-head k/v)
+    _ops.kernel_paths.note(
+        "flash_attention", "composite",
+        "backend is not tpu" if not _ops.flash_attention_available()
+        else "dropout or return_softmax requested")
     h = (query.shape[2] if hasattr(query, "shape") else None)
     hkv = (key.shape[2] if hasattr(key, "shape") else None)
     if h is not None and hkv is not None and h != hkv:

@@ -268,15 +268,13 @@ def _idle_slots(s: dict):
 def _exec_prof(s: dict, *kinds) -> Optional[dict]:
     """The exec-registry roofline digest riding stats['exec_profile']
     (observability.exec_registry.profile): first matching kind's row,
-    or None.  Nominal-peak digests (host backends) are ignored unless
-    PADDLE_TPU_ROOFLINE_DOCTOR=1 forces them — a laptop smoke must not
-    read as a TPU roofline verdict."""
+    or None.  Digests taken on a device with no tabled peak carry no
+    roofline fractions and are ignored."""
     prof = s.get("exec_profile")
     if not isinstance(prof, dict):
         return None
     peaks = prof.get("_peaks") or {}
-    if peaks.get("peaks_nominal") and \
-            os.environ.get("PADDLE_TPU_ROOFLINE_DOCTOR") != "1":
+    if peaks.get("peaks_known") is False:
         return None
     for k in kinds:
         row = prof.get(k)
@@ -565,7 +563,7 @@ RULES: List[Rule] = [
     Rule("recompile-churn", ("train", "serve"),
          "pin shapes: prefill buckets (PADDLE_TPU_PREFILL_BUCKETS), "
          "fixed batch/seq, persistent compile cache "
-         "(PADDLE_TPU_COMPILE_CACHE)",
+         "(JAX_COMPILATION_CACHE_DIR)",
          _recompile_churn,
          action={"op": "prefill_buckets", "param": "prefill_buckets",
                  "env": "PADDLE_TPU_PREFILL_BUCKETS",

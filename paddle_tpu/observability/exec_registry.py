@@ -33,8 +33,10 @@ Three pieces:
   WEAKREF: a dead engine's entries degrade to timing-only instead of
   pinning its params in HBM (bench candidate teardown relies on that).
 - **Roofline** — per-device-kind peak FLOP/s and HBM GB/s tables (the
-  bench.py device-kind lookup, extended with bandwidth + host-backend
-  nominals so CPU smokes exercise the same math).  Each analyzed entry
+  bench.py device-kind lookup, extended with bandwidth).  A device
+  kind that is not in the tables has NO peak: asking for one raises
+  UnknownDevicePeak, and a snapshot taken there carries timings and XLA
+  figures without roofline fractions.  Each analyzed entry
   reports achieved FLOP/s, achieved HBM bandwidth, arithmetic
   intensity, its ridge point, compute-vs-bandwidth classification,
   fraction of its own roof, MFU, and an MFU *attribution*: the share
@@ -55,6 +57,7 @@ parts use these).
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -112,12 +115,10 @@ HBM_CAPACITY_BYTES = {
     "v2": 8 << 30,
 }
 
-# nominal host-backend figures: CPU smokes run the same roofline MATH
-# (AI classification, fractions) without claiming hardware numbers —
-# snapshots carry peaks_nominal=True so the doctor does not diagnose a
-# laptop as a TPU
-HOST_PEAK_FLOPS = 5e10
-HOST_PEAK_HBM_GBPS = 10.0
+
+class UnknownDevicePeak(LookupError):
+    """No peak is tabled for this device kind (a host backend, a new
+    chip): utilization against it cannot be stated."""
 
 
 def enabled() -> bool:
@@ -141,25 +142,32 @@ def _kind_lookup(table: Dict[str, float], kind: Optional[str]
     return None
 
 
-def peak_flops(kind: Optional[str] = None) -> Tuple[float, bool]:
-    """(peak FLOP/s, nominal?) for a device kind.  Env
-    PADDLE_TPU_PEAK_FLOPS overrides (treated as authoritative); unknown
-    kinds get the host nominal with nominal=True."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        return float(env), False
-    hit = _kind_lookup(PEAK_FLOPS_BF16, kind)
-    return (hit, False) if hit else (HOST_PEAK_FLOPS, True)
+def _peak(table: Dict[str, float], kind: Optional[str], env: str,
+          what: str) -> float:
+    override = os.environ.get(env)
+    if override:
+        return float(override)
+    kind = kind if kind is not None else device_kind()
+    hit = _kind_lookup(table, kind)
+    if hit is None:
+        raise UnknownDevicePeak(
+            f"no {what} tabled for device kind {kind!r}; add it to "
+            f"exec_registry or pin it with {env}")
+    return hit
 
 
-def peak_hbm_bytes_per_s(kind: Optional[str] = None) -> Tuple[float, bool]:
-    """(peak HBM bytes/s, nominal?); PADDLE_TPU_PEAK_HBM_GBPS
-    overrides."""
-    env = os.environ.get("PADDLE_TPU_PEAK_HBM_GBPS")
-    if env:
-        return float(env) * 1e9, False
-    hit = _kind_lookup(PEAK_HBM_GBPS, kind)
-    return (hit * 1e9, False) if hit else (HOST_PEAK_HBM_GBPS * 1e9, True)
+def peak_flops(kind: Optional[str] = None) -> float:
+    """Peak dense bf16 FLOP/s for a device kind (PADDLE_TPU_PEAK_FLOPS
+    overrides); an unknown kind raises UnknownDevicePeak."""
+    return _peak(PEAK_FLOPS_BF16, kind, "PADDLE_TPU_PEAK_FLOPS",
+                 "peak FLOP/s")
+
+
+def peak_hbm_bytes_per_s(kind: Optional[str] = None) -> float:
+    """Peak HBM bytes/s (PADDLE_TPU_PEAK_HBM_GBPS overrides); an
+    unknown kind raises UnknownDevicePeak."""
+    return _peak(PEAK_HBM_GBPS, kind, "PADDLE_TPU_PEAK_HBM_GBPS",
+                 "peak HBM GB/s") * 1e9
 
 
 def device_hbm_capacity() -> Optional[int]:
@@ -349,8 +357,18 @@ class ExecRegistry:
             e.analysis_error = e.analysis_error or "owner released"
             return False
         try:
-            compiled = jitfn.lower(
-                *self._normalized_arg_shapes(e)).compile()
+            # re-lower under the entry's own mesh, as its first call
+            # traced: trace-time decisions that read the ambient mesh
+            # (the Pallas kernels' shard_map wrappers) must repeat, or
+            # the program lowered here is not the one that runs
+            mesh = self._entry_mesh(e)
+            guard = contextlib.nullcontext()
+            if mesh is not None:
+                from ..distributed.mesh import compile_mesh_guard
+                guard = compile_mesh_guard(mesh)
+            with guard:
+                compiled = jitfn.lower(
+                    *self._normalized_arg_shapes(e, mesh)).compile()
         except Exception as exc:
             self._m_failures.labels(stage="lower_compile").inc()
             e.analysis_error = (f"lower_compile: {type(exc).__name__}: "
@@ -373,7 +391,12 @@ class ExecRegistry:
         except Exception:
             pass
         e.analysis = {"cost": cost, "memory": mem,
-                      "out_shardings": out_sh}
+                      "out_shardings": out_sh,
+                      # Pallas kernels in the COMPILED program (each
+                      # lowers to a tpu_custom_call): the evidence that
+                      # a kernel, not its composite, is what runs
+                      "tpu_custom_calls":
+                          compiled.as_text().count("tpu_custom_call")}
         # pod-scale serving (ISSUE 18): an entry that compiled against a
         # multi-device (sub)mesh folds in its collective traffic, split
         # per MESH AXIS — the tp/dp attribution bench --serve rows and
@@ -395,42 +418,49 @@ class ExecRegistry:
                 "cost_analysis/memory_analysis unavailable"
         return True
 
-    def _normalized_arg_shapes(self, e: ExecEntry):
-        """Arg structs safe to AOT-lower.  A first call mixes
-        mesh-committed operands (params, cache) with host-resident ones
-        (the first token batch), and ``lower()`` rejects the mixed
-        device sets it would accept at runtime.  When the entry records
-        a multi-device submesh, rebuild it and commit every leaf that
-        does not already span it as REPLICATED on that submesh — which
-        is where GSPMD puts those operands at runtime anyway."""
+    @staticmethod
+    def _entry_mesh(e: ExecEntry):
+        """The multi-device (sub)mesh an entry compiled against, rebuilt
+        from its meta, or None for a single-device entry."""
         sub = (e.meta or {}).get("submesh") or {}
         shape, dev_ids = sub.get("shape") or {}, sub.get("devices") or []
         if len(dev_ids) <= 1:
+            return None
+        import jax
+        from jax.sharding import Mesh
+        by_id = {d.id: d for d in jax.devices()}
+        return Mesh(
+            np.asarray([by_id[i] for i in dev_ids]).reshape(
+                [int(n) for n in shape.values()]),
+            tuple(shape.keys()))
+
+    @staticmethod
+    def _normalized_arg_shapes(e: ExecEntry, mesh):
+        """Arg structs safe to AOT-lower.  A first call mixes
+        mesh-committed operands (params, cache) with host-resident ones
+        (the first token batch, lr, the step number), and ``lower()``
+        rejects the mixed device sets it would accept at runtime: every
+        leaf that does not already span the entry's mesh is committed
+        as REPLICATED on it — which is where GSPMD puts those operands
+        at runtime anyway."""
+        if mesh is None:
             return e._arg_shapes
         import jax
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec
-        try:
-            by_id = {d.id: d for d in jax.devices()}
-            mesh = Mesh(
-                np.asarray([by_id[i] for i in dev_ids]).reshape(
-                    [int(n) for n in shape.values()]),
-                tuple(shape.keys()))
-            repl = NamedSharding(mesh, PartitionSpec())
-            dev_set = frozenset(dev_ids)
+        from jax.sharding import NamedSharding, PartitionSpec
+        repl = NamedSharding(mesh, PartitionSpec())
+        dev_set = {d.id for d in mesh.devices.flat}
 
-            def fix(leaf):
-                if not isinstance(leaf, jax.ShapeDtypeStruct):
-                    return leaf
-                sh = leaf.sharding
-                ids = {d.id for d in sh.device_set} if sh is not None \
-                    else set()
-                if ids == dev_set:
-                    return leaf
-                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                            sharding=repl)
-            return jax.tree_util.tree_map(fix, e._arg_shapes)
-        except Exception:
-            return e._arg_shapes
+        def fix(leaf):
+            if not isinstance(leaf, jax.ShapeDtypeStruct):
+                return leaf
+            sh = leaf.sharding
+            ids = {d.id for d in sh.device_set} if sh is not None \
+                else set()
+            if ids == dev_set:
+                return leaf
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=repl)
+        return jax.tree_util.tree_map(fix, e._arg_shapes)
 
     def analyze_all(self, component: Optional[str] = None) -> int:
         """Analyze every (matching) entry; returns how many have
@@ -453,8 +483,8 @@ class ExecRegistry:
             self._entries.clear()
 
     # ---- roofline snapshot --------------------------------------------
-    def _entry_snapshot(self, e: ExecEntry, pf: float, pb: float,
-                        nominal: bool) -> dict:
+    def _entry_snapshot(self, e: ExecEntry, pf: Optional[float],
+                        pb: Optional[float]) -> dict:
         mean_ms = (e.runtime_ms / e.calls) if e.calls else None
         d = {
             "component": e.component, "name": e.name, "kind": e.kind,
@@ -466,7 +496,6 @@ class ExecRegistry:
             "donate_argnums": list(e.donate_argnums),
             "in_shardings": e.in_shardings,
             "analyzed": e.analysis is not None,
-            "peaks_nominal": nominal,
         }
         if e.meta:
             d["meta"] = dict(e.meta)
@@ -486,6 +515,10 @@ class ExecRegistry:
             d["out_shardings"] = e.analysis["out_shardings"]
         if e.analysis.get("collectives"):
             d["collectives"] = e.analysis["collectives"]
+        if "tpu_custom_calls" in e.analysis:
+            d["tpu_custom_calls"] = e.analysis["tpu_custom_calls"]
+        if pf is None:
+            return d            # no peak for this device: no fractions
         flops = cost.get("flops") or 0.0
         nbytes = cost.get("bytes_accessed") or 0.0
         if mean_ms and mean_ms > 0:
@@ -519,11 +552,12 @@ class ExecRegistry:
         if analyze:
             self.analyze_all(component)
         kind = device_kind()
-        pf, f_nom = peak_flops(kind)
-        pb, b_nom = peak_hbm_bytes_per_s(kind)
-        nominal = f_nom or b_nom
+        try:
+            pf, pb = peak_flops(kind), peak_hbm_bytes_per_s(kind)
+        except UnknownDevicePeak:
+            pf = pb = None
         es = self.entries(component)
-        rows = [self._entry_snapshot(e, pf, pb, nominal) for e in es]
+        rows = [self._entry_snapshot(e, pf, pb) for e in es]
         rows.sort(key=lambda r: -(r["runtime_ms"] or 0.0))
         total_rt = sum(r["runtime_ms"] for r in rows) or 0.0
         total_flops = 0.0
@@ -541,12 +575,12 @@ class ExecRegistry:
                         max(MFU_TARGET - mfu, 0.0) / MFU_TARGET, 4)
                     total_flops += (r.get("flops") or 0.0) * r["calls"]
         overall_mfu = (total_flops / (total_rt / 1e3) / pf) \
-            if total_rt > 0 and total_flops else None
+            if pf and total_rt > 0 and total_flops else None
         out = {
             "device_kind": kind or "host",
             "peak_flops": pf,
-            "peak_hbm_gbps": round(pb / 1e9, 1),
-            "peaks_nominal": nominal,
+            "peak_hbm_gbps": round(pb / 1e9, 1) if pb else None,
+            "peaks_known": pf is not None,
             "mfu_target": MFU_TARGET,
             "executables": rows,
             "overall": {
@@ -618,7 +652,7 @@ def profile_from_snapshot(snap: dict) -> Optional[dict]:
     prof["_peaks"] = {"device_kind": snap.get("device_kind"),
                       "peak_flops": snap.get("peak_flops"),
                       "peak_hbm_gbps": snap.get("peak_hbm_gbps"),
-                      "peaks_nominal": snap.get("peaks_nominal")}
+                      "peaks_known": snap.get("peaks_known")}
     return prof
 
 

@@ -66,11 +66,12 @@ def render_executables(execsnap: Optional[dict]) -> str:
     cost/memory figures and roofline position."""
     if not execsnap or not execsnap.get("executables"):
         return "executables: none registered"
-    head = (f"executables on {execsnap.get('device_kind', '?')} "
-            f"(peak {execsnap.get('peak_flops', 0) / 1e12:.1f} TFLOP/s, "
-            f"{execsnap.get('peak_hbm_gbps', 0):.0f} GB/s HBM"
-            + (", NOMINAL host peaks" if execsnap.get("peaks_nominal")
-               else "") + ")")
+    if execsnap.get("peak_flops"):
+        peaks = (f"peak {execsnap['peak_flops'] / 1e12:.1f} TFLOP/s, "
+                 f"{execsnap.get('peak_hbm_gbps') or 0:.0f} GB/s HBM")
+    else:
+        peaks = "no peak tabled for this device: no roofline fractions"
+    head = f"executables on {execsnap.get('device_kind', '?')} ({peaks})"
     rows = []
     for r in execsnap["executables"]:
         flops = r.get("flops")

@@ -6,6 +6,7 @@ op set (paddle_tpu.tensor / nn.functional lowerings), and this package
 holds only the kernels XLA won't produce on its own — fused attention
 today, with room for fused optimizers / collectives-overlapped matmuls.
 """
+from . import kernel_paths  # noqa: F401
 from .flash_attention import (  # noqa: F401
     flash_attention, flash_attention_available, get_block_sizes,
     set_interpret_mode)

@@ -23,7 +23,7 @@ ground truth for the kernel tests; both use f32 score accumulation.
 
 Quantized KV (``kv_dtype='int8'`` in the caches): both entry points
 accept optional per-(position, head) ``k_scale``/``v_scale`` arrays
-(``[B, S, Hkv]`` dense / ``[num_blocks, block_size, Hkv]`` paged, f32;
+(``[B, S, Hkv]`` dense / ``[num_blocks, Hkv, block_size]`` paged, f32;
 see ops.quantized_matmul.quantize_kv).  The kernels stream the int8
 values + f32 scales and dequantize INSIDE the block loop, so the bytes
 leaving HBM per decode step halve (decode attention is bandwidth-bound
@@ -54,6 +54,8 @@ from jax.experimental import pallas as pl
 
 import importlib
 
+from . import kernel_paths
+
 # the package __init__ rebinds the name `flash_attention` to the public
 # FUNCTION; fetch the sibling module itself (its _INTERPRET flag is
 # mutable state we must read live)
@@ -73,6 +75,7 @@ def set_interpret_mode(flag):
     """True/False force interpret mode; None follows
     flash_attention.set_interpret_mode (so one test switch drives both
     kernels)."""
+    _fa.check_interpret_allowed(flag)
     _STATE["interpret"] = flag
 
 
@@ -83,14 +86,7 @@ def _interpret() -> bool:
 
 
 def decode_attention_available() -> bool:
-    if not _fa._HAS_PLTPU:
-        return False
-    if _interpret():
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return _interpret() or jax.default_backend() == "tpu"
 
 
 def _tp_mesh(hkv: int, h: int):
@@ -171,7 +167,7 @@ def _decode_gqa(q3, k3, v3, mask, block_k=512):
     scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_decode_kernel, block_k=block_k,
                                scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bhkv,),
         in_specs=[
@@ -184,7 +180,8 @@ def _decode_gqa(q3, k3, v3, mask, block_k=512):
         out_specs=pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
         interpret=_interpret(),
-    )(q3, k3, v3, mask)
+    )
+    return _fa.run_kernel(q3.dtype, call, q3, k3, v3, mask)
 
 
 def _decode_kernel_q(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, o_ref,
@@ -240,7 +237,7 @@ def _decode_gqa_q(q3, k3, v3, ks3, vs3, mask, block_k=512):
     scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_decode_kernel_q, block_k=block_k,
                                scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bhkv,),
         in_specs=[
@@ -255,7 +252,8 @@ def _decode_gqa_q(q3, k3, v3, ks3, vs3, mask, block_k=512):
         out_specs=pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
         interpret=_interpret(),
-    )(q3, k3, v3, ks3, vs3, mask)
+    )
+    return _fa.run_kernel(q3.dtype, call, q3, k3, v3, ks3, vs3, mask)
 
 
 def _dequant_cache(cache, scale, dtype):
@@ -312,8 +310,10 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
                  and h % hkv == 0
                  and (not quantized or k_cache.dtype == jnp.int8))
     if not supported or not decode_attention_available():
+        kernel_paths.note_composite("decode_attention", supported)
         return _decode_composite(q, k_cache, v_cache, lengths,
                                  k_scale, v_scale)
+    kernel_paths.note("decode_attention", "kernel")
     mesh, _tp = _tp_mesh(hkv, h)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
@@ -355,21 +355,41 @@ def _decode_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
 # paged variant: K/V live in a block pool, streamed through a block table
 # ---------------------------------------------------------------------------
 def paged_decode_attention_available() -> bool:
-    """The paged kernel additionally needs scalar prefetch (the block
-    table drives the K/V DMA addresses), so it requires the pltpu grid
-    spec — same availability surface as the dense kernel otherwise."""
-    return decode_attention_available() and _fa.pltpu is not None
+    """Same availability surface as the dense kernel (the paged kernel
+    additionally drives its K/V DMA addresses from scalar-prefetched
+    block tables)."""
+    return decode_attention_available()
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, block_size: int, hkv: int,
-                  scale: float):
-    """One (b·hkv, j) program: j walks the slot's block table; the
-    BlockSpec index_map already resolved table entry j to a pool block,
-    so k_ref/v_ref hold that block's ``[block_size, D]`` strip for this
-    kv head.  Online-softmax state (m/l/acc) persists in VMEM scratch
-    across the j steps (TPU grids run sequentially, innermost fastest);
-    the output is written once on the last block."""
+def _paged_supported(k_pool, h, d, quantized) -> bool:
+    """Shapes the paged kernels serve: block size a multiple of the
+    bf16 sublane tile (any such size is a legal block — the per-head
+    strip is the pool's two minor dimensions), D 64 or a multiple of
+    128, int8 when quantized (fp8 rides the composite)."""
+    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    return (bs % 16 == 0 and (d % 128 == 0 or d == 64)
+            and h % hkv == 0
+            and (not quantized or k_pool.dtype == jnp.int8))
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                  block_size: int, hkv: int, g: int, scale: float,
+                  quantized: bool):
+    """One (b·hkv, j) program of the paged kernels (single-token decode
+    is the W = 1 window): j walks the slot's block table; the BlockSpec
+    index_map already resolved table entry j to a pool block, so
+    k_ref/v_ref hold that block's ``[block_size, D]`` strip for this kv
+    head (int8 plus ``[1, block_size]`` f32 scale strips when
+    ``quantized`` — dequantized after the DMA).  q_ref is ``[W·G, D]``,
+    rows grouped w·G+g; row r's window index is r//g, so position p is
+    valid iff ``p < len_ref[b] + r//g + 1`` (len_ref counts tokens
+    cached BEFORE the window).  Online-softmax state (m/l/acc) persists
+    in VMEM scratch across the j steps (TPU grids run sequentially,
+    innermost fastest); the output is written once on the last block."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(1)
     n_blocks = pl.num_programs(1)
     b = pl.program_id(0) // hkv
@@ -380,15 +400,22 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[:]                                        # [G, D]
+    q = q_ref[:]                                        # [W·G, D]
+    wg = q.shape[0]
     k_blk = k_ref[:]                                    # [bs, D]
     v_blk = v_ref[:]
+    if quantized:
+        ks = ks_ref[0, :]                               # (bs,) f32
+        vs = vs_ref[0, :]
+        k_blk = (k_blk.astype(jnp.float32) * ks[:, None]).astype(q.dtype)
+        v_blk = (v_blk.astype(jnp.float32) * vs[:, None]).astype(q.dtype)
     sblk = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale     # [G, bs] f32
+        preferred_element_type=jnp.float32) * scale     # [wg, bs] f32
     pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    sblk = jnp.where(pos < len_ref[b], sblk, _NEG)
+        jnp.int32, (1, block_size), 1)                  # [1, bs]
+    win = jax.lax.broadcasted_iota(jnp.int32, (wg, 1), 0) // g
+    sblk = jnp.where(pos < len_ref[b] + win + 1, sblk, _NEG)
     m_prev = m_scr[:, :1]
     l_prev = l_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
@@ -408,44 +435,70 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                     jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_gqa(q3, k_pool, v_pool, tables, lengths):
-    """q3 [B·Hkv, G, D]; pools [NB, bs, Hkv, D]; tables [B, MB] int32;
-    lengths [B] int32.  Scalar-prefetched tables/lengths let each grid
-    step's index_map pick its pool block, so only the slot's own blocks
-    ever leave HBM (no gather of the whole table into dense form)."""
+def _paged_gqa(q3, k_pool, v_pool, tables, lengths, w,
+               k_scale=None, v_scale=None):
+    """q3 [B·Hkv, W·G, D]; pools [NB, Hkv, bs, D]; tables [B, MB] int32;
+    lengths [B] int32 EXCLUDING the window; quantized pools add their
+    [NB, Hkv, bs] f32 scale pools.  Scalar-prefetched tables/lengths let
+    each grid step's index_map pick its pool block, so only the slot's
+    own blocks ever leave HBM (no gather of the whole table into dense
+    form).  Heads sit AHEAD of the block dimension in the pool, so the
+    per-head ``[bs, D]`` strip (and, with a unit axis spliced in, the
+    ``[1, bs]`` scale strip) is the pool's two minor dimensions — the
+    block shape the TPU lowering accepts at every block size."""
     pltpu = _fa.pltpu
-    bhkv, g, d = q3.shape
-    bs = k_pool.shape[1]
+    bhkv, wg, d = q3.shape
+    bs = k_pool.shape[2]
     b, mb = tables.shape
     hkv = bhkv // b
-    scale = 1.0 / math.sqrt(d)
-    kv_spec = pl.BlockSpec(
-        (None, bs, None, d),
-        lambda i, j, tbl, lens, hkv=hkv: (tbl[i // hkv, j], 0, i % hkv, 0))
+    quantized = k_scale is not None
+
+    def pool_index(i, j, tbl, lens):
+        return (tbl[i // hkv, j], i % hkv, 0, 0)
+
+    kv_spec = pl.BlockSpec((None, None, bs, d), pool_index)
+    io_spec = pl.BlockSpec((None, wg, d),
+                           lambda i, j, tbl, lens: (i, 0, 0))
+    in_specs = [io_spec, kv_spec, kv_spec]
+    args = [q3, k_pool, v_pool]
+    if quantized:
+        sc_spec = pl.BlockSpec((None, None, 1, bs), pool_index)
+        in_specs += [sc_spec, sc_spec]
+        args += [k_scale.astype(jnp.float32)[:, :, None, :],
+                 v_scale.astype(jnp.float32)[:, :, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bhkv, mb),
-        in_specs=[
-            pl.BlockSpec((None, g, d), lambda i, j, tbl, lens: (i, 0, 0)),
-            kv_spec, kv_spec,
-        ],
-        out_specs=pl.BlockSpec((None, g, d),
-                               lambda i, j, tbl, lens: (i, 0, 0)),
+        in_specs=in_specs,
+        out_specs=io_spec,
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),   # running max
-            pltpu.VMEM((g, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((g, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((wg, 128), jnp.float32),   # running max
+            pltpu.VMEM((wg, 128), jnp.float32),   # running denominator
+            pltpu.VMEM((wg, d), jnp.float32),     # output accumulator
         ],
     )
-    kernel = functools.partial(_paged_kernel, block_size=bs, hkv=hkv,
-                               scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(
+        _paged_kernel, block_size=bs, hkv=hkv, g=wg // w,
+        scale=1.0 / math.sqrt(d), quantized=quantized)
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
+        out_shape=jax.ShapeDtypeStruct((bhkv, wg, d), q3.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q3, k_pool, v_pool)
+        name="paged_decode_attention",
+    )
+    return _fa.run_kernel(
+        q3.dtype, call, tables.astype(jnp.int32), lengths.astype(jnp.int32),
+        *args)
+
+
+def _gather_pool(pool, tables):
+    """Pool blocks [NB, Hkv, bs, ...] through tables [B, MB] -> the
+    dense per-slot layout [B, MB·bs, Hkv, ...]."""
+    g = jnp.swapaxes(pool[tables], 2, 3)        # [B, MB, bs, Hkv, ...]
+    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
 def _paged_composite(q, k_pool, v_pool, tables, lengths, k_scale=None,
@@ -454,107 +507,14 @@ def _paged_composite(q, k_pool, v_pool, tables, lengths, k_scale=None,
     ``[B, S, Hkv, D]`` layout (S = MB·bs) and reuse the dense composite.
     Bitwise-identical to the dense path on identical cache contents —
     the parity oracle tests/test_paged_kv.py leans on.  Quantized pools
-    gather their ``[num_blocks, bs, Hkv]`` scale pools the same way."""
-    b, mb = tables.shape
-    bs, hkv, d = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
-    kg = k_pool[tables].reshape(b, mb * bs, hkv, d)
-    vg = v_pool[tables].reshape(b, mb * bs, hkv, d)
+    gather their ``[num_blocks, Hkv, bs]`` scale pools the same way."""
     ksg = vsg = None
     if k_scale is not None:
-        ksg = k_scale[tables].reshape(b, mb * bs, hkv)
-        vsg = v_scale[tables].reshape(b, mb * bs, hkv)
-    return _decode_composite(q, kg, vg, lengths, ksg, vsg)
-
-
-def _paged_kernel_q(tbl_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                    vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                    block_size: int, hkv: int, scale: float):
-    """Quantized-pool variant of _paged_kernel: the BlockSpec index_map
-    resolved table entry j to a pool block for the int8 values AND the
-    f32 scale strip ([1, bs], from the [NB, Hkv, bs]-transposed scale
-    pools); dequantize after the DMA, then the same online softmax."""
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
-    b = pl.program_id(0) // hkv
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[:]                                        # [G, D]
-    ks = ks_ref[0, :]                                   # (bs,) f32
-    vs = vs_ref[0, :]
-    k_blk = (k_ref[:].astype(jnp.float32) * ks[:, None]).astype(q.dtype)
-    v_blk = (v_ref[:].astype(jnp.float32) * vs[:, None]).astype(q.dtype)
-    sblk = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale     # [G, bs] f32
-    pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    sblk = jnp.where(pos < len_ref[b], sblk, _NEG)
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-    p = jnp.exp(sblk - m_new)
-    p = jnp.where(sblk <= _NEG / 2, 0.0, p)             # fully-masked blocks
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] /
-                    jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_gqa_q(q3, k_pool, v_pool, k_scale, v_scale, tables, lengths):
-    """Quantized paged wrapper: value pools int8 [NB, bs, Hkv, D],
-    scale pools [NB, bs, Hkv] f32 (transposed here to [NB, Hkv, bs] so
-    each grid step's scale block is a 2-D [1, bs] strip)."""
-    pltpu = _fa.pltpu
-    bhkv, g, d = q3.shape
-    bs = k_pool.shape[1]
-    b, mb = tables.shape
-    hkv = bhkv // b
-    scale = 1.0 / math.sqrt(d)
-    ks_t = jnp.swapaxes(k_scale.astype(jnp.float32), 1, 2)
-    vs_t = jnp.swapaxes(v_scale.astype(jnp.float32), 1, 2)
-    kv_spec = pl.BlockSpec(
-        (None, bs, None, d),
-        lambda i, j, tbl, lens, hkv=hkv: (tbl[i // hkv, j], 0, i % hkv, 0))
-    sc_spec = pl.BlockSpec(
-        (None, 1, bs),
-        lambda i, j, tbl, lens, hkv=hkv: (tbl[i // hkv, j], i % hkv, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bhkv, mb),
-        in_specs=[
-            pl.BlockSpec((None, g, d), lambda i, j, tbl, lens: (i, 0, 0)),
-            kv_spec, kv_spec, sc_spec, sc_spec,
-        ],
-        out_specs=pl.BlockSpec((None, g, d),
-                               lambda i, j, tbl, lens: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),   # running max
-            pltpu.VMEM((g, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((g, d), jnp.float32),     # output accumulator
-        ],
-    )
-    kernel = functools.partial(_paged_kernel_q, block_size=bs, hkv=hkv,
-                               scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
-        interpret=_interpret(),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q3, k_pool, v_pool, ks_t, vs_t)
+        ksg = _gather_pool(k_scale, tables)
+        vsg = _gather_pool(v_scale, tables)
+    return _decode_composite(q, _gather_pool(k_pool, tables),
+                             _gather_pool(v_pool, tables), lengths,
+                             ksg, vsg)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
@@ -562,57 +522,30 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
     """Single-token attention over a PAGED, length-masked KV cache.
 
     q ``[B, H, D]`` — the new token's query per slot; k_pool/v_pool
-    ``[num_blocks, block_size, Hkv, D]`` — the shared block pool AFTER
+    ``[num_blocks, Hkv, block_size, D]`` — the shared block pool AFTER
     the new token's k/v were written; tables ``[B, max_blocks]`` int32 —
     per-slot block table (pool indices; entries past the slot's extent
     point at the reserved null block and stay masked); lengths ``[B]``
     int32 — valid tokens per slot including the new one.  With a
     quantized pool, ``k_scale``/``v_scale`` are the
-    ``[num_blocks, block_size, Hkv]`` f32 scale pools and the value
+    ``[num_blocks, Hkv, block_size]`` f32 scale pools and the value
     pools are int8 (fp8 rides the composite).  Returns ``[B, H, D]``.
     The Pallas kernel streams K/V (and scales) block-by-block through
     the block table via scalar prefetch; the XLA composite gathers the
     table into dense form and is the CPU/fallback ground truth.
     """
     b, h, d = q.shape
-    bs, hkv = k_pool.shape[1], k_pool.shape[2]
-    quantized = k_scale is not None
-    supported = (bs % 128 == 0 and (d % 128 == 0 or d == 64)
-                 and h % hkv == 0
-                 and (not quantized or k_pool.dtype == jnp.int8))
+    supported = _paged_supported(k_pool, h, d, k_scale is not None)
     if not supported or not paged_decode_attention_available():
+        kernel_paths.note_composite("paged_decode_attention", supported)
         return _paged_composite(q, k_pool, v_pool, tables, lengths,
                                 k_scale, v_scale)
-    mesh, _tp = _tp_mesh(hkv, h)
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-        specs = [P(None, "tp", None), P(None, None, "tp", None),
-                 P(None, None, "tp", None), P(None, None), P(None)]
-        args = [q, k_pool, v_pool, tables, lengths]
-        if quantized:
-            specs += [P(None, None, "tp"), P(None, None, "tp")]
-            args += [k_scale, v_scale]
-        return _shard_over_tp(_paged_kernel_path, mesh, specs,
-                              P(None, "tp", None), args)
-    return _paged_kernel_path(q, k_pool, v_pool, tables, lengths,
-                              k_scale, v_scale)
-
-
-def _paged_kernel_path(q, k_pool, v_pool, tables, lengths, k_scale=None,
-                       v_scale=None):
-    """The paged kernel dispatch AFTER the support gate — also the
-    shard_map body under tp (block tables stay replicated: allocation
-    is host state, each shard walks the same tables over its own
-    head-slice of the pool)."""
-    b, h, d = q.shape
-    hkv = k_pool.shape[2]
-    q3 = q.reshape(b, hkv, h // hkv, d).reshape(b * hkv, h // hkv, d)
-    if k_scale is not None:
-        o3 = _paged_gqa_q(q3, k_pool, v_pool, k_scale, v_scale, tables,
-                          lengths)
-    else:
-        o3 = _paged_gqa(q3, k_pool, v_pool, tables, lengths)
-    return o3.reshape(b, hkv, h // hkv, d).reshape(b, h, d)
+    kernel_paths.note("paged_decode_attention", "kernel")
+    # single-token decode IS the W = 1 window whose lengths exclude it
+    out = _paged_window_dispatch(
+        q[:, None], k_pool, v_pool, tables,
+        lengths.astype(jnp.int32) - 1, k_scale, v_scale)
+    return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -733,14 +666,15 @@ def _window_gqa(q3, k3, v3, mask, ks3=None, vs3=None, block_k=512):
         in_specs = [io_spec, kv_spec, kv_spec, sc_spec, sc_spec,
                     mask_spec]
         args = (q3, k3, v3, ks3, vs3, mask)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bhkv,),
         in_specs=in_specs,
         out_specs=io_spec,
         out_shape=jax.ShapeDtypeStruct((bhkv, wg, d), q3.dtype),
         interpret=_interpret(),
-    )(*args)
+    )
+    return _fa.run_kernel(q3.dtype, call, *args)
 
 
 def _window_composite(q, k_cache, v_cache, lengths, k_scale=None,
@@ -790,8 +724,10 @@ def decode_attention_window(q, k_cache, v_cache, lengths, k_scale=None,
                  and h % hkv == 0
                  and (not quantized or k_cache.dtype == jnp.int8))
     if not supported or not decode_attention_available():
+        kernel_paths.note_composite("decode_attention_window", supported)
         return _window_composite(q, k_cache, v_cache, lengths,
                                  k_scale, v_scale)
+    kernel_paths.note("decode_attention_window", "kernel")
     mesh, _tp = _tp_mesh(hkv, h)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
@@ -833,167 +769,17 @@ def _window_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
         .reshape(b, w, h, d)
 
 
-def _paged_window_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, block_size: int,
-                         hkv: int, g: int, scale: float):
-    """Paged window program (b·hkv, j): like _paged_kernel with W·G
-    query rows and the staircase mask computed in-kernel — row r's
-    window index is r//g, so position p is valid iff
-    p < len_ref[b] + r//g + 1."""
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
-    b = pl.program_id(0) // hkv
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[:]                                        # [W·G, D]
-    wg = q.shape[0]
-    k_blk = k_ref[:]
-    v_blk = v_ref[:]
-    sblk = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale     # [wg, bs] f32
-    pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)                  # [1, bs]
-    win = jax.lax.broadcasted_iota(jnp.int32, (wg, 1), 0) // g
-    sblk = jnp.where(pos < len_ref[b] + win + 1, sblk, _NEG)
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-    p = jnp.exp(sblk - m_new)
-    p = jnp.where(sblk <= _NEG / 2, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] /
-                    jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_window_kernel_q(tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                           ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr,
-                           *, block_size: int, hkv: int, g: int,
-                           scale: float):
-    """Quantized paged window program: dequantize the int8 strip with
-    its [1, bs] scale strip after the DMA, then _paged_window_kernel's
-    math."""
-    j = pl.program_id(1)
-    n_blocks = pl.num_programs(1)
-    b = pl.program_id(0) // hkv
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[:]
-    wg = q.shape[0]
-    ks = ks_ref[0, :]
-    vs = vs_ref[0, :]
-    k_blk = (k_ref[:].astype(jnp.float32) * ks[:, None]).astype(q.dtype)
-    v_blk = (v_ref[:].astype(jnp.float32) * vs[:, None]).astype(q.dtype)
-    sblk = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    win = jax.lax.broadcasted_iota(jnp.int32, (wg, 1), 0) // g
-    sblk = jnp.where(pos < len_ref[b] + win + 1, sblk, _NEG)
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-    p = jnp.exp(sblk - m_new)
-    p = jnp.where(sblk <= _NEG / 2, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] /
-                    jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_window_gqa(q3, k_pool, v_pool, tables, lengths, w,
-                      k_scale=None, v_scale=None):
-    """q3 [B·Hkv, W·G, D]; pools/tables/lengths as _paged_gqa; scale
-    pools transposed to [NB, Hkv, bs] strips when quantized."""
-    pltpu = _fa.pltpu
-    bhkv, wg, d = q3.shape
-    bs = k_pool.shape[1]
-    b, mb = tables.shape
-    hkv = bhkv // b
-    g = wg // w
-    scale = 1.0 / math.sqrt(d)
-    kv_spec = pl.BlockSpec(
-        (None, bs, None, d),
-        lambda i, j, tbl, lens, hkv=hkv: (tbl[i // hkv, j], 0, i % hkv, 0))
-    io_spec = pl.BlockSpec((None, wg, d),
-                           lambda i, j, tbl, lens: (i, 0, 0))
-    scratch = [
-        pltpu.VMEM((wg, 128), jnp.float32),
-        pltpu.VMEM((wg, 128), jnp.float32),
-        pltpu.VMEM((wg, d), jnp.float32),
-    ]
-    if k_scale is None:
-        in_specs = [io_spec, kv_spec, kv_spec]
-        kernel = functools.partial(_paged_window_kernel, block_size=bs,
-                                   hkv=hkv, g=g, scale=scale)
-        args = (q3, k_pool, v_pool)
-    else:
-        sc_spec = pl.BlockSpec(
-            (None, 1, bs),
-            lambda i, j, tbl, lens, hkv=hkv: (tbl[i // hkv, j],
-                                              i % hkv, 0))
-        in_specs = [io_spec, kv_spec, kv_spec, sc_spec, sc_spec]
-        kernel = functools.partial(_paged_window_kernel_q, block_size=bs,
-                                   hkv=hkv, g=g, scale=scale)
-        args = (q3, k_pool, v_pool,
-                jnp.swapaxes(k_scale.astype(jnp.float32), 1, 2),
-                jnp.swapaxes(v_scale.astype(jnp.float32), 1, 2))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bhkv, mb),
-        in_specs=in_specs,
-        out_specs=io_spec,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bhkv, wg, d), q3.dtype),
-        interpret=_interpret(),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
-
-
 def _paged_window_composite(q, k_pool, v_pool, tables, lengths,
                             k_scale=None, v_scale=None):
     """Gather the slot's blocks dense, reuse the dense window composite
     — bitwise the dense path on identical cache contents."""
-    b, mb = tables.shape
-    bs, hkv, d = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
-    kg = k_pool[tables].reshape(b, mb * bs, hkv, d)
-    vg = v_pool[tables].reshape(b, mb * bs, hkv, d)
     ksg = vsg = None
     if k_scale is not None:
-        ksg = k_scale[tables].reshape(b, mb * bs, hkv)
-        vsg = v_scale[tables].reshape(b, mb * bs, hkv)
-    return _window_composite(q, kg, vg, lengths, ksg, vsg)
+        ksg = _gather_pool(k_scale, tables)
+        vsg = _gather_pool(v_scale, tables)
+    return _window_composite(q, _gather_pool(k_pool, tables),
+                             _gather_pool(v_pool, tables), lengths,
+                             ksg, vsg)
 
 
 def paged_decode_attention_window(q, k_pool, v_pool, tables, lengths,
@@ -1007,41 +793,48 @@ def paged_decode_attention_window(q, k_pool, v_pool, tables, lengths,
     Pallas scalar-prefetch kernel when shapes allow, gather composite
     (ground truth) otherwise."""
     b, w, h, d = q.shape
-    bs, hkv = k_pool.shape[1], k_pool.shape[2]
-    quantized = k_scale is not None
-    supported = (bs % 128 == 0 and (d % 128 == 0 or d == 64)
-                 and h % hkv == 0
-                 and (not quantized or k_pool.dtype == jnp.int8))
+    supported = _paged_supported(k_pool, h, d, k_scale is not None)
     if not supported or not paged_decode_attention_available():
+        kernel_paths.note_composite("paged_decode_attention_window", supported)
         return _paged_window_composite(q, k_pool, v_pool, tables,
                                        lengths, k_scale, v_scale)
+    kernel_paths.note("paged_decode_attention_window", "kernel")
+    return _paged_window_dispatch(q, k_pool, v_pool, tables, lengths,
+                                  k_scale, v_scale)
+
+
+def _paged_window_dispatch(q, k_pool, v_pool, tables, lengths,
+                           k_scale=None, v_scale=None):
+    """The paged kernel dispatch AFTER the support gate: plain, or
+    wrapped in shard_map over 'tp' on a serving mesh."""
+    h, hkv = q.shape[2], k_pool.shape[1]
     mesh, _tp = _tp_mesh(hkv, h)
-    if mesh is not None:
-        from jax.sharding import PartitionSpec as P
-        specs = [P(None, None, "tp", None), P(None, None, "tp", None),
-                 P(None, None, "tp", None), P(None, None), P(None)]
-        args = [q, k_pool, v_pool, tables, lengths]
-        if quantized:
-            specs += [P(None, None, "tp"), P(None, None, "tp")]
-            args += [k_scale, v_scale]
-        return _shard_over_tp(
-            functools.partial(_paged_window_kernel_path, w=w), mesh,
-            specs, P(None, None, "tp", None), args)
-    return _paged_window_kernel_path(q, k_pool, v_pool, tables, lengths,
-                                     k_scale, v_scale, w=w)
+    if mesh is None:
+        return _paged_window_kernel_path(q, k_pool, v_pool, tables,
+                                         lengths, k_scale, v_scale)
+    from jax.sharding import PartitionSpec as P
+    specs = [P(None, None, "tp", None), P(None, "tp", None, None),
+             P(None, "tp", None, None), P(None, None), P(None)]
+    args = [q, k_pool, v_pool, tables, lengths]
+    if k_scale is not None:
+        specs += [P(None, "tp", None), P(None, "tp", None)]
+        args += [k_scale, v_scale]
+    return _shard_over_tp(_paged_window_kernel_path, mesh, specs,
+                          P(None, None, "tp", None), args)
 
 
 def _paged_window_kernel_path(q, k_pool, v_pool, tables, lengths,
-                              k_scale=None, v_scale=None, *, w):
-    """The paged window-kernel dispatch AFTER the support gate — also
-    the shard_map body under tp (tables replicated; each shard walks
-    the same tables over its own head-slice of the pool)."""
-    b, _, h, d = q.shape
-    hkv = k_pool.shape[2]
+                              k_scale=None, v_scale=None):
+    """The paged kernel itself on [B, W, H, D] queries — also the
+    shard_map body under tp (tables replicated: allocation is host
+    state, each shard walks the same tables over its own head-slice of
+    the pool)."""
+    b, w, h, d = q.shape
+    hkv = k_pool.shape[1]
     q3 = q.reshape(b, w, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b * hkv, w * (h // hkv), d)
-    o3 = _paged_window_gqa(q3, k_pool, v_pool, tables, lengths, w,
-                           k_scale, v_scale)
+    o3 = _paged_gqa(q3, k_pool, v_pool, tables, lengths, w,
+                    k_scale, v_scale)
     return o3.reshape(b, hkv, w, h // hkv, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, w, h, d)
 

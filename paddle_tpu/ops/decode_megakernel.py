@@ -39,7 +39,7 @@ Two layouts, mirroring ops.decode_attention:
 - :func:`decode_layer_step` — Static (dense) cache ``[B, cap, Hkv, D]``
   streamed strip by strip, lengths via scalar prefetch.
 - :func:`decode_layer_step_paged` — Paged block pool
-  ``[NB, bs, Hkv, D]`` streamed through the slot's block table, the
+  ``[NB, Hkv, bs, D]`` streamed through the slot's block table, the
   same scalar-prefetch indirection as ``paged_decode_attention`` (MLP
   phases pin the KV index map to the null block so no stray re-fetch
   rides the weight tiles).
@@ -76,6 +76,8 @@ from jax.experimental import pallas as pl
 
 import importlib
 
+from . import kernel_paths
+
 # the package __init__ rebinds sibling names to public functions; fetch
 # the modules themselves (their _INTERPRET flags are live state)
 _fa = importlib.import_module(__package__ + ".flash_attention")
@@ -104,6 +106,7 @@ def set_interpret_mode(flag):
     """True/False force interpret mode; None follows
     flash_attention.set_interpret_mode (one test switch for all
     kernels)."""
+    _fa.check_interpret_allowed(flag)
     _STATE["interpret"] = flag
 
 
@@ -116,14 +119,7 @@ def _interpret() -> bool:
 def decode_megakernel_available() -> bool:
     """Pallas fused path available (needs scalar prefetch, same surface
     as the paged decode kernel)."""
-    if not _fa._HAS_PLTPU or _fa.pltpu is None:
-        return False
-    if _interpret():
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return _interpret() or jax.default_backend() == "tpu"
 
 
 def megakernel_enabled(cfg) -> bool:
@@ -207,7 +203,9 @@ def _mega_kernel(len_ref, x_ref, ln1w_ref, ln1b_ref, wqkv_ref, bqkv_ref,
     """One (phase, slot) program.  Scalar-prefetched ``len_ref`` carries
     per-slot lengths (EXCLUDING the new token, engine convention); for
     the paged layout the block table already acted inside the index
-    maps, so the body only sees [block_s, Hkv, D] strips either way.
+    maps, so the body sees one strip per phase either way: [block_s,
+    Hkv, D] from the dense cache, head-major [Hkv, block_s, D] from
+    the block pool.
     ``ks_ref``/``vs_ref`` are the f32 scale strips of an int8 cache
     (aliases of k_ref/v_ref in the fp path, unread).
 
@@ -265,11 +263,17 @@ def _mega_kernel(len_ref, x_ref, ln1w_ref, ln1b_ref, wqkv_ref, bqkv_ref,
         valid = pos < idx                                 # [1, block_s]
         scores, vals = [], []
         for hk in range(hkv):
-            kh = k_ref[:, hk, :]                          # [block_s, d]
-            vh = v_ref[:, hk, :]
+            # dense strips are [block_s, Hkv, D]; pool blocks are
+            # head-major [Hkv, block_s, D] (scales likewise)
+            if paged:
+                kh, vh = k_ref[hk], v_ref[hk]             # [block_s, d]
+            else:
+                kh, vh = k_ref[:, hk, :], v_ref[:, hk, :]
             if quantized:
-                kh = kh.astype(jnp.float32) * ks_ref[:, hk][:, None]
-                vh = vh.astype(jnp.float32) * vs_ref[:, hk][:, None]
+                ksh = ks_ref[hk] if paged else ks_ref[:, hk]
+                vsh = vs_ref[hk] if paged else vs_ref[:, hk]
+                kh = kh.astype(jnp.float32) * ksh[:, None]
+                vh = vh.astype(jnp.float32) * vsh[:, None]
             qg = q[hk * g:(hk + 1) * g].astype(kh.dtype)  # [g, d]
             scores.append(jax.lax.dot_general(
                 qg, kh, (((1,), (1,)), ((), ())),
@@ -394,7 +398,7 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
     (ln1_w, ln1_b, w_qkv, b_qkv, w_out, b_out,
      ln2_w, ln2_b, w_up, b_up, w_down, b_down) = w
     bsz, h = x.shape
-    hkv, d = k_src.shape[-2], k_src.shape[-1]
+    hkv, d = k_src.shape[1 if paged else 2], k_src.shape[3]
     kvd = hkv * d
     # q width is the qkv columns minus the two kv blocks; head count
     # from the cache head_dim
@@ -402,7 +406,7 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
     f = w_up.shape[1]
     qkv_cols = h + 2 * kvd
     if paged:
-        block_s = k_src.shape[1]          # one pool block per phase
+        block_s = k_src.shape[2]          # one pool block per phase
         _, block_f, block_q, block_o = _pick_blocks(block_s, f,
                                                     qkv_cols, h)
     else:
@@ -439,14 +443,16 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
     def _tile_down(p, b, *s):
         return (jnp.clip(p - nq - ns - no - 1, 0, nf - 1), 0)
 
+    kv_block = (None, hkv, block_s, d) if paged \
+        else (None, block_s, hkv, d)
+    sc_block = kv_block[:-1]
     if quantized:
-        sc_spec = pl.BlockSpec((None, block_s, hkv), sc_index_map)
+        sc_spec = pl.BlockSpec(sc_block, sc_index_map)
     else:
         # unread placeholder: one block pinned at index 0, fetched once
-        sc_spec = pl.BlockSpec((None, block_s, hkv),
-                               lambda p, b, *s: (0, 0, 0))
+        sc_spec = pl.BlockSpec(sc_block, lambda p, b, *s: (0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, h), lambda p, b, *s: (b, 0)),          # x
+        pl.BlockSpec((None, 1, h), lambda p, b, *s: (b, 0, 0)),  # x
         _const((1, h)), _const((1, h)),                         # ln1 w/b
         pl.BlockSpec((h, block_q), _tile_qkv),                  # qkv w
         pl.BlockSpec((1, block_q), _tile_qkv),                  # qkv b
@@ -457,13 +463,13 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
         pl.BlockSpec((1, block_f), _tile_up),                   # up b
         pl.BlockSpec((block_f, h), _tile_down),                 # down w
         _const((1, h)),                                         # down b
-        pl.BlockSpec((None, block_s, hkv, d), kv_index_map),    # k
-        pl.BlockSpec((None, block_s, hkv, d), kv_index_map),    # v
+        pl.BlockSpec(kv_block, kv_index_map),                   # k
+        pl.BlockSpec(kv_block, kv_index_map),                   # v
         sc_spec,                                                # k scale
         sc_spec,                                                # v scale
     ]
     out_specs = [
-        pl.BlockSpec((1, h), lambda p, b, *s: (b, 0)),
+        pl.BlockSpec((None, 1, h), lambda p, b, *s: (b, 0, 0)),
         pl.BlockSpec((None, hkv, d), lambda p, b, *s: (b, 0, 0)),
         pl.BlockSpec((None, hkv, d), lambda p, b, *s: (b, 0, 0)),
     ]
@@ -501,23 +507,26 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
                         vs_src.astype(jnp.float32))
     else:
         # unread by the kernel; one-block placeholders keep arity fixed
-        ks_in = jnp.zeros((1, block_s, hkv), jnp.float32)
+        ks_in = jnp.zeros((1,) + sc_block[1:], jnp.float32)
         vs_in = ks_in
     scalars = tuple(jnp.asarray(s, jnp.int32) for s in extra_scalars) + \
         (lengths.astype(jnp.int32),)
     out_shapes = [
-        jax.ShapeDtypeStruct((bsz, h), x.dtype),
+        jax.ShapeDtypeStruct((bsz, 1, h), x.dtype),
         jax.ShapeDtypeStruct((bsz, hkv, d), x.dtype),
         jax.ShapeDtypeStruct((bsz, hkv, d), x.dtype),
     ]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=_interpret(),
-    )(*scalars, x, vec2(ln1_w), vec2(ln1_b), w_qkv, vec2(b_qkv),
-      w_out, vec2(b_out), vec2(ln2_w), vec2(ln2_b), w_up, vec2(b_up),
-      w_down, vec2(b_down), k_src, v_src, ks_in, vs_in)
+    )
+    xo, k_new, v_new = _fa.run_kernel(
+        w_qkv.dtype, call, *scalars, x[:, None, :], vec2(ln1_w), vec2(ln1_b),
+        w_qkv, vec2(b_qkv), w_out, vec2(b_out), vec2(ln2_w), vec2(ln2_b), w_up,
+        vec2(b_up), w_down, vec2(b_down), k_src, v_src, ks_in, vs_in)
+    return xo[:, 0, :], k_new, v_new
 
 
 # ---------------------------------------------------------------------------
@@ -605,7 +614,7 @@ def _dense_attend(q, k_new, v_new, k_cache, v_cache, lengths, k_scale,
 def _paged_attend(q, k_new, v_new, k_pool, v_pool, tables, lengths,
                   k_scale, v_scale):
     bsz = q.shape[0]
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     mb = tables.shape[1]
     lens = lengths.astype(jnp.int32)
     blk_pos = jnp.minimum(lens // bs, mb - 1)
@@ -617,48 +626,58 @@ def _paged_attend(q, k_new, v_new, k_pool, v_pool, tables, lengths,
         mode = kv_quant_mode(k_pool.dtype)
         kq, ks = quantize_kv(k_new, mode)
         vq, vs = quantize_kv(v_new, mode)
-        k_eff = k_pool.at[blk, off].set(kq)
-        v_eff = v_pool.at[blk, off].set(vq)
-        ks_eff = k_scale.at[blk, off].set(ks.astype(k_scale.dtype))
-        vs_eff = v_scale.at[blk, off].set(vs.astype(v_scale.dtype))
+        k_eff = k_pool.at[blk, :, off].set(kq)
+        v_eff = v_pool.at[blk, :, off].set(vq)
+        ks_eff = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
+        vs_eff = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
         return _da.paged_decode_attention(q, k_eff, v_eff, tables,
                                          lens + 1, ks_eff, vs_eff)
-    k_eff = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype))
-    v_eff = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))
+    k_eff = k_pool.at[blk, :, off].set(k_new.astype(k_pool.dtype))
+    v_eff = v_pool.at[blk, :, off].set(v_new.astype(v_pool.dtype))
     return _da.paged_decode_attention(
         q.astype(k_pool.dtype), k_eff, v_eff, tables,
         lens + 1).astype(q.dtype)
 
 
-def _fused_supported(x, w, hkv, d, block_s, quantize, kv_dtype,
-                     kv_item, quantized):
+def _fused_refusal(x, w, hkv, d, block_s, quantize, kv_dtype,
+                   kv_item, quantized) -> str:
+    """Why the fused kernel does not serve this call ('' = it does).
+    The reason lands in ops.kernel_paths, so a composite that stood in
+    for the megakernel is visible to the engine's stats."""
     (ln1_w, ln1_b, w_qkv, b_qkv, w_out, b_out,
      ln2_w, ln2_b, w_up, b_up, w_down, b_down) = w
     h = x.shape[1]
     f = w_up.shape[1]
     kvd = hkv * d
     heads = (w_qkv.shape[1] - 2 * kvd) // d
+    if not decode_megakernel_available():
+        return "backend is not tpu"
     if quantize:
         # quantized COMPUTE runs the composite (whose projections take
         # the int8 qmm path with tuned tiles); the fused kernel serves
         # the fp-compute case, with or without an int8 KV cache
-        return False
+        return "quantized compute rides the composite"
     if quantized and kv_dtype != jnp.int8:
-        return False        # fp8 caches ride the composite
-    if heads * d != h or heads % hkv:
-        return False
-    if h % 128 or f % 128 or (d != 64 and d % 128):
-        return False
-    if block_s % 128:
-        return False
+        return "fp8 caches ride the composite"
+    if (heads * d != h or heads % hkv or h % 128 or f % 128
+            or (d != 64 and d % 128) or block_s % 128):
+        return "shape not served by the kernel"
     _, block_f, block_q, block_o = _pick_blocks(block_s, f,
                                                 h + 2 * kvd, h)
     w_item = jnp.dtype(w_qkv.dtype).itemsize
     est = _vmem_estimate(h, kvd, f, block_s, block_f, block_q, block_o,
                          hkv, d, w_item, kv_item, quantized, x.shape[0])
     if not _interpret() and est > _VMEM_BUDGET:
-        return False
-    return True
+        return (f"VMEM estimate {est} bytes over the budget "
+                f"{_VMEM_BUDGET}")
+    return ""
+
+
+def _note_path(refusal: str) -> None:
+    if refusal:
+        kernel_paths.note("decode_megakernel", "composite", refusal)
+    else:
+        kernel_paths.note("decode_megakernel", "kernel")
 
 
 def decode_layer_step(x, w, k_cache, v_cache, lengths, k_scale=None,
@@ -685,12 +704,11 @@ def decode_layer_step(x, w, k_cache, v_cache, lengths, k_scale=None,
     quantized = k_scale is not None
     cap = k_cache.shape[1]
     block_s = _pick_blocks(cap, w[8].shape[1])[0]
-    supported = (cap % block_s == 0 and
-                 _fused_supported(x, w, hkv, d, block_s, quantize,
-                                  k_cache.dtype,
-                                  jnp.dtype(k_cache.dtype).itemsize,
-                                  quantized))
-    if not supported or not decode_megakernel_available():
+    refusal = "shape not served by the kernel" if cap % block_s else \
+        _fused_refusal(x, w, hkv, d, block_s, quantize, k_cache.dtype,
+                       jnp.dtype(k_cache.dtype).itemsize, quantized)
+    _note_path(refusal)
+    if refusal:
         attend = functools.partial(_dense_attend, k_cache=k_cache,
                                    v_cache=v_cache, lengths=lengths,
                                    k_scale=k_scale, v_scale=v_scale)
@@ -729,15 +747,14 @@ def decode_layer_step_paged(x, w, k_pool, v_pool, tables, lengths,
     tables ``[B, MB]`` int32; lengths EXCLUDE the new token.  Returns
     ``(x_out, k_new, v_new)`` — the caller scatters the new k/v at
     ``(tables[b, lengths[b]//bs], lengths[b]%bs)``."""
-    hkv, d = k_pool.shape[2], k_pool.shape[3]
+    hkv, d = k_pool.shape[1], k_pool.shape[3]
     quantized = k_scale is not None
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     mb = tables.shape[1]
-    supported = _fused_supported(x, w, hkv, d, bs, quantize,
-                                 k_pool.dtype,
-                                 jnp.dtype(k_pool.dtype).itemsize,
-                                 quantized)
-    if not supported or not decode_megakernel_available():
+    refusal = _fused_refusal(x, w, hkv, d, bs, quantize, k_pool.dtype,
+                             jnp.dtype(k_pool.dtype).itemsize, quantized)
+    _note_path(refusal)
+    if refusal:
         attend = functools.partial(_paged_attend, k_pool=k_pool,
                                    v_pool=v_pool, tables=tables,
                                    lengths=lengths, k_scale=k_scale,

@@ -39,31 +39,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is optional on CPU-only hosts
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+
+from . import kernel_paths
 
 _INTERPRET = False  # set True in tests to run the kernel on CPU
 _NEG = -1e30
 
 
+def check_interpret_allowed(flag) -> None:
+    """Interpret mode is a CPU test switch: refuse it on a chip, where
+    it would quietly replace every kernel with its interpreter."""
+    if flag and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "Pallas interpret mode is a CPU test switch and cannot be "
+            "turned on when the backend is tpu")
+
+
+def run_kernel(dtype, call, *operands):
+    """Invoke a built ``pl.pallas_call`` on its operands.  A process-wide
+    ``jax_default_matmul_precision`` of 'highest' (the test suite pins it,
+    and so may a user checking numerics) reaches every dot traced without
+    an explicit precision, the kernels' included — and Mosaic refuses an
+    fp32-precision contraction of bf16 or int8 operands ("Bad lhs type").
+    Kernels over float32 operands keep the caller's precision; all others
+    trace at the default, which is exact for them anyway (bf16 x bf16 and
+    int8 x int8 products fit the f32/int32 accumulator)."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return call(*operands)
+    with jax.default_matmul_precision("default"):
+        return call(*operands)
+
+
 def set_interpret_mode(flag: bool):
     global _INTERPRET
+    check_interpret_allowed(flag)
     _INTERPRET = bool(flag)
 
 
 def flash_attention_available() -> bool:
-    if not _HAS_PLTPU:
-        return False
-    if _INTERPRET:
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return _INTERPRET or jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -305,8 +320,8 @@ _SWEEP_CANDIDATES = (128, 256, 512, 1024)
 # of seconds of compile+measure per shape, so PADDLE_TPU_FLASH_AUTOTUNE=
 # sweep pays once per (device_kind, seq, head_dim, causal) ACROSS
 # processes, not once per run.  PADDLE_TPU_FLASH_AUTOTUNE_CACHE names the
-# legacy JSON file ("0"/"off" disables persistence; default
-# ~/.cache/paddle_tpu/flash_autotune.json).  Sweep winners ALSO land in
+# legacy JSON file ("0"/"off"/unset: no file — the tables in the code
+# decide).  Sweep winners ALSO land in
 # the unified tuning table (utils.tuning, op "flash_blocks") — the
 # generalization of this cache that serves quantized-matmul tiles, MoE
 # a2a chunks and prefill buckets too; get_block_sizes consults it even
@@ -319,11 +334,7 @@ def _sweep_store_path():
     p = os.environ.get("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", "").strip()
     if p.lower() in ("0", "off", "false", "none"):
         return None
-    if p:
-        return os.path.expanduser(p)
-    base = os.environ.get("XDG_CACHE_HOME") or \
-        os.path.join(os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "paddle_tpu", "flash_autotune.json")
+    return os.path.expanduser(p) if p else None
 
 
 def _unified_table_enabled() -> bool:
@@ -541,7 +552,7 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
     grid = (bhkv, g, s // block_q)
     kernel = functools.partial(_fwd_kernel, block_k=block_k,
                                causal=causal, scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -563,7 +574,8 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
             jax.ShapeDtypeStruct((bhkv, g, 1, s), jnp.float32),
         ],
         interpret=_INTERPRET,
-    )(q4, k3, v3, mask)
+    )
+    return run_kernel(q4.dtype, call, q4, k3, v3, mask)
 
 
 def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
@@ -579,7 +591,7 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
 
     dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k,
                                   causal=causal, scale=scale)
-    dq = pl.pallas_call(
+    call = pl.pallas_call(
         dq_kernel,
         grid=(bhkv, g, s // block_q),
         in_specs=[
@@ -600,12 +612,13 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
                                lambda b, gi, i: (b, gi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bhkv, g, s, d), q4.dtype),
         interpret=_INTERPRET,
-    )(q4, k3, v3, do4, lse, delta, mask)
+    )
+    dq = run_kernel(q4.dtype, call, q4, k3, v3, do4, lse, delta, mask)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
                                    causal=causal, scale=scale,
                                    n_groups=g)
-    dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         dkv_kernel,
         grid=(bhkv, s // block_k, g),   # g innermost: in-place accumulate
         in_specs=[
@@ -633,7 +646,8 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
             jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
         ],
         interpret=_INTERPRET,
-    )(k3, v3, q4, do4, lse, delta, mask)
+    )
+    dk, dv = run_kernel(q4.dtype, call, k3, v3, q4, do4, lse, delta, mask)
     return dq, dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
@@ -732,7 +746,52 @@ def flash_attention(q, k, v, causal=False, kv_mask=None):
     supported = (s == sk and s % 128 == 0 and (d % 128 == 0 or d == 64)
                  and h % hkv == 0)
     if not supported or not flash_attention_available():
+        kernel_paths.note_composite("flash_attention", supported)
         return _composite(q, k, v, causal, kv_mask)
+    kernel_paths.note("flash_attention", "kernel")
     mask = jnp.ones((b, 1, s), jnp.float32) if kv_mask is None \
         else kv_mask.reshape(b, 1, s).astype(jnp.float32)
-    return _flash(q, k, v, mask, causal)
+    part = _mesh_partition(b, h, hkv)
+    if part is None:
+        return _flash(q, k, v, mask, causal)
+    where, qkv_spec, mask_spec = part
+    from ..distributed.mesh import shard_map
+    return shard_map(
+        lambda q, k, v, mask: _flash(q, k, v, mask, causal),
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
+        out_specs=qkv_spec, check_vma=False, **where)(q, k, v, mask)
+
+
+def _mesh_partition(b: int, h: int, hkv: int):
+    """(shard_map mesh arguments, q/k/v spec, mask spec) when a compiled
+    trainer is tracing its step over a multi-device mesh, else None.
+    The Pallas calls are custom calls GSPMD cannot partition ("Mosaic
+    kernels cannot be automatically partitioned"), and attention is
+    independent per sequence and per head: shard_map them — batch over
+    the data axes, heads over 'tp' — with no collectives.  Axes that
+    divide neither stay replicated (every device of that axis computes
+    the same).
+
+    A caller already inside a shard_map body (the overlapped ZeRO-3
+    scan, the pipeline schedules) holds per-shard operands: the axes
+    that body made Manual are left alone, and only the still-automatic
+    ones are mapped, through the context mesh shard_map insists on
+    there.  With every axis Manual the kernel is called as it is."""
+    from jax.sharding import PartitionSpec as P
+    from ..distributed.mesh import get_compile_mesh
+    mesh = get_compile_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    auto = [ax for ax in mesh.axis_names if ax not in manual]
+    if not auto:
+        return None
+    data = tuple(ax for ax in ("dcn", "dp")
+                 if ax in auto and mesh.shape[ax] > 1)
+    if data and b % math.prod(mesh.shape[ax] for ax in data):
+        data = ()
+    tp = mesh.shape["tp"] if "tp" in auto else 1
+    heads = "tp" if tp > 1 and h % tp == 0 and hkv % tp == 0 else None
+    where = {"axis_names": frozenset(auto)} if manual else {"mesh": mesh}
+    return (where, P(data or None, None, heads, None),
+            P(data or None, None, None))

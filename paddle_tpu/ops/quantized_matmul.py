@@ -41,6 +41,8 @@ from jax.experimental import pallas as pl
 
 import importlib
 
+from . import kernel_paths
+
 # live view of the sibling module's mutable interpret flag (the package
 # __init__ rebinds `flash_attention` to the public function)
 _fa = importlib.import_module(__package__ + ".flash_attention")
@@ -168,14 +170,7 @@ def _qmm_composite(qx, qw, sx, sw, out_dtype):
 # Pallas kernel: int8 MXU dots, int32 accumulation, f32 rescale
 # ---------------------------------------------------------------------------
 def quantized_matmul_available() -> bool:
-    if not _fa._HAS_PLTPU:
-        return False
-    if _fa._INTERPRET:
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return _fa._INTERPRET or jax.default_backend() == "tpu"
 
 
 def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, *, block_k: int):
@@ -248,7 +243,7 @@ def _qmm_pallas(qx, qw, sx, sw, out_dtype, dtype, tiles=None):
     n = qw.shape[1]
     bm, bn, bk = tiles or get_qmm_tiles(m, n, k, dtype)
     kernel = functools.partial(_qmm_kernel, block_k=bk)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn),
         in_specs=[
@@ -260,7 +255,8 @@ def _qmm_pallas(qx, qw, sx, sw, out_dtype, dtype, tiles=None):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=_fa._INTERPRET,
-    )(qx, qw, sx, sw)
+    )
+    return _fa.run_kernel(qx.dtype, call, qx, qw, sx, sw)
 
 
 def _qmm_forward(x, w, dtype, out_dtype):
@@ -281,8 +277,10 @@ def _qmm_forward(x, w, dtype, out_dtype):
     supported = (dtype == "int8" and m % 32 == 0 and n % 128 == 0
                  and k % 128 == 0)
     if supported and quantized_matmul_available():
+        kernel_paths.note("quantized_matmul", "kernel")
         y = _qmm_pallas(qx, qw, sx, sw, out_dtype, dtype)
     else:
+        kernel_paths.note_composite("quantized_matmul", supported)
         y = _qmm_composite(qx, qw, sx, sw, out_dtype)
     return y.reshape(*lead, n), qx, sx, qw, sw
 

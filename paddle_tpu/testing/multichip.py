@@ -1,9 +1,8 @@
 """Shared multichip overlap-parity phases.
 
-One implementation backs the two heavyweight consumers — the driver
-dryrun (`__graft_entry__.dryrun_multichip`) and ``bench.py
---multichip-smoke`` — so "the overlapped schedule matches its
-synchronous counterpart" is asserted by the same code in both.  The
+These back ``bench.py --multichip-smoke`` (a CPU-only child on eight
+virtual devices), where "the overlapped schedule matches its
+synchronous counterpart" is asserted at GPT size.  The
 tier-1 tests (tests/test_overlap_collectives.py) assert the SAME
 contract (parity at PARITY_RTOL, zero recompiles, comm fields) but on
 deliberately smaller configs — the suite runs close to its time

@@ -1,136 +1,53 @@
 """Persistent XLA compilation cache wiring.
 
-BENCH_r05 paid a 95.4s warmup+compile on EVERY bench run because nothing
-persisted XLA executables across processes.  JAX ships a persistent
-compilation cache (``jax_compilation_cache_dir``); this module turns it
-on by default for paddle_tpu trainers and the bench:
+Cold compile of a trainer or a serving engine is tens of seconds to
+minutes; JAX's persistent compilation cache (``jax_compilation_cache_dir``)
+lets the next process deserialize instead.  This module turns it on for
+paddle_tpu trainers, engines, the bench and the test suite:
 
-- ``PADDLE_TPU_COMPILE_CACHE=<dir>`` picks the location;
-- ``PADDLE_TPU_COMPILE_CACHE=0`` (or ``off``) disables it;
-- unset: ``$XDG_CACHE_HOME/paddle_tpu/xla_cache`` (``~/.cache/...``).
-
-An already-configured cache dir (e.g. the test suite's conftest) is
-respected and never overridden.
-
-CPU-backend guard: jaxlib 0.4.x ABORTS (duplicate JIT symbol
-registration) when a multi-device SPMD executable is *deserialized* from
-the persistent cache on the CPU backend.  Writing those entries is fine
-and single-device programs deserialize fine, so the guard serves cache
-HITS only for 1-partition/1-replica programs on CPU — the same policy
-the test suite has run under since PR 1.  On TPU all programs are
-served.  Failures anywhere in this wiring degrade to "no cache", never
-to a crashed trainer (the remote-compile retry path must keep working
-when the cache backend misbehaves).
+- ``JAX_COMPILATION_CACHE_DIR`` set (or ``jax_compilation_cache_dir``
+  already configured): the cache is there and nothing here sets another;
+- unset: one fixed path inside the checkout, ``<repo>/.jax_cache``
+  (git-ignored).  The path is part of the cache key, so it never depends
+  on ``~``, a pid, a time or a temporary name;
+- ``PADDLE_TPU_COMPILE_CACHE=0`` (or ``off``) disables it.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Optional
 
 __all__ = ["ensure_compile_cache", "compile_cache_dir",
-           "compile_cache_enabled", "suspend_cpu_cache_hits"]
+           "compile_cache_enabled"]
 
 _STATE: dict = {"resolved": False, "dir": None}
-_SUSPEND = {"depth": 0}
 _OFF_VALUES = ("0", "off", "false", "none", "disabled")
-
-
-@contextlib.contextmanager
-def suspend_cpu_cache_hits():
-    """While active, the CPU-backend guard refuses ALL persistent-cache
-    hits (entries are still WRITTEN, so nothing is lost for later TPU
-    runs).  Used when compiling executables with DONATED operands on the
-    CPU backend: jaxlib 0.4.x mis-aliases donated buffers in executables
-    deserialized from the persistent cache (the hazard PR 2 hit with
-    rollback; the serving engine's decode executable donates its KV
-    cache the same way) — compiling fresh is the dodge.  No-op on TPU.
-    """
-    _SUSPEND["depth"] += 1
-    try:
-        yield
-    finally:
-        _SUSPEND["depth"] -= 1
-
-
-def _default_dir() -> str:
-    base = os.environ.get("XDG_CACHE_HOME") or \
-        os.path.join(os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "paddle_tpu", "xla_cache")
-
-
-def _install_cpu_spmd_guard() -> None:
-    """Serve persistent-cache hits on CPU only for single-device
-    programs (see module docstring). Idempotent."""
-    try:
-        from jax._src import compilation_cache as _cc
-    except Exception:  # pragma: no cover - jax internals moved
-        return
-    if getattr(_cc.get_executable_and_time, "_pd_spmd_guard", False):
-        return
-    orig_get = _cc.get_executable_and_time
-
-    def _guarded_get(cache_key, compile_options, backend):
-        try:
-            if getattr(backend, "platform", "cpu") == "cpu":
-                if _SUSPEND["depth"] > 0:
-                    # donated-operand executable being built (serving
-                    # engine decode/prefill): deserializing those on CPU
-                    # mis-aliases the donation — force a fresh compile
-                    return None, None
-                ebo = compile_options.executable_build_options
-                if ebo.num_partitions > 1 or ebo.num_replicas > 1:
-                    return None, None
-        except Exception:
-            return None, None
-        return orig_get(cache_key, compile_options, backend)
-
-    _guarded_get._pd_spmd_guard = True
-    _cc.get_executable_and_time = _guarded_get
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def ensure_compile_cache() -> Optional[str]:
     """Enable the persistent XLA compile cache (idempotent); returns the
-    active cache directory, or None when disabled/unavailable."""
+    active cache directory, or None when disabled."""
     if _STATE["resolved"]:
         return _STATE["dir"]
     _STATE["resolved"] = True
     env = os.environ.get("PADDLE_TPU_COMPILE_CACHE", "").strip()
     if env.lower() in _OFF_VALUES:
         return None
-    try:
-        import jax
-        current = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if current:
-            # someone (conftest, user) already configured it: adopt
-            _install_cpu_spmd_guard()
-            _STATE["dir"] = current
-            return current
-        path = os.path.abspath(os.path.expanduser(env or _default_dir()))
+    import jax
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # the cache is LIVE from here: record it and install the guard
-        # first, so a failure on the tunables below can never leave an
-        # active cache without the CPU-SPMD abort guard (or report a
-        # live cache as disabled)
-        _install_cpu_spmd_guard()
-        _STATE["dir"] = path
-        try:
-            # trainer executables are exactly the entries worth
-            # persisting; the default 1s/min-size thresholds would also
-            # skip the small eval/update programs, so disable them
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:
-            pass  # older jax: thresholds keep their defaults
-        return path
-    except Exception:
-        # cache is an optimization: a read-only FS, an old jax, or a
-        # flag rename must never take the trainer down
-        _STATE["dir"] = None
-        return None
+    # trainer/engine executables are exactly the entries worth
+    # persisting; the default 1s/min-size thresholds would also skip the
+    # small eval/update programs, so disable them
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _STATE["dir"] = path
+    return path
 
 
 def compile_cache_dir() -> Optional[str]:

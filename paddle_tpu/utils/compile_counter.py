@@ -60,11 +60,8 @@ def _listener(key: str, duration: float, **kwargs) -> None:
 
 
 def install() -> bool:
-    """Register the monitoring listener (idempotent). Returns True when
-    the counters are live, False when jax's monitoring API is missing
-    (counters then stay at 0 — callers must treat 0-delta as 'no
-    evidence of a recompile', which is still the correct assertion
-    direction for the recompile-free contract)."""
+    """Register the monitoring listener (idempotent); the counters are
+    live from here on."""
     with _lock:
         if _STATE["installed"]:
             return True
@@ -75,11 +72,8 @@ def install() -> bool:
             "xla_compiles_total", "XLA backend compiles")
         _METRICS["traces"] = _obs_metrics.counter(
             "jaxpr_traces_total", "jaxpr traces")
-        try:
-            from jax._src import monitoring
-            monitoring.register_event_duration_secs_listener(_listener)
-        except Exception:  # pragma: no cover - jax internals moved
-            return False
+        from jax._src import monitoring
+        monitoring.register_event_duration_secs_listener(_listener)
         _STATE["installed"] = True
         return True
 

@@ -94,11 +94,10 @@ def tuning_path() -> Optional[str]:
     p = os.environ.get("PADDLE_TPU_TUNING_CACHE", "").strip()
     if p.lower() in ("0", "off", "false", "none"):
         return None
-    if p:
-        return os.path.expanduser(p)
-    base = os.environ.get("XDG_CACHE_HOME") or \
-        os.path.join(os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "paddle_tpu", "tuning.json")
+    # no path given: no table on disk — lookups use the tables in the
+    # code, so what is compiled never depends on state around the
+    # checkout
+    return os.path.expanduser(p) if p else None
 
 
 def key_str(op: str, parts) -> str:

@@ -477,6 +477,11 @@ def live_engine():
         yield eng
     finally:
         os.environ.pop("PADDLE_TPU_AUTOTUNE", None)
+        # an episode that merges [8, 16] into [16] records it for
+        # (cpu, 64) in the process's tuning cache: every later engine of
+        # this worker with max_seq_len 64 and default buckets would then
+        # refuse a 20-token prompt
+        tuning.reset_for_tests()
 
 
 def test_live_engine_is_armed_and_episode_is_compile_free(live_engine):

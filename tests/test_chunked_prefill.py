@@ -105,8 +105,6 @@ def test_chunk_window_kernel_interpret_edges(quantized):
     """Interpret-mode Pallas window kernel ≡ the XLA composite at the
     chunk-edge prefix lengths (GQA, fp and int8, kernel-eligible
     shapes) — the kernel the chunk executable actually dispatches."""
-    if not da._fa._HAS_PLTPU:
-        pytest.skip("pallas TPU surface unavailable")
     rng = np.random.RandomState(2)
     B, S, H, Hkv, D, W = 4, 128, 4, 2, 64, 8
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))

@@ -105,7 +105,10 @@ def test_megakernel_decode_kind():
         m.enable_decode_megakernel(False)
 
 
-def test_trainer_train_step_registered_and_analyzed():
+def test_trainer_train_step_registered_and_analyzed(monkeypatch):
+    # the CPU has no tabled peak: pin one so the roofline math runs
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "100e9")
+    monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_GBPS", "10")
     tr = linear_trainer()
     x = np.random.RandomState(0).randn(8, 16).astype(np.float32)
     y = np.random.RandomState(0).randint(0, 10, size=(8,)) \
@@ -211,7 +214,7 @@ def test_roofline_classification_and_attribution(monkeypatch):
     reg._entries = {("c", ("big_matmul",)): compute,
                     ("c", ("decode",)): bandwidth}
     snap = reg.snapshot("c")
-    assert snap["peaks_nominal"] is False
+    assert snap["peaks_known"] is True
     rows = {r["name"]: r for r in snap["executables"]}
     mm, dec = rows["big_matmul"], rows["decode"]
     # AI 1000 vs ridge 10 -> compute; AI 0.1 -> bandwidth
@@ -281,13 +284,13 @@ def test_engine_feeds_ledger_params_and_kv():
 # ---------------------------------------------------------------------------
 # roofline-aware doctor
 # ---------------------------------------------------------------------------
-def _decode_profile(bw_frac, bound="bandwidth", nominal=False):
+def _decode_profile(bw_frac, bound="bandwidth", known=True):
     return {
         "decode": {"kind": "decode", "bound": bound,
                    "hbm_bw_frac": bw_frac, "achieved_hbm_gbps": 590.0,
                    "arithmetic_intensity": 1.2, "ridge_ai": 240.0,
                    "mfu": 0.04, "calls": 100, "runtime_ms": 500.0},
-        "_peaks": {"peaks_nominal": nominal, "device_kind": "tpu v5e"},
+        "_peaks": {"peaks_known": known, "device_kind": "tpu v5e"},
     }
 
 
@@ -306,11 +309,11 @@ def test_doctor_bandwidth_bound_decode_roofline():
     assert hit["score"] == pytest.approx(0.72, abs=1e-4)
 
 
-def test_doctor_roofline_skips_nominal_peaks():
+def test_doctor_roofline_skips_unknown_peaks():
     v = doctor.diagnose(
         {"decode_steps": 100, "kv_dtype": "int8",
          "decode_megakernel": True,
-         "exec_profile": _decode_profile(0.9, nominal=True)},
+         "exec_profile": _decode_profile(0.9, known=False)},
         kind="serve")
     assert "bandwidth-bound-decode" not in \
         [x["bottleneck"] for x in v]
@@ -343,7 +346,7 @@ def test_doctor_mfu_below_target_train_rule():
                        "ridge_ai": 240.0, "mean_ms": 120.0,
                        "gap_share": 0.2, "runtime_ms": 2400.0,
                        "calls": 20},
-        "_peaks": {"peaks_nominal": False}}}
+        "_peaks": {"peaks_known": True}}}
     v = doctor.diagnose(stats, kind="train")
     names = [x["bottleneck"] for x in v]
     assert "mfu-below-target" in names
@@ -371,7 +374,10 @@ def test_doctor_oom_risk_rule():
 # ---------------------------------------------------------------------------
 # snapshot -> report round-trip
 # ---------------------------------------------------------------------------
-def test_snapshot_and_report_round_trip(tmp_path):
+def test_snapshot_and_report_round_trip(tmp_path, monkeypatch):
+    # the CPU has no tabled peak: pin one so the rows carry fractions
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "100e9")
+    monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_GBPS", "10")
     eng = InferenceEngine(tiny_model(), batch_slots=2,
                           prefill_buckets=[16])
     eng.warmup(buckets=[16])
@@ -545,3 +551,40 @@ def test_snapshot_single_fat_line_still_lands(tmp_path, monkeypatch):
     with open(path) as f:
         lines = [json.loads(ln) for ln in f if ln.strip()]
     assert len(lines) == 1               # history dropped, state kept
+
+
+def test_analyze_relowers_under_the_entrys_mesh(monkeypatch):
+    """A trainer on a multi-device mesh: analyze() must re-trace under
+    that mesh, so the Pallas kernels' shard_map wrappers engage again —
+    without it the chip's compiler refuses the re-lowered step ("Mosaic
+    kernels cannot be automatically partitioned", PR 21's first
+    four-chip run)."""
+    import importlib
+
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.ops import set_interpret_mode
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    seen = []
+    orig = fa._mesh_partition
+
+    def spy(*a):
+        seen.append(orig(*a))
+        return seen[-1]
+
+    monkeypatch.setattr(fa, "_mesh_partition", spy)
+    set_interpret_mode(True)
+    try:
+        _, _, tr, _ = chip_smoke.run_train(
+            chip_smoke.Sizes(True), {"dp": 2, "tp": 2}, jax.devices()[:4],
+            2, zero2=True)
+        traced = len(seen)
+        assert traced and all(p is not None for p in seen)
+        entry = [e for e in er.registry().entries(tr._exec_component)
+                 if e.kind == "train_step"][0]
+        assert er.registry().analyze(entry), entry.analysis_error
+        assert len(seen) > traced and seen[-1] is not None
+        assert entry.analysis["tpu_custom_calls"] == 0    # interpreted
+    finally:
+        set_interpret_mode(False)

@@ -156,3 +156,43 @@ def test_unsupported_shapes_fall_back():
     ref = fa._composite(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("outer", ["none", "full", "dp_only"])
+def test_mesh_partition_inside_a_manual_region(outer):
+    """Under a compile mesh the kernel is shard_mapped (GSPMD cannot
+    partition a Mosaic call).  A caller already inside a shard_map body
+    holds per-shard operands: axes that body made Manual are left alone
+    (every axis Manual: the bare kernel; dp Manual, tp still automatic:
+    only tp is mapped, through the context mesh)."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.distributed.mesh import (compile_mesh_guard,
+                                             create_mesh, shard_map)
+    mesh = create_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    q, k, v = make_qkv(b=4, s=128, h=4)
+    seen = []
+
+    def attn(q, k, v):
+        seen.append(fa._mesh_partition(q.shape[0], q.shape[2],
+                                       k.shape[2]))
+        return fa.flash_attention(q, k, v, causal=True)
+
+    fn = attn
+    if outer != "none":
+        where = {} if outer == "full" else {"axis_names": {"dp"}}
+        fn = shard_map(attn, mesh=mesh, in_specs=P("dp"),
+                       out_specs=P("dp"), check_vma=False, **where)
+    with compile_mesh_guard(mesh):
+        out = jax.jit(fn)(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(fa._composite(q, k, v, True)),
+        rtol=2e-5, atol=2e-5)
+    part = seen[0]
+    if outer == "full":
+        assert part is None
+    elif outer == "none":
+        assert part[0] == {"mesh": mesh}
+        assert part[1] == P(("dp",), None, "tp", None)
+    else:
+        assert part[0] == {"axis_names": frozenset({"tp"})}
+        assert part[1] == P(None, None, "tp", None)

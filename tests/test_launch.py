@@ -11,7 +11,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# The baked-in jaxlib 0.4.x CPU backend cannot run multiprocess
+# The CPU backend here cannot run multiprocess
 # collectives at all — both 2-process tests die in the child with
 # "XlaRuntimeError: Multiprocess computations aren't implemented on the
 # CPU backend" (verified identical on the untouched seed tree), burning

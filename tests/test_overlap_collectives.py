@@ -225,6 +225,54 @@ def test_zero3_overlap_matches_sync_and_recompile_free(dp8_mesh,
     assert w.addressable_shards[0].data.size == w.size // 8
 
 
+def test_zero3_scan_with_flash_kernel_inside_the_manual_region():
+    """The chip's default recipe (flash + scan over layers) under
+    overlapped ZeRO-3: the flash kernel is traced INSIDE zero3's
+    shard_map body, where every mesh axis is already Manual, so its own
+    mesh partition must stand down instead of nesting a second
+    shard_map over the same mesh (PR 21 review: "The context mesh ...
+    should match the mesh passed to shard_map" at trace).  Kernel
+    interpreted; losses equal the composite's."""
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
+                                   GPTPretrainingCriterion)
+    from paddle_tpu.ops import kernel_paths, set_interpret_mode
+    mesh = create_mesh({"dp": 4}, devices=jax.devices()[:4])
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, 128, (8, 128)).astype(np.int32)
+    labels = np.roll(ids, -1, 1).astype(np.int64)
+
+    def run(flash):
+        paddle.seed(11)
+        cfg = GPTConfig(vocab_size=128, hidden_size=128, num_layers=2,
+                        num_heads=2, max_seq_len=128,
+                        use_flash_attention=flash)
+        model = GPTForCausalLM(cfg)
+        opt = paddle.optimizer.Adam(learning_rate=1e-3,
+                                    parameters=model.parameters())
+        crit = GPTPretrainingCriterion()
+        st = DistributedStrategy()
+        st.sharding = True
+        st.sharding_configs = {"stage": 3, "overlap": True}
+        st.recompute = True
+        st.recompute_configs = {"scan_layers": True,
+                                "policy": "dots_no_batch"}
+        model.enable_recompute("dots_no_batch")
+        tr = SpmdTrainer(model, opt, lambda o, l: crit(o, l),
+                         mesh=mesh, strategy=st)
+        assert tr.zero3_overlap
+        return [float(tr.train_step(ids, labels)) for _ in range(2)]
+
+    set_interpret_mode(True)
+    kernel_paths.reset()
+    try:
+        with_kernel = run(True)
+        assert kernel_paths.counts()["flash_attention"]["kernel"] >= 1
+    finally:
+        set_interpret_mode(False)
+    np.testing.assert_allclose(with_kernel, run(False), rtol=1e-5)
+    assert with_kernel[1] < with_kernel[0]
+
+
 # ---------------------------------------------------------------------------
 # 1F1B pipeline schedule
 # ---------------------------------------------------------------------------
@@ -367,13 +415,17 @@ def test_overlap_knob_defaults(monkeypatch):
     assert overlap_mod.moe_a2a_chunks(6) == 3
 
 
-def test_overlap_flags_cpu_noop(monkeypatch):
-    """On the host platform the XLA accelerator flags must NOT be
-    appended (the CPU backend aborts on unknown flags)."""
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setenv("XLA_FLAGS", "")
-    assert overlap_mod.ensure_xla_overlap_flags() is False
-    assert "async" not in os.environ.get("XLA_FLAGS", "")
+def test_launcher_writes_no_accelerator_flags(monkeypatch):
+    """A launched child's XLA_FLAGS are its parent's: nothing guesses
+    the child's platform and appends accelerator options (an option the
+    installed compiler does not know aborts the child at start-up)."""
+    from paddle_tpu.distributed import launch
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("PADDLE_TPU_OVERLAP", "1")
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
+    env = launch._trainer_env(0, 2, ["127.0.0.1:1", "127.0.0.1:2"],
+                              "127.0.0.1:3")
+    assert env["XLA_FLAGS"] == "--xla_force_host_platform_device_count=2"
 
 
 # ---------------------------------------------------------------------------

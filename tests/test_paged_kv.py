@@ -86,10 +86,11 @@ def _pool_from_dense(k_dense, tables, bs):
     b, s, hkv, d = k_dense.shape
     mb = s // bs
     nb = int(tables.max()) + 1
-    pool = np.zeros((nb, bs, hkv, d), k_dense.dtype)
+    pool = np.zeros((nb, hkv, bs, d), k_dense.dtype)   # head-major
     for bi in range(b):
         for j in range(mb):
-            pool[tables[bi, j]] = k_dense[bi, j * bs:(j + 1) * bs]
+            pool[tables[bi, j]] = \
+                k_dense[bi, j * bs:(j + 1) * bs].swapaxes(0, 1)
     return pool
 
 
@@ -120,17 +121,15 @@ def test_paged_composite_bitwise_matches_dense_composite():
 def test_paged_kernel_matches_composite(hkv):
     """Pallas paged kernel (interpret mode, scalar-prefetched block
     table) vs the gather composite, incl. GQA and length masking."""
-    if not da._fa._HAS_PLTPU:
-        pytest.skip("pallas TPU backend unavailable")
     da.set_interpret_mode(True)
     try:
         rng = np.random.RandomState(1)
         b, h, d, bs, mb, nb = 3, 4, 64, 128, 2, 8
         q = jnp.asarray(rng.randn(b, h, d).astype(np.float32) * 0.3)
         k_pool = jnp.asarray(
-            rng.randn(nb, bs, hkv, d).astype(np.float32) * 0.3)
+            rng.randn(nb, hkv, bs, d).astype(np.float32) * 0.3)
         v_pool = jnp.asarray(
-            rng.randn(nb, bs, hkv, d).astype(np.float32) * 0.3)
+            rng.randn(nb, hkv, bs, d).astype(np.float32) * 0.3)
         tables = jnp.asarray(
             (1 + rng.permutation(nb - 1))[:b * mb].reshape(b, mb)
             .astype(np.int32))

@@ -94,8 +94,6 @@ def test_quantized_matmul_kernel_matches_composite():
     """Pallas int8 kernel (interpret mode) vs the dot_general composite:
     both accumulate in exact int32, so the only difference is the f32
     rescale ordering — epsilon, not tolerance."""
-    if not qm._fa._HAS_PLTPU:
-        pytest.skip("pallas TPU backend unavailable")
     x, w = _xw()
     ref = qm.quantized_matmul(x, w)          # composite on CPU
     qm._fa.set_interpret_mode(True)
@@ -296,23 +294,23 @@ def test_paged_quant_op_parity_with_dense_quant_op():
     mb = s // bs
     tables = (1 + rng.permutation(b * mb)).reshape(b, mb).astype(np.int32)
     nb = b * mb + 1
-    kp = np.zeros((nb, bs, hkv, d), np.int8)
-    vp = np.zeros((nb, bs, hkv, d), np.int8)
-    ksp = np.zeros((nb, bs, hkv), np.float32)
-    vsp = np.zeros((nb, bs, hkv), np.float32)
+    # pools are head-major: [nb, hkv, bs, d] values, [nb, hkv, bs] scales
+    kp = np.zeros((nb, hkv, bs, d), np.int8)
+    vp = np.zeros((nb, hkv, bs, d), np.int8)
+    ksp = np.zeros((nb, hkv, bs), np.float32)
+    vsp = np.zeros((nb, hkv, bs), np.float32)
     for bi in range(b):
         for j in range(mb):
-            kp[tables[bi, j]] = np.asarray(qk)[bi, j * bs:(j + 1) * bs]
-            vp[tables[bi, j]] = np.asarray(qv)[bi, j * bs:(j + 1) * bs]
-            ksp[tables[bi, j]] = np.asarray(sk)[bi, j * bs:(j + 1) * bs]
-            vsp[tables[bi, j]] = np.asarray(sv)[bi, j * bs:(j + 1) * bs]
+            rows = slice(j * bs, (j + 1) * bs)
+            kp[tables[bi, j]] = np.asarray(qk)[bi, rows].swapaxes(0, 1)
+            vp[tables[bi, j]] = np.asarray(qv)[bi, rows].swapaxes(0, 1)
+            ksp[tables[bi, j]] = np.asarray(sk)[bi, rows].swapaxes(0, 1)
+            vsp[tables[bi, j]] = np.asarray(sv)[bi, rows].swapaxes(0, 1)
     paged = da._paged_composite(q, jnp.asarray(kp), jnp.asarray(vp),
                                 jnp.asarray(tables), lengths,
                                 jnp.asarray(ksp), jnp.asarray(vsp))
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
 
-    if not da._fa._HAS_PLTPU:
-        return
     da.set_interpret_mode(True)
     try:
         kd = da.decode_attention(q, qk, qv, lengths, sk, sv)

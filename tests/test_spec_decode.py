@@ -99,12 +99,14 @@ def test_paged_window_matches_dense_window():
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([4, 8], np.int32))
     tables = np.array([[1, 2], [3, 4]], np.int32)
-    pool_k = np.zeros((5, bs, Hkv, D), np.float32)
+    pool_k = np.zeros((5, Hkv, bs, D), np.float32)     # head-major
     pool_v = np.zeros_like(pool_k)
     for b in range(B):
         for j in range(S // bs):
-            pool_k[tables[b, j]] = k[b, j * bs:(j + 1) * bs]
-            pool_v[tables[b, j]] = v[b, j * bs:(j + 1) * bs]
+            pool_k[tables[b, j]] = \
+                k[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
+            pool_v[tables[b, j]] = \
+                v[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
     dense = da.decode_attention_window(q, jnp.asarray(k), jnp.asarray(v),
                                        lens)
     paged = da.paged_decode_attention_window(
@@ -118,8 +120,6 @@ def test_paged_window_matches_dense_window():
 def test_window_kernel_interpret_vs_composite(quantized):
     """Interpret-mode Pallas window kernel ≡ the XLA composite (dense
     layout, kernel-eligible shapes, GQA, fp and int8)."""
-    if not da._fa._HAS_PLTPU:
-        pytest.skip("pallas TPU surface unavailable")
     rng = np.random.RandomState(2)
     B, S, H, Hkv, D, W = 2, 128, 4, 2, 64, 3
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
@@ -151,28 +151,23 @@ def test_window_kernel_interpret_vs_composite(quantized):
 def test_paged_window_kernel_interpret_vs_composite(quantized):
     """Interpret-mode scalar-prefetch paged window kernel ≡ the gather
     composite."""
-    if not da.paged_decode_attention_available() and \
-            not da._fa._HAS_PLTPU:
-        pytest.skip("pallas TPU surface unavailable")
-    if da._fa.pltpu is None:
-        pytest.skip("scalar prefetch unavailable")
     rng = np.random.RandomState(3)
     B, H, Hkv, D, W, bs, nb, mb = 2, 4, 2, 64, 3, 128, 5, 2
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     tables = jnp.asarray(np.array([[1, 2], [3, 4]], np.int32))
     lens = jnp.asarray(np.array([100, 200], np.int32))
     if quantized:
-        kp = jnp.asarray(rng.randint(-127, 128, (nb, bs, Hkv, D))
+        kp = jnp.asarray(rng.randint(-127, 128, (nb, Hkv, bs, D))
                          .astype(np.int8))
-        vp = jnp.asarray(rng.randint(-127, 128, (nb, bs, Hkv, D))
+        vp = jnp.asarray(rng.randint(-127, 128, (nb, Hkv, bs, D))
                          .astype(np.int8))
-        ks = jnp.asarray(rng.rand(nb, bs, Hkv).astype(np.float32) * 0.02)
-        vs = jnp.asarray(rng.rand(nb, bs, Hkv).astype(np.float32) * 0.02)
+        ks = jnp.asarray(rng.rand(nb, Hkv, bs).astype(np.float32) * 0.02)
+        vs = jnp.asarray(rng.rand(nb, Hkv, bs).astype(np.float32) * 0.02)
         args = (q, kp, vp, tables, lens, ks, vs)
         ref = da._paged_window_composite(*args)
     else:
-        kp = jnp.asarray(rng.randn(nb, bs, Hkv, D).astype(np.float32))
-        vp = jnp.asarray(rng.randn(nb, bs, Hkv, D).astype(np.float32))
+        kp = jnp.asarray(rng.randn(nb, Hkv, bs, D).astype(np.float32))
+        vp = jnp.asarray(rng.randn(nb, Hkv, bs, D).astype(np.float32))
         args = (q, kp, vp, tables, lens)
         ref = da._paged_window_composite(*args)
     da.set_interpret_mode(True)
