@@ -526,7 +526,7 @@ def phase_serve4(sz: Sizes, fail: bool) -> None:
     check_spread("serve4", before, device_bytes(devs), 1.5)
     check_tp_sharded("serve4",
                      eng.params["gpt.blocks.0.attn.qkv_proj.weight"], 4)
-    check_tp_sharded("serve4_cache", eng.cache.k, 4)
+    check_tp_sharded("serve4_cache", eng.cache.k[0], 4)
     say("serve4", model=sz.serve_name, tp=4, **info4)
     release(eng)
     del eng

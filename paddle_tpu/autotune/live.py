@@ -193,10 +193,8 @@ class LiveRetuner:
             # drop the trial garbage exactly like engine.warmup(): zero
             # every slot length so the junk written at slot 0 stays
             # masked (host-side constant, no new executable)
-            c = eng.cache
-            eng.cache = type(c)(c.k, c.v,
-                                jnp.zeros((eng.batch_slots,), jnp.int32),
-                                c.k_scale, c.v_scale)
+            eng.cache = eng.cache.with_lengths(
+                jnp.zeros((eng.batch_slots,), jnp.int32))
         return out
 
     @staticmethod

@@ -27,9 +27,10 @@ same discipline for inference, built from three papers:
   exhaustion the scheduler first evicts unpinned cache blocks, then
   PREEMPTS the youngest request back onto the queue (it resumes later
   via a prefill over prompt+generated — which usually hits the radix
-  cache) instead of deadlocking.  ``kv_layout='dense'`` (default) keeps
-  the PR-4 ``StaticKVCache`` and is the parity oracle for the paged
-  path.
+  cache) instead of deadlocking.  ``kv_layout='dense'`` (default) is
+  the slot-addressed ``StaticKVCache``: one head-major
+  ``[slots, kv_heads, max_seq, head_dim]`` buffer per layer for k and
+  for v, each donated to every executable and written in place.
 
 Sampling (greedy / temperature / top-k / top-p) runs inside the decode
 executable, so each step costs exactly one host read-back — the sampled
@@ -608,18 +609,20 @@ class InferenceEngine:
         return out
 
     def _shard_dense_cache_arrays(self, mesh, cache):
-        """StaticKVCache layout on the mesh: k/v [L, B, S, Hkv, D] —
-        batch slots over 'dp', KV heads over 'tp'; lengths follow the
-        slots.  Returns a new cache of the same type."""
+        """StaticKVCache layout on the mesh: every layer's k/v buffer
+        [B, Hkv, S, D] (scale planes [B, Hkv, S]) with batch slots over
+        'dp' and KV heads over 'tp'; lengths follow the slots.  Returns
+        a new cache of the same type."""
+        def put(layers, dims):
+            return tuple(self._put(mesh, a, dims) for a in layers)
+
         scales = ()
         if cache.quantized:
-            scales = (self._put(mesh, cache.k_scale,
-                                (None, "dp", None, "tp")),
-                      self._put(mesh, cache.v_scale,
-                                (None, "dp", None, "tp")))
+            scales = (put(cache.k_scale, ("dp", "tp", None)),
+                      put(cache.v_scale, ("dp", "tp", None)))
         return type(cache)(
-            self._put(mesh, cache.k, (None, "dp", None, "tp", None)),
-            self._put(mesh, cache.v, (None, "dp", None, "tp", None)),
+            put(cache.k, ("dp", "tp", None, None)),
+            put(cache.v, ("dp", "tp", None, None)),
             self._put(mesh, cache.lengths, ("dp",)),
             *scales)
 
@@ -2110,8 +2113,7 @@ class InferenceEngine:
                 zeros = self._put(self.mesh, zeros, ("dp",))
             except Exception as e:
                 self._shard_failed("warmup_lengths", e)
-        self.cache = type(cache)(cache.k, cache.v, zeros,
-                                 cache.k_scale, cache.v_scale)
+        self.cache = cache.with_lengths(zeros)
         return self
 
     def _warmup_paged(self, buckets):
@@ -2284,7 +2286,7 @@ class InferenceEngine:
         # sharding helpers replicate otherwise — mirror that here)
         hkv = cfg.num_kv_heads // tp if cfg.num_kv_heads % tp == 0 \
             else cfg.num_kv_heads
-        kv_item = jnp.dtype(self.cache.k.dtype).itemsize
+        kv_item = jnp.dtype(self.cache.dtype).itemsize
         if self.kv_layout == "paged":
             per_slot_pos = self.blocks_per_slot * self.block_size
         else:

@@ -1,7 +1,7 @@
 """Paged KV cache: a fixed block pool + per-slot block tables.
 
-The PR-4 serving engine preallocates a DENSE per-slot cache
-``[layers, batch_slots, max_seq, kv_heads, head_dim]`` — every slot
+The dense serving cache (``models.StaticKVCache``) preallocates, per
+layer, ``[batch_slots, kv_heads, max_seq, head_dim]`` — every slot
 owns ``max_seq`` positions whether it uses them or not, so slot count
 (= concurrent users) is capped by ``slots × max_seq`` memory even when
 every live request is short.  This module is the vLLM-style fix
@@ -72,7 +72,7 @@ def blocks_to_extend(have_blocks: int, new_len: int,
 
 def blocks_to_rows(blocks):
     """Pool blocks ``[n, Hkv, bs, ...]`` -> position-major rows
-    ``[n·bs, Hkv, ...]`` (the dense cache's per-slot layout).  Works for
+    ``[n·bs, Hkv, ...]`` (the paged prefill's working buffer).  Works for
     value blocks (trailing D) and scale blocks (no trailing dim)."""
     rows = jnp.swapaxes(blocks, 1, 2)
     return rows.reshape((-1,) + rows.shape[2:])
@@ -107,6 +107,10 @@ class PagedKVCache:
     @property
     def num_layers(self):
         return self.k.shape[0]
+
+    @property
+    def dtype(self):
+        return self.k.dtype
 
     @property
     def num_blocks(self):

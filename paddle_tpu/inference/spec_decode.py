@@ -70,7 +70,6 @@ import jax.numpy as jnp
 
 from ..distributed import moe as _moe
 from ..func import functional_apply, functional_state
-from ..models.gpt import StaticKVCache
 
 __all__ = ["SpecDecoder", "resolve_spec_k"]
 
@@ -196,10 +195,8 @@ class SpecDecoder:
         logits_d, d_cache = functional_apply(
             self.draft, "verify_step", d_params, last_win, d_cache)
         # advance the draft past the nprev real catch-up tokens
-        d_cache = StaticKVCache(
-            d_cache.k, d_cache.v,
-            d_cache.lengths + nprev.astype(jnp.int32) * active,
-            d_cache.k_scale, d_cache.v_scale)
+        d_cache = d_cache.with_lengths(
+            d_cache.lengths + nprev.astype(jnp.int32) * active)
         idx = jnp.maximum(nprev.astype(jnp.int32) - 1, 0)
         last_logits = jnp.take_along_axis(
             logits_d, idx[:, None, None], axis=1)[:, 0]    # [B, V]
@@ -286,9 +283,7 @@ class SpecDecoder:
         overshoot is K-1 - n_acc, floored at 0."""
         overshoot = jnp.maximum(self.k - 1 - n_acc, 0) * \
             active.astype(jnp.int32)
-        return StaticKVCache(d_cache.k, d_cache.v,
-                             d_cache.lengths - overshoot,
-                             d_cache.k_scale, d_cache.v_scale)
+        return d_cache.with_lengths(d_cache.lengths - overshoot)
 
     def _tick_dense_fn(self, t_params, d_params, t_cache, d_cache,
                        last_win, nprev, active, key, temps, top_ps):
@@ -311,10 +306,8 @@ class SpecDecoder:
         moe = _moe.fold_expert_stats(b)
         toks, n_acc, n_emit, key = self._accept(
             drafts, q, logits_t, active, key, temps, top_ps)
-        t_cache = StaticKVCache(
-            t_cache.k, t_cache.v,
-            jnp.minimum(t_cache.lengths + n_emit, t_cache.capacity),
-            t_cache.k_scale, t_cache.v_scale)
+        t_cache = t_cache.with_lengths(
+            jnp.minimum(t_cache.lengths + n_emit, t_cache.capacity))
         d_cache = self._draft_rollback(d_cache, n_acc, active)
         out = jnp.concatenate([toks, n_emit[:, None]], axis=1)
         return out, key, t_cache, d_cache, moe
@@ -426,7 +419,7 @@ class SpecDecoder:
                 jnp.dtype(leaf.dtype).itemsize
         dcfg = self.draft.cfg
         eng = self.engine
-        kv_item = jnp.dtype(self.draft_cache.k.dtype).itemsize
+        kv_item = jnp.dtype(self.draft_cache.dtype).itemsize
         kv = (2 * dcfg.num_layers * eng.max_seq_len *
               dcfg.num_kv_heads * dcfg.head_dim * kv_item)
         return int(pbytes / eng.batch_slots + kv)
@@ -460,10 +453,6 @@ class SpecDecoder:
                 except Exception as e:
                     eng._shard_failed("spec_warmup_lengths", e)
             return z
-        self.draft_cache = StaticKVCache(
-            self.draft_cache.k, self.draft_cache.v, zeros(),
-            self.draft_cache.k_scale, self.draft_cache.v_scale)
+        self.draft_cache = self.draft_cache.with_lengths(zeros())
         if eng.kv_layout != "paged":
-            eng.cache = StaticKVCache(
-                eng.cache.k, eng.cache.v, zeros(),
-                eng.cache.k_scale, eng.cache.v_scale)
+            eng.cache = eng.cache.with_lengths(zeros())

@@ -50,13 +50,25 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
 
 
 class StaticKVCache:
-    """Preallocated serving KV cache: ``k``/``v`` are
-    ``[layers, batch_slots, max_seq, kv_heads, head_dim]`` and
-    ``lengths`` is ``[batch_slots]`` int32 — valid tokens per slot.
+    """Preallocated serving KV cache: ``k``/``v`` are TUPLES of one
+    buffer per layer, each HEAD-MAJOR
+    ``[batch_slots, kv_heads, max_seq, head_dim]``, and ``lengths`` is
+    ``[batch_slots]`` int32 — valid tokens per slot.
+
+    One buffer per layer, because a step reads and writes layer ``i``
+    only inside layer ``i``: handed in donated, each buffer is updated
+    where it lies (a decode tick writes ``batch_slots × kv_heads ×
+    head_dim`` elements a layer and nothing else) and no layer is ever
+    sliced out of, or written back into, a stacked array.  Head-major,
+    because the decode kernels stream one (slot, kv head)'s
+    ``[max_seq, head_dim]`` strip at a time: those are the buffer's two
+    minor dimensions, so the kernels' ``[B·Hkv, S, D]`` view is a
+    reshape, not a transpose (the paged pool is head-major for the same
+    reason).
 
     Statically shaped on purpose (Pope et al., *Efficiently Scaling
     Transformer Inference*): every prefill/decode executable sees the
-    same cache shape, so generating N tokens never changes a shape and
+    same cache shapes, so generating N tokens never changes a shape and
     never recompiles.  All updates are functional (`lax.dynamic_update_
     slice` / scatter); under jit with donated cache operands XLA turns
     them into true in-place writes.  Registered as a pytree so it rides
@@ -64,12 +76,11 @@ class StaticKVCache:
 
     Quantized form (``kv_dtype='int8'``/``'fp8'`` in init_kv_cache):
     ``k``/``v`` hold 8-bit values and ``k_scale``/``v_scale`` the
-    per-(position, head) f32 scales
-    ``[layers, batch_slots, max_seq, kv_heads]`` — decode streams half
+    per-(position, head) f32 scale planes, tuples of
+    ``[batch_slots, kv_heads, max_seq]`` per layer — decode streams half
     the bytes and dequantizes inside the fused attention kernel.  The
-    fp cache (``k_scale is None``) stays the default and the parity
-    oracle; shapes are static either way, so the zero-recompile
-    contract is unchanged.
+    fp cache (``k_scale is None``) stays the default; shapes are static
+    either way, so the zero-recompile contract is unchanged.
     """
 
     __slots__ = ("k", "v", "lengths", "k_scale", "v_scale")
@@ -80,24 +91,49 @@ class StaticKVCache:
 
     @property
     def num_layers(self):
-        return self.k.shape[0]
+        return len(self.k)
 
     @property
     def batch_slots(self):
-        return self.k.shape[1]
+        return self.k[0].shape[0]
+
+    @property
+    def kv_heads(self):
+        return self.k[0].shape[1]
 
     @property
     def capacity(self):
-        return self.k.shape[2]
+        return self.k[0].shape[2]
+
+    @property
+    def dtype(self):
+        return self.k[0].dtype
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    def with_lengths(self, lengths) -> "StaticKVCache":
+        """The same buffers under new per-slot lengths."""
+        return StaticKVCache(self.k, self.v, lengths, self.k_scale,
+                             self.v_scale)
+
+    def _buffer_lists(self) -> list:
+        """``[k, v]`` (``+ [k_scale, v_scale]`` when quantized) as
+        per-layer lists a serving forward fills in layer by layer, and
+        ``_with_buffers`` takes back."""
+        planes = (self.k, self.v) + \
+            ((self.k_scale, self.v_scale) if self.quantized else ())
+        return [list(p) for p in planes]
+
+    def _with_buffers(self, bufs, lengths) -> "StaticKVCache":
+        return StaticKVCache(tuple(bufs[0]), tuple(bufs[1]), lengths,
+                             *(tuple(b) for b in bufs[2:]))
+
     def __repr__(self):
-        return (f"StaticKVCache(layers={self.k.shape[0]}, "
-                f"slots={self.k.shape[1]}, capacity={self.k.shape[2]}, "
-                f"kv_heads={self.k.shape[3]}, dtype={self.k.dtype}"
+        return (f"StaticKVCache(layers={self.num_layers}, "
+                f"slots={self.batch_slots}, capacity={self.capacity}, "
+                f"kv_heads={self.kv_heads}, dtype={self.dtype}"
                 f"{', quantized' if self.quantized else ''})")
 
 
@@ -284,11 +320,13 @@ class GPTAttention(Layer):
     @staticmethod
     def _upgrade_cache(cache, b, hkv, d, cap, dtype):
         """Adopt any accepted cache form into the fixed-capacity triple
-        ``(k_buf [B, cap, Hkv, D], v_buf, length)``.
+        ``(k_buf [B, Hkv, cap, D], v_buf, length)`` — head-major, the
+        StaticKVCache layer layout.
 
         Accepted: the triple itself; the legacy 2-tuple ``(pk, pv)`` of
-        dense past keys/values (padded into a fresh buffer — its static
-        `past` length stays static, so adopting is compile-stable); and
+        dense past keys/values ``[B, past, Hkv, D]`` (padded into a
+        fresh buffer — its static `past` length stays static, so
+        adopting is compile-stable); and
         ``(None, None)`` / empty to start a fresh buffer.  The fixed
         capacity is what kills the per-token recompile: the old concat
         path changed the cache shape every generated token, forcing XLA
@@ -300,16 +338,16 @@ class GPTAttention(Layer):
             v_buf = v_buf.data if isinstance(v_buf, Tensor) else v_buf
             return k_buf, v_buf, length
         pk, pv = cache
-        k_buf = jnp.zeros((b, cap, hkv, d), dtype)
-        v_buf = jnp.zeros((b, cap, hkv, d), dtype)
+        k_buf = jnp.zeros((b, hkv, cap, d), dtype)
+        v_buf = jnp.zeros((b, hkv, cap, d), dtype)
         if pk is None:
             return k_buf, v_buf, 0
         pk = pk.data if isinstance(pk, Tensor) else jnp.asarray(pk)
         pv = pv.data if isinstance(pv, Tensor) else jnp.asarray(pv)
         k_buf = jax.lax.dynamic_update_slice(
-            k_buf, pk.astype(dtype), (0, 0, 0, 0))
+            k_buf, jnp.swapaxes(pk, 1, 2).astype(dtype), (0, 0, 0, 0))
         v_buf = jax.lax.dynamic_update_slice(
-            v_buf, pv.astype(dtype), (0, 0, 0, 0))
+            v_buf, jnp.swapaxes(pv, 1, 2).astype(dtype), (0, 0, 0, 0))
         return k_buf, v_buf, int(pk.shape[1])
 
     def _attend_fresh(self, q, k, v, b, s):
@@ -370,9 +408,11 @@ class GPTAttention(Layer):
         # same offset for every row (the legacy API is uniform-length;
         # per-slot offsets live in StaticKVCache/forward_decode)
         k_buf = jax.lax.dynamic_update_slice(
-            k_buf, k.astype(k_buf.dtype), (0, length, 0, 0))
+            k_buf, jnp.swapaxes(k, 1, 2).astype(k_buf.dtype),
+            (0, 0, length, 0))
         v_buf = jax.lax.dynamic_update_slice(
-            v_buf, v.astype(v_buf.dtype), (0, length, 0, 0))
+            v_buf, jnp.swapaxes(v, 1, 2).astype(v_buf.dtype),
+            (0, 0, length, 0))
         new_len = length + s
         if s == 1:
             from .. import ops as _ops
@@ -387,7 +427,9 @@ class GPTAttention(Layer):
             # instead of a [s, cap] masked composite
             out = self._attend_fresh(q, k, v, b, s)
         else:
-            kf, vf = k_buf, v_buf
+            # multi-token continuation of a non-empty cache: rare, and
+            # the one place the buffer is read position-major
+            kf, vf = jnp.swapaxes(k_buf, 1, 2), jnp.swapaxes(v_buf, 1, 2)
             if cfg.num_kv_heads != cfg.num_heads:
                 rep = cfg.num_heads // cfg.num_kv_heads
                 kf = jnp.repeat(kf, rep, axis=2)
@@ -404,48 +446,51 @@ class GPTAttention(Layer):
 
     def forward_prefill(self, x):
         """Causal attention over a fresh prompt, also returning the
-        per-token k/v arrays so the caller can write them into a
-        StaticKVCache slot.  Returns ``(out, k [B,S,Hkv,D], v)``."""
+        prompt's k/v HEAD-MAJOR so the caller can write them into a
+        StaticKVCache slot.  Returns ``(out, k [B,Hkv,S,D], v)``.  The
+        flash path makes the same transpose of the same arrays for its
+        own strips, so inside one jitted prefill XLA keeps one."""
         b, s = x.shape[0], x.shape[1]
         q, k, v = self._qkv_arrays(x)
         out = self._attend_fresh(q, k, v, b, s)
-        return self._proj_out(out, b, s), k, v
+        return (self._proj_out(out, b, s), jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(v, 1, 2))
 
     def forward_decode(self, x, k_layer, v_layer, lengths,
                        k_scale=None, v_scale=None):
         """One decode step over a StaticKVCache layer: write each slot's
-        new k/v at its own ``lengths[b]`` (scatter), then run the fused
-        single-token attention masked to ``j <= lengths[b]``.  x is
-        [B, 1, hidden]; k_layer/v_layer [B, cap, Hkv, D]; lengths [B]
-        int32 (tokens already in the cache, EXCLUDING this one).
-        Returns ``(out, k_layer, v_layer)``.
+        new k/v at its own ``lengths[b]`` (a scatter of B × Hkv × D
+        elements — in place when the layer's buffer is donated), then
+        run the fused single-token attention masked to
+        ``j <= lengths[b]``.  x is [B, 1, hidden]; k_layer/v_layer
+        [B, Hkv, cap, D]; lengths [B] int32 (tokens already in the
+        cache, EXCLUDING this one).  Returns ``(out, k_layer, v_layer)``.
 
-        Quantized cache layer: ``k_scale``/``v_scale`` [B, cap, Hkv]
+        Quantized cache layer: ``k_scale``/``v_scale`` [B, Hkv, cap]
         f32 — the new token's k/v are quantized per head on write and
         the fused kernel dequantizes while streaming; returns
         ``(out, k_layer, v_layer, k_scale, v_scale)``."""
         b = x.shape[0]
-        cap = k_layer.shape[1]
+        cap = k_layer.shape[2]
         q, k, v = self._qkv_arrays(x)
         idx = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-        rows = jnp.arange(b)
         from .. import ops as _ops
         if k_scale is not None:
             from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
             mode = kv_quant_mode(k_layer.dtype)
             kq, ks = quantize_kv(k[:, 0], mode)         # [b,Hkv,D],[b,Hkv]
             vq, vs = quantize_kv(v[:, 0], mode)
-            k_layer = k_layer.at[rows, idx].set(kq)
-            v_layer = v_layer.at[rows, idx].set(vq)
-            k_scale = k_scale.at[rows, idx].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[rows, idx].set(vs.astype(v_scale.dtype))
+            k_layer = _ops.write_kv(k_layer, idx, kq)
+            v_layer = _ops.write_kv(v_layer, idx, vq)
+            k_scale = _ops.write_kv(k_scale, idx, ks)
+            v_scale = _ops.write_kv(v_scale, idx, vs)
             out = _ops.decode_attention(q[:, 0], k_layer, v_layer,
                                         idx + 1, k_scale, v_scale)
             out = out[:, None].astype(q.dtype)           # [b, 1, H, D]
             return (self._proj_out(out, b, 1), k_layer, v_layer,
                     k_scale, v_scale)
-        k_layer = k_layer.at[rows, idx].set(k[:, 0].astype(k_layer.dtype))
-        v_layer = v_layer.at[rows, idx].set(v[:, 0].astype(v_layer.dtype))
+        k_layer = _ops.write_kv(k_layer, idx, k[:, 0])
+        v_layer = _ops.write_kv(v_layer, idx, v[:, 0])
         out = _ops.decode_attention(
             q[:, 0].astype(k_layer.dtype), k_layer, v_layer, idx + 1)
         out = out[:, None].astype(q.dtype)               # [b, 1, H, D]
@@ -457,35 +502,35 @@ class GPTAttention(Layer):
         spec-decode verify/catch-up primitive: write the W new tokens'
         k/v at positions ``lengths[b]..lengths[b]+W-1`` (scatter), then
         run the fused window attention where query i sees
-        ``j <= lengths[b]+i``.  x is [B, W, hidden]; lengths [B] int32
+        ``j <= lengths[b]+i``.  x is [B, W, hidden]; k_layer/v_layer
+        [B, Hkv, cap, D] (scales [B, Hkv, cap]); lengths [B] int32
         EXCLUDING the window.  Returns ``(out, k_layer, v_layer)`` (+
         scale planes when quantized).  W=1 is numerically the
         forward_decode step."""
         b, w = x.shape[0], x.shape[1]
-        cap = k_layer.shape[1]
+        cap = k_layer.shape[2]
         q, k, v = self._qkv_arrays(x)
         lens = lengths.astype(jnp.int32)
         idx = jnp.minimum(
             lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :],
             cap - 1)                                     # [B, W]
-        rows = jnp.arange(b)[:, None]
         from .. import ops as _ops
         if k_scale is not None:
             from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
             mode = kv_quant_mode(k_layer.dtype)
             kq, ks = quantize_kv(k, mode)           # [b,w,Hkv,D],[b,w,Hkv]
             vq, vs = quantize_kv(v, mode)
-            k_layer = k_layer.at[rows, idx].set(kq)
-            v_layer = v_layer.at[rows, idx].set(vq)
-            k_scale = k_scale.at[rows, idx].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[rows, idx].set(vs.astype(v_scale.dtype))
+            k_layer = _ops.write_kv(k_layer, idx, kq)
+            v_layer = _ops.write_kv(v_layer, idx, vq)
+            k_scale = _ops.write_kv(k_scale, idx, ks)
+            v_scale = _ops.write_kv(v_scale, idx, vs)
             out = _ops.decode_attention_window(q, k_layer, v_layer, lens,
                                                k_scale, v_scale)
             out = out.astype(q.dtype)               # [b, w, H, D]
             return (self._proj_out(out, b, w), k_layer, v_layer,
                     k_scale, v_scale)
-        k_layer = k_layer.at[rows, idx].set(k.astype(k_layer.dtype))
-        v_layer = v_layer.at[rows, idx].set(v.astype(v_layer.dtype))
+        k_layer = _ops.write_kv(k_layer, idx, k)
+        v_layer = _ops.write_kv(v_layer, idx, v)
         out = _ops.decode_attention_window(
             q.astype(k_layer.dtype), k_layer, v_layer, lens)
         out = out.astype(q.dtype)                    # [b, w, H, D]
@@ -741,7 +786,7 @@ class GPTBlock(Layer):
 
     def forward_prefill(self, x):
         """Block forward that also surfaces this layer's k/v for the
-        StaticKVCache write. Returns (x, k [B,S,Hkv,D], v)."""
+        StaticKVCache write. Returns (x, k [B,Hkv,S,D], v)."""
         a, k, v = self.attn.forward_prefill(self.ln_1(x))
         x = x + a
         x = x + self.mlp(self.ln_2(x))
@@ -830,9 +875,8 @@ class GPTBlock(Layer):
         backend/shape allow, the mirrored XLA composite otherwise) —
         same signature and cache-write semantics as forward_decode, so
         the two paths are drop-in interchangeable per layer."""
-        from ..ops import decode_megakernel as _mk
+        from ..ops import decode_megakernel as _mk, write_kv
         arr = x.data if isinstance(x, Tensor) else x      # [B, 1, H]
-        b = arr.shape[0]
         xo, k_new, v_new = _mk.decode_layer_step(
             arr[:, 0], self._megakernel_weights(), k_layer, v_layer,
             lengths, k_scale, v_scale,
@@ -840,23 +884,18 @@ class GPTBlock(Layer):
             # enable_quantize() flips after construction
             quantize=self.attn.qkv_proj.quantize,
             eps=self.ln_1._epsilon)
-        cap = k_layer.shape[1]
+        cap = k_layer.shape[2]
         idx = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-        rows = jnp.arange(b)
         if k_scale is not None:
             from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
             mode = kv_quant_mode(k_layer.dtype)
             kq, ks = quantize_kv(k_new, mode)
             vq, vs = quantize_kv(v_new, mode)
-            k_layer = k_layer.at[rows, idx].set(kq)
-            v_layer = v_layer.at[rows, idx].set(vq)
-            k_scale = k_scale.at[rows, idx].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[rows, idx].set(vs.astype(v_scale.dtype))
-            return (Tensor(xo[:, None]), k_layer, v_layer, k_scale,
-                    v_scale)
-        k_layer = k_layer.at[rows, idx].set(k_new.astype(k_layer.dtype))
-        v_layer = v_layer.at[rows, idx].set(v_new.astype(v_layer.dtype))
-        return Tensor(xo[:, None]), k_layer, v_layer
+            return (Tensor(xo[:, None]), write_kv(k_layer, idx, kq),
+                    write_kv(v_layer, idx, vq), write_kv(k_scale, idx, ks),
+                    write_kv(v_scale, idx, vs))
+        return (Tensor(xo[:, None]), write_kv(k_layer, idx, k_new),
+                write_kv(v_layer, idx, v_new))
 
     def forward_decode_paged_fused(self, x, k_pool, v_pool, tables,
                                    lengths, k_scale=None, v_scale=None):
@@ -1127,13 +1166,16 @@ class GPTModel(Layer):
     # ---- serving path: static KV cache --------------------------------
     def init_kv_cache(self, batch_slots: int, capacity: Optional[int] = None,
                       dtype=None, kv_dtype=None) -> StaticKVCache:
-        """Allocate the fixed-shape serving cache
-        ``[layers, batch_slots, capacity, kv_heads, head_dim]`` (zeros;
-        per-slot lengths 0). ``capacity`` defaults to max_seq_len;
-        ``dtype`` defaults to the embedding dtype.  ``kv_dtype='int8'``
-        (or ``'fp8'``; default from ``PADDLE_TPU_KV_DTYPE``) stores
-        8-bit values plus per-(position, head) f32 scale planes — half
-        the decode HBM traffic, dequantized inside the fused kernel."""
+        """Allocate the fixed-shape serving cache: per layer one k and
+        one v buffer ``[batch_slots, kv_heads, capacity, head_dim]``
+        (head-major; zeros; per-slot lengths 0), every buffer its own
+        array so that each can be donated and updated in place.
+        ``capacity`` defaults to max_seq_len; ``dtype`` defaults to the
+        embedding dtype.  ``kv_dtype='int8'`` (or ``'fp8'``; default
+        from ``PADDLE_TPU_KV_DTYPE``) stores 8-bit values plus
+        per-(position, head) f32 scale planes
+        ``[batch_slots, kv_heads, capacity]`` — half the decode HBM
+        traffic, dequantized inside the fused kernel."""
         from ..ops.quantized_matmul import (kv_storage_dtype,
                                             resolve_kv_quant)
         cfg = self.cfg
@@ -1141,63 +1183,75 @@ class GPTModel(Layer):
         mode = resolve_kv_quant(kv_dtype)
         dt = kv_storage_dtype(mode) if mode else \
             (dtype or self.wte.weight.dtype)
-        shape = (cfg.num_layers, int(batch_slots), cap,
-                 cfg.num_kv_heads, cfg.head_dim)
-        scales = (jnp.zeros(shape[:-1], jnp.float32),
-                  jnp.zeros(shape[:-1], jnp.float32)) if mode \
+        shape = (int(batch_slots), cfg.num_kv_heads, cap, cfg.head_dim)
+
+        def per_layer(shp, dtype):
+            return tuple(jnp.zeros(shp, dtype)
+                         for _ in range(cfg.num_layers))
+
+        scales = (per_layer(shape[:-1], jnp.float32),
+                  per_layer(shape[:-1], jnp.float32)) if mode \
             else (None, None)
-        return StaticKVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt),
+        return StaticKVCache(per_layer(shape, dt), per_layer(shape, dt),
                              jnp.zeros((int(batch_slots),), jnp.int32),
                              *scales)
+
+    def _step_dense_layers(self, x, cache: StaticKVCache, lens, step,
+                           new_lengths):
+        """Run block method ``step`` (forward_decode, forward_verify,
+        forward_decode_fused) through the stack, handing layer ``i`` its
+        own cache buffers (and scale planes) and taking them back: no
+        layer is sliced out of, or written back into, anything larger.
+        Returns ``(hidden, cache)`` with the cache at ``new_lengths``."""
+        bufs = cache._buffer_lists()
+        for i, blk in enumerate(self.blocks):
+            x, *layer = getattr(blk, step)(
+                x, bufs[0][i], bufs[1][i], lens, *(b[i] for b in bufs[2:]))
+            for b, new in zip(bufs, layer):
+                b[i] = new
+        return self.ln_f(x), cache._with_buffers(bufs, new_lengths)
 
     def forward_prefill(self, input_ids, cache: StaticKVCache, slot,
                         prompt_len):
         """Prefill ONE slot: run the causal forward over a (possibly
         padded) prompt ``input_ids [1, s_bucket]``, write every layer's
-        k/v into ``cache`` at ``(layer, slot, 0)``, and set
-        ``lengths[slot] = prompt_len``.  Tokens past ``prompt_len`` are
-        bucket padding: their k/v land beyond the recorded length and
-        are masked out of every later decode step.  Returns
-        ``(hidden [1, s, H], cache)``."""
+        k/v ``[1, Hkv, s, D]`` into that layer's buffer at
+        ``(slot, 0, 0, 0)``, and set ``lengths[slot] = prompt_len``.
+        Tokens past ``prompt_len`` are bucket padding: their k/v land
+        beyond the recorded length and are masked out of every later
+        decode step.  Returns ``(hidden [1, s, H], cache)``."""
         ids = input_ids.data if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
         s = ids.shape[1]
         pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
         x = self.wte(Tensor(ids)) + self.wpe(pos)
         x = self.drop(x)
-        ks, vs = [], []
-        for blk in self.blocks:
-            x, k, v = blk.forward_prefill(x)
-            ks.append(k[0])
-            vs.append(v[0])
-        k_new = jnp.stack(ks)[:, None]        # [L, 1, s, Hkv, D]
-        v_new = jnp.stack(vs)[:, None]
         slot = jnp.asarray(slot, jnp.int32)
         zero = jnp.asarray(0, jnp.int32)
-        k_scale = v_scale = None
+
+        def put(buf, new):
+            return jax.lax.dynamic_update_slice(
+                buf, new.astype(buf.dtype),
+                (slot,) + (zero,) * (buf.ndim - 1))
+
+        bufs = cache._buffer_lists()
         if cache.quantized:
-            # attention ran on the full-precision k/v above (bitwise
-            # the dense prefill); only the STORED copy is quantized
             from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(cache.k.dtype)
-            k_new, k_s = quantize_kv(k_new, mode)   # [L,1,s,Hkv]
-            v_new, v_s = quantize_kv(v_new, mode)
-            k_scale = jax.lax.dynamic_update_slice(
-                cache.k_scale, k_s.astype(cache.k_scale.dtype),
-                (zero, slot, zero, zero))
-            v_scale = jax.lax.dynamic_update_slice(
-                cache.v_scale, v_s.astype(cache.v_scale.dtype),
-                (zero, slot, zero, zero))
-        cache_k = jax.lax.dynamic_update_slice(
-            cache.k, k_new.astype(cache.k.dtype),
-            (zero, slot, zero, zero, zero))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache.v, v_new.astype(cache.v.dtype),
-            (zero, slot, zero, zero, zero))
+            mode = kv_quant_mode(cache.dtype)
+        for i, blk in enumerate(self.blocks):
+            x, k, v = blk.forward_prefill(x)        # k/v [1, Hkv, s, D]
+            layer = (k, v)
+            if cache.quantized:
+                # attention ran on the full-precision k/v (bitwise the
+                # dense prefill); only the STORED copy is quantized
+                k, k_s = quantize_kv(k, mode)       # scales [1, Hkv, s]
+                v, v_s = quantize_kv(v, mode)
+                layer = (k, v, k_s, v_s)
+            for b, new in zip(bufs, layer):
+                b[i] = put(b[i], new)
         lengths = cache.lengths.at[slot].set(
             jnp.asarray(prompt_len, jnp.int32))
-        return self.ln_f(x), StaticKVCache(cache_k, cache_v, lengths,
-                                           k_scale, v_scale)
+        return self.ln_f(x), cache._with_buffers(bufs, lengths)
 
     def forward_decode(self, tokens, cache: StaticKVCache, active):
         """One decode step for every slot: append ``tokens [B]`` at each
@@ -1214,28 +1268,13 @@ class GPTModel(Layer):
         x = self.wte(Tensor(toks.reshape(b, 1))) + \
             self.wpe(Tensor(pos.reshape(b, 1)))
         x = self.drop(x)
-        cache_k, cache_v = cache.k, cache.v
-        k_sc, v_sc = cache.k_scale, cache.v_scale
-        fused = self._megakernel_active()
-        for i, blk in enumerate(self.blocks):
-            step = blk.forward_decode_fused if fused else \
-                blk.forward_decode
-            if k_sc is not None:
-                x, k_layer, v_layer, ks_l, vs_l = step(
-                    x, cache_k[i], cache_v[i], cache.lengths,
-                    k_sc[i], v_sc[i])
-                k_sc = k_sc.at[i].set(ks_l)
-                v_sc = v_sc.at[i].set(vs_l)
-            else:
-                x, k_layer, v_layer = step(
-                    x, cache_k[i], cache_v[i], cache.lengths)
-            cache_k = cache_k.at[i].set(k_layer)
-            cache_v = cache_v.at[i].set(v_layer)
         lengths = jnp.minimum(
             cache.lengths + jnp.asarray(active, jnp.int32),
             cache.capacity)
-        return self.ln_f(x), StaticKVCache(cache_k, cache_v, lengths,
-                                           k_sc, v_sc)
+        return self._step_dense_layers(
+            x, cache, cache.lengths,
+            "forward_decode_fused" if self._megakernel_active()
+            else "forward_decode", lengths)
 
     def forward_verify(self, tokens, cache: StaticKVCache):
         """Windowed multi-token step for every slot — the spec-decode
@@ -1260,21 +1299,8 @@ class GPTModel(Layer):
             cfg.max_seq_len - 1)
         x = self.wte(Tensor(toks)) + self.wpe(Tensor(pos))
         x = self.drop(x)
-        cache_k, cache_v = cache.k, cache.v
-        k_sc, v_sc = cache.k_scale, cache.v_scale
-        for i, blk in enumerate(self.blocks):
-            if k_sc is not None:
-                x, k_layer, v_layer, ks_l, vs_l = blk.forward_verify(
-                    x, cache_k[i], cache_v[i], lens, k_sc[i], v_sc[i])
-                k_sc = k_sc.at[i].set(ks_l)
-                v_sc = v_sc.at[i].set(vs_l)
-            else:
-                x, k_layer, v_layer = blk.forward_verify(
-                    x, cache_k[i], cache_v[i], lens)
-            cache_k = cache_k.at[i].set(k_layer)
-            cache_v = cache_v.at[i].set(v_layer)
-        return self.ln_f(x), StaticKVCache(cache_k, cache_v,
-                                           cache.lengths, k_sc, v_sc)
+        return self._step_dense_layers(x, cache, lens, "forward_verify",
+                                       cache.lengths)
 
     def forward_verify_paged(self, tokens, cache, tables, lengths):
         """Paged twin of forward_verify: W consecutive tokens per slot
@@ -1336,23 +1362,10 @@ class GPTModel(Layer):
             cfg.max_seq_len - 1)
         x = self.wte(Tensor(toks)) + self.wpe(Tensor(pos))
         x = self.drop(x)
-        cache_k, cache_v = cache.k, cache.v
-        k_sc, v_sc = cache.k_scale, cache.v_scale
-        for i, blk in enumerate(self.blocks):
-            if k_sc is not None:
-                x, k_layer, v_layer, ks_l, vs_l = blk.forward_verify(
-                    x, cache_k[i], cache_v[i], lens, k_sc[i], v_sc[i])
-                k_sc = k_sc.at[i].set(ks_l)
-                v_sc = v_sc.at[i].set(vs_l)
-            else:
-                x, k_layer, v_layer = blk.forward_verify(
-                    x, cache_k[i], cache_v[i], lens)
-            cache_k = cache_k.at[i].set(k_layer)
-            cache_v = cache_v.at[i].set(v_layer)
         new_len = jnp.minimum(lens + jnp.asarray(advance, jnp.int32),
                               cache.capacity)
-        return self.ln_f(x), StaticKVCache(cache_k, cache_v, new_len,
-                                           k_sc, v_sc)
+        return self._step_dense_layers(x, cache, lens, "forward_verify",
+                                       new_len)
 
     def forward_prefill_chunk_paged(self, tokens, cache, tables,
                                     lengths, advance):

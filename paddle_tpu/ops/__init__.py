@@ -14,7 +14,8 @@ from .decode_attention import (  # noqa: F401
     chunk_prefill_attention, decode_attention,
     decode_attention_available, decode_attention_window,
     paged_chunk_prefill_attention, paged_decode_attention,
-    paged_decode_attention_available, paged_decode_attention_window)
+    paged_decode_attention_available, paged_decode_attention_window,
+    write_kv)
 from .fused_cross_entropy import (  # noqa: F401
     fused_linear_cross_entropy, pick_vocab_block)
 from .quantized_matmul import (  # noqa: F401

@@ -2,8 +2,12 @@
 
 The serving hot loop (inference.engine) appends ONE token per slot per
 step and attends it against a preallocated, fixed-capacity cache
-``[batch_slots, max_seq, kv_heads, head_dim]`` whose per-slot occupancy
-is a ``lengths`` vector.  Decode attention is memory-bound — the whole
+layer ``[batch_slots, kv_heads, max_seq, head_dim]`` whose per-slot
+occupancy is a ``lengths`` vector.  The layer is HEAD-MAJOR, like the
+paged pool: each (slot, kv head)'s ``[max_seq, head_dim]`` strip is the
+layer's two minor dimensions, so the kernels' ``[B·Hkv, S, D]`` view is
+a reshape of the buffer as it lies in HBM (no transpose, no copy) and
+the composites' einsums read it as it is.  Decode attention is memory-bound — the whole
 cost is streaming the KV cache through the chip once — so the fusion
 target is different from training flash attention: there is no softmax
 tiling problem (one query row), the win is reading each K/V block from
@@ -23,7 +27,7 @@ ground truth for the kernel tests; both use f32 score accumulation.
 
 Quantized KV (``kv_dtype='int8'`` in the caches): both entry points
 accept optional per-(position, head) ``k_scale``/``v_scale`` arrays
-(``[B, S, Hkv]`` dense / ``[num_blocks, Hkv, block_size]`` paged, f32;
+(``[B, Hkv, S]`` dense / ``[num_blocks, Hkv, block_size]`` paged, f32;
 see ops.quantized_matmul.quantize_kv).  The kernels stream the int8
 values + f32 scales and dequantize INSIDE the block loop, so the bytes
 leaving HBM per decode step halve (decode attention is bandwidth-bound
@@ -61,7 +65,7 @@ from . import kernel_paths
 # mutable state we must read live)
 _fa = importlib.import_module(__package__ + ".flash_attention")
 
-__all__ = ["decode_attention", "decode_attention_available",
+__all__ = ["decode_attention", "decode_attention_available", "write_kv",
            "paged_decode_attention", "paged_decode_attention_available",
            "decode_attention_window", "paged_decode_attention_window",
            "chunk_prefill_attention", "paged_chunk_prefill_attention",
@@ -117,6 +121,35 @@ def _shard_over_tp(body, mesh, in_specs, out_spec, args):
     from ..distributed.mesh import shard_map
     return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=out_spec, check_vma=False)(*args)
+
+
+def write_kv(buf, idx, new):
+    """The write half of write-then-attend: store new tokens' k or v
+    (or their scales) into one head-major cache buffer, each slot at
+    its own position(s).
+
+    buf ``[B, Hkv, S, ...]`` (values carry a trailing D, scale planes
+    none); idx ``[B]`` with new ``[B, Hkv, ...]`` (one token a slot),
+    or idx ``[B, W]`` with new ``[B, W, Hkv, ...]`` (a window).  The
+    scatter names slot, head AND position of every row it writes, so
+    its only window dimension is the minor one and the buffer keeps its
+    layout: on a donated buffer the compiler writes the B·Hkv·W rows
+    where they lie.  (Indexing ``[rows, :, idx]`` instead makes the
+    head axis a window dimension, and the TPU compiler then relays the
+    whole buffer out position-major and back, two passes over the
+    layer a write.)  Slot and head indices are iotas, which GSPMD
+    partitions over 'dp' and 'tp' without a collective."""
+    b, hkv = buf.shape[:2]
+    window = idx.ndim == 2
+    if not window:
+        idx, new = idx[:, None], new[:, None]
+    new = jnp.moveaxis(new, 1, 2).astype(buf.dtype)     # [B, Hkv, W, ...]
+    return buf.at[jnp.arange(b)[:, None, None],
+                  jnp.arange(hkv)[None, :, None],
+                  idx[:, None, :]].set(
+        new, indices_are_sorted=True,
+        # window positions clamp at the capacity's edge and may repeat
+        unique_indices=not window)
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, *, block_k: int,
@@ -257,35 +290,33 @@ def _decode_gqa_q(q3, k3, v3, ks3, vs3, mask, block_k=512):
 
 
 def _dequant_cache(cache, scale, dtype):
-    """int8/f8 cache values [..., Hkv, D] × per-(position, head) scales
-    [..., Hkv] -> compute dtype."""
+    """int8/f8 cache values [..., D] × per-(position, head) scales
+    [...] -> compute dtype."""
     return (cache.astype(jnp.float32) *
             scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
 def _decode_composite(q, k_cache, v_cache, lengths, k_scale=None,
                       v_scale=None):
-    """XLA reference math. q [B, H, D]; caches [B, S, Hkv, D]; lengths
+    """XLA reference math. q [B, H, D]; caches [B, Hkv, S, D]; lengths
     [B] int32 (valid tokens per slot, INCLUDING the one just written).
-    With ``k_scale``/``v_scale`` ([B, S, Hkv] f32) the caches hold
+    With ``k_scale``/``v_scale`` ([B, Hkv, S] f32) the caches hold
     quantized values: dequantize up front, then the IDENTICAL dense
     math — bitwise the dense composite on the dequantized contents."""
     if k_scale is not None:
         k_cache = _dequant_cache(k_cache, k_scale, q.dtype)
         v_cache = _dequant_cache(v_cache, v_scale, q.dtype)
     b, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
     qg = q.reshape(b, hkv, g, d)
-    kh = jnp.swapaxes(k_cache, 1, 2)                 # [b, hkv, s, d]
-    vh = jnp.swapaxes(v_cache, 1, 2)
-    scores = jnp.einsum("bkgd,bksd->bkgs", qg, kh,
+    scores = jnp.einsum("bkgd,bksd->bkgs", qg, k_cache,
                         preferred_element_type=jnp.float32) / math.sqrt(d)
     valid = jnp.arange(s)[None, None, None, :] < \
         lengths.astype(jnp.int32)[:, None, None, None]
     scores = jnp.where(valid, scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgs,bksd->bkgd", probs, vh)
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v_cache)
     return out.reshape(b, h, d).astype(q.dtype)
 
 
@@ -294,17 +325,17 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
     """Single-token attention over a static, length-masked KV cache.
 
     q ``[B, H, D]`` — the new token's query per slot; k_cache/v_cache
-    ``[B, S, Hkv, D]`` — fixed-capacity cache AFTER the new token's k/v
-    were written; lengths ``[B]`` int32 — valid tokens per slot
-    (including the new one).  With a quantized cache, ``k_scale``/
-    ``v_scale`` carry the per-(position, head) f32 scales
-    (``[B, S, Hkv]``) and the cache values are int8 (fp8 rides the
+    ``[B, Hkv, S, D]`` — one head-major layer of the fixed-capacity
+    cache AFTER the new token's k/v were written; lengths ``[B]`` int32
+    — valid tokens per slot (including the new one).  With a quantized
+    cache, ``k_scale``/``v_scale`` carry the per-(position, head) f32
+    scales (``[B, Hkv, S]``) and the cache values are int8 (fp8 rides the
     composite).  Returns ``[B, H, D]``.  GQA is native (H % Hkv == 0,
     grouped ``h = hk·G + g`` like flash_attention).  Pallas fused
     kernel when shapes allow, XLA composite otherwise.
     """
     b, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     quantized = k_scale is not None
     supported = (s % 128 == 0 and (d % 128 == 0 or d == 64)
                  and h % hkv == 0
@@ -317,11 +348,11 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
     mesh, _tp = _tp_mesh(hkv, h)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        specs = [P(None, "tp", None), P(None, None, "tp", None),
-                 P(None, None, "tp", None), P(None)]
+        specs = [P(None, "tp", None), P(None, "tp", None, None),
+                 P(None, "tp", None, None), P(None)]
         args = [q, k_cache, v_cache, lengths]
         if quantized:
-            specs += [P(None, None, "tp"), P(None, None, "tp")]
+            specs += [P(None, "tp", None), P(None, "tp", None)]
             args += [k_scale, v_scale]
         return _shard_over_tp(_decode_kernel_path, mesh, specs,
                               P(None, "tp", None), args)
@@ -332,19 +363,20 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
 def _decode_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
                         v_scale=None):
     """The dense kernel dispatch AFTER the support gate — also the
-    shard_map body under tp (per-shard head ranges, same code)."""
+    shard_map body under tp (per-shard head ranges, same code).  The
+    head-major layer reshapes to the kernel's [B·Hkv, S, D] strips in
+    place: no whole-layer copy stands between the cache and the
+    kernel."""
     b, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     mask = (jnp.arange(s)[None, :] <
             lengths.astype(jnp.int32)[:, None]).astype(jnp.float32)
     q3 = q.reshape(b, hkv, h // hkv, d).reshape(b * hkv, h // hkv, d)
-    k3 = jnp.swapaxes(k_cache, 1, 2).reshape(b * hkv, s, d)
-    v3 = jnp.swapaxes(v_cache, 1, 2).reshape(b * hkv, s, d)
+    k3 = k_cache.reshape(b * hkv, s, d)
+    v3 = v_cache.reshape(b * hkv, s, d)
     if k_scale is not None:
-        ks3 = jnp.swapaxes(k_scale.astype(jnp.float32), 1, 2) \
-            .reshape(b * hkv, 1, s)
-        vs3 = jnp.swapaxes(v_scale.astype(jnp.float32), 1, 2) \
-            .reshape(b * hkv, 1, s)
+        ks3 = k_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
+        vs3 = v_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
         o3 = _decode_gqa_q(q3, k3, v3, ks3, vs3, mask.reshape(b, 1, s))
     else:
         o3 = _decode_gqa(q3, k3, v3, mask.reshape(b, 1, s))
@@ -496,15 +528,16 @@ def _paged_gqa(q3, k_pool, v_pool, tables, lengths, w,
 
 def _gather_pool(pool, tables):
     """Pool blocks [NB, Hkv, bs, ...] through tables [B, MB] -> the
-    dense per-slot layout [B, MB·bs, Hkv, ...]."""
-    g = jnp.swapaxes(pool[tables], 2, 3)        # [B, MB, bs, Hkv, ...]
-    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+    dense per-slot head-major layout [B, Hkv, MB·bs, ...]."""
+    g = jnp.swapaxes(pool[tables], 1, 2)        # [B, Hkv, MB, bs, ...]
+    return g.reshape(g.shape[:2] + (g.shape[2] * g.shape[3],) +
+                     g.shape[4:])
 
 
 def _paged_composite(q, k_pool, v_pool, tables, lengths, k_scale=None,
                      v_scale=None):
     """XLA reference math: gather each slot's blocks into the dense
-    ``[B, S, Hkv, D]`` layout (S = MB·bs) and reuse the dense composite.
+    ``[B, Hkv, S, D]`` layout (S = MB·bs) and reuse the dense composite.
     Bitwise-identical to the dense path on identical cache contents —
     the parity oracle tests/test_paged_kv.py leans on.  Quantized pools
     gather their ``[num_blocks, Hkv, bs]`` scale pools the same way."""
@@ -680,25 +713,23 @@ def _window_gqa(q3, k3, v3, mask, ks3=None, vs3=None, block_k=512):
 def _window_composite(q, k_cache, v_cache, lengths, k_scale=None,
                       v_scale=None):
     """XLA reference math for the window variant. q [B, W, H, D];
-    caches [B, S, Hkv, D]; lengths [B] int32 EXCLUDING the window
+    caches [B, Hkv, S, D]; lengths [B] int32 EXCLUDING the window
     (query i sees cache positions j <= lengths[b]+i)."""
     if k_scale is not None:
         k_cache = _dequant_cache(k_cache, k_scale, q.dtype)
         v_cache = _dequant_cache(v_cache, v_scale, q.dtype)
     b, w, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     g = h // hkv
     qg = q.reshape(b, w, hkv, g, d)
-    kh = jnp.swapaxes(k_cache, 1, 2)                 # [b, hkv, s, d]
-    vh = jnp.swapaxes(v_cache, 1, 2)
-    scores = jnp.einsum("bwkgd,bksd->bkwgs", qg, kh,
+    scores = jnp.einsum("bwkgd,bksd->bkwgs", qg, k_cache,
                         preferred_element_type=jnp.float32) / math.sqrt(d)
     limit = lengths.astype(jnp.int32)[:, None] + \
         jnp.arange(w, dtype=jnp.int32)[None, :] + 1        # [b, w]
     valid = jnp.arange(s)[None, None, :] < limit[:, :, None]
     scores = jnp.where(valid[:, None, :, None, :], scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkwgs,bksd->bwkgd", probs, vh)
+    out = jnp.einsum("bkwgs,bksd->bwkgd", probs, v_cache)
     return out.reshape(b, w, h, d).astype(q.dtype)
 
 
@@ -709,16 +740,16 @@ def decode_attention_window(q, k_cache, v_cache, lengths, k_scale=None,
 
     q ``[B, W, H, D]`` — W consecutive new tokens' queries per slot
     (W = draft K + 1 in the verify step); k_cache/v_cache
-    ``[B, S, Hkv, D]`` AFTER the window's k/v were written at positions
+    ``[B, Hkv, S, D]`` AFTER the window's k/v were written at positions
     ``lengths..lengths+W-1``; lengths ``[B]`` int32 — tokens cached
     BEFORE the window.  Query i attends ``j <= lengths[b]+i`` (itself
     included), so logits[i] is exactly what a sequential decode of
     token i would produce — that equivalence is the token-identity
     guarantee speculative decoding rests on.  ``W=1`` reduces to
     ``decode_attention`` with lengths+1.  Quantized caches pass their
-    ``[B, S, Hkv]`` f32 scale planes.  Returns ``[B, W, H, D]``."""
+    ``[B, Hkv, S]`` f32 scale planes.  Returns ``[B, W, H, D]``."""
     b, w, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     quantized = k_scale is not None
     supported = (s % 128 == 0 and (d % 128 == 0 or d == 64)
                  and h % hkv == 0
@@ -731,11 +762,11 @@ def decode_attention_window(q, k_cache, v_cache, lengths, k_scale=None,
     mesh, _tp = _tp_mesh(hkv, h)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        specs = [P(None, None, "tp", None), P(None, None, "tp", None),
-                 P(None, None, "tp", None), P(None)]
+        specs = [P(None, None, "tp", None), P(None, "tp", None, None),
+                 P(None, "tp", None, None), P(None)]
         args = [q, k_cache, v_cache, lengths]
         if quantized:
-            specs += [P(None, None, "tp"), P(None, None, "tp")]
+            specs += [P(None, "tp", None), P(None, "tp", None)]
             args += [k_scale, v_scale]
         return _shard_over_tp(_window_kernel_path, mesh, specs,
                               P(None, None, "tp", None), args)
@@ -748,7 +779,7 @@ def _window_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
     """The dense window-kernel dispatch AFTER the support gate — also
     the shard_map body under tp."""
     b, w, h, d = q.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
     limit = lengths.astype(jnp.int32)[:, None] + \
         jnp.arange(w, dtype=jnp.int32)[None, :] + 1
     mask = (jnp.arange(s)[None, None, :] <
@@ -756,14 +787,12 @@ def _window_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
     # rows grouped (w, g): [b, w, hkv, g, d] -> [b, hkv, w, g, d]
     q3 = q.reshape(b, w, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b * hkv, w * (h // hkv), d)
-    k3 = jnp.swapaxes(k_cache, 1, 2).reshape(b * hkv, s, d)
-    v3 = jnp.swapaxes(v_cache, 1, 2).reshape(b * hkv, s, d)
+    k3 = k_cache.reshape(b * hkv, s, d)
+    v3 = v_cache.reshape(b * hkv, s, d)
     ks3 = vs3 = None
     if k_scale is not None:
-        ks3 = jnp.swapaxes(k_scale.astype(jnp.float32), 1, 2) \
-            .reshape(b * hkv, 1, s)
-        vs3 = jnp.swapaxes(v_scale.astype(jnp.float32), 1, 2) \
-            .reshape(b * hkv, 1, s)
+        ks3 = k_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
+        vs3 = v_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
     o3 = _window_gqa(q3, k3, v3, mask, ks3, vs3)
     return o3.reshape(b, hkv, w, h // hkv, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, w, h, d)

@@ -203,9 +203,9 @@ def _mega_kernel(len_ref, x_ref, ln1w_ref, ln1b_ref, wqkv_ref, bqkv_ref,
     """One (phase, slot) program.  Scalar-prefetched ``len_ref`` carries
     per-slot lengths (EXCLUDING the new token, engine convention); for
     the paged layout the block table already acted inside the index
-    maps, so the body sees one strip per phase either way: [block_s,
-    Hkv, D] from the dense cache, head-major [Hkv, block_s, D] from
-    the block pool.
+    maps, so the body sees one head-major ``[Hkv, block_s, D]`` strip
+    per phase either way (the dense layer and the block pool share
+    that layout).
     ``ks_ref``/``vs_ref`` are the f32 scale strips of an int8 cache
     (aliases of k_ref/v_ref in the fp path, unread).
 
@@ -263,17 +263,10 @@ def _mega_kernel(len_ref, x_ref, ln1w_ref, ln1b_ref, wqkv_ref, bqkv_ref,
         valid = pos < idx                                 # [1, block_s]
         scores, vals = [], []
         for hk in range(hkv):
-            # dense strips are [block_s, Hkv, D]; pool blocks are
-            # head-major [Hkv, block_s, D] (scales likewise)
-            if paged:
-                kh, vh = k_ref[hk], v_ref[hk]             # [block_s, d]
-            else:
-                kh, vh = k_ref[:, hk, :], v_ref[:, hk, :]
+            kh, vh = k_ref[hk], v_ref[hk]                 # [block_s, d]
             if quantized:
-                ksh = ks_ref[hk] if paged else ks_ref[:, hk]
-                vsh = vs_ref[hk] if paged else vs_ref[:, hk]
-                kh = kh.astype(jnp.float32) * ksh[:, None]
-                vh = vh.astype(jnp.float32) * vsh[:, None]
+                kh = kh.astype(jnp.float32) * ks_ref[hk][:, None]
+                vh = vh.astype(jnp.float32) * vs_ref[hk][:, None]
             qg = q[hk * g:(hk + 1) * g].astype(kh.dtype)  # [g, d]
             scores.append(jax.lax.dot_general(
                 qg, kh, (((1,), (1,)), ((), ())),
@@ -398,7 +391,7 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
     (ln1_w, ln1_b, w_qkv, b_qkv, w_out, b_out,
      ln2_w, ln2_b, w_up, b_up, w_down, b_down) = w
     bsz, h = x.shape
-    hkv, d = k_src.shape[1 if paged else 2], k_src.shape[3]
+    hkv, d = k_src.shape[1], k_src.shape[3]
     kvd = hkv * d
     # q width is the qkv columns minus the two kv blocks; head count
     # from the cache head_dim
@@ -411,7 +404,7 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
                                                     qkv_cols, h)
     else:
         block_s, block_f, block_q, block_o = _pick_blocks(
-            k_src.shape[1], f, qkv_cols, h)
+            k_src.shape[2], f, qkv_cols, h)
     nq = qkv_cols // block_q
     no = h // block_o
     nf = f // block_f
@@ -443,8 +436,7 @@ def _run_mega(x, w, k_src, v_src, ks_src, vs_src, lengths, *, ns, cap,
     def _tile_down(p, b, *s):
         return (jnp.clip(p - nq - ns - no - 1, 0, nf - 1), 0)
 
-    kv_block = (None, hkv, block_s, d) if paged \
-        else (None, block_s, hkv, d)
+    kv_block = (None, hkv, block_s, d)
     sc_block = kv_block[:-1]
     if quantized:
         sc_spec = pl.BlockSpec(sc_block, sc_index_map)
@@ -590,25 +582,20 @@ def _composite(x, w, lengths, attend, *, quantize, eps, hkv, d):
 
 def _dense_attend(q, k_new, v_new, k_cache, v_cache, lengths, k_scale,
                   v_scale):
-    bsz = q.shape[0]
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     idx = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-    rows = jnp.arange(bsz)
     if k_scale is not None:
         from .quantized_matmul import kv_quant_mode, quantize_kv
         mode = kv_quant_mode(k_cache.dtype)
         kq, ks = quantize_kv(k_new, mode)
         vq, vs = quantize_kv(v_new, mode)
-        k_eff = k_cache.at[rows, idx].set(kq)
-        v_eff = v_cache.at[rows, idx].set(vq)
-        ks_eff = k_scale.at[rows, idx].set(ks.astype(k_scale.dtype))
-        vs_eff = v_scale.at[rows, idx].set(vs.astype(v_scale.dtype))
-        return _da.decode_attention(q, k_eff, v_eff, idx + 1, ks_eff,
-                                   vs_eff)
-    k_eff = k_cache.at[rows, idx].set(k_new.astype(k_cache.dtype))
-    v_eff = v_cache.at[rows, idx].set(v_new.astype(v_cache.dtype))
-    return _da.decode_attention(q.astype(k_cache.dtype), k_eff, v_eff,
-                               idx + 1).astype(q.dtype)
+        return _da.decode_attention(
+            q, _da.write_kv(k_cache, idx, kq),
+            _da.write_kv(v_cache, idx, vq), idx + 1,
+            _da.write_kv(k_scale, idx, ks), _da.write_kv(v_scale, idx, vs))
+    return _da.decode_attention(
+        q.astype(k_cache.dtype), _da.write_kv(k_cache, idx, k_new),
+        _da.write_kv(v_cache, idx, v_new), idx + 1).astype(q.dtype)
 
 
 def _paged_attend(q, k_new, v_new, k_pool, v_pool, tables, lengths,
@@ -686,12 +673,13 @@ def decode_layer_step(x, w, k_cache, v_cache, lengths, k_scale=None,
 
     x ``[B, H]`` — the residual stream at this layer for the new token;
     ``w`` — the 12 per-layer arrays in :data:`LAYER_WEIGHTS` order;
-    k_cache/v_cache ``[B, cap, Hkv, D]`` — the cache BEFORE the new
+    k_cache/v_cache ``[B, Hkv, cap, D]`` — one head-major layer of the
+    cache BEFORE the new
     token is written (the kernel folds the new token's k/v from VMEM;
     the CALLER scatters the returned ``k_new``/``v_new`` into the cache,
     exactly like the composed path does); lengths ``[B]`` int32 tokens
     already cached (excluding the new one).  int8 caches pass their
-    ``[B, cap, Hkv]`` f32 scale planes.  Returns
+    ``[B, Hkv, cap]`` f32 scale planes.  Returns
     ``(x_out [B, H], k_new [B, Hkv, D] f32, v_new)``.
 
     Pallas fused kernel when shapes/VMEM allow, XLA composite (the
@@ -700,9 +688,9 @@ def decode_layer_step(x, w, k_cache, v_cache, lengths, k_scale=None,
     projections then run the int8 qmm kernel with tiles from the
     unified tuning table.
     """
-    hkv, d = k_cache.shape[2], k_cache.shape[3]
+    hkv, d = k_cache.shape[1], k_cache.shape[3]
     quantized = k_scale is not None
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     block_s = _pick_blocks(cap, w[8].shape[1])[0]
     refusal = "shape not served by the kernel" if cap % block_s else \
         _fused_refusal(x, w, hkv, d, block_s, quantize, k_cache.dtype,
@@ -719,15 +707,15 @@ def decode_layer_step(x, w, k_cache, v_cache, lengths, k_scale=None,
     def kv_maps(nq):
         def kv_map(p, b, lens):
             in_kv = (p >= nq) & (p < nq + ns)
-            return (jnp.where(in_kv, b, 0),
-                    jnp.clip(p - nq, 0, ns - 1), 0, 0)
+            return (jnp.where(in_kv, b, 0), 0,
+                    jnp.clip(p - nq, 0, ns - 1), 0)
         return kv_map
 
     def sc_maps(nq):
         def sc_map(p, b, lens):
             in_kv = (p >= nq) & (p < nq + ns)
-            return (jnp.where(in_kv, b, 0),
-                    jnp.clip(p - nq, 0, ns - 1), 0)
+            return (jnp.where(in_kv, b, 0), 0,
+                    jnp.clip(p - nq, 0, ns - 1))
         return sc_map
 
     return _run_mega(x, w, k_cache, v_cache, k_scale, v_scale, lengths,

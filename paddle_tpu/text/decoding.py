@@ -92,8 +92,9 @@ def beam_search(step_fn: Callable, init_state, batch_size: int,
 
     def regather(a, parent):
         """Shuffle a state leaf by beam parents.  Leaves with a leading
-        [B*K] dim gather on axis 0; [L, B*K, ...] leaves (a
-        StaticKVCache's stacked-layer k/v) gather on axis 1.  (A leaf
+        [B*K] dim gather on axis 0 (a StaticKVCache's per-layer
+        [B*K, Hkv, S, D] buffers among them); [L, B*K, ...] leaves
+        (layer-stacked state) gather on axis 1.  (A leaf
         whose axis-0 length coincidentally equals B*K takes the axis-0
         branch — lay out such state batch-first.)"""
         if a.ndim >= 1 and a.shape[0] == B * K:

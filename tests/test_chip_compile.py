@@ -16,6 +16,7 @@ them (a compile for a described chip is written to the cache but cannot
 be read back without the chip).  Nothing runs: a compile that passes is
 not a chip run.
 """
+import contextlib
 import importlib
 import os
 
@@ -49,25 +50,32 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def persistent_cache_off():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="module")
 def compile_for_chip(one_chip):
     """compile_for_chip(fn, *(shape, dtype)) -> the compiled program's
     text, with the persistent cache off around the compile."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     def run(fn, *specs):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in specs]
-        was = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        try:
+        with persistent_cache_off():
             # under the suite's 'highest' matmul precision, which the
             # bf16/int8 kernels must not inherit (flash_attention.run_kernel)
             return jax.jit(fn).lower(*args).compile().as_text()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", was)
-            compilation_cache.reset_cache()
     return run
 
 
@@ -94,16 +102,16 @@ def test_flash_forward_and_backward(compile_for_chip, heads, d):
 @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
 def test_dense_decode(compile_for_chip, quantized):
     heads, d = 16, 128
-    cache = ((SLOTS, SEQ, heads, d), i8 if quantized else bf16)
+    cache = ((SLOTS, heads, SEQ, d), i8 if quantized else bf16)
     specs = [((SLOTS, heads, d), bf16), cache, cache, ((SLOTS,), i32)]
     if quantized:
-        specs += [((SLOTS, SEQ, heads), f32)] * 2
+        specs += [((SLOTS, heads, SEQ), f32)] * 2
     assert_kernel(compile_for_chip(da._decode_kernel_path, *specs))
 
 
 def test_dense_window(compile_for_chip):
     heads, d, w = 16, 128, 8
-    cache = ((SLOTS, SEQ, heads, d), bf16)
+    cache = ((SLOTS, heads, SEQ, d), bf16)
     assert_kernel(compile_for_chip(
         da._window_kernel_path, ((SLOTS, w, heads, d), bf16), cache, cache,
         ((SLOTS,), i32)))
@@ -170,9 +178,66 @@ def test_decode_megakernel_compiles(compile_for_chip, monkeypatch):
     weights = [vec(h), vec(h), ((h, 3 * h), bf16), vec(3 * h),
                ((h, h), bf16), vec(h), vec(h), vec(h), ((h, f), bf16),
                vec(f), ((f, h), bf16), vec(h)]
-    cache = ((SLOTS, SEQ, heads, d), bf16)
+    cache = ((SLOTS, heads, SEQ, d), bf16)
     compile_for_chip(run, ((SLOTS, h), bf16), *weights, cache, cache,
                      ((SLOTS,), i32))
+
+
+# ---------------------------------------------------------------------------
+# the whole decode step: the cache is written where it lies
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_decode_step_never_copies_the_cache(one_chip, monkeypatch,
+                                            kv_dtype):
+    """The engine's jitted decode step, 2 layers at gpt3-1.3b widths over
+    8 slots x 2048 with the cache donated: the attention kernel is in the
+    program, every cache buffer comes out in the memory it went in by,
+    and nothing the size of a layer stands beside them, neither as a
+    temporary nor as a ``copy``.  (At the stacked seq-major layout of
+    PR 23 this program needed 194.5 MiB of temporaries in bf16 and
+    101.8 MiB in int8: a layer sliced out, transposed for the kernel and
+    written back.)"""
+    import re
+    from paddle_tpu.inference import InferenceEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    # the CPU process's dispatch would take the composite: the compile
+    # is for the chip, so say so here and not through an option
+    monkeypatch.setattr(da, "decode_attention_available", lambda: True)
+    heads, d, layers = 16, 128, 2
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=50304, hidden_size=heads * d, num_layers=layers,
+        num_heads=heads, ffn_hidden_size=4 * heads * d, max_seq_len=SEQ))
+    model.eval()
+    eng = InferenceEngine(model, batch_slots=SLOTS, max_seq_len=SEQ,
+                          cache_dtype=bf16, kv_dtype=kv_dtype,
+                          prefill_buckets=[128])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                    sharding=one_chip)
+
+    slots_i32 = struct(jnp.zeros(SLOTS, i32))
+    slots_f32 = struct(jnp.zeros(SLOTS, f32))
+    args = ({k: struct(v, bf16) for k, v in eng.params.items()},
+            jax.tree_util.tree_map(struct, eng.cache),
+            slots_i32, slots_i32, struct(eng._key), slots_f32, slots_f32)
+    with persistent_cache_off():
+        compiled = jax.jit(eng._decode_fn, donate_argnums=(1,)) \
+            .lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= layers
+    # k and v per layer (and their scale planes), and the lengths
+    leaves = jax.tree_util.tree_leaves(eng.cache)
+    assert len(leaves) == layers * (4 if kv_dtype else 2) + 1
+    aliased = re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)",
+                         text.split("\n", 1)[0])
+    assert len(aliased) == len(leaves), text.split("\n", 1)[0][:400]
+    layer_k = eng.cache.k[0]
+    layer_bytes = layer_k.size * layer_k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    shape = ",".join(str(n) for n in layer_k.shape)
+    assert not re.search(r"\[%s\]\S* copy\(" % shape, text), \
+        "a whole cache layer is copied"
 
 
 # ---------------------------------------------------------------------------

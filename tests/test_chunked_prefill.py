@@ -88,8 +88,8 @@ def test_chunk_window_from_empty_matches_sequential():
     — must equal a sequential chain of single-token decode calls."""
     rng = np.random.RandomState(0)
     B, S, H, Hkv, D, W = 4, 16, 4, 2, 8, 4
-    k = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
+    k = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([0, 1, 3, 4], np.int32))
     out = da.decode_attention_window(q, k, v, lens)
@@ -110,17 +110,17 @@ def test_chunk_window_kernel_interpret_edges(quantized):
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([0, 1, 7, 8], np.int32))
     if quantized:
-        k = jnp.asarray(rng.randint(-127, 128, (B, S, Hkv, D))
+        k = jnp.asarray(rng.randint(-127, 128, (B, Hkv, S, D))
                         .astype(np.int8))
-        v = jnp.asarray(rng.randint(-127, 128, (B, S, Hkv, D))
+        v = jnp.asarray(rng.randint(-127, 128, (B, Hkv, S, D))
                         .astype(np.int8))
-        ks = jnp.asarray(rng.rand(B, S, Hkv).astype(np.float32) * 0.02)
-        vs = jnp.asarray(rng.rand(B, S, Hkv).astype(np.float32) * 0.02)
+        ks = jnp.asarray(rng.rand(B, Hkv, S).astype(np.float32) * 0.02)
+        vs = jnp.asarray(rng.rand(B, Hkv, S).astype(np.float32) * 0.02)
         args = (q, k, v, lens, ks, vs)
         ref = da._window_composite(q, k, v, lens, ks, vs)
     else:
-        k = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
-        v = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
+        k = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
+        v = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
         args = (q, k, v, lens)
         ref = da._window_composite(q, k, v, lens)
     da.set_interpret_mode(True)
@@ -179,10 +179,11 @@ def test_prefill_chunk_matches_monolithic_prefill(target):
     for b in range(2):
         np.testing.assert_allclose(done_logits[b], logits_mono[b],
                                    rtol=2e-5, atol=2e-5)
-        np.testing.assert_allclose(
-            np.asarray(chunked.k).astype(np.float32)[:, b, :lens[b]],
-            np.asarray(mono.k).astype(np.float32)[:, b, :lens[b]],
-            rtol=1e-5, atol=1e-5)
+        for ck, mk_ in zip(chunked.k, mono.k):            # per layer
+            np.testing.assert_allclose(
+                np.asarray(ck).astype(np.float32)[b, :, :lens[b]],
+                np.asarray(mk_).astype(np.float32)[b, :, :lens[b]],
+                rtol=1e-5, atol=1e-5)
 
 
 # ---- engine level: the token-identity matrix ----------------------------

@@ -71,9 +71,9 @@ def test_kernel_matches_composite(paged, quantized, hkv):
             args = (x, w, kp, vp, tables, lengths)
         fn = mk.decode_layer_step_paged
     else:
-        k = jnp.asarray(rng.randn(B, cap, hkv, d).astype(np.float32)
+        k = jnp.asarray(rng.randn(B, hkv, cap, d).astype(np.float32)
                         * 0.1)
-        v = jnp.asarray(rng.randn(B, cap, hkv, d).astype(np.float32)
+        v = jnp.asarray(rng.randn(B, hkv, cap, d).astype(np.float32)
                         * 0.1)
         if quantized:
             kq, ks = quantize_kv(k)
@@ -107,8 +107,8 @@ def test_kernel_gate_falls_back_not_crashes():
     h = 16
     w = _weights(rng, h, hkv, d, f)
     x = jnp.asarray(rng.randn(B, h).astype(np.float32))
-    k = jnp.asarray(rng.randn(B, 32, hkv, d).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, 32, hkv, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(B, hkv, 32, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(B, hkv, 32, d).astype(np.float32))
     mk.set_interpret_mode(True)
     try:
         xo, kn, vn = mk.decode_layer_step(

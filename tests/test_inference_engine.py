@@ -73,8 +73,8 @@ def test_decode_attention_kernel_matches_composite(hkv):
         rng = np.random.RandomState(0)
         b, s, h, d = 3, 256, 4, 64
         q = jnp.asarray(rng.randn(b, h, d).astype(np.float32) * 0.3)
-        k = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32) * 0.3)
-        v = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32) * 0.3)
+        k = jnp.asarray(rng.randn(b, hkv, s, d).astype(np.float32) * 0.3)
+        v = jnp.asarray(rng.randn(b, hkv, s, d).astype(np.float32) * 0.3)
         lengths = jnp.asarray([1, 100, 256], jnp.int32)
         out = da.decode_attention(q, k, v, lengths)
         ref = da._decode_composite(q, k, v, lengths)
@@ -89,12 +89,12 @@ def test_decode_attention_length_masks_tail():
     rng = np.random.RandomState(1)
     b, s, hkv, d = 2, 128, 2, 16
     q = jnp.asarray(rng.randn(b, 4, d).astype(np.float32))
-    k = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32))
-    v = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(b, hkv, s, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(b, hkv, s, d).astype(np.float32))
     lengths = jnp.asarray([5, 9], jnp.int32)
     base = np.asarray(da._decode_composite(q, k, v, lengths))
-    poisoned_k = k.at[:, 10:].set(1e3)
-    poisoned_v = v.at[:, 10:].set(-1e3)
+    poisoned_k = k.at[:, :, 10:].set(1e3)
+    poisoned_v = v.at[:, :, 10:].set(-1e3)
     out = np.asarray(da._decode_composite(q, poisoned_k, poisoned_v,
                                           lengths))
     np.testing.assert_allclose(out, base, rtol=1e-6, atol=1e-6)
@@ -160,7 +160,7 @@ def test_legacy_cache_fresh_matches_no_cache():
     np.testing.assert_allclose(out_plain.numpy(), out_cached.numpy(),
                                rtol=1e-5, atol=1e-5)
     k_buf, v_buf, length = triple
-    assert k_buf.shape == (2, 64, 4, 16) and length == 5
+    assert k_buf.shape == (2, 4, 64, 16) and length == 5
 
 
 def test_legacy_cache_decode_matches_full():
@@ -176,7 +176,7 @@ def test_legacy_cache_decode_matches_full():
         out, cache = attn(paddle.to_tensor(x[:, t:t + 1]), cache=cache)
         np.testing.assert_allclose(out.numpy()[:, 0], full[:, t],
                                    rtol=1e-4, atol=1e-4)
-    assert cache[0].shape == (2, 64, 4, 16)   # capacity never grew
+    assert cache[0].shape == (2, 4, 64, 16)   # capacity never grew
 
 
 def test_legacy_cache_adopts_dense_past():
@@ -218,8 +218,8 @@ def test_legacy_cache_decode_is_recompile_free():
     step = jax.jit(lambda xt, cache: attn(paddle.Tensor(xt),
                                           cache=cache))
     x0 = jnp.asarray(rng.randn(1, 1, 64).astype(np.float32))
-    out, cache = step(x0, (jnp.zeros((1, 64, 4, 16), jnp.float32),
-                           jnp.zeros((1, 64, 4, 16), jnp.float32),
+    out, cache = step(x0, (jnp.zeros((1, 4, 64, 16), jnp.float32),
+                           jnp.zeros((1, 4, 64, 16), jnp.float32),
                            jnp.asarray(0, jnp.int32)))
     snap = compile_counter.snapshot()
     for _ in range(6):

@@ -81,16 +81,16 @@ def assert_greedy_rollout(model, prompt, gen):
 # ---- paged decode attention op ----------------------------------------
 
 def _pool_from_dense(k_dense, tables, bs):
-    """Scatter a dense [B, S, Hkv, D] cache into a pool laid out by
-    `tables` (so a gather through the table reconstructs it exactly)."""
-    b, s, hkv, d = k_dense.shape
+    """Scatter a dense head-major [B, Hkv, S, D] cache into a pool laid
+    out by `tables` (so a gather through the table reconstructs it
+    exactly)."""
+    b, hkv, s, d = k_dense.shape
     mb = s // bs
     nb = int(tables.max()) + 1
     pool = np.zeros((nb, hkv, bs, d), k_dense.dtype)   # head-major
     for bi in range(b):
         for j in range(mb):
-            pool[tables[bi, j]] = \
-                k_dense[bi, j * bs:(j + 1) * bs].swapaxes(0, 1)
+            pool[tables[bi, j]] = k_dense[bi, :, j * bs:(j + 1) * bs]
     return pool
 
 
@@ -101,8 +101,8 @@ def test_paged_composite_bitwise_matches_dense_composite():
     rng = np.random.RandomState(0)
     b, s, h, hkv, d, bs = 3, 64, 4, 2, 16, 16
     q = jnp.asarray(rng.randn(b, h, d).astype(np.float32) * 0.3)
-    k = rng.randn(b, s, hkv, d).astype(np.float32) * 0.3
-    v = rng.randn(b, s, hkv, d).astype(np.float32) * 0.3
+    k = rng.randn(b, hkv, s, d).astype(np.float32) * 0.3
+    v = rng.randn(b, hkv, s, d).astype(np.float32) * 0.3
     # distinct shuffled blocks per slot, as a real allocator would hand out
     tables = (1 + rng.permutation(b * (s // bs))).reshape(b, s // bs) \
         .astype(np.int32)

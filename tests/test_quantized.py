@@ -222,7 +222,7 @@ def test_int8_kv_decode_tracks_dense_static(model, kv_heads):
     scale = float(np.max(np.abs(full)))
 
     cache = m.init_kv_cache(batch_slots=2, kv_dtype="int8")
-    assert cache.quantized and cache.k.dtype == jnp.int8
+    assert cache.quantized and cache.dtype == jnp.int8
     logits, cache = m.prefill(jnp.asarray(ids[:, :7]), cache, 0, 7)
     # prefill attends the fp k/v (only the stored copy is quantized):
     # bitwise the dense prefill
@@ -284,8 +284,8 @@ def test_paged_quant_op_parity_with_dense_quant_op():
     rng = np.random.RandomState(4)
     b, s, h, hkv, d, bs = 2, 256, 4, 2, 64, 128
     q = jnp.asarray(rng.randn(b, h, d).astype(np.float32) * 0.3)
-    k = rng.randn(b, s, hkv, d).astype(np.float32) * 0.3
-    v = rng.randn(b, s, hkv, d).astype(np.float32) * 0.3
+    k = rng.randn(b, hkv, s, d).astype(np.float32) * 0.3   # head-major
+    v = rng.randn(b, hkv, s, d).astype(np.float32) * 0.3
     lengths = jnp.asarray([37, 256], jnp.int32)
     qk, sk = qm.quantize_kv(jnp.asarray(k))
     qv, sv = qm.quantize_kv(jnp.asarray(v))
@@ -302,10 +302,10 @@ def test_paged_quant_op_parity_with_dense_quant_op():
     for bi in range(b):
         for j in range(mb):
             rows = slice(j * bs, (j + 1) * bs)
-            kp[tables[bi, j]] = np.asarray(qk)[bi, rows].swapaxes(0, 1)
-            vp[tables[bi, j]] = np.asarray(qv)[bi, rows].swapaxes(0, 1)
-            ksp[tables[bi, j]] = np.asarray(sk)[bi, rows].swapaxes(0, 1)
-            vsp[tables[bi, j]] = np.asarray(sv)[bi, rows].swapaxes(0, 1)
+            kp[tables[bi, j]] = np.asarray(qk)[bi, :, rows]
+            vp[tables[bi, j]] = np.asarray(qv)[bi, :, rows]
+            ksp[tables[bi, j]] = np.asarray(sk)[bi, :, rows]
+            vsp[tables[bi, j]] = np.asarray(sv)[bi, :, rows]
     paged = da._paged_composite(q, jnp.asarray(kp), jnp.asarray(vp),
                                 jnp.asarray(tables), lengths,
                                 jnp.asarray(ksp), jnp.asarray(vsp))

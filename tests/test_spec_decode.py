@@ -76,8 +76,8 @@ def test_window_attention_matches_sequential():
     spec-decode verify correctness argument."""
     rng = np.random.RandomState(0)
     B, S, H, Hkv, D, W = 2, 16, 4, 2, 8, 3
-    k = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
+    k = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([5, 9], np.int32))
     out = da.decode_attention_window(q, k, v, lens)
@@ -94,8 +94,8 @@ def test_paged_window_matches_dense_window():
     extended to W > 1)."""
     rng = np.random.RandomState(1)
     B, S, H, Hkv, D, W, bs = 2, 16, 4, 2, 8, 3, 8
-    k = rng.randn(B, S, Hkv, D).astype(np.float32)
-    v = rng.randn(B, S, Hkv, D).astype(np.float32)
+    k = rng.randn(B, Hkv, S, D).astype(np.float32)     # head-major
+    v = rng.randn(B, Hkv, S, D).astype(np.float32)
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([4, 8], np.int32))
     tables = np.array([[1, 2], [3, 4]], np.int32)
@@ -103,10 +103,8 @@ def test_paged_window_matches_dense_window():
     pool_v = np.zeros_like(pool_k)
     for b in range(B):
         for j in range(S // bs):
-            pool_k[tables[b, j]] = \
-                k[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
-            pool_v[tables[b, j]] = \
-                v[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
+            pool_k[tables[b, j]] = k[b, :, j * bs:(j + 1) * bs]
+            pool_v[tables[b, j]] = v[b, :, j * bs:(j + 1) * bs]
     dense = da.decode_attention_window(q, jnp.asarray(k), jnp.asarray(v),
                                        lens)
     paged = da.paged_decode_attention_window(
@@ -125,16 +123,16 @@ def test_window_kernel_interpret_vs_composite(quantized):
     q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
     lens = jnp.asarray(np.array([37, 90], np.int32))
     if quantized:
-        k = jnp.asarray(rng.randint(-127, 128, (B, S, Hkv, D))
+        k = jnp.asarray(rng.randint(-127, 128, (B, Hkv, S, D))
                         .astype(np.int8))
-        v = jnp.asarray(rng.randint(-127, 128, (B, S, Hkv, D))
+        v = jnp.asarray(rng.randint(-127, 128, (B, Hkv, S, D))
                         .astype(np.int8))
-        ks = jnp.asarray(rng.rand(B, S, Hkv).astype(np.float32) * 0.02)
-        vs = jnp.asarray(rng.rand(B, S, Hkv).astype(np.float32) * 0.02)
+        ks = jnp.asarray(rng.rand(B, Hkv, S).astype(np.float32) * 0.02)
+        vs = jnp.asarray(rng.rand(B, Hkv, S).astype(np.float32) * 0.02)
         args = (q, k, v, lens, ks, vs)
     else:
-        k = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
-        v = jnp.asarray(rng.randn(B, S, Hkv, D).astype(np.float32))
+        k = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
+        v = jnp.asarray(rng.randn(B, Hkv, S, D).astype(np.float32))
         args = (q, k, v, lens)
     ref = da._window_composite(q, args[1], args[2], lens,
                                *(args[4:] if quantized else ()))
@@ -210,10 +208,11 @@ def test_verify_step_matches_sequential(target, kv_dtype):
     for i in range(3):
         np.testing.assert_allclose(win_logits[:, i], seq_logits[i],
                                    rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(win_cache.k).astype(np.float32)[:, :, :8],
-        np.asarray(seq_cache.k).astype(np.float32)[:, :, :8],
-        rtol=1e-5, atol=1e-5)
+    for win_k, seq_k in zip(win_cache.k, seq_cache.k):    # per layer
+        np.testing.assert_allclose(
+            np.asarray(win_k).astype(np.float32)[:, :, :8],
+            np.asarray(seq_k).astype(np.float32)[:, :, :8],
+            rtol=1e-5, atol=1e-5)
 
 
 # ---- engine level: the token-identity matrix ----------------------------
