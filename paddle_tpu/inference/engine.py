@@ -406,6 +406,9 @@ class InferenceEngine:
             "prefill_stall_ms": 0.0,
             "compile_ms_cold": 0.0, "prefills": 0, "prefill_tokens": 0,
             "decode_steps": 0, "tokens_generated": 0,
+            # decode ticks in which some slot had temperature > 0: the
+            # sampler's own predicate (a retired slot's temps is 0)
+            "sampled_ticks": 0,
             "occupancy_sum": 0.0, "block_occupancy_sum": 0.0,
             "preemptions": 0, "memory_capped_retirements": 0,
             "deadline_retirements": 0, "drain_forced_retirements": 0,
@@ -696,28 +699,46 @@ class InferenceEngine:
                                              suffix_len)
         return logits, cache, _moe.fold_expert_stats(b)
 
-    def _sample_from_logits(self, logits, key, temps, top_ps):
-        """Greedy when temps<=0, else temperature + (static) top-k +
-        (per-slot) top-p sampling. logits [N, V] f32."""
+    def _warp_sorted(self, logits, temps, top_ps):
+        """The sampler's warp (temperature, static top-k, per-slot
+        top-p), in sorted space: (s_logits, sort_idx), both [N, V], the
+        warped logits in descending order (-1e30 where cut) and the
+        token each came from.  The sorted logits are the sort's own
+        keys, so nothing is gathered over the vocabulary."""
         logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         v = logits.shape[-1]
         scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
         if self.top_k and self.top_k < v:
             kth = jax.lax.top_k(scaled, self.top_k)[0][:, -1:]
             scaled = jnp.where(scaled < kth, -1e30, scaled)
+        neg, sort_idx = jax.lax.sort(
+            (-scaled, jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)),
+            dimension=1, num_keys=1, is_stable=True)
+        s_logits = -neg
         # top-p in sorted space: keep tokens whose PRECEDING cumulative
         # mass is < p (the first token always survives)
-        sort_idx = jnp.argsort(-scaled, axis=-1)
-        s_logits = jnp.take_along_axis(scaled, sort_idx, axis=-1)
         probs = jax.nn.softmax(s_logits, axis=-1)
         csum = jnp.cumsum(probs, axis=-1)
         s_logits = jnp.where(csum - probs < top_ps[:, None],
                              s_logits, -1e30)
-        choice = jax.random.categorical(key, s_logits, axis=-1)
-        sampled = jnp.take_along_axis(
-            sort_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
-        return jnp.where(temps > 0, sampled, greedy)
+        return s_logits, sort_idx
+
+    def _sample_from_logits(self, logits, key, temps, top_ps):
+        """Greedy when temps<=0, else temperature + (static) top-k +
+        (per-slot) top-p sampling. logits [N, V] f32.  The
+        vocabulary-wide work runs only when a row of this call samples:
+        the program branches on its own ``temps`` operand."""
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def sampling():
+            s_logits, sort_idx = self._warp_sorted(logits, temps, top_ps)
+            choice = jax.random.categorical(key, s_logits, axis=-1)
+            sampled = jnp.take_along_axis(
+                sort_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+            return jnp.where(temps > 0, sampled, greedy)
+
+        return jax.lax.cond(jnp.any(temps > 0), sampling, lambda: greedy)
 
     def _decode_fn(self, params, cache, tokens, active, key, temps,
                    top_ps):
@@ -1775,6 +1796,7 @@ class InferenceEngine:
         n_active = int(active_np.sum())
         self._m_active.set(n_active)
         tick_t0 = self._tracer.now_us() if self._tracer.active else 0.0
+        sampled = int((self._temps > 0).any())
         if self.kv_layout == "paged":
             nxt, self._key, cache, moe = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_paged_jit,
@@ -1803,13 +1825,15 @@ class InferenceEngine:
         async_dispatch.record_host_sync()
         self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
         self._timings["decode_steps"] += 1
+        self._timings["sampled_ticks"] += sampled
         self._m_ticks.inc()
         self._m_tokens.inc(n_active)
         if self._tracer.active:
             now_us = self._tracer.now_us()
-            self._tracer.complete("decode_tick", tick_t0,
-                                  now_us - tick_t0, cat="serve",
-                                  args={"active": n_active})
+            self._tracer.complete(
+                "decode_tick", tick_t0, now_us - tick_t0, cat="serve",
+                args={"active": n_active,
+                      "sampled_ticks": self._timings["sampled_ticks"]})
         commit_now = time.perf_counter()
         for slot, req in enumerate(self._slots):
             # prefilling rows were inactive this step: their sampled
@@ -1880,6 +1904,7 @@ class InferenceEngine:
         async_dispatch.record_host_sync()
         self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
         self._timings["decode_steps"] += 1
+        self._timings["sampled_ticks"] += int((self._temps > 0).any())
         self._timings["spec_ticks"] += 1
         self._timings["spec_slot_ticks"] += int(active_np.sum())
         produced = 0

@@ -156,22 +156,13 @@ class SpecDecoder:
         sampled from, not the raw softmaxes.  logits [N, V] f32;
         returns [N, V] probs (rows with temp<=0 are still valid — they
         are simply never read, greedy rows use argmax)."""
-        eng = self.engine
-        logits = logits.astype(jnp.float32)
-        v = logits.shape[-1]
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        if eng.top_k and eng.top_k < v:
-            kth = jax.lax.top_k(scaled, eng.top_k)[0][:, -1:]
-            scaled = jnp.where(scaled < kth, -1e30, scaled)
-        sort_idx = jnp.argsort(-scaled, axis=-1)
-        s_logits = jnp.take_along_axis(scaled, sort_idx, axis=-1)
-        probs = jax.nn.softmax(s_logits, axis=-1)
-        csum = jnp.cumsum(probs, axis=-1)
-        s_logits = jnp.where(csum - probs < top_ps[:, None],
-                             s_logits, -1e30)
+        s_logits, sort_idx = self.engine._warp_sorted(logits, temps,
+                                                      top_ps)
         s_probs = jax.nn.softmax(s_logits, axis=-1)
-        inv = jnp.argsort(sort_idx, axis=-1)   # unsort to token order
-        return jnp.take_along_axis(s_probs, inv, axis=-1)
+        # unsort to token order: sort_idx is a permutation, so sorting
+        # by it carries each probability home with no gather
+        return jax.lax.sort((sort_idx, s_probs), dimension=1,
+                            num_keys=1)[1]
 
     def _propose_from(self, logits, key, temps, top_ps):
         """One proposal from the draft's logit row: greedy slots take

@@ -30,7 +30,7 @@ fa = importlib.import_module("paddle_tpu.ops.flash_attention")
 mk = importlib.import_module("paddle_tpu.ops.decode_megakernel")
 qm = importlib.import_module("paddle_tpu.ops.quantized_matmul")
 
-SLOTS, SEQ = 8, 2048
+SLOTS, SEQ, VOCAB = 8, 2048, 50304
 WIDTHS = [(12, 64), (16, 128)]          # (heads, head_dim): 125m, 1.3b
 
 
@@ -186,6 +186,44 @@ def test_decode_megakernel_compiles(compile_for_chip, monkeypatch):
 # ---------------------------------------------------------------------------
 # the whole decode step: the cache is written where it lies
 # ---------------------------------------------------------------------------
+def _decode_engine(monkeypatch, kv_dtype=None):
+    """An engine of 2 layers at gpt3-1.3b widths over 8 slots x 2048,
+    whose decode step the tests below compile for the chip."""
+    from paddle_tpu.inference import InferenceEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    # the CPU process's dispatch would take the composite: the compile
+    # is for the chip, so say so here and not through an option
+    monkeypatch.setattr(da, "decode_attention_available", lambda: True)
+    heads, d = 16, 128
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=VOCAB, hidden_size=heads * d, num_layers=2,
+        num_heads=heads, ffn_hidden_size=4 * heads * d, max_seq_len=SEQ))
+    model.eval()
+    return InferenceEngine(model, batch_slots=SLOTS, max_seq_len=SEQ,
+                           cache_dtype=bf16, kv_dtype=kv_dtype,
+                           prefill_buckets=[128])
+
+
+def _compile_decode_step(eng, params, cache, operand):
+    """The engine's jitted decode step over described operands;
+    ``operand(array)`` describes a replicated one."""
+    slots_i32 = operand(jnp.zeros(SLOTS, i32))
+    slots_f32 = operand(jnp.zeros(SLOTS, f32))
+    with persistent_cache_off():
+        return jax.jit(eng._decode_fn, donate_argnums=(1,)).lower(
+            params, cache, slots_i32, slots_i32, operand(eng._key),
+            slots_f32, slots_f32).compile()
+
+
+def _compile_on_one_chip(eng, one_chip):
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                    sharding=one_chip)
+    return _compile_decode_step(
+        eng, {k: struct(v, bf16) for k, v in eng.params.items()},
+        jax.tree_util.tree_map(struct, eng.cache), struct)
+
+
 @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
 def test_decode_step_never_copies_the_cache(one_chip, monkeypatch,
                                             kv_dtype):
@@ -198,32 +236,9 @@ def test_decode_step_never_copies_the_cache(one_chip, monkeypatch,
     101.8 MiB in int8: a layer sliced out, transposed for the kernel and
     written back.)"""
     import re
-    from paddle_tpu.inference import InferenceEngine
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-    # the CPU process's dispatch would take the composite: the compile
-    # is for the chip, so say so here and not through an option
-    monkeypatch.setattr(da, "decode_attention_available", lambda: True)
-    heads, d, layers = 16, 128, 2
-    model = GPTForCausalLM(GPTConfig(
-        vocab_size=50304, hidden_size=heads * d, num_layers=layers,
-        num_heads=heads, ffn_hidden_size=4 * heads * d, max_seq_len=SEQ))
-    model.eval()
-    eng = InferenceEngine(model, batch_slots=SLOTS, max_seq_len=SEQ,
-                          cache_dtype=bf16, kv_dtype=kv_dtype,
-                          prefill_buckets=[128])
-
-    def struct(a, dtype=None):
-        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
-                                    sharding=one_chip)
-
-    slots_i32 = struct(jnp.zeros(SLOTS, i32))
-    slots_f32 = struct(jnp.zeros(SLOTS, f32))
-    args = ({k: struct(v, bf16) for k, v in eng.params.items()},
-            jax.tree_util.tree_map(struct, eng.cache),
-            slots_i32, slots_i32, struct(eng._key), slots_f32, slots_f32)
-    with persistent_cache_off():
-        compiled = jax.jit(eng._decode_fn, donate_argnums=(1,)) \
-            .lower(*args).compile()
+    eng = _decode_engine(monkeypatch, kv_dtype)
+    layers = eng.cache.num_layers
+    compiled = _compile_on_one_chip(eng, one_chip)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= layers
     # k and v per layer (and their scale planes), and the lengths
@@ -238,6 +253,124 @@ def test_decode_step_never_copies_the_cache(one_chip, monkeypatch,
     shape = ",".join(str(n) for n in layer_k.shape)
     assert not re.search(r"\[%s\]\S* copy\(" % shape, text), \
         "a whole cache layer is copied"
+
+
+# ---------------------------------------------------------------------------
+# the decode step's sampler: vocabulary-wide work only inside a branch
+# ---------------------------------------------------------------------------
+def _computations(text):
+    """{name: body} of a compiled program's computations, and which of
+    them each one calls."""
+    import re
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", text, re.M | re.S)}
+    calls = {name: set(re.findall(r"%([\w.-]+)", " ".join(re.findall(
+        r"(?:to_apply|calls|body|condition|branch_computations)="
+        r"(\{[^}]*\}|%[\w.-]+)", body)))) for name, body in bodies.items()}
+    return bodies, calls
+
+
+def _reachable(calls, roots):
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo.extend(calls.get(name, ()))
+    return seen
+
+
+def _outside_the_conditional(text, wanted):
+    """Names of the computations that hold a line ``wanted`` matches and
+    that the entry reaches without passing through the one
+    ``conditional``'s branches."""
+    import re
+    bodies, calls = _computations(text)
+    entry = re.search(r"^ENTRY %(\S+)", text, re.M).group(1)
+    conds = [line for body in bodies.values()
+             for line in body.splitlines() if " conditional(" in line]
+    assert len(conds) == 1, [line[:200] for line in conds]
+    branches = set(re.findall(r"%([\w.-]+)", re.search(
+        r"branch_computations=\{([^}]*)\}", conds[0]).group(1)))
+    assert len(branches) == 2
+    holders = {name for name, body in bodies.items()
+               if any(wanted(line) for line in body.splitlines())}
+    assert holders <= _reachable(calls, branches) | {entry}
+    return holders & _reachable(
+        {n: c - branches for n, c in calls.items()}, [entry])
+
+
+def test_decode_step_sorts_only_inside_a_branch(one_chip, monkeypatch):
+    """The sampler of the compiled decode step: a ``conditional`` on its
+    own ``temps``, the vocabulary's ``sort`` reachable only through a
+    branch, and no ``gather`` (alone or fused, flattened or not) with a
+    result of slots x vocabulary.  (At PR 26 the sort stood in the entry
+    computation and a fused ``gather`` of ``f32[slots, vocab]``
+    re-derived the sorted logits: 12.3 ms of a 32.5 ms tick on the
+    chip.)"""
+    import re
+    eng = _decode_engine(monkeypatch)
+    text = _compile_on_one_chip(eng, one_chip).as_text()
+    assert " sort(" in text, "the sampling branch lost its sort"
+    assert not _outside_the_conditional(text, lambda l: " sort(" in l)
+    wide = {f"[{SLOTS},{VOCAB}]", f"[{SLOTS * VOCAB}]"}
+    for line in text.splitlines():
+        if " gather(" in line:
+            shape = re.search(r"= \w+(\[[\d,]*\])", line).group(1)
+            assert shape not in wide, line[:300]
+
+
+def test_mesh_decode_step_gains_no_collective(topo, monkeypatch):
+    """The same step over a described 2x2 mesh (dp x tp, weights and
+    cache laid out by the engine's own rules): the conditional's
+    predicate is a replicated scalar, so the program carries no more
+    collectives than with the sampler of PR 26 (kept in
+    tests/test_sampler.py), and none as wide as the tp shard of the
+    vocabulary outside the branches.  (The sorted logits come back from
+    the sort by an all-to-all where the gather all-gathered them.)"""
+    import re
+    import numpy as np
+    from jax.sharding import Mesh
+    from paddle_tpu.distributed.mesh import compile_mesh_guard
+    from test_sampler import old_sample_from_logits
+    eng = _decode_engine(monkeypatch)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+
+    def described(mesh, a, dims):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=eng._spec_for(mesh, a, dims))
+
+    # the engine's own layout rules, handed shapes in place of arrays
+    monkeypatch.setattr(eng, "_put", described)
+    params = {k: jax.ShapeDtypeStruct(v.shape, bf16, sharding=v.sharding)
+              for k, v in eng._shard_params_over(mesh, eng.params,
+                                                 eng.model).items()}
+    cache = eng._shard_dense_cache_arrays(mesh, eng.cache)
+
+    def replicated(a):
+        return described(mesh, a, (None,) * a.ndim)
+
+    collective = re.compile(
+        r" (all-reduce|all-gather|all-to-all|collective-permute"
+        r"|reduce-scatter)(?:-start)?\(")
+
+    def program():
+        with compile_mesh_guard(mesh):
+            return _compile_decode_step(eng, params, cache,
+                                        replicated).as_text()
+
+    text = program()
+    monkeypatch.setattr(
+        eng, "_sample_from_logits",
+        lambda *a: old_sample_from_logits(eng.top_k, *a))
+    assert len(collective.findall(text)) <= \
+        len(collective.findall(program()))
+
+    def vocab_wide(line):
+        return collective.search(line) and re.search(
+            r"= \w+\[[\d,]*\b%d\b" % (VOCAB // 2), line)
+    assert any(vocab_wide(line) for line in text.splitlines())
+    assert not _outside_the_conditional(text, vocab_wide)
 
 
 # ---------------------------------------------------------------------------
