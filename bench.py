@@ -151,7 +151,6 @@ def _serve_row_key(row) -> tuple:
     return ("serve", str(row.get("config")),
             int(row.get("batch_slots", 0)),
             str(row.get("kv_dtype") or "dense"),
-            bool(row.get("decode_megakernel")),
             int(row.get("prompt_len", 0)), int(row.get("gen_tokens", 0)),
             int(row.get("tp", 1) or 1), int(row.get("ep", 1) or 1),
             int(row.get("prefill_chunk", 0) or 0))
@@ -651,46 +650,6 @@ def run_tuning_sweeps():
         log(f"  tuning: a2a sweep skipped: {type(e).__name__}: {e}")
 
 
-def _serve_sweep():
-    """TPU serve bench with megakernel off/on as enumerated candidates
-    (ROADMAP item 1's missing serve axis), resume-aware; the winner is
-    THE one JSON line."""
-    measured = _measured_rows("serve")
-    config = os.environ.get("BENCH_CONFIG", "gpt3-125m")
-    from paddle_tpu.ops.quantized_matmul import resolve_kv_quant
-    kv_dtype = resolve_kv_quant(None) or "dense"
-    best, rows, last_err = None, [], None
-    for mk in (False, True):
-        key = ("serve", config, _serve_slots(), kv_dtype, mk,
-               _SERVE_DEFAULTS["prompt_len"],
-               _SERVE_DEFAULTS["gen_tokens"],
-               int(os.environ.get("PADDLE_TPU_SERVE_TP", "1") or 1))
-        if key in measured:
-            log(f"  serve resume: skipping measured megakernel={mk}")
-            row = dict(measured[key])
-        else:
-            try:
-                row = bench_serve(smoke=False, decode_megakernel=mk,
-                                  emit=False)
-            except Exception as e:
-                last_err = f"{type(e).__name__}: {str(e)[:300]}"
-                log(f"  serve megakernel={mk} failed: {last_err}")
-                continue
-        rows.append(row)
-        if best is None or (row.get("value") or 0) > \
-                (best.get("value") or 0):
-            best = row
-    if best is None:
-        raise SystemExit(f"all serve candidates failed: {last_err}")
-    best = dict(best)
-    best["candidates"] = [
-        {k: r.get(k) for k in ("decode_megakernel", "value",
-                               "decode_hbm_bytes_per_tok",
-                               "step_ms_p50", "decode_tokens_per_sec")}
-        for r in rows]
-    print(json.dumps(best))
-
-
 def bench_flash(seqs=(1024, 2048, 4096), batch=8):
     """Secondary microbench: Pallas flash vs XLA composite, fwd+bwd."""
     import numpy as np
@@ -732,18 +691,8 @@ def bench_flash(seqs=(1024, 2048, 4096), batch=8):
     return rows
 
 
-# TPU serve-bench candidate defaults, shared with _serve_sweep's resume
-# keys so the two can never drift apart
-_SERVE_DEFAULTS = {"prompt_len": 128, "gen_tokens": 64}
-
-
-def _serve_slots() -> int:
-    return int(os.environ.get("PADDLE_TPU_DECODE_SLOTS", 8))
-
-
 def bench_serve(config_name=None, batch_slots=None, prompt_len=None,
-                gen_tokens=None, num_requests=None, smoke=False,
-                decode_megakernel=None, emit=True):
+                gen_tokens=None, num_requests=None, smoke=False):
     """Serving-path bench (`--serve`): continuous-batching engine
     throughput on the winning train config's model — prefill+decode
     tokens/sec, p50/p95 per-decode-step latency, slot occupancy, and
@@ -774,9 +723,10 @@ def bench_serve(config_name=None, batch_slots=None, prompt_len=None,
         # the default train config
         config_name = config_name or os.environ.get("BENCH_CONFIG",
                                                     "gpt3-125m")
-        batch_slots = batch_slots or _serve_slots()
-        prompt_len = prompt_len or _SERVE_DEFAULTS["prompt_len"]
-        gen_tokens = gen_tokens or _SERVE_DEFAULTS["gen_tokens"]
+        batch_slots = batch_slots or \
+            int(os.environ.get("PADDLE_TPU_DECODE_SLOTS", 8))
+        prompt_len = prompt_len or 128
+        gen_tokens = gen_tokens or 64
         num_requests = num_requests or 2 * batch_slots
         seq = int(os.environ.get("BENCH_SEQ", 2048))
     cfg = replace(gpt_configs()[config_name], max_seq_len=seq,
@@ -787,10 +737,6 @@ def bench_serve(config_name=None, batch_slots=None, prompt_len=None,
 
     paddle.seed(0)
     model = GPTForCausalLM(cfg)
-    if decode_megakernel is not None:
-        # candidate axis of the serve sweep; None keeps the config/env
-        # default (ops.decode_megakernel.megakernel_enabled)
-        model.enable_decode_megakernel(bool(decode_megakernel))
     eng = InferenceEngine(model, batch_slots=batch_slots)
     rng = np.random.RandomState(0)
 
@@ -849,9 +795,7 @@ def bench_serve(config_name=None, batch_slots=None, prompt_len=None,
         "prefill_ms_total": stats["prefill_ms"],
         "decode_ms_total": stats["decode_ms"],
         "decode_tokens_per_sec": stats["decode_tokens_per_sec"],
-        # megakernel sweep axis + the decode loop's HBM traffic per
-        # token (int8-aware; the fused kernel's saving as a NUMBER)
-        "decode_megakernel": stats["decode_megakernel"],
+        # the decode loop's HBM traffic per token (int8-aware)
         "decode_hbm_bytes_per_tok": stats["decode_hbm_bytes_per_tok"],
         # pod-scale serving (ISSUE 18/19): the tensor- and
         # expert-parallel sweep axes (both join the resume row key)
@@ -937,8 +881,7 @@ def bench_serve(config_name=None, batch_slots=None, prompt_len=None,
         except Exception as e:
             log(f"  exec profile skipped: {type(e).__name__}: {e}")
     _persist_row(out, kind="serve")
-    if emit:
-        print(json.dumps(out))
+    print(json.dumps(out))
     return out
 
 
@@ -1627,68 +1570,6 @@ def _smoke_quantized_decode():
             "quantized_kv_dtype": "int8"}
 
 
-def _smoke_megakernel():
-    """Megakernel leg of --smoke (ISSUE 11): the fused decode step's
-    logits must match the composed kernels path at 1e-5 on the CPU
-    composite, and a warmed megakernel engine must decode with ZERO new
-    XLA compiles — the fused path is exercised in tier-1, not only on
-    hardware."""
-    import numpy as np
-    import jax.numpy as jnp
-    import paddle_tpu as paddle
-    from paddle_tpu.inference import InferenceEngine
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-    from paddle_tpu.utils import compile_counter
-
-    cfg = GPTConfig(vocab_size=97, hidden_size=64, num_layers=2,
-                    num_heads=4, max_seq_len=64,
-                    use_flash_attention=False)
-    paddle.seed(0)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, 97, (1, 9)).astype(np.int32)
-    tok = jnp.asarray([ids[0, -1]], jnp.int32)
-    act = jnp.ones((1,), jnp.int32)
-
-    # parity leg: composed path vs fused path, same params, fresh caches
-    m.enable_decode_megakernel(False)
-    cc = m.init_kv_cache(1)
-    _, cc = m.prefill(jnp.asarray(ids[:, :-1]), cc, 0, 8)
-    lc, _ = m.decode_step(tok, cc, act)
-    m.enable_decode_megakernel(True)
-    cm = m.init_kv_cache(1)
-    _, cm = m.prefill(jnp.asarray(ids[:, :-1]), cm, 0, 8)
-    lm, _ = m.decode_step(tok, cm, act)
-    diff = float(np.max(np.abs(np.asarray(lm) - np.asarray(lc))))
-    if diff > 1e-5:
-        raise SystemExit(
-            f"bench --smoke: megakernel decode diverged from the "
-            f"composed path (max abs logit diff {diff:.2e} > 1e-5)")
-
-    # zero-recompile leg: a warmed megakernel engine generates
-    # compile-free (the fused op must be shape-stable in the decode
-    # executable exactly like the composed kernels)
-    eng = InferenceEngine(m, batch_slots=2, prefill_buckets=[16])
-    eng.warmup(buckets=[16])
-    # stats report what COMPILED: on the CPU the fused op traces its
-    # composite and the engine says why
-    assert eng.stats["decode_megakernel_refusal"] == "backend is not tpu", \
-        "megakernel flag did not reach the engine stats"
-    with compile_counter.assert_no_recompiles("megakernel decode smoke"):
-        rid = eng.add_request(ids[0, :7], max_new_tokens=8)
-        gen = eng.run()[rid]
-    if len(gen) < 8:
-        raise SystemExit("bench --smoke: megakernel decode produced "
-                         f"{len(gen)} tokens (expected 8)")
-    hbm = eng.stats["decode_hbm_bytes_per_tok"]
-    log(f"  megakernel smoke ok: logit diff {diff:.2e}, {len(gen)} "
-        f"tokens, 0 compiles, {hbm} HBM bytes/tok")
-    return {"megakernel_decode_ok": True,
-            "megakernel_logit_diff": round(diff, 8),
-            "decode_hbm_bytes_per_tok": hbm}
-
-
 def _smoke_telemetry():
     """Telemetry leg of --smoke (ISSUE 13): the unified observability
     layer must actually EXPORT — the Prometheus exposition parses back
@@ -1860,7 +1741,7 @@ def _smoke_exec_profile(train_row):
     eng.run()
     _er.analyze_all(eng._exec_component)
     sprof = _er.profile(eng._exec_component) or {}
-    dec = sprof.get("decode") or sprof.get("megakernel_decode")
+    dec = sprof.get("decode")
     if not dec:
         raise SystemExit("bench --smoke: serve exec_profile has no "
                          "decode digest")
@@ -2212,7 +2093,6 @@ def bench_smoke():
         raise SystemExit("bench --smoke: train row lost the 'doctor' "
                          "field")
     qrow = _smoke_quantized_decode()
-    mkrow = _smoke_megakernel()
     trow = _smoke_telemetry()
     drow = _smoke_doctor()
     erow = _smoke_exec_profile(cold)
@@ -2226,7 +2106,6 @@ def bench_smoke():
         "exec_profile": cold["exec_profile"],
         **{k: cold[k] for k in required},
         **qrow,
-        **mkrow,
         **trow,
         **drow,
         **erow,
@@ -2267,11 +2146,8 @@ def main():
         smoke = "--smoke" in sys.argv
         if "--loadtest" in sys.argv:
             bench_loadtest(smoke=smoke)
-        elif smoke:
-            bench_serve(smoke=True)
         else:
-            # megakernel off/on enumerated (resume-aware), winner wins
-            _serve_sweep()
+            bench_serve(smoke=smoke)
         return
 
     if "--serve-tp-child" in sys.argv:

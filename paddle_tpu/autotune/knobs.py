@@ -77,12 +77,6 @@ AXES: Dict[str, KnobAxis] = {a.name: a for a in [
              env="PADDLE_TPU_SPEC_K"),
     KnobAxis("kv_dtype", ("serve",), candidates=["dense", "int8"],
              env="PADDLE_TPU_KV_DTYPE"),
-    KnobAxis("decode_megakernel", ("serve",), candidates=[False, True],
-             env="PADDLE_TPU_DECODE_MEGAKERNEL",
-             table_op="megakernel_blocks"),
-    KnobAxis("megakernel_blocks", ("serve",), candidates=[],
-             env="PADDLE_TPU_MEGAKERNEL_BLOCKS",
-             table_op="megakernel_blocks"),
     KnobAxis("prefill_buckets", ("serve",), candidates=[],
              env="PADDLE_TPU_PREFILL_BUCKETS",
              table_op="prefill_buckets", hot_apply=True),

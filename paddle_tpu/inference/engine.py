@@ -808,10 +808,8 @@ class InferenceEngine:
         # it compiled against and the tp degree, so the observatory can
         # tell a tp-sharded decode from a single-chip one (and the
         # disagg prefill submesh from the decode submesh)
-        tp = 1
         if mesh is not None:
-            tp = int(dict(mesh.shape).get("tp", 1))
-            meta["tp"] = tp
+            meta["tp"] = int(dict(mesh.shape).get("tp", 1))
             # expert parallelism (ISSUE 19): the submesh shape below
             # already carries every axis — recording ep explicitly lets
             # the observatory (and comm_stats' per-axis collective
@@ -822,12 +820,6 @@ class InferenceEngine:
                 "devices": [int(d.id) for d in
                             np.asarray(mesh.devices).flat]}
         if kind == "decode":
-            from ..ops.decode_megakernel import megakernel_enabled
-            # the megakernel stands down under tp>1 (gpt._megakernel
-            # _active) — the registry must say what actually compiled
-            if megakernel_enabled(self.model.cfg) and tp == 1:
-                kind = "megakernel_decode"
-                meta["megakernel"] = True
             meta["batch_slots"] = self.batch_slots
         elif kind == "spec_verify":
             meta["spec_k"] = self.spec_k
@@ -870,9 +862,9 @@ class InferenceEngine:
         if mesh is not None and key not in self._first_call_keys:
             # first call per key = the trace: publish the mesh on BOTH
             # channels (ambient + compile) so trace-time decisions —
-            # _megakernel_active's tp gate, the decode kernels'
-            # shard_map wrapper — see the serving mesh.  Steady-state
-            # calls skip the guard entirely (zero per-tick overhead).
+            # the decode kernels' shard_map wrapper — see the serving
+            # mesh.  Steady-state calls skip the guard entirely (zero
+            # per-tick overhead).
             from ..distributed.mesh import compile_mesh_guard
             with compile_mesh_guard(mesh):
                 return self._timed_inner(kind, key, fn)
@@ -2277,10 +2269,9 @@ class InferenceEngine:
 
     def _decode_hbm_bytes_per_tok(self) -> int:
         """The decode loop's HBM read traffic per generated token, from
-        the live shapes (satellite of the megakernel ISSUE: the fused
-        kernel's saving must be a reported number, not a claim): every
-        step streams the parameters once (amortized over the
-        batch_slots tokens it produces) plus each slot's full KV extent
+        the live shapes: every step streams the parameters once
+        (amortized over the batch_slots tokens it produces) plus each
+        slot's full KV extent
         — int8-aware, counting the 8-bit values AND the f32 scale
         planes the kernels stream alongside them.  Under a tp-sharded
         serving mesh the number is PER SHARD (ISSUE 18): each device
@@ -2354,8 +2345,7 @@ class InferenceEngine:
         s["prefill_chunk"] = self.prefill_chunk
         # pod-scale serving (ISSUE 18): tp degree + mesh layout ride
         # every stats snapshot (and through it, bench rows + loadgen
-        # reports); the megakernel flag reports what actually runs —
-        # it stands down under tp>1 (see gpt._megakernel_active)
+        # reports)
         s["tp"] = self.tp_degree
         s["ep"] = self.ep_degree
         if self.mesh is not None:
@@ -2382,20 +2372,6 @@ class InferenceEngine:
         else:
             s.pop("moe_assigned_tokens", None)
             s.pop("moe_dropped_tokens", None)
-        # what the decode executable COMPILED, not the knob: an armed
-        # megakernel that traced its composite (VMEM gate, backend,
-        # shape) or stood down under tp reads False, with the reason
-        from ..ops.decode_megakernel import megakernel_enabled
-        mk = self.kernel_paths.get(("decode", 0), {}).get(
-            "decode_megakernel", {})
-        s["decode_megakernel"] = mk.get("kernel", 0) > 0
-        s["decode_megakernel_refusal"] = None
-        if megakernel_enabled(self.model.cfg) and \
-                not s["decode_megakernel"]:
-            s["decode_megakernel_refusal"] = \
-                "stands down under tp>1" if self.tp_degree > 1 else \
-                _kernel_paths.last_reason("decode_megakernel") or \
-                "decode executable not compiled yet"
         s["decode_hbm_bytes_per_tok"] = self._decode_hbm_bytes_per_tok()
         if self._spec is not None:
             s["spec_k"] = self._spec.k

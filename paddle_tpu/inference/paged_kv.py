@@ -41,6 +41,7 @@ hundred int32s per step is noise next to the cache itself.
 from __future__ import annotations
 
 import itertools
+from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
@@ -48,6 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..models.gpt import KVLayerView
 from ..observability import metrics as _metrics
 
 __all__ = ["PagedKVCache", "BlockAllocator", "init_paged_cache",
@@ -83,6 +85,40 @@ def rows_to_blocks(rows, block_size: int):
     ``[n, Hkv, bs, ...]``."""
     return jnp.swapaxes(
         rows.reshape((-1, int(block_size)) + rows.shape[1:]), 1, 2)
+
+
+@dataclass
+class PagedKVLayer(KVLayerView):
+    """A PagedKVCache layer behind the slots' block ``tables``
+    ``[B, MB]``: ``k``/``v`` ``[num_blocks, Hkv, bs, D]``, scale pools
+    ``[num_blocks, Hkv, bs]``.  Position p of slot b lives at
+    ``(tables[b, p // bs], p % bs)``; slots with no blocks (all-zero
+    table rows) and positions past a table's last entry write into the
+    reserved null block — masked garbage by construction."""
+
+    tables: Optional[jax.Array] = None
+
+    def _locate(self, pos):
+        bs, (b, mb) = self.k.shape[2], self.tables.shape
+        blk_pos = jnp.minimum(pos // bs, mb - 1)
+        off = pos % bs
+        rows = jnp.arange(b) if pos.ndim == 1 else jnp.arange(b)[:, None]
+        return self.tables[rows, blk_pos], off
+
+    def _put(self, pool, at, new):
+        blk, off = at
+        return pool.at[blk, :, off].set(new.astype(pool.dtype))
+
+    def _attend_token(self, q, lens, at):
+        from ..ops import paged_decode_attention
+        return paged_decode_attention(q, self.k, self.v, self.tables,
+                                      lens + 1, self.k_scale, self.v_scale)
+
+    def _attend_window(self, q, lens):
+        from ..ops import paged_decode_attention_window
+        return paged_decode_attention_window(
+            q, self.k, self.v, self.tables, lens, self.k_scale,
+            self.v_scale)
 
 
 class PagedKVCache:
@@ -123,6 +159,24 @@ class PagedKVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    def layer(self, i, tables) -> PagedKVLayer:
+        """Layer ``i``'s pools behind the slots' block tables, as a
+        serving step's view."""
+        k, v = self.k[i], self.v[i]
+        scales = (self.k_scale[i], self.v_scale[i]) if self.quantized \
+            else (None, None)
+        return PagedKVLayer(k, v, *scales,
+                            tables=jnp.asarray(tables, jnp.int32))
+
+    def with_layer(self, i, kv: PagedKVLayer) -> "PagedKVCache":
+        """The cache with layer ``i``'s pools written back."""
+        k_scale, v_scale = self.k_scale, self.v_scale
+        if self.quantized:
+            k_scale = k_scale.at[i].set(kv.k_scale)
+            v_scale = v_scale.at[i].set(kv.v_scale)
+        return PagedKVCache(self.k.at[i].set(kv.k), self.v.at[i].set(kv.v),
+                            k_scale, v_scale)
 
     def __repr__(self):
         return (f"PagedKVCache(layers={self.k.shape[0]}, "

@@ -49,6 +49,103 @@ __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM",
            "GPTPretrainingCriterion", "gpt_configs", "StaticKVCache"]
 
 
+def window_positions(lengths, w: int):
+    """Positions of the W tokens a step appends per slot: ``lengths``
+    itself for one token a slot (``[B]``: the decode tick adds no
+    window offsets, so its program stays what it was, operation for
+    operation), ``lengths[b] + i`` as ``[B, W]`` for a window."""
+    if w == 1:
+        return lengths
+    return lengths[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+
+
+@dataclass
+class KVLayerView:
+    """One layer of a serving KV cache, as a serving step sees it: the
+    layer's buffers behind two operations.
+
+    ``write(k, v, lengths)`` stores W new tokens' k and v
+    ``[B, W, Hkv, D]`` for every slot at positions ``lengths[b] ..
+    lengths[b]+W-1`` and returns the written view; ``attend(q)`` runs
+    that window's queries ``[B, W, H, D]`` against it, query i seeing
+    positions ``j <= lengths[b]+i``.  What the view hides: how a
+    position becomes an address (``_locate`` / ``_put``: an index into a
+    dense buffer, or a block and an offset through a block table), and
+    how a value is stored (as it is, or as 8-bit codes beside f32 scale
+    planes ``k_scale`` / ``v_scale``, quantized on the write and
+    dequantized inside the attention kernel).
+
+    W is a static shape and picks the kernel: one token a slot goes
+    through the single-token entry points with a ``[B]`` index, a
+    window through the window entry points with a ``[B, W]`` index.
+    The two are not interchangeable on the chip until measured so: the
+    shape of the write's scatter decides whether the compiler updates a
+    donated buffer where it lies (PERF.md §6, PR 26)."""
+
+    k: jax.Array
+    v: jax.Array
+    k_scale: Optional[jax.Array] = None
+    v_scale: Optional[jax.Array] = None
+    # (lengths, address) of the last write, for its queries' attend
+    window: Optional[tuple] = None
+
+    def write(self, k, v, lengths) -> "KVLayerView":
+        w = k.shape[1]
+        lens = lengths.astype(jnp.int32)
+        at = self._locate(window_positions(lens, w))
+        new = (lambda x: x[:, 0]) if w == 1 else (lambda x: x)
+        if self.k_scale is None:
+            k_buf = self._put(self.k, at, new(k))
+            v_buf = self._put(self.v, at, new(v))
+            return replace(self, k=k_buf, v=v_buf, window=(lens, at))
+        from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
+        mode = kv_quant_mode(self.k.dtype)
+        kq, ks = quantize_kv(new(k), mode)      # scales [B, (W,) Hkv]
+        vq, vs = quantize_kv(new(v), mode)
+        return replace(
+            self, k=self._put(self.k, at, kq), v=self._put(self.v, at, vq),
+            k_scale=self._put(self.k_scale, at, ks),
+            v_scale=self._put(self.v_scale, at, vs), window=(lens, at))
+
+    def attend(self, q):
+        """The queries of the window just written; ``[B, W, H, D]``."""
+        lens, at = self.window
+        token = q.shape[1] == 1
+        if token:
+            q = q[:, 0]
+        if self.k_scale is None:
+            # the kernels take storage-dtype MXU inputs; 8-bit codes
+            # are dequantized to the query's dtype instead
+            q = q.astype(self.k.dtype)
+        if token:
+            return self._attend_token(q, lens, at)[:, None]
+        return self._attend_window(q, lens)
+
+
+class DenseKVLayer(KVLayerView):
+    """A StaticKVCache layer: ``k``/``v`` ``[B, Hkv, cap, D]``, scale
+    planes ``[B, Hkv, cap]``.  A position is its own address, clamped
+    to the buffer's last (a slot at capacity writes masked garbage
+    there; callers bound generation, as the engine does)."""
+
+    def _locate(self, pos):
+        return jnp.minimum(pos, self.k.shape[2] - 1)
+
+    def _put(self, buf, idx, new):
+        from ..ops import write_kv
+        return write_kv(buf, idx, new)
+
+    def _attend_token(self, q, lens, idx):
+        from ..ops import decode_attention
+        return decode_attention(q, self.k, self.v, idx + 1,
+                                self.k_scale, self.v_scale)
+
+    def _attend_window(self, q, lens):
+        from ..ops import decode_attention_window
+        return decode_attention_window(q, self.k, self.v, lens,
+                                       self.k_scale, self.v_scale)
+
+
 class StaticKVCache:
     """Preallocated serving KV cache: ``k``/``v`` are TUPLES of one
     buffer per layer, each HEAD-MAJOR
@@ -118,17 +215,23 @@ class StaticKVCache:
         return StaticKVCache(self.k, self.v, lengths, self.k_scale,
                              self.v_scale)
 
-    def _buffer_lists(self) -> list:
-        """``[k, v]`` (``+ [k_scale, v_scale]`` when quantized) as
-        per-layer lists a serving forward fills in layer by layer, and
-        ``_with_buffers`` takes back."""
-        planes = (self.k, self.v) + \
-            ((self.k_scale, self.v_scale) if self.quantized else ())
-        return [list(p) for p in planes]
+    def layer(self, i, tables=None) -> DenseKVLayer:
+        """Layer ``i``'s own buffers, as a serving step's view."""
+        scales = (self.k_scale[i], self.v_scale[i]) if self.quantized \
+            else ()
+        return DenseKVLayer(self.k[i], self.v[i], *scales)
 
-    def _with_buffers(self, bufs, lengths) -> "StaticKVCache":
-        return StaticKVCache(tuple(bufs[0]), tuple(bufs[1]), lengths,
-                             *(tuple(b) for b in bufs[2:]))
+    def with_layer(self, i, kv: DenseKVLayer) -> "StaticKVCache":
+        """The cache with layer ``i``'s buffers taken back from a view:
+        no layer is sliced out of, or written back into, anything
+        larger."""
+        def put(planes, new):
+            if planes is None:
+                return None
+            return planes[:i] + (new,) + planes[i + 1:]
+        return StaticKVCache(put(self.k, kv.k), put(self.v, kv.v),
+                             self.lengths, put(self.k_scale, kv.k_scale),
+                             put(self.v_scale, kv.v_scale))
 
     def __repr__(self):
         return (f"StaticKVCache(layers={self.num_layers}, "
@@ -171,15 +274,6 @@ class GPTConfig:
     # LM head stay full precision (the standard sensitivity split).
     # None (default) keeps every path bitwise-identical to unquantized.
     quantize: Optional[str] = None
-    # serving: fuse each layer's WHOLE decode step (attention over the
-    # KV cache + new-token fold + out proj + residual + LayerNorm + MLP)
-    # into one Pallas kernel (ops.decode_megakernel) — intermediates
-    # stay in VMEM, no HBM round-trips between sub-ops.  Off (default)
-    # keeps the composed kernels path, which remains the parity oracle;
-    # PADDLE_TPU_DECODE_MEGAKERNEL overrides at trace time.  On CPU the
-    # fused op lowers to an XLA composite that matches the composed
-    # path op for op, so the flag is safe everywhere.
-    decode_megakernel: bool = False
     tp_axis: str = "tp"
     # MoE (0 experts = dense; BASELINE.json config #5 switch-transformer)
     moe_num_experts: int = 0
@@ -406,7 +500,7 @@ class GPTAttention(Layer):
                 f"concat cache grew past this silently; the static "
                 f"cache cannot")
         # same offset for every row (the legacy API is uniform-length;
-        # per-slot offsets live in StaticKVCache/forward_decode)
+        # per-slot offsets live in StaticKVCache/step)
         k_buf = jax.lax.dynamic_update_slice(
             k_buf, jnp.swapaxes(k, 1, 2).astype(k_buf.dtype),
             (0, 0, length, 0))
@@ -456,124 +550,25 @@ class GPTAttention(Layer):
         return (self._proj_out(out, b, s), jnp.swapaxes(k, 1, 2),
                 jnp.swapaxes(v, 1, 2))
 
-    def forward_decode(self, x, k_layer, v_layer, lengths,
-                       k_scale=None, v_scale=None):
-        """One decode step over a StaticKVCache layer: write each slot's
-        new k/v at its own ``lengths[b]`` (a scatter of B × Hkv × D
-        elements — in place when the layer's buffer is donated), then
-        run the fused single-token attention masked to
-        ``j <= lengths[b]``.  x is [B, 1, hidden]; k_layer/v_layer
-        [B, Hkv, cap, D]; lengths [B] int32 (tokens already in the
-        cache, EXCLUDING this one).  Returns ``(out, k_layer, v_layer)``.
-
-        Quantized cache layer: ``k_scale``/``v_scale`` [B, Hkv, cap]
-        f32 — the new token's k/v are quantized per head on write and
-        the fused kernel dequantizes while streaming; returns
-        ``(out, k_layer, v_layer, k_scale, v_scale)``."""
-        b = x.shape[0]
-        cap = k_layer.shape[2]
-        q, k, v = self._qkv_arrays(x)
-        idx = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-        from .. import ops as _ops
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_layer.dtype)
-            kq, ks = quantize_kv(k[:, 0], mode)         # [b,Hkv,D],[b,Hkv]
-            vq, vs = quantize_kv(v[:, 0], mode)
-            k_layer = _ops.write_kv(k_layer, idx, kq)
-            v_layer = _ops.write_kv(v_layer, idx, vq)
-            k_scale = _ops.write_kv(k_scale, idx, ks)
-            v_scale = _ops.write_kv(v_scale, idx, vs)
-            out = _ops.decode_attention(q[:, 0], k_layer, v_layer,
-                                        idx + 1, k_scale, v_scale)
-            out = out[:, None].astype(q.dtype)           # [b, 1, H, D]
-            return (self._proj_out(out, b, 1), k_layer, v_layer,
-                    k_scale, v_scale)
-        k_layer = _ops.write_kv(k_layer, idx, k[:, 0])
-        v_layer = _ops.write_kv(v_layer, idx, v[:, 0])
-        out = _ops.decode_attention(
-            q[:, 0].astype(k_layer.dtype), k_layer, v_layer, idx + 1)
-        out = out[:, None].astype(q.dtype)               # [b, 1, H, D]
-        return self._proj_out(out, b, 1), k_layer, v_layer
-
-    def forward_verify(self, x, k_layer, v_layer, lengths,
-                       k_scale=None, v_scale=None):
-        """Windowed multi-token step over one StaticKVCache layer — the
-        spec-decode verify/catch-up primitive: write the W new tokens'
-        k/v at positions ``lengths[b]..lengths[b]+W-1`` (scatter), then
-        run the fused window attention where query i sees
-        ``j <= lengths[b]+i``.  x is [B, W, hidden]; k_layer/v_layer
-        [B, Hkv, cap, D] (scales [B, Hkv, cap]); lengths [B] int32
-        EXCLUDING the window.  Returns ``(out, k_layer, v_layer)`` (+
-        scale planes when quantized).  W=1 is numerically the
-        forward_decode step."""
+    def step(self, x, kv, lengths):
+        """One serving step over one cache layer, for a decode tick
+        (W = 1), a spec-decode verify window or a prefill chunk: write
+        the W new tokens' k/v for every slot at positions
+        ``lengths[b] .. lengths[b]+W-1`` (in place when the layer's
+        buffers are donated), then attend query i against positions
+        ``j <= lengths[b]+i``.  x is ``[B, W, hidden]``; ``kv`` the
+        cache's view of this layer (a ``KVLayerView``: where a position
+        lives and how a value is stored are its business); lengths
+        ``[B]`` int32, the tokens already in the cache, EXCLUDING the
+        window.  Rows
+        past a slot's real tokens write masked garbage above its
+        length, overwritten by the next step.  Returns
+        ``(out, kv)``."""
         b, w = x.shape[0], x.shape[1]
-        cap = k_layer.shape[2]
         q, k, v = self._qkv_arrays(x)
-        lens = lengths.astype(jnp.int32)
-        idx = jnp.minimum(
-            lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :],
-            cap - 1)                                     # [B, W]
-        from .. import ops as _ops
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_layer.dtype)
-            kq, ks = quantize_kv(k, mode)           # [b,w,Hkv,D],[b,w,Hkv]
-            vq, vs = quantize_kv(v, mode)
-            k_layer = _ops.write_kv(k_layer, idx, kq)
-            v_layer = _ops.write_kv(v_layer, idx, vq)
-            k_scale = _ops.write_kv(k_scale, idx, ks)
-            v_scale = _ops.write_kv(v_scale, idx, vs)
-            out = _ops.decode_attention_window(q, k_layer, v_layer, lens,
-                                               k_scale, v_scale)
-            out = out.astype(q.dtype)               # [b, w, H, D]
-            return (self._proj_out(out, b, w), k_layer, v_layer,
-                    k_scale, v_scale)
-        k_layer = _ops.write_kv(k_layer, idx, k)
-        v_layer = _ops.write_kv(v_layer, idx, v)
-        out = _ops.decode_attention_window(
-            q.astype(k_layer.dtype), k_layer, v_layer, lens)
-        out = out.astype(q.dtype)                    # [b, w, H, D]
-        return self._proj_out(out, b, w), k_layer, v_layer
-
-    def forward_verify_paged(self, x, k_pool, v_pool, tables, lengths,
-                             k_scale=None, v_scale=None):
-        """Paged twin of forward_verify: scatter the W new tokens' k/v
-        through each slot's block table at positions
-        ``lengths[b]+i``, then run the paged window attention.  x
-        [B, W, hidden]; tables [B, MB] int32; lengths [B] int32
-        EXCLUDING the window."""
-        b, w = x.shape[0], x.shape[1]
-        bs = k_pool.shape[2]
-        mb = tables.shape[1]
-        q, k, v = self._qkv_arrays(x)
-        lens = lengths.astype(jnp.int32)
-        pos = lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-        blk_pos = jnp.minimum(pos // bs, mb - 1)         # [B, W]
-        off = pos % bs
-        rows = jnp.arange(b)[:, None]
-        blk = tables[rows, blk_pos]                      # [B, W]
-        from .. import ops as _ops
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_pool.dtype)
-            kq, ks = quantize_kv(k, mode)
-            vq, vs = quantize_kv(v, mode)
-            k_pool = k_pool.at[blk, :, off].set(kq)
-            v_pool = v_pool.at[blk, :, off].set(vq)
-            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
-            out = _ops.paged_decode_attention_window(
-                q, k_pool, v_pool, tables, lens, k_scale, v_scale)
-            out = out.astype(q.dtype)
-            return (self._proj_out(out, b, w), k_pool, v_pool,
-                    k_scale, v_scale)
-        k_pool = k_pool.at[blk, :, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(v.astype(v_pool.dtype))
-        out = _ops.paged_decode_attention_window(
-            q.astype(k_pool.dtype), k_pool, v_pool, tables, lens)
-        out = out.astype(q.dtype)
-        return self._proj_out(out, b, w), k_pool, v_pool
+        kv = kv.write(k, v, lengths)
+        out = kv.attend(q).astype(q.dtype)          # [b, w, H, D]
+        return self._proj_out(out, b, w), kv
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
         """Prefill attention over ONE slot's gathered block buffer:
@@ -617,55 +612,6 @@ class GPTAttention(Layer):
                 Tensor(vf.astype(q.dtype)),
                 attn_mask=mask[None, None], training=False).data
         return self._proj_out(out, b, s), k_buf, v_buf
-
-    def forward_decode_paged(self, x, k_pool, v_pool, tables, lengths,
-                             k_scale=None, v_scale=None):
-        """One decode step over a PagedKVCache layer: write each slot's
-        new k/v at pool position ``(tables[b, lengths[b]//bs],
-        lengths[b]%bs)`` (scatter), then run the paged fused attention
-        streaming the slot's blocks through its table.  x [B, 1, H];
-        k_pool/v_pool [num_blocks, Hkv, bs, D]; tables [B, MB] int32;
-        lengths [B] int32 EXCLUDING the new token.  Inactive slots write
-        into the reserved null block (their table rows are all-zero) —
-        masked garbage by construction.  Returns
-        ``(out, k_pool, v_pool)``.
-
-        Quantized pools: ``k_scale``/``v_scale`` [num_blocks, Hkv, bs]
-        f32 — new k/v quantized per head on write, scales streamed and
-        dequantized inside the paged kernel; returns
-        ``(out, k_pool, v_pool, k_scale, v_scale)``."""
-        b = x.shape[0]
-        bs = k_pool.shape[2]
-        mb = tables.shape[1]
-        q, k, v = self._qkv_arrays(x)
-        lens = lengths.astype(jnp.int32)
-        blk_pos = jnp.minimum(lens // bs, mb - 1)
-        off = lens % bs
-        rows = jnp.arange(b)
-        blk = tables[rows, blk_pos]
-        from .. import ops as _ops
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_pool.dtype)
-            kq, ks = quantize_kv(k[:, 0], mode)         # [b,Hkv,D],[b,Hkv]
-            vq, vs = quantize_kv(v[:, 0], mode)
-            k_pool = k_pool.at[blk, :, off].set(kq)
-            v_pool = v_pool.at[blk, :, off].set(vq)
-            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
-            out = _ops.paged_decode_attention(
-                q[:, 0], k_pool, v_pool, tables, lens + 1,
-                k_scale, v_scale)
-            out = out[:, None].astype(q.dtype)           # [b, 1, H, D]
-            return (self._proj_out(out, b, 1), k_pool, v_pool,
-                    k_scale, v_scale)
-        k_pool = k_pool.at[blk, :, off].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(v[:, 0].astype(v_pool.dtype))
-        out = _ops.paged_decode_attention(
-            q[:, 0].astype(k_pool.dtype), k_pool, v_pool, tables,
-            lens + 1)
-        out = out[:, None].astype(q.dtype)               # [b, 1, H, D]
-        return self._proj_out(out, b, 1), k_pool, v_pool
 
     def forward(self, x, attn_mask=None, cache=None):
         cfg = self.cfg
@@ -792,145 +738,14 @@ class GPTBlock(Layer):
         x = x + self.mlp(self.ln_2(x))
         return x, k, v
 
-    def forward_decode(self, x, k_layer, v_layer, lengths,
-                       k_scale=None, v_scale=None):
-        """Single-token block step over one StaticKVCache layer
-        (quantized layers thread their scale planes through)."""
-        if k_scale is not None:
-            a, k_layer, v_layer, k_scale, v_scale = \
-                self.attn.forward_decode(self.ln_1(x), k_layer, v_layer,
-                                         lengths, k_scale, v_scale)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
-            return x, k_layer, v_layer, k_scale, v_scale
-        a, k_layer, v_layer = self.attn.forward_decode(
-            self.ln_1(x), k_layer, v_layer, lengths)
+    def step(self, x, kv, lengths):
+        """Block step over one cache layer's view (LN/MLP are
+        position-wise, so only attention needs the window machinery).
+        Returns ``(x, kv)``."""
+        a, kv = self.attn.step(self.ln_1(x), kv, lengths)
         x = x + a
         x = x + self.mlp(self.ln_2(x))
-        return x, k_layer, v_layer
-
-    def forward_verify(self, x, k_layer, v_layer, lengths,
-                       k_scale=None, v_scale=None):
-        """Windowed multi-token block step over one StaticKVCache layer
-        (LN/MLP are position-wise, so only attention needs the window
-        machinery)."""
-        if k_scale is not None:
-            a, k_layer, v_layer, k_scale, v_scale = \
-                self.attn.forward_verify(self.ln_1(x), k_layer, v_layer,
-                                         lengths, k_scale, v_scale)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
-            return x, k_layer, v_layer, k_scale, v_scale
-        a, k_layer, v_layer = self.attn.forward_verify(
-            self.ln_1(x), k_layer, v_layer, lengths)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, k_layer, v_layer
-
-    def forward_verify_paged(self, x, k_pool, v_pool, tables, lengths,
-                             k_scale=None, v_scale=None):
-        """Windowed multi-token block step over one PagedKVCache
-        layer."""
-        if k_scale is not None:
-            a, k_pool, v_pool, k_scale, v_scale = \
-                self.attn.forward_verify_paged(
-                    self.ln_1(x), k_pool, v_pool, tables, lengths,
-                    k_scale, v_scale)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
-            return x, k_pool, v_pool, k_scale, v_scale
-        a, k_pool, v_pool = self.attn.forward_verify_paged(
-            self.ln_1(x), k_pool, v_pool, tables, lengths)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, k_pool, v_pool
-
-    # ---- fused (megakernel) decode step --------------------------------
-    def _megakernel_weights(self):
-        """The 12 per-layer arrays the fused decode step consumes, in
-        ops.decode_megakernel.LAYER_WEIGHTS order."""
-        a, m = self.attn, self.mlp
-        return tuple(t.data for t in (
-            self.ln_1.weight, self.ln_1.bias,
-            a.qkv_proj.weight, a.qkv_proj.bias,
-            a.out_proj.weight, a.out_proj.bias,
-            self.ln_2.weight, self.ln_2.bias,
-            m.up_proj.weight, m.up_proj.bias,
-            m.down_proj.weight, m.down_proj.bias))
-
-    def _megakernel_ok(self) -> bool:
-        """This block can run the fused decode step: a dense (non-MoE)
-        MLP and every projection carrying its bias."""
-        a = self.attn
-        m = self.mlp
-        if not hasattr(m, "up_proj") or not hasattr(m, "down_proj"):
-            return False
-        return not any(p is None for p in (
-            a.qkv_proj.bias, a.out_proj.bias, m.up_proj.bias,
-            m.down_proj.bias, self.ln_1.bias, self.ln_2.bias))
-
-    def forward_decode_fused(self, x, k_layer, v_layer, lengths,
-                             k_scale=None, v_scale=None):
-        """Single-token block step as ONE fused op (megakernel when the
-        backend/shape allow, the mirrored XLA composite otherwise) —
-        same signature and cache-write semantics as forward_decode, so
-        the two paths are drop-in interchangeable per layer."""
-        from ..ops import decode_megakernel as _mk, write_kv
-        arr = x.data if isinstance(x, Tensor) else x      # [B, 1, H]
-        xo, k_new, v_new = _mk.decode_layer_step(
-            arr[:, 0], self._megakernel_weights(), k_layer, v_layer,
-            lengths, k_scale, v_scale,
-            # the LIVE projection attribute, not attn.cfg: it's what
-            # enable_quantize() flips after construction
-            quantize=self.attn.qkv_proj.quantize,
-            eps=self.ln_1._epsilon)
-        cap = k_layer.shape[2]
-        idx = jnp.minimum(lengths.astype(jnp.int32), cap - 1)
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_layer.dtype)
-            kq, ks = quantize_kv(k_new, mode)
-            vq, vs = quantize_kv(v_new, mode)
-            return (Tensor(xo[:, None]), write_kv(k_layer, idx, kq),
-                    write_kv(v_layer, idx, vq), write_kv(k_scale, idx, ks),
-                    write_kv(v_scale, idx, vs))
-        return (Tensor(xo[:, None]), write_kv(k_layer, idx, k_new),
-                write_kv(v_layer, idx, v_new))
-
-    def forward_decode_paged_fused(self, x, k_pool, v_pool, tables,
-                                   lengths, k_scale=None, v_scale=None):
-        """Paged twin of forward_decode_fused: one fused op per layer
-        step, then the same scatter-through-the-block-table write as
-        forward_decode_paged."""
-        from ..ops import decode_megakernel as _mk
-        arr = x.data if isinstance(x, Tensor) else x      # [B, 1, H]
-        b = arr.shape[0]
-        bs = k_pool.shape[2]
-        mb = tables.shape[1]
-        xo, k_new, v_new = _mk.decode_layer_step_paged(
-            arr[:, 0], self._megakernel_weights(), k_pool, v_pool,
-            tables, lengths, k_scale, v_scale,
-            quantize=self.attn.qkv_proj.quantize,
-            eps=self.ln_1._epsilon)
-        lens = lengths.astype(jnp.int32)
-        blk_pos = jnp.minimum(lens // bs, mb - 1)
-        off = lens % bs
-        rows = jnp.arange(b)
-        blk = tables[rows, blk_pos]
-        if k_scale is not None:
-            from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
-            mode = kv_quant_mode(k_pool.dtype)
-            kq, ks = quantize_kv(k_new, mode)
-            vq, vs = quantize_kv(v_new, mode)
-            k_pool = k_pool.at[blk, :, off].set(kq)
-            v_pool = v_pool.at[blk, :, off].set(vq)
-            k_scale = k_scale.at[blk, :, off].set(ks.astype(k_scale.dtype))
-            v_scale = v_scale.at[blk, :, off].set(vs.astype(v_scale.dtype))
-            return (Tensor(xo[:, None]), k_pool, v_pool, k_scale,
-                    v_scale)
-        k_pool = k_pool.at[blk, :, off].set(k_new.astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(v_new.astype(v_pool.dtype))
-        return Tensor(xo[:, None]), k_pool, v_pool
+        return x, kv
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
         """Block prefill over one slot's gathered block buffer."""
@@ -939,24 +754,6 @@ class GPTBlock(Layer):
         x = x + a
         x = x + self.mlp(self.ln_2(x))
         return x, k_buf, v_buf
-
-    def forward_decode_paged(self, x, k_pool, v_pool, tables, lengths,
-                             k_scale=None, v_scale=None):
-        """Single-token block step over one PagedKVCache layer
-        (quantized pools thread their scale pools through)."""
-        if k_scale is not None:
-            a, k_pool, v_pool, k_scale, v_scale = \
-                self.attn.forward_decode_paged(
-                    self.ln_1(x), k_pool, v_pool, tables, lengths,
-                    k_scale, v_scale)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
-            return x, k_pool, v_pool, k_scale, v_scale
-        a, k_pool, v_pool = self.attn.forward_decode_paged(
-            self.ln_1(x), k_pool, v_pool, tables, lengths)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, k_pool, v_pool
 
 
 class GPTModel(Layer):
@@ -1046,37 +843,6 @@ class GPTModel(Layer):
                 if lin is not None:
                     lin.quantize = mode
         return self
-
-    def enable_decode_megakernel(self, flag: bool = True):
-        """Route every serving decode step through the fused per-layer
-        megakernel (ops.decode_megakernel).  Parameters and cache
-        layouts are untouched — only the decode lowering changes — so
-        the composed path stays available as the parity oracle by
-        flipping the flag back."""
-        self.cfg = replace(self.cfg, decode_megakernel=bool(flag))
-        # blocks read their attention's cfg for quantize/epsilon only;
-        # the routing decision lives here, at the model
-        return self
-
-    def _megakernel_active(self) -> bool:
-        """The fused decode path runs for this trace: knob armed
-        (config or PADDLE_TPU_DECODE_MEGAKERNEL), homogeneous dense
-        blocks with biases, and no live tensor-parallel sharding (tp>1
-        block weights keep the composed GSPMD path)."""
-        from ..ops.decode_megakernel import megakernel_enabled
-        cfg = self.cfg
-        if not megakernel_enabled(cfg):
-            return False
-        if cfg.moe_num_experts > 0:
-            return False
-        if self.training and (cfg.dropout > 0 or cfg.attn_dropout > 0):
-            return False
-        from ..distributed.mesh import get_mesh
-        m = get_mesh()
-        if (m is not None and cfg.tp_axis in m.axis_names
-                and m.shape[cfg.tp_axis] > 1):
-            return False
-        return all(blk._megakernel_ok() for blk in self.blocks)
 
     def _zero3_mesh(self, x):
         """The compile mesh when the overlapped ZeRO-3 scan can run for
@@ -1196,21 +962,6 @@ class GPTModel(Layer):
                              jnp.zeros((int(batch_slots),), jnp.int32),
                              *scales)
 
-    def _step_dense_layers(self, x, cache: StaticKVCache, lens, step,
-                           new_lengths):
-        """Run block method ``step`` (forward_decode, forward_verify,
-        forward_decode_fused) through the stack, handing layer ``i`` its
-        own cache buffers (and scale planes) and taking them back: no
-        layer is sliced out of, or written back into, anything larger.
-        Returns ``(hidden, cache)`` with the cache at ``new_lengths``."""
-        bufs = cache._buffer_lists()
-        for i, blk in enumerate(self.blocks):
-            x, *layer = getattr(blk, step)(
-                x, bufs[0][i], bufs[1][i], lens, *(b[i] for b in bufs[2:]))
-            for b, new in zip(bufs, layer):
-                b[i] = new
-        return self.ln_f(x), cache._with_buffers(bufs, new_lengths)
-
     def forward_prefill(self, input_ids, cache: StaticKVCache, slot,
                         prompt_len):
         """Prefill ONE slot: run the causal forward over a (possibly
@@ -1234,152 +985,68 @@ class GPTModel(Layer):
                 buf, new.astype(buf.dtype),
                 (slot,) + (zero,) * (buf.ndim - 1))
 
-        bufs = cache._buffer_lists()
         if cache.quantized:
             from ..ops.quantized_matmul import kv_quant_mode, quantize_kv
             mode = kv_quant_mode(cache.dtype)
         for i, blk in enumerate(self.blocks):
             x, k, v = blk.forward_prefill(x)        # k/v [1, Hkv, s, D]
-            layer = (k, v)
+            new = (k, v)
             if cache.quantized:
                 # attention ran on the full-precision k/v (bitwise the
                 # dense prefill); only the STORED copy is quantized
                 k, k_s = quantize_kv(k, mode)       # scales [1, Hkv, s]
                 v, v_s = quantize_kv(v, mode)
-                layer = (k, v, k_s, v_s)
-            for b, new in zip(bufs, layer):
-                b[i] = put(b[i], new)
+                new = (k, v, k_s, v_s)
+            kv = cache.layer(i)
+            cache = cache.with_layer(i, DenseKVLayer(*(
+                put(buf, rows) for buf, rows in
+                zip((kv.k, kv.v, kv.k_scale, kv.v_scale), new))))
         lengths = cache.lengths.at[slot].set(
             jnp.asarray(prompt_len, jnp.int32))
-        return self.ln_f(x), cache._with_buffers(bufs, lengths)
+        return self.ln_f(x), cache.with_lengths(lengths)
 
-    def forward_decode(self, tokens, cache: StaticKVCache, active):
-        """One decode step for every slot: append ``tokens [B]`` at each
-        slot's current length, run the fused single-token attention per
-        layer, and advance ``lengths`` by ``active [B]`` (0/1 — retired
-        or empty slots keep their length; their writes land at a masked
-        position and their outputs are ignored by the scheduler).
-        Returns ``(hidden [B, 1, H], cache)``."""
-        cfg = self.cfg
-        b = cache.batch_slots
-        toks = tokens.data if isinstance(tokens, Tensor) \
-            else jnp.asarray(tokens)
-        pos = jnp.minimum(cache.lengths, cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks.reshape(b, 1))) + \
-            self.wpe(Tensor(pos.reshape(b, 1)))
-        x = self.drop(x)
-        lengths = jnp.minimum(
-            cache.lengths + jnp.asarray(active, jnp.int32),
-            cache.capacity)
-        return self._step_dense_layers(
-            x, cache, cache.lengths,
-            "forward_decode_fused" if self._megakernel_active()
-            else "forward_decode", lengths)
+    def step(self, tokens, cache, lengths, advance=None, tables=None):
+        """One serving step for every slot, W tokens a slot: the decode
+        tick (``tokens [B]``, W = 1), the spec-decode verify / draft
+        catch-up window and the chunked-prefill step (``tokens
+        [B, W]``).  Token i of slot b is embedded at position
+        ``lengths[b]+i``; each block is handed the cache's view of its
+        layer (``cache.layer(i, tables)``; ``tables [B, MB]`` are the
+        slots' block tables where the cache keeps its layers in a block
+        pool, None where every slot owns its rows), writes the window's
+        k/v there, attends query i against positions
+        ``j <= lengths[b]+i``, and the layer is taken back
+        (``cache.with_layer``).
 
-    def forward_verify(self, tokens, cache: StaticKVCache):
-        """Windowed multi-token step for every slot — the spec-decode
-        verify (and draft catch-up) primitive: process ``tokens
-        [B, W]`` as W consecutive new tokens per slot starting at each
-        slot's current length, writing their k/v into the cache and
-        attending each window query i against positions
-        ``j <= lengths[b]+i``.  Returns ``(hidden [B, W, H], cache)``
-        with lengths UNCHANGED — the caller (the spec tick) advances
-        them by the count it actually commits, which it only knows
-        after the acceptance rule runs on these logits.  Positions
-        beyond the committed count hold garbage above the advanced
-        length, exactly the masked-garbage convention of
-        forward_decode."""
-        cfg = self.cfg
-        toks = tokens.data if isinstance(tokens, Tensor) \
-            else jnp.asarray(tokens)
-        b, w = toks.shape
-        lens = cache.lengths.astype(jnp.int32)
-        pos = jnp.minimum(
-            lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :],
-            cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks)) + self.wpe(Tensor(pos))
-        x = self.drop(x)
-        return self._step_dense_layers(x, cache, lens, "forward_verify",
-                                       cache.lengths)
-
-    def forward_verify_paged(self, tokens, cache, tables, lengths):
-        """Paged twin of forward_verify: W consecutive tokens per slot
-        scattered through the block tables.  Lengths are HOST state
-        (the scheduler owns block accounting) and ride in as an
-        operand, EXCLUDING the window.  Returns
+        ``lengths [B]`` int32 is the tokens already in the cache,
+        EXCLUDING the window: ``cache.lengths``, or the scheduler's own
+        mirror (a slot retired between chunks must not leave a stale
+        in-graph length behind; a block pool has no in-graph lengths at
+        all).  ``advance [B]`` advances the cache's in-graph lengths to
+        ``lengths + advance`` (capped at the capacity); None leaves
+        them — the spec tick advances by what it commits, a scheduler
+        that owns the block accounting advances its own.  Rows
+        whose real tokens are fewer than W (retired slots, chunk
+        padding) write masked garbage above their new length,
+        overwritten by the next step.  Returns
         ``(hidden [B, W, H], cache)``."""
         cfg = self.cfg
-        tables = jnp.asarray(tables, jnp.int32)
         toks = tokens.data if isinstance(tokens, Tensor) \
             else jnp.asarray(tokens)
-        b, w = toks.shape
         lens = jnp.asarray(lengths, jnp.int32)
-        pos = jnp.minimum(
-            lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :],
-            cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks)) + self.wpe(Tensor(pos))
+        b = lens.shape[0]
+        w = toks.size // b
+        pos = jnp.minimum(window_positions(lens, w), cfg.max_seq_len - 1)
+        x = self.wte(Tensor(toks.reshape(b, w))) + \
+            self.wpe(Tensor(pos.reshape(b, w)))
         x = self.drop(x)
-        cache_k, cache_v = cache.k, cache.v
-        k_sc, v_sc = cache.k_scale, cache.v_scale
+        if advance is not None:
+            cache = cache.with_lengths(jnp.minimum(
+                lens + jnp.asarray(advance, jnp.int32), cache.capacity))
         for i, blk in enumerate(self.blocks):
-            if k_sc is not None:
-                x, k_pool, v_pool, ks_p, vs_p = blk.forward_verify_paged(
-                    x, cache_k[i], cache_v[i], tables, lens,
-                    k_sc[i], v_sc[i])
-                k_sc = k_sc.at[i].set(ks_p)
-                v_sc = v_sc.at[i].set(vs_p)
-            else:
-                x, k_pool, v_pool = blk.forward_verify_paged(
-                    x, cache_k[i], cache_v[i], tables, lens)
-            cache_k = cache_k.at[i].set(k_pool)
-            cache_v = cache_v.at[i].set(v_pool)
-        return self.ln_f(x), type(cache)(cache_k, cache_v, k_sc, v_sc)
-
-    def forward_prefill_chunk(self, tokens, cache: StaticKVCache,
-                              lengths, advance):
-        """Chunked-prefill step for every slot over the DENSE cache —
-        the Sarathi-style stall-free admission primitive: ``tokens
-        [B, C]`` carries the next (up to) C prompt tokens per
-        still-prefilling slot, written and attended with the same
-        window machinery as forward_verify (query i sees positions
-        ``j <= lengths[b]+i``).  ``lengths`` rides in as a HOST
-        operand — the scheduler's per-slot mirror, not
-        ``cache.lengths`` — so a slot retired between chunks can't
-        leave a stale in-graph length behind; ``advance [B]`` (0 for
-        decode/empty slots, the real chunk token count otherwise)
-        advances lengths in-graph so subsequent decode ticks see the
-        grown prefix.  Rows with ``advance[b] < C`` write padded
-        positions above their new length — masked garbage, overwritten
-        by the next chunk or decode, the forward_decode convention.
-        Returns ``(hidden [B, C, H], cache)``."""
-        cfg = self.cfg
-        toks = tokens.data if isinstance(tokens, Tensor) \
-            else jnp.asarray(tokens)
-        b, w = toks.shape
-        lens = jnp.asarray(lengths, jnp.int32)
-        pos = jnp.minimum(
-            lens[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :],
-            cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks)) + self.wpe(Tensor(pos))
-        x = self.drop(x)
-        new_len = jnp.minimum(lens + jnp.asarray(advance, jnp.int32),
-                              cache.capacity)
-        return self._step_dense_layers(x, cache, lens, "forward_verify",
-                                       new_len)
-
-    def forward_prefill_chunk_paged(self, tokens, cache, tables,
-                                    lengths, advance):
-        """Paged twin of forward_prefill_chunk.  The paged layout
-        already keeps lengths on the host (the scheduler owns block
-        accounting), so the chunk step IS the paged verify window —
-        scatter C tokens per slot through the block tables at
-        ``lengths[b]+i`` and attend the staircase; out-of-extent rows
-        (decode slots, padding above ``advance[b]``) write into the
-        reserved null block.  ``advance`` only documents the contract
-        here; the scheduler advances its host lengths itself.  Returns
-        ``(hidden [B, C, H], cache)``."""
-        del advance  # host-side bookkeeping with the paged layout
-        return self.forward_verify_paged(tokens, cache, tables, lengths)
+            x, kv = blk.step(x, cache.layer(i, tables), lens)
+            cache = cache.with_layer(i, kv)
+        return self.ln_f(x), cache
 
     # ---- serving path: paged KV cache ---------------------------------
     def forward_prefill_paged(self, input_ids, cache, table_row,
@@ -1458,42 +1125,6 @@ class GPTModel(Layer):
                     rows_to_blocks(v_buf, bs))
         return self.ln_f(x), type(cache)(cache_k, cache_v, k_sc, v_sc)
 
-    def forward_decode_paged(self, tokens, cache, tables, lengths):
-        """One decode step for every slot over the PAGED cache: append
-        ``tokens [B]`` at each slot's ``lengths[b]`` through its block
-        table, run the paged fused attention per layer.  Lengths are
-        HOST state with the paged layout (the scheduler owns block
-        accounting), so they ride in as an operand and are not advanced
-        in-graph.  Returns ``(hidden [B, 1, H], cache)``."""
-        cfg = self.cfg
-        tables = jnp.asarray(tables, jnp.int32)
-        b = tables.shape[0]
-        toks = tokens.data if isinstance(tokens, Tensor) \
-            else jnp.asarray(tokens)
-        lens = jnp.asarray(lengths, jnp.int32)
-        pos = jnp.minimum(lens, cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks.reshape(b, 1))) + \
-            self.wpe(Tensor(pos.reshape(b, 1)))
-        x = self.drop(x)
-        cache_k, cache_v = cache.k, cache.v
-        k_sc, v_sc = cache.k_scale, cache.v_scale
-        fused = self._megakernel_active()
-        for i, blk in enumerate(self.blocks):
-            step = blk.forward_decode_paged_fused if fused else \
-                blk.forward_decode_paged
-            if k_sc is not None:
-                x, k_pool, v_pool, ks_p, vs_p = step(
-                    x, cache_k[i], cache_v[i], tables, lens,
-                    k_sc[i], v_sc[i])
-                k_sc = k_sc.at[i].set(ks_p)
-                v_sc = v_sc.at[i].set(vs_p)
-            else:
-                x, k_pool, v_pool = step(
-                    x, cache_k[i], cache_v[i], tables, lens)
-            cache_k = cache_k.at[i].set(k_pool)
-            cache_v = cache_v.at[i].set(v_pool)
-        return self.ln_f(x), type(cache)(cache_k, cache_v, k_sc, v_sc)
-
     def forward(self, input_ids, attn_mask=None):
         from ..distributed.recompute import recompute as _rc
         s = input_ids.shape[1]
@@ -1544,11 +1175,6 @@ class GPTForCausalLM(Layer):
 
     def enable_quantize(self, mode: Optional[str] = "int8"):
         self.gpt.enable_quantize(mode)
-        self.cfg = self.gpt.cfg
-        return self
-
-    def enable_decode_megakernel(self, flag: bool = True):
-        self.gpt.enable_decode_megakernel(flag)
         self.cfg = self.gpt.cfg
         return self
 
@@ -1609,7 +1235,8 @@ class GPTForCausalLM(Layer):
     def decode_step(self, tokens, cache: StaticKVCache, active):
         """One decode step for all slots; returns
         ``(logits [B, V], cache)``."""
-        h, cache = self.gpt.forward_decode(tokens, cache, active)
+        h, cache = self.gpt.step(tokens, cache, cache.lengths,
+                                 advance=active)
         logits = self._head_logits(h)                     # [B, 1, V]
         return logits.data[:, 0], cache
 
@@ -1618,16 +1245,17 @@ class GPTForCausalLM(Layer):
         draft catch-up); returns ``(logits [B, W, V], cache)`` — the
         logits at every window position, i.e. logits[:, i] is the
         next-token distribution after consuming tokens[:, :i+1].
-        Lengths are NOT advanced (see GPTModel.forward_verify)."""
-        h, cache = self.gpt.forward_verify(tokens, cache)
+        Lengths are NOT advanced: the caller (the spec tick) advances
+        them by the count it commits, which it only knows after the
+        acceptance rule has run on these logits."""
+        h, cache = self.gpt.step(tokens, cache, cache.lengths)
         logits = self._head_logits(h)                     # [B, W, V]
         return logits.data, cache
 
     def verify_step_paged(self, tokens, cache, tables, lengths):
         """Paged windowed multi-token step for all slots; returns
         ``(logits [B, W, V], cache)``."""
-        h, cache = self.gpt.forward_verify_paged(tokens, cache, tables,
-                                                 lengths)
+        h, cache = self.gpt.step(tokens, cache, lengths, tables=tables)
         logits = self._head_logits(h)
         return logits.data, cache
 
@@ -1651,16 +1279,15 @@ class GPTForCausalLM(Layer):
         ``(logits [B, V], cache)`` — logits after each slot's last
         real chunk token, i.e. the first-generated-token distribution
         for slots whose chunk completes their prompt."""
-        h, cache = self.gpt.forward_prefill_chunk(tokens, cache,
-                                                  lengths, advance)
+        h, cache = self.gpt.step(tokens, cache, lengths,
+                                 advance=advance)
         return self._chunk_last_logits(h, advance), cache
 
     def prefill_chunk_paged(self, tokens, cache, tables, lengths,
                             advance):
         """Paged chunked-prefill step for all slots; returns
         ``(logits [B, V], cache)``."""
-        h, cache = self.gpt.forward_prefill_chunk_paged(
-            tokens, cache, tables, lengths, advance)
+        h, cache = self.gpt.step(tokens, cache, lengths, tables=tables)
         return self._chunk_last_logits(h, advance), cache
 
     def prefill_paged(self, input_ids, cache, table_row, prefix_len,
@@ -1683,8 +1310,7 @@ class GPTForCausalLM(Layer):
     def decode_step_paged(self, tokens, cache, tables, lengths):
         """One paged decode step for all slots; returns
         ``(logits [B, V], cache)``."""
-        h, cache = self.gpt.forward_decode_paged(tokens, cache, tables,
-                                                 lengths)
+        h, cache = self.gpt.step(tokens, cache, lengths, tables=tables)
         logits = self._head_logits(h)                     # [B, 1, V]
         return logits.data[:, 0], cache
 

@@ -292,9 +292,8 @@ def _hbm_heavy_decode(s: dict):
     if steps is None or steps < 8:
         return None
     kv = s.get("kv_dtype")
-    mk = s.get("decode_megakernel")
-    saver_on = kv not in (None, "dense") or bool(mk)
-    row = _exec_prof(s, "decode", "megakernel_decode", "spec_verify")
+    saver_on = kv not in (None, "dense")
+    row = _exec_prof(s, "decode", "spec_verify")
     if row is not None and row.get("bound"):
         # measured roofline evidence is AUTHORITATIVE: a compute-bound
         # or below-the-floor decode must not fall through to the byte
@@ -309,8 +308,7 @@ def _hbm_heavy_decode(s: dict):
               "arithmetic_intensity": row.get("arithmetic_intensity"),
               "ridge_ai": row.get("ridge_ai"),
               "bound": "bandwidth",
-              "kv_dtype": kv or "dense",
-              "decode_megakernel": bool(mk)}
+              "kv_dtype": kv or "dense"}
         if row.get("mfu") is not None:
             ev["mfu"] = row["mfu"]
         # a byte-saver already on shrinks the verdict to informational
@@ -320,8 +318,7 @@ def _hbm_heavy_decode(s: dict):
     if hbm is None or saver_on:
         return None
     return {"decode_hbm_bytes_per_tok": int(hbm),
-            "kv_dtype": kv or "dense",
-            "decode_megakernel": bool(mk)}, 0.3
+            "kv_dtype": kv or "dense"}, 0.3
 
 
 def _roofline_train(s: dict):
@@ -384,12 +381,8 @@ def _spec_k_action(s: dict, ev: dict) -> dict:
 
 
 def _decode_bw_action(s: dict, ev: dict) -> dict:
-    """First byte-saver not already on: megakernel, then int8 KV, then
-    speculative decoding to amortize the streamed bytes."""
-    if not s.get("decode_megakernel"):
-        return {"op": "megakernel_blocks", "param": "decode_megakernel",
-                "env": "PADDLE_TPU_DECODE_MEGAKERNEL",
-                "candidates": [True]}
+    """First byte-saver not already on: int8 KV, then speculative
+    decoding to amortize the streamed bytes."""
     if s.get("kv_dtype") in (None, "dense"):
         return {"op": None, "param": "kv_dtype",
                 "env": "PADDLE_TPU_KV_DTYPE", "candidates": ["int8"]}
@@ -606,8 +599,7 @@ RULES: List[Rule] = [
          "absorbs / rebalance gating (aux loss weight) upstream",
          _expert_imbalance, action=_moe_imbalance_action),
     Rule("bandwidth-bound-decode", ("serve",),
-         "enable the decode megakernel (PADDLE_TPU_DECODE_MEGAKERNEL=1)"
-         " / int8 KV (PADDLE_TPU_KV_DTYPE=int8) / speculative decoding "
+         "int8 KV (PADDLE_TPU_KV_DTYPE=int8) / speculative decoding "
          "(PADDLE_TPU_SPEC_K) to amortize the streamed bytes",
          _hbm_heavy_decode, action=_decode_bw_action),
     Rule("mfu-below-target", ("train",),

@@ -10,8 +10,8 @@ registry (PAPER.md §1 layer 0); our unit of attribution is the XLA
 executable, and this registry is also the scouting party for ROADMAP
 item 5's unified ``Executable`` abstraction: every entry point that
 compiles something (SpmdTrainer fused step, GPipeTrainer tick, engine
-prefill buckets, dense/paged decode, spec verify tick, megakernel
-decode, disagg prefill worker, bench candidates) registers it here.
+prefill buckets, dense/paged decode, spec verify tick, disagg prefill
+worker, bench candidates) registers it here.
 
 Three pieces:
 

@@ -203,7 +203,7 @@ def render_snapshot(rec: dict, doctor_rows: Optional[list] = None) -> str:
         stats["exec_profile"] = prof
         stats["decode_steps"] = max(
             (r.get("calls", 0) for k, r in prof.items()
-             if k in ("decode", "megakernel_decode", "spec_verify")),
+             if k in ("decode", "spec_verify")),
             default=0)
     parts += ["", render_doctor(_doctor.diagnose(stats))]
     if doctor_rows:

@@ -21,6 +21,3 @@ from .fused_cross_entropy import (  # noqa: F401
 from .quantized_matmul import (  # noqa: F401
     quantized_matmul, quantized_matmul_available, fake_quant_matmul,
     quantize_channel, quantize_kv, dequantize_kv, get_qmm_tiles)
-from .decode_megakernel import (  # noqa: F401
-    decode_layer_step, decode_layer_step_paged,
-    decode_megakernel_available, megakernel_enabled)

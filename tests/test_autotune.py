@@ -47,6 +47,7 @@ def tmp_tables(tmp_path, monkeypatch):
                        str(tmp_path / "tuning.json"))
     monkeypatch.setenv("PADDLE_TPU_FLIGHTREC_DIR",
                        str(tmp_path / "flightrec"))
+    monkeypatch.delenv("PADDLE_TPU_TUNING", raising=False)
     tuning.reset_for_tests()
     yield tmp_path
     tuning.reset_for_tests()
@@ -561,6 +562,131 @@ def test_compact_rows_noop_under_budget(tmp_path):
     with open(path, "w") as f:
         f.write(json.dumps({"kind": "smoke", "metric": "m"}) + "\n")
     assert bench._compact_rows(path, max_bytes=1 << 20) is False
+
+
+# ---- bench sweep resume ------------------------------------------------
+
+def _bench_module():
+    import importlib
+    import bench
+    return importlib.reload(bench)
+
+
+def test_bench_resume_matches_persisted_rows(tmp_path, monkeypatch):
+    """_persist_row tags rows with the run id and _measured_rows only
+    returns rows whose (run, candidate identity) matches — the rerun
+    after a late transient failure re-measures only the tail."""
+    rows = tmp_path / "rows.jsonl"
+    monkeypatch.setenv("BENCH_ROWS_FILE", str(rows))
+    monkeypatch.setenv("BENCH_RUN", "r06")
+    monkeypatch.delenv("BENCH_RECOMPUTE", raising=False)
+    monkeypatch.delenv("BENCH_QUANTIZE", raising=False)
+    monkeypatch.delenv("BENCH_SCAN_LAYERS", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_OVERLAP", raising=False)
+    bench = _bench_module()
+    row = {"config": "gpt3-125m", "batch": 8, "seq": 2048,
+           "use_flash": True, "remat": False, "remat_policy": "off",
+           "scan_layers": True, "overlap": True, "quantize": "int8",
+           "mfu": 0.40, "step_ms": 10.0, "pathological": False}
+    bench._persist_row(row, kind="train")
+    measured = bench._measured_rows("train")
+    spec = dict(config="gpt3-125m", batch=8, seq=2048, flash=True,
+                remat=False, quantize="int8")
+    assert bench._candidate_key(spec) in measured
+    assert measured[bench._candidate_key(spec)]["mfu"] == 0.40
+    # a different candidate (fp) must NOT match
+    other = dict(spec, quantize="off")
+    assert bench._candidate_key(other) not in measured
+    # rows from another run are invisible
+    monkeypatch.setenv("BENCH_RUN", "r07")
+    assert bench._measured_rows("train") == {}
+    # no run id => resume disabled entirely
+    monkeypatch.setenv("BENCH_RUN", "")
+    assert bench._measured_rows("train") == {}
+
+
+def test_bench_resume_serve_rows(tmp_path, monkeypatch):
+    rows = tmp_path / "rows.jsonl"
+    monkeypatch.setenv("BENCH_ROWS_FILE", str(rows))
+    monkeypatch.setenv("BENCH_RUN", "r06")
+    bench = _bench_module()
+    row = {"config": "gpt3-125m", "batch_slots": 8, "kv_dtype": "dense",
+           "prompt_len": 128, "gen_tokens": 64, "value": 900.0}
+    bench._persist_row(row, kind="serve")
+    measured = bench._measured_rows("serve")
+    # tp (ISSUE 18), ep (ISSUE 19) and prefill_chunk (ISSUE 20) joined
+    # the candidate key: a row without the columns resumes as the
+    # tp=1/ep=1/monolithic candidate; a tp=2, ep=2 or chunked row is a
+    # DIFFERENT point
+    key = ("serve", "gpt3-125m", 8, "dense", 128, 64, 1, 1, 0)
+    assert key in measured and measured[key]["value"] == 900.0
+    assert ("serve", "gpt3-125m", 8, "int8", 128, 64, 1, 1, 0) \
+        not in measured
+    assert ("serve", "gpt3-125m", 8, "dense", 128, 64, 2, 1, 0) \
+        not in measured
+    assert ("serve", "gpt3-125m", 8, "dense", 128, 64, 1, 2, 0) \
+        not in measured
+    assert ("serve", "gpt3-125m", 8, "dense", 128, 64, 1, 1, 64) \
+        not in measured
+
+
+# ---- tuning-table nearest-shape fallbacks ------------------------------
+
+def test_qmm_tiles_nearest_shape_fallback(tmp_tables):
+    from paddle_tpu.ops.quantized_matmul import get_qmm_tiles
+    kind = tuning.device_kind()
+    tuning.record("qmm_tiles", (kind, 1024, 512, 256, "int8"),
+                   [64, 128, 128])
+    # exact hit
+    assert get_qmm_tiles(1024, 512, 256) == (64, 128, 128)
+    # near miss (m bucket 2048, same n/k): nearest entry serves,
+    # clamped — NOT the (256, 256, 256) hard defaults
+    assert get_qmm_tiles(2048, 512, 256) == (64, 128, 128)
+    # different n/k within log-distance still beats hard defaults
+    assert get_qmm_tiles(1024, 256, 256) == (64, 128, 128)
+
+
+def test_flash_blocks_nearest_seq_from_unified_table(tmp_tables,
+                                                     monkeypatch):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
+    monkeypatch.delenv("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", "1")
+    kind = tuning.device_kind()
+    saved = dict(fa._SWEEP_CACHE)
+    fa._SWEEP_CACHE.clear()
+    fa._SWEEP_STORE_STATE["loaded"] = False
+    try:
+        tuning.record("flash_blocks", (kind, 1024, 64, True),
+                       [256, 256])
+        # seq 512 has no exact entry anywhere on CPU: the swept 1024
+        # entry is the nearest and must serve (defaults are 512/512)
+        assert fa.get_block_sizes(512, 64, True) == (256, 256)
+    finally:
+        fa._SWEEP_CACHE.clear()
+        fa._SWEEP_CACHE.update(saved)
+        fa._SWEEP_STORE_STATE["loaded"] = False
+
+
+def test_tuned_remat_policy_consumed(tmp_tables):
+    from paddle_tpu.distributed.spmd import tuned_remat_policy
+
+    class _Cfg:
+        hidden_size, num_layers, max_seq_len = 128, 2, 64
+
+    class _Model:
+        cfg = _Cfg()
+
+    kind = tuning.device_kind()
+    assert tuned_remat_policy(_Model()) is None
+    tuning.record("remat_policy", (kind, 128, 2, 64), "dots_no_batch")
+    assert tuned_remat_policy(_Model()) == "dots_no_batch"
+    # nearest shape serves a near-miss model
+    _Cfg.hidden_size = 256
+    assert tuned_remat_policy(_Model()) == "dots_no_batch"
+    # 'off' entries mean "winner ran without remat": ignored
+    tuning.record("remat_policy", (kind, 256, 2, 64), "off")
+    assert tuned_remat_policy(_Model()) is None
 
 
 # ---- bench CLI wiring (satellite 6 + acceptance) -----------------------

@@ -27,7 +27,6 @@ from jax.sharding import SingleDeviceSharding
 
 da = importlib.import_module("paddle_tpu.ops.decode_attention")
 fa = importlib.import_module("paddle_tpu.ops.flash_attention")
-mk = importlib.import_module("paddle_tpu.ops.decode_megakernel")
 qm = importlib.import_module("paddle_tpu.ops.quantized_matmul")
 
 SLOTS, SEQ, VOCAB = 8, 2048, 50304
@@ -156,31 +155,6 @@ def test_int8_matmul(compile_for_chip):
 
     assert_kernel(compile_for_chip(
         run, ((m, k), i8), ((k, n), i8), ((m, 1), f32), ((1, n), f32)))
-
-
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="Mosaic: 'infer-vector-layout: unsupported shape "
-                          "cast' on tpu.reshape vector<1x768xf32> -> "
-                          "vector<12x64xf32> — the megakernel splits the "
-                          "[1, H] qkv row into [heads, d] in-kernel "
-                          "(ops/decode_megakernel.py, _attend/_finalize); "
-                          "ROADMAP D5")
-def test_decode_megakernel_compiles(compile_for_chip, monkeypatch):
-    """Off by default and off the main path: kept as a strict xfail that
-    carries the compiler's message until the kernel is restructured."""
-    monkeypatch.setattr(mk, "decode_megakernel_available", lambda: True)
-    h, heads, d, f = 768, 12, 64, 3072
-
-    def run(x, *rest):
-        return mk.decode_layer_step(x, rest[:12], *rest[12:])
-
-    vec = lambda n: ((n,), bf16)                          # noqa: E731
-    weights = [vec(h), vec(h), ((h, 3 * h), bf16), vec(3 * h),
-               ((h, h), bf16), vec(h), vec(h), vec(h), ((h, f), bf16),
-               vec(f), ((f, h), bf16), vec(h)]
-    cache = ((SLOTS, heads, SEQ, d), bf16)
-    compile_for_chip(run, ((SLOTS, h), bf16), *weights, cache, cache,
-                     ((SLOTS,), i32))
 
 
 # ---------------------------------------------------------------------------

@@ -92,19 +92,6 @@ def test_spec_and_paged_kinds_registered():
     assert spec.meta["spec_k"] == 2
 
 
-def test_megakernel_decode_kind():
-    m = tiny_model()
-    m.enable_decode_megakernel(True)
-    try:
-        eng = InferenceEngine(m, batch_slots=2, prefill_buckets=[16])
-        eng.warmup(buckets=[16])
-        kinds = {e.kind for e in
-                 er.registry().entries(eng._exec_component)}
-        assert "megakernel_decode" in kinds
-    finally:
-        m.enable_decode_megakernel(False)
-
-
 def test_trainer_train_step_registered_and_analyzed(monkeypatch):
     # the CPU has no tabled peak: pin one so the roofline math runs
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "100e9")
@@ -297,22 +284,22 @@ def _decode_profile(bw_frac, bound="bandwidth", known=True):
 def test_doctor_bandwidth_bound_decode_roofline():
     v = doctor.diagnose(
         {"decode_steps": 100, "kv_dtype": None,
-         "decode_megakernel": False,
          "exec_profile": _decode_profile(0.72)}, kind="serve")
     names = [x["bottleneck"] for x in v]
     assert "bandwidth-bound-decode" in names
     hit = v[names.index("bandwidth-bound-decode")]
     assert hit["evidence"]["hbm_bw_frac"] == 0.72
     assert hit["evidence"]["bound"] == "bandwidth"
-    assert "PADDLE_TPU_KV_DTYPE=int8" in hit["knob"]
-    assert "MEGAKERNEL" in hit["knob"].upper()
+    # int8 KV is the first byte-saver the rule names and proposes
+    assert hit["knob"].startswith("int8 KV (PADDLE_TPU_KV_DTYPE=int8)")
+    assert hit["action"]["param"] == "kv_dtype"
+    assert hit["action"]["candidates"] == ["int8"]
     assert hit["score"] == pytest.approx(0.72, abs=1e-4)
 
 
 def test_doctor_roofline_skips_unknown_peaks():
     v = doctor.diagnose(
         {"decode_steps": 100, "kv_dtype": "int8",
-         "decode_megakernel": True,
          "exec_profile": _decode_profile(0.9, known=False)},
         kind="serve")
     assert "bandwidth-bound-decode" not in \
@@ -323,7 +310,7 @@ def test_doctor_threshold_fallback_without_exec_profile():
     # pre-registry evidence still produces the advisory verdict
     v = doctor.diagnose(
         {"decode_steps": 100, "decode_hbm_bytes_per_tok": 10_000_000,
-         "kv_dtype": None, "decode_megakernel": False}, kind="serve")
+         "kv_dtype": None}, kind="serve")
     assert "bandwidth-bound-decode" in [x["bottleneck"] for x in v]
 
 
@@ -332,7 +319,7 @@ def test_doctor_measured_compute_bound_beats_byte_fallback():
     # the byte-count heuristic must not fall through and contradict it
     v = doctor.diagnose(
         {"decode_steps": 100, "decode_hbm_bytes_per_tok": 10_000_000,
-         "kv_dtype": None, "decode_megakernel": False,
+         "kv_dtype": None,
          "exec_profile": _decode_profile(0.2, bound="compute")},
         kind="serve")
     assert "bandwidth-bound-decode" not in \

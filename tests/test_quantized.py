@@ -390,6 +390,25 @@ def test_int8_engine_matches_model_level_rollout(model, int8_dense_eng):
 
 
 # ---------------------------------------------------------------------------
+# decode HBM byte accounting
+# ---------------------------------------------------------------------------
+def test_decode_hbm_bytes_per_tok_int8_smaller(model):
+    fp = InferenceEngine(model, batch_slots=2, prefill_buckets=[16])
+    q8 = InferenceEngine(model, batch_slots=2, prefill_buckets=[16],
+                         kv_dtype="int8")
+    b_fp = fp.stats["decode_hbm_bytes_per_tok"]
+    b_q8 = q8.stats["decode_hbm_bytes_per_tok"]
+    assert b_fp > 0 and b_q8 > 0
+    # int8 shrinks the KV values but adds an f32 scale per (position,
+    # head): 16 + 4 bytes against 64 at this model's d=16 in f32
+    assert b_q8 < b_fp
+    cfg = model.cfg
+    kv_fp = 2 * cfg.num_layers * fp.max_seq_len * cfg.num_kv_heads * \
+        cfg.head_dim * 4            # f32 cache on CPU
+    assert b_fp >= kv_fp            # params amortized on top
+
+
+# ---------------------------------------------------------------------------
 # unified tuning table
 # ---------------------------------------------------------------------------
 @pytest.fixture()

@@ -73,9 +73,9 @@ def model():
 def test_tp_dense_parity_and_observability(model):
     """The dense leg carries the full contract in one pair of engines:
     tp=2 tokens ≡ tp=1, ZERO compiles after warmup, stats carry
-    tp/serving_mesh, the megakernel stands down, registry entries name
-    the submesh, and the deferred analysis folds tp-attributed
-    collectives into the snapshot row."""
+    tp/serving_mesh, registry entries name the submesh, and the
+    deferred analysis folds tp-attributed collectives into the snapshot
+    row."""
     from paddle_tpu.observability import exec_registry
 
     prompts = _prompts(0)
@@ -87,7 +87,6 @@ def test_tp_dense_parity_and_observability(model):
     assert toks == base
     s = eng.stats
     assert s["tp"] == 2 and s["serving_mesh"] == {"dp": 1, "tp": 2}
-    assert s["decode_megakernel"] is False  # stands down under tp>1
 
     reg = exec_registry.registry()
     reg.analyze_all(eng._exec_component)
