@@ -22,13 +22,29 @@ Design (GShard/Switch-transformer dispatch, expressed two ways):
    all_to_all back). Both paths compute the same math; the manual one is
    the single-axis (dp==ep) formulation.
 
-Gating: top-k router with capacity factor; tokens beyond an expert's
-capacity C = ceil(cf * k * S / E) are dropped (their combine weight is
-zero and the residual connection carries them — Switch semantics). The
-load-balance auxiliary loss is E * sum_e(frac_tokens_e * mean_prob_e)
-(Switch eq. 4), optionally plus a router z-loss; they reach the training
-loss through the collect_aux_losses() collector, which the compiled
-trainers open around the model call.
+Gating, two routers inside the ONE layer class (``MoELayer``):
+
+- the CAPACITY path (``capacity_factor`` a number; what ``GPTConfig``'s
+  MoE blocks use): softmax top-k with a capacity factor; tokens beyond
+  an expert's capacity C = ceil(cf * k * S / E) are DROPPED (their
+  combine weight is zero and the residual connection carries them —
+  Switch semantics), experts are gelu FFNs with biases, and it always
+  holds all E experts.  The load-balance auxiliary loss is
+  E * sum_e(frac_tokens_e * mean_prob_e) (Switch eq. 4), optionally plus
+  a router z-loss; they reach the training loss through the
+  collect_aux_losses() collector, which the compiled trainers open
+  around the model call.
+- the DROPLESS path (``capacity_factor=None``): sigmoid scores over
+  all E experts (the router's product in float32 at the highest
+  precision), top-k chosen by score plus a correction bias, the chosen scores normalised and scaled, NO token dropped, no
+  auxiliary loss.  ``held_experts=(lo, hi)`` tells the layer which
+  experts live on this chip: it routes over all E and computes its own
+  experts' part of the result (``None`` holds all E, and the parts of
+  all shares add up to that).  The (token, expert) pairs that fall on
+  held experts are laid out in tiles of one expert each
+  (``dropless_layout``) and go through ``ops.grouped_matmul``; pairs on
+  absent experts are left out — the exchange that would carry them to
+  other chips is not built here.
 """
 from __future__ import annotations
 
@@ -50,7 +66,9 @@ from .parallel_layers import mark_sharding, _in_shard_map
 __all__ = ["MoELayer", "ExpertParallelFFN", "top_k_gating",
            "collect_aux_losses", "add_aux_loss", "moe_capacity",
            "collect_expert_stats", "record_expert_stats",
-           "fold_expert_stats", "nearest_chunk_divisors"]
+           "fold_expert_stats", "nearest_chunk_divisors",
+           "route_top_k", "dropless_layout", "publish_expert_totals",
+           "expert_totals", "reset_expert_totals"]
 
 
 # ---------------------------------------------------------------------------
@@ -112,15 +130,81 @@ def collect_expert_stats():
         _EXPERT_STATS_STACK.pop()
 
 
-def record_expert_stats(load, assigned: int):
-    """MoE layers call this with their per-expert KEPT-token counts
-    ``load [E]`` (dispatch mask sums — may be traced) and the static
-    number of (token, expert) assignments the router made
-    (``top_k * B * S``); dropped = assigned - sum(load). No-op when no
-    collector is open (training, eager use)."""
+def record_expert_stats(load, assigned, tokens=None, into=None):
+    """MoE layers call this with their per-expert KEPT-pair counts
+    ``load [E]`` (what the expert computation really held — may be
+    traced) and the number of (token, expert) assignments the router
+    made to those experts (static ``top_k * B * S`` on the capacity
+    path; traced on a share of the experts, where it depends on the
+    routing); dropped = assigned - sum(load).
+
+    Two sinks, one mechanism.  An open collector (the serving engine's
+    trace) gets the record.  ``into``, a layer's own int32 buffer
+    ``[E + 2]`` (load per held expert, pairs assigned, tokens seen),
+    is added to in place: a buffer is state the compiled train step
+    threads through and donates, so the counts accumulate on the device
+    with no host sync until ``publish_expert_totals`` takes them."""
     if _EXPERT_STATS_STACK:
-        _EXPERT_STATS_STACK[-1].append(
-            {"load": load, "assigned": int(assigned)})
+        _EXPERT_STATS_STACK[-1].append({"load": load, "assigned": assigned})
+    if into is not None:
+        row = jnp.concatenate([
+            load.astype(jnp.int32),
+            jnp.stack([jnp.asarray(assigned, jnp.int32),
+                       jnp.asarray(tokens, jnp.int32)])])
+        into._data = into._data + row
+
+
+# process-wide totals of the layers' buffers, as ops.kernel_paths keeps
+# its counts: whoever holds the buffers (SpmdTrainer.stats) publishes,
+# whoever reports (a benchmark reader) reads after the run
+_EXPERT_TOTALS: dict = {}
+EXPERT_STATS_BUFFER = "expert_stats"
+
+
+def publish_expert_totals(buffers: dict):
+    """TAKE every ``*.expert_stats`` buffer of `buffers` (one host
+    read-back; call at a log boundary, never per step): what the buffers
+    counted since they were last taken is added to the process-wide
+    totals (Python integers, which do not overflow) and the buffers in
+    `buffers` are set back to zero, so an int32 count only has to hold
+    the tokens between two readings (2**31: 131,072 steps of 16,384).
+    Returns ``expert_totals()``, or None where the model has no such
+    buffer (then nothing is read)."""
+    names = [n for n in buffers
+             if n.rsplit(".", 1)[-1] == EXPERT_STATS_BUFFER]
+    if not names:
+        return None
+    for name, row in zip(names, jax.device_get([buffers[n]
+                                                for n in names])):
+        total = _EXPERT_TOTALS.setdefault(name, [0] * len(row))
+        for i, v in enumerate(row):
+            total[i] += int(v)
+        held = buffers[name]
+        buffers[name] = jax.device_put(
+            jnp.zeros(held.shape, held.dtype), held.sharding)
+    return expert_totals()
+
+
+def expert_totals() -> dict:
+    """What was published since ``reset_expert_totals``: per layer the
+    kept pairs of each held expert, the pairs the router assigned to
+    them and the tokens seen; over all layers the local pairs a token,
+    the busiest held expert's load over the mean load, and the pairs
+    dropped (assigned less kept)."""
+    layers = {n: {"load": row[:-2], "assigned": row[-2], "tokens": row[-1]}
+              for n, row in _EXPERT_TOTALS.items()}
+    loads = [v for rec in layers.values() for v in rec["load"]]
+    kept = sum(loads)
+    tokens = sum(rec["tokens"] for rec in layers.values())
+    assigned = sum(rec["assigned"] for rec in layers.values())
+    return {"layers": layers, "pairs_dropped": assigned - kept,
+            "local_pairs_per_token": kept / tokens if tokens else None,
+            "load_max_over_mean":
+                max(loads) * len(loads) / kept if kept else None}
+
+
+def reset_expert_totals() -> None:
+    _EXPERT_TOTALS.clear()
 
 
 def fold_expert_stats(bucket):
@@ -133,8 +217,11 @@ def fold_expert_stats(bucket):
     load = bucket[0]["load"].astype(jnp.float32)
     for rec in bucket[1:]:
         load = load + rec["load"].astype(jnp.float32)
-    assigned = jnp.asarray(
-        float(sum(r["assigned"] for r in bucket)), jnp.float32)
+    counts = [r["assigned"] for r in bucket]
+    if all(isinstance(c, int) for c in counts):
+        assigned = jnp.asarray(float(sum(counts)), jnp.float32)
+    else:       # a share of the experts: the count depends on the routing
+        assigned = sum(jnp.asarray(c, jnp.float32) for c in counts)
     return {"load": load, "assigned": assigned}
 
 
@@ -205,6 +292,150 @@ def top_k_gating(logits, top_k: int, capacity: int,
 
 
 # ---------------------------------------------------------------------------
+# Dropless routing: scores over all experts, the pairs on held experts
+# laid out expert by expert in whole tiles, gathers both ways
+# ---------------------------------------------------------------------------
+def route_top_k(logits, score_bias, top_k: int, normalize: bool = True,
+                scaling: float = 1.0):
+    """``logits [T, E]`` float32 -> ``(idx [T, k] int32, weight [T, k]
+    float32)``: sigmoid scores, the top k by ``score + score_bias``,
+    weighted by the chosen scores themselves (the bias only picks),
+    divided by their sum when `normalize` and multiplied by `scaling`."""
+    score = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(score + score_bias.astype(jnp.float32), top_k)
+    weight = jnp.take_along_axis(score, idx, axis=-1)
+    if normalize:
+        weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weight * scaling
+
+
+# rows of a tile of the dropless buffer: one expert a tile, the MXU-sized
+# block ops.grouped_matmul multiplies at a time
+DROPLESS_TILE = 512
+
+
+def dropless_layout(idx, lo: int, n_held: int, tile_m: int) -> dict:
+    """Where every (token, expert) pair of ``idx [T, k]`` that falls on
+    the held experts ``lo .. lo + n_held`` sits in a buffer of
+    ``M = (ceil(T k / tile_m) + n_held) * tile_m`` rows: expert by
+    expert, in token order, each expert in whole tiles and in at least
+    one (so a tile has ONE expert and every expert has a tile — what
+    ``ops.grouped_matmul`` asks).  M is the worst case (every pair on a
+    held expert), so no pair is ever dropped; nothing is computed for the
+    tiles past ``tiles_used``.
+
+    Returns int32 arrays: ``dest_row [T, k]`` (the pair's row; M for a
+    pair on an absent expert), ``src_token [M]`` and ``src_pair [M]``
+    (the row's token and flat pair; T and T k for an empty row),
+    ``tile_group [M / tile_m]``, ``tiles_used [1]``, and the counters
+    ``assigned`` (pairs the router put on held experts) and ``load
+    [n_held]`` (rows the buffer really holds, by expert)."""
+    t, k = idx.shape
+    pairs = t * k
+    n_tiles = -(-pairs // tile_m) + n_held
+    m = n_tiles * tile_m
+    local = idx.reshape(-1) - lo
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held)
+    experts = jnp.arange(n_held, dtype=jnp.int32)
+    hit = (key[:, None] == experts[None, :]).astype(jnp.int32)
+    upto = jnp.cumsum(hit, axis=0)
+    counts = upto[-1]                                        # [n_held]
+    rank = jnp.sum((upto - hit) * hit, axis=1)               # among its expert
+    tiles_per = jnp.maximum(1, -(-counts // tile_m))
+    tile_end = jnp.cumsum(tiles_per)
+    row_start = (tile_end - tiles_per) * tile_m
+    pair_start = jnp.cumsum(counts) - counts
+    safe = jnp.minimum(key, n_held - 1)
+    dest_row = jnp.where(held, row_start[safe] + rank, m)
+    # the other direction, by gathers alone: which pair sits in row r
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right"), n_held - 1).astype(jnp.int32)
+    rows = jnp.arange(m, dtype=jnp.int32)
+    row_group = jnp.repeat(tile_group, tile_m)
+    row_rank = rows - row_start[row_group]
+    row_live = (row_rank < counts[row_group]) & \
+        (rows < tile_end[-1] * tile_m)
+    src_pair = jnp.where(
+        row_live, order[jnp.minimum(pair_start[row_group] + row_rank,
+                                    pairs - 1)], pairs)
+    load = jnp.sum((row_group[:, None] == experts[None, :]) &
+                   row_live[:, None], axis=0)
+    return {"dest_row": dest_row.reshape(t, k).astype(jnp.int32),
+            "src_pair": src_pair.astype(jnp.int32),
+            "src_token": jnp.where(row_live, src_pair // k, t).astype(
+                jnp.int32),
+            "tile_group": tile_group,
+            "tiles_used": tile_end[-1:].astype(jnp.int32),
+            "assigned": jnp.sum(held), "load": load}
+
+
+def _rows(table, index):
+    """``table[index]``, reading zero where an index equals
+    ``len(table)`` (an empty row, a pair on an absent expert)."""
+    n = table.shape[0]
+    got = table[jnp.minimum(index, n - 1)]
+    return jnp.where((index < n).reshape(index.shape + (1,) * (table.ndim - 1)),
+                     got, jnp.zeros_like(got))
+
+
+def _sum_over_pairs(table, dest_row, weight=None):
+    """``sum_k weight[:, k] * table[dest_row[:, k]]`` in float32, one
+    gather of T rows a choice (never the [T, k, H] block at once)."""
+    acc = 0.0
+    for j in range(dest_row.shape[1]):
+        got = _rows(table, dest_row[:, j]).astype(jnp.float32)
+        acc = acc + (got if weight is None else got * weight[:, j, None])
+    return acc
+
+
+@jax.custom_vjp
+def _dispatch(x, src_token, dest_row):
+    """``x [T, H]`` -> the buffer ``[M, H]``; both directions gather."""
+    return _rows(x, src_token)
+
+
+def _dispatch_fwd(x, src_token, dest_row):
+    return _rows(x, src_token), dest_row
+
+
+def _dispatch_bwd(dest_row, d_buf):
+    return _sum_over_pairs(d_buf, dest_row).astype(d_buf.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y_buf, weight, layout):
+    """``y_buf [M, H]``, ``weight [T, k]`` -> ``y [T, H]``: each token's
+    rows, weighted and summed in float32."""
+    return _sum_over_pairs(y_buf, layout["dest_row"],
+                           weight).astype(y_buf.dtype)
+
+
+def _combine_fwd(y_buf, weight, layout):
+    return _combine(y_buf, weight, layout), (y_buf, weight, layout)
+
+
+def _combine_bwd(saved, dy):
+    y_buf, weight, layout = saved
+    row_weight = _rows(weight.reshape(-1), layout["src_pair"])
+    d_buf = (_rows(dy, layout["src_token"]).astype(jnp.float32) *
+             row_weight[:, None]).astype(y_buf.dtype)
+    dy32 = dy.astype(jnp.float32)
+    d_weight = jnp.stack(
+        [jnp.sum(_rows(y_buf, layout["dest_row"][:, j]).astype(jnp.float32)
+                 * dy32, axis=-1) for j in range(weight.shape[1])], axis=1)
+    return d_buf, d_weight.astype(weight.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
 class ExpertParallelFFN(Layer):
@@ -212,12 +443,14 @@ class ExpertParallelFFN(Layer):
 
     Parameters are the batched analogue of GPTMLP: w_up [E, H, F],
     w_down [E, F, H]; each expert e computes
-    down(act(up(x_e))) on its capacity slice.
+    down(act(up(x_e))) on its capacity slice.  ``has_bias=False`` (the
+    dropless path's experts) leaves b_up and b_down out.
     """
 
     def __init__(self, num_experts: int, hidden_size: int, ffn_size: int,
                  weight_attr=None, down_weight_attr=None,
-                 ep_axis: str = "ep", activation: str = "gelu"):
+                 ep_axis: str = "ep", activation: str = "gelu",
+                 has_bias: bool = True):
         super().__init__()
         self.num_experts = num_experts
         self.hidden_size = hidden_size
@@ -228,42 +461,71 @@ class ExpertParallelFFN(Layer):
             [num_experts, hidden_size, ffn_size], attr=weight_attr,
             default_initializer=I.Normal(0.0, 0.02))
         self.b_up = self.create_parameter(
-            [num_experts, ffn_size], is_bias=True)
+            [num_experts, ffn_size], is_bias=True) if has_bias else None
         self.w_down = self.create_parameter(
             [num_experts, ffn_size, hidden_size],
             attr=down_weight_attr or weight_attr,
             default_initializer=I.Normal(0.0, 0.02))
         self.b_down = self.create_parameter(
-            [num_experts, hidden_size], is_bias=True)
+            [num_experts, hidden_size], is_bias=True) if has_bias else None
         for p in (self.w_up, self.b_up, self.w_down, self.b_down):
-            mark_sharding(p, PartitionSpec(ep_axis,
-                                           *([None] * (p.ndim - 1))))
+            if p is not None:
+                mark_sharding(p, PartitionSpec(ep_axis,
+                                               *([None] * (p.ndim - 1))))
 
     def act(self, x):
         if self.activation == "gelu":
             return jax.nn.gelu(x, approximate=True)
         if self.activation == "relu":
             return jax.nn.relu(x)
+        if self.activation == "relu2":
+            return jnp.square(jax.nn.relu(x))
         raise ValueError(f"unknown activation {self.activation}")
 
 
 class MoELayer(Layer):
-    """Switch/GShard MoE layer: router + expert-parallel FFN + combine.
+    """MoE layer: router + experts + combine, a drop-in replacement for
+    an MLP block: forward(x [B,S,H]) -> [B,S,H].  One class, two routers
+    (the module docstring has both):
 
-    Drop-in replacement for an MLP block: forward(x [B,S,H]) -> [B,S,H].
-    Router aux losses are emitted via add_aux_loss() (scaled by
-    aux_loss_coeff / z_loss_coeff) AND kept on self.last_aux_loss for
-    direct inspection.
+    - ``capacity_factor`` a number: the Switch/GShard CAPACITY path over
+      all E experts (softmax top-k, tokens over capacity dropped, biased
+      gelu experts, expert-parallel over 'ep').  Router aux losses are
+      emitted via add_aux_loss() (scaled by aux_loss_coeff /
+      z_loss_coeff) AND kept on self.last_aux_loss for inspection.
+    - ``capacity_factor=None``: the DROPLESS path.  Sigmoid scores over
+      all E experts, the top k chosen by score plus
+      ``e_score_correction_bias``, weights the chosen scores, normalised
+      (``normalize_gates``) and multiplied by ``routed_scaling``.  ``held_experts=(lo, hi)`` is this chip's share
+      (None: all E); only those experts' weights exist here, and the
+      result is their part alone.  Experts have no bias.  The layer keeps
+      an int32 buffer ``expert_stats`` (kept pairs by held expert, pairs
+      assigned, tokens), fed through ``record_expert_stats``.
     """
 
     def __init__(self, hidden_size: int, ffn_size: int, num_experts: int,
-                 top_k: int = 2, capacity_factor: float = 1.25,
+                 top_k: int = 2, capacity_factor: Optional[float] = 1.25,
                  aux_loss_coeff: float = 0.01, z_loss_coeff: float = 0.0,
                  normalize_gates: bool = True, ep_axis: str = "ep",
                  weight_attr=None, down_weight_attr=None,
                  activation: str = "gelu",
-                 a2a_chunks: Optional[int] = None):
+                 a2a_chunks: Optional[int] = None,
+                 routed_scaling: float = 1.0,
+                 held_experts: Optional[tuple] = None):
         super().__init__()
+        self.dropless = capacity_factor is None
+        if not self.dropless and (held_experts is not None
+                                  or routed_scaling != 1.0):
+            raise ValueError(
+                "routed_scaling and held_experts belong to the dropless "
+                "path: pass capacity_factor=None with them")
+        lo, hi = held_experts if held_experts is not None \
+            else (0, num_experts)
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(f"held_experts {held_experts} is no range "
+                             f"of the {num_experts} experts")
+        self.held = (int(lo), int(hi))
+        self.routed_scaling = float(routed_scaling)
         self.num_experts = num_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
@@ -282,10 +544,56 @@ class MoELayer(Layer):
         # router stays replicated: every device scores its own tokens
         mark_sharding(self.gate, PartitionSpec(None, None))
         self.experts = ExpertParallelFFN(
-            num_experts, hidden_size, ffn_size, weight_attr=weight_attr,
+            hi - lo, hidden_size, ffn_size, weight_attr=weight_attr,
             down_weight_attr=down_weight_attr, ep_axis=ep_axis,
-            activation=activation)
+            activation=activation, has_bias=not self.dropless)
         self.last_aux_loss: Optional[Tensor] = None
+        if self.dropless:
+            self.e_score_correction_bias = self.create_parameter(
+                [num_experts], default_initializer=I.Constant(0.0))
+            self.register_buffer(
+                EXPERT_STATS_BUFFER,
+                Tensor(jnp.zeros((hi - lo + 2,), jnp.int32)))
+
+    # -- dropless formulation: this chip's experts, every token kept ---
+    def _fn_dropless(self, x, gate, score_bias, w_up, w_down):
+        from ..ops.grouped_matmul import grouped_matmul
+        b, s, h = x.shape
+        tokens = x.reshape(b * s, h)
+        lo, hi = self.held
+        with jax.named_scope("moe_route"):
+            # all float32 passes: at the backend's default a float32
+            # product is one bf16 pass, and near-tied choices then flip
+            logits = jnp.dot(tokens.astype(jnp.float32),
+                             gate.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            idx, weight = route_top_k(
+                logits, score_bias, self.top_k, self.normalize_gates,
+                self.routed_scaling)
+            layout = dropless_layout(idx, lo, hi - lo, DROPLESS_TILE)
+        with jax.named_scope("expert_ffn"):
+            tiles = (layout["tile_group"], layout["tiles_used"])
+            x_buf = _dispatch(tokens, layout["src_token"],
+                              layout["dest_row"])
+            up = grouped_matmul(x_buf, w_up.astype(x.dtype), *tiles,
+                                tile_m=DROPLESS_TILE)
+            y_buf = grouped_matmul(self.experts.act(up),
+                                   w_down.astype(x.dtype), *tiles,
+                                   tile_m=DROPLESS_TILE)
+            y = _combine(y_buf, weight,
+                         {k: layout[k] for k in
+                          ("dest_row", "src_pair", "src_token")})
+        return (y.reshape(b, s, h), layout["load"], layout["assigned"])
+
+    def _forward_dropless(self, x):
+        y, load, assigned = apply(
+            self._fn_dropless, x, self.gate, self.e_score_correction_bias,
+            self.experts.w_up, self.experts.w_down, name="moe_layer")
+        arr = x.data if isinstance(x, Tensor) else jnp.asarray(x)
+        record_expert_stats(
+            load.data, assigned.data, tokens=arr.shape[0] * arr.shape[1],
+            into=self._buffers[EXPERT_STATS_BUFFER])
+        return y
 
     # -- dense/GSPMD formulation -------------------------------------
     def _fn_dense(self, x, gate, w_up, b_up, w_down, b_down):
@@ -542,6 +850,8 @@ class MoELayer(Layer):
 
     def forward(self, x):
         import functools
+        if self.dropless:
+            return self._forward_dropless(x)
         in_sm = _in_shard_map(self.ep_axis)
         serve_mesh = None if in_sm else self._serve_ep_mesh()
         if in_sm:

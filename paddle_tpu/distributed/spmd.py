@@ -1613,6 +1613,14 @@ class SpmdTrainer:
             async_dispatch.record_host_sync()
         else:
             s["skipped_steps"] = 0
+        # expert-balance totals the dropless MoE layers keep in their
+        # buffers (kept pairs by held expert, pairs assigned, tokens):
+        # read here, once, and published process-wide (moe.expert_totals);
+        # None, and nothing read, for a model without such a buffer
+        from .moe import publish_expert_totals
+        s["expert_stats"] = publish_expert_totals(self.buffers)
+        if s["expert_stats"] is not None:
+            async_dispatch.record_host_sync()
         self._timings["sync_ms"] += (time.perf_counter() - t_sync) * 1e3
         for k, v in self._timings.items():
             s[k] = round(v, 3) if isinstance(v, float) else v

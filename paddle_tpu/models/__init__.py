@@ -3,3 +3,5 @@ benchmarks (BASELINE.json configs #3-#5)."""
 from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion,
     StaticKVCache, gpt_configs)
+from .nemotron_h import (NemotronHConfig, NemotronHModel,  # noqa: F401
+                         NemotronHForCausalLM)

@@ -275,7 +275,15 @@ class GPTConfig:
     # None (default) keeps every path bitwise-identical to unquantized.
     quantize: Optional[str] = None
     tp_axis: str = "tp"
-    # MoE (0 experts = dense; BASELINE.json config #5 switch-transformer)
+    # MoE (0 experts = dense; BASELINE.json config #5 switch-transformer).
+    # These blocks take distributed.moe.MoELayer's CAPACITY path: softmax
+    # top-k over all experts with a capacity factor, tokens over an
+    # expert's capacity dropped, biased gelu experts, every expert held
+    # (sharded over 'ep').  The same layer class has a DROPLESS path
+    # beside it (capacity_factor=None: sigmoid scores with a correction
+    # bias, no token dropped, a held_experts share through
+    # ops.grouped_matmul), which models/nemotron_h.py uses; GPTConfig
+    # does not reach it.
     moe_num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 2.0

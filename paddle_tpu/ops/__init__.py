@@ -18,6 +18,9 @@ from .decode_attention import (  # noqa: F401
     write_kv)
 from .fused_cross_entropy import (  # noqa: F401
     fused_linear_cross_entropy, pick_vocab_block)
+from .grouped_matmul import (  # noqa: F401
+    grouped_matmul, grouped_matmul_available)
+from .ssd_scan import causal_conv1d, ssd_scan  # noqa: F401
 from .quantized_matmul import (  # noqa: F401
     quantized_matmul, quantized_matmul_available, fake_quant_matmul,
     quantize_channel, quantize_kv, dequantize_kv, get_qmm_tiles)

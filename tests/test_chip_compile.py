@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 da = importlib.import_module("paddle_tpu.ops.decode_attention")
 fa = importlib.import_module("paddle_tpu.ops.flash_attention")
 qm = importlib.import_module("paddle_tpu.ops.quantized_matmul")
+gm = importlib.import_module("paddle_tpu.ops.grouped_matmul")
 
 SLOTS, SEQ, VOCAB = 8, 2048, 50304
 WIDTHS = [(12, 64), (16, 128)]          # (heads, head_dim): 125m, 1.3b
@@ -145,6 +146,38 @@ def test_paged_window(compile_for_chip, quantized):
     assert_kernel(compile_for_chip(
         da._paged_window_kernel_path,
         *_paged_specs(16, 128, 128, 128, quantized)))
+
+
+def test_flash_gqa_at_8192(compile_for_chip):
+    """The hybrid stack's attention: 32 query heads on 2 KV heads of 128
+    at sequence 8192 (the kernel holds a whole K and V strip in VMEM)."""
+    def loss(q, k, v):
+        mask = jnp.ones((q.shape[0], 1, q.shape[1]), f32)
+        return fa._flash(q, k, v, mask, True).astype(f32).sum()
+
+    text = compile_for_chip(
+        jax.grad(loss, argnums=(0, 1, 2)), ((2, 8192, 32, 128), bf16),
+        ((2, 8192, 2, 128), bf16), ((2, 8192, 2, 128), bf16))
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
+                         ids=["up", "down"])
+def test_grouped_matmul_forward_and_backward(compile_for_chip, k, n):
+    """8 held experts at the published expert width, the dropless worst
+    case of 2 x 8192 tokens x 6 pairs in tiles of 512: the product, its
+    transpose (dx) and the per-expert weight gradient."""
+    tile_m, held = 512, 8
+    tiles = 2 * 8192 * 6 // tile_m + held
+
+    def loss(x, w, group, used):
+        return jnp.square(
+            gm._gmm_vjp(x, w, group, used, tile_m).astype(f32)).sum()
+
+    text = compile_for_chip(
+        jax.grad(loss, argnums=(0, 1)), ((tiles * tile_m, k), bf16),
+        ((held, k, n), bf16), ((tiles,), i32), ((1,), i32))
+    assert text.count("tpu_custom_call") >= 3
 
 
 def test_int8_matmul(compile_for_chip):
