@@ -44,11 +44,18 @@ Gating, two routers inside the ONE layer class (``MoELayer``):
   held experts are laid out in tiles of one expert each
   (``dropless_layout``) and go through ``ops.grouped_matmul``; pairs on
   absent experts are left out — the exchange that would carry them to
-  other chips is not built here.
+  other chips is not built here.  The layout is sized for the worst
+  case (every pair on a held expert), but a share of the experts runs
+  its passes over the first ``dropless_short_tiles`` tiles of it, the
+  load that share should see twice over, and takes the whole of it,
+  inside the step (``lax.cond`` on the tiles in use: no host sync, no
+  second executable), only when the load does not fit.  Either way
+  every pair has its row: nothing is dropped.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import List, Optional
 
@@ -130,7 +137,7 @@ def collect_expert_stats():
         _EXPERT_STATS_STACK.pop()
 
 
-def record_expert_stats(load, assigned, tokens=None, into=None):
+def record_expert_stats(load, assigned, tokens=None, into=None, short=None):
     """MoE layers call this with their per-expert KEPT-pair counts
     ``load [E]`` (what the expert computation really held — may be
     traced) and the number of (token, expert) assignments the router
@@ -140,7 +147,10 @@ def record_expert_stats(load, assigned, tokens=None, into=None):
 
     Two sinks, one mechanism.  An open collector (the serving engine's
     trace) gets the record.  ``into``, a layer's own int32 buffer
-    ``[E + 2]`` (load per held expert, pairs assigned, tokens seen),
+    ``[E + 4]`` (load per held expert, pairs assigned, tokens seen, and
+    `short [2]`: the tokens of the calls that had a short dropless buffer
+    to take and of those that took it, traced, since the branch is taken
+    on the device; zeros from a call with no branch),
     is added to in place: a buffer is state the compiled train step
     threads through and donates, so the counts accumulate on the device
     with no host sync until ``publish_expert_totals`` takes them."""
@@ -150,7 +160,8 @@ def record_expert_stats(load, assigned, tokens=None, into=None):
         row = jnp.concatenate([
             load.astype(jnp.int32),
             jnp.stack([jnp.asarray(assigned, jnp.int32),
-                       jnp.asarray(tokens, jnp.int32)])])
+                       jnp.asarray(tokens, jnp.int32)]),
+            short.astype(jnp.int32)])
         into._data = into._data + row
 
 
@@ -188,17 +199,24 @@ def publish_expert_totals(buffers: dict):
 def expert_totals() -> dict:
     """What was published since ``reset_expert_totals``: per layer the
     kept pairs of each held expert, the pairs the router assigned to
-    them and the tokens seen; over all layers the local pairs a token,
-    the busiest held expert's load over the mean load, and the pairs
-    dropped (assigned less kept)."""
-    layers = {n: {"load": row[:-2], "assigned": row[-2], "tokens": row[-1]}
+    them, the tokens seen, those of them whose call had a short buffer
+    to take and those whose call took it; over all layers the local
+    pairs a token, the busiest held expert's load over the mean load,
+    the pairs dropped (assigned less kept) and the short buffer's share
+    of the tokens that had one to take (1.0: the worst case never ran;
+    None where no call had a branch)."""
+    layers = {n: {"load": row[:-4], "assigned": row[-4], "tokens": row[-3],
+                  "branch_tokens": row[-2], "short_tokens": row[-1]}
               for n, row in _EXPERT_TOTALS.items()}
     loads = [v for rec in layers.values() for v in rec["load"]]
     kept = sum(loads)
     tokens = sum(rec["tokens"] for rec in layers.values())
     assigned = sum(rec["assigned"] for rec in layers.values())
+    branch = sum(rec["branch_tokens"] for rec in layers.values())
+    short = sum(rec["short_tokens"] for rec in layers.values())
     return {"layers": layers, "pairs_dropped": assigned - kept,
             "local_pairs_per_token": kept / tokens if tokens else None,
+            "short_buffer_share": short / branch if branch else None,
             "load_max_over_mean":
                 max(loads) * len(loads) / kept if kept else None}
 
@@ -293,7 +311,9 @@ def top_k_gating(logits, top_k: int, capacity: int,
 
 # ---------------------------------------------------------------------------
 # Dropless routing: scores over all experts, the pairs on held experts
-# laid out expert by expert in whole tiles, gathers both ways
+# laid out expert by expert in whole tiles; between tokens and the
+# buffer's rows by gathers both ways over the worst-case buffer, by rows
+# (a gather out, a scatter-add back) over the short one
 # ---------------------------------------------------------------------------
 def route_top_k(logits, score_bias, top_k: int, normalize: bool = True,
                 scaling: float = 1.0):
@@ -312,6 +332,19 @@ def route_top_k(logits, score_bias, top_k: int, normalize: bool = True,
 # rows of a tile of the dropless buffer: one expert a tile, the MXU-sized
 # block ops.grouped_matmul multiplies at a time
 DROPLESS_TILE = 512
+# the short dropless buffer holds this many times the load a share of the
+# experts should see (tokens x top_k x held / all); a heavier load takes
+# the worst-case buffer inside the step
+DROPLESS_SHORT_LOAD = 2
+
+
+def dropless_short_tiles(tokens: int, top_k: int, n_held: int,
+                         n_experts: int, tile_m: int) -> int:
+    """Tiles of the short buffer: ``DROPLESS_SHORT_LOAD`` times the
+    expected pairs in whole tiles, and one more an expert for the part
+    tile each may end in."""
+    expected = tokens * top_k * n_held / n_experts
+    return math.ceil(DROPLESS_SHORT_LOAD * expected / tile_m) + n_held
 
 
 def dropless_layout(idx, lo: int, n_held: int, tile_m: int) -> dict:
@@ -321,8 +354,11 @@ def dropless_layout(idx, lo: int, n_held: int, tile_m: int) -> dict:
     expert, in token order, each expert in whole tiles and in at least
     one (so a tile has ONE expert and every expert has a tile — what
     ``ops.grouped_matmul`` asks).  M is the worst case (every pair on a
-    held expert), so no pair is ever dropped; nothing is computed for the
-    tiles past ``tiles_used``.
+    held expert), so no pair is ever dropped.  The used tiles are a
+    prefix of the buffer: a caller whose load fits in fewer tiles may cut
+    ``src_token``, ``src_pair`` and ``tile_group`` to them and leave
+    ``dest_row`` as it is (``MoELayer`` does, see
+    ``dropless_short_tiles``).
 
     Returns int32 arrays: ``dest_row [T, k]`` (the pair's row; M for a
     pair on an absent expert), ``src_token [M]`` and ``src_pair [M]``
@@ -435,6 +471,93 @@ def _combine_bwd(saved, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _sum_by_token(rows, src_token, n_tokens, row_weight=None):
+    """``rows [M, H]`` summed in float32 into the tokens they came from
+    ``[n_tokens, H]``: one scatter-add of M rows (an empty row's token is
+    ``n_tokens`` and falls off the end)."""
+    rows = rows.astype(jnp.float32)
+    if row_weight is not None:
+        rows = rows * row_weight[:, None]
+    return jax.ops.segment_sum(rows, src_token, num_segments=n_tokens)
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, src_token, dest_row):
+    """``_dispatch`` whose backward goes by the buffer's rows."""
+    return _rows(x, src_token)
+
+
+def _dispatch_rows_fwd(x, src_token, dest_row):
+    return _rows(x, src_token), (src_token, dest_row)
+
+
+def _dispatch_rows_bwd(saved, d_buf):
+    src_token, dest_row = saved
+    dx = _sum_by_token(d_buf, src_token, dest_row.shape[0])
+    return dx.astype(d_buf.dtype), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(y_buf, weight, layout):
+    """``_combine`` by the buffer's rows, both ways: the forward one
+    scatter-add of M weighted rows, ``d_weight`` a row's dot with the
+    ``dy`` row that ``d_buf`` gathers anyway."""
+    row_weight = _rows(weight.reshape(-1), layout["src_pair"])
+    return _sum_by_token(y_buf, layout["src_token"], weight.shape[0],
+                         row_weight).astype(y_buf.dtype)
+
+
+def _combine_rows_fwd(y_buf, weight, layout):
+    return _combine_rows(y_buf, weight, layout), (y_buf, weight, layout)
+
+
+def _combine_rows_bwd(saved, dy):
+    y_buf, weight, layout = saved
+    row_weight = _rows(weight.reshape(-1), layout["src_pair"])
+    dy_rows = _rows(dy, layout["src_token"]).astype(jnp.float32)
+    d_buf = (dy_rows * row_weight[:, None]).astype(y_buf.dtype)
+    d_row_weight = jnp.sum(y_buf.astype(jnp.float32) * dy_rows, axis=-1)
+    d_weight = _rows(d_row_weight, layout["dest_row"])
+    return d_buf, d_weight.astype(weight.dtype), None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _cond_both_ways(short, full, fits, layout, operands):
+    """``lax.cond(fits, short, full, layout, *operands)`` whose backward
+    is a ``cond`` as well, of each branch's own backward with its forward
+    run again inside it (what a layer under remat does anyway).  Left to
+    differentiate the ``cond`` itself, every branch would write zeros in
+    the shape of the other branch's residuals: the short branch would
+    fill the worst-case buffers it is there to avoid."""
+    return jax.lax.cond(fits, short, full, layout, *operands)
+
+
+def _cond_both_ways_fwd(short, full, fits, layout, operands):
+    return (_cond_both_ways(short, full, fits, layout, operands),
+            (fits, layout, operands))
+
+
+def _cond_both_ways_bwd(short, full, saved, dy):
+    fits, layout, operands = saved
+
+    def backward_of(branch):
+        return lambda layout, operands, dy: jax.vjp(
+            functools.partial(branch, layout), *operands)[1](dy)
+
+    grads = jax.lax.cond(fits, backward_of(short), backward_of(full),
+                         layout, operands, dy)
+    return None, None, grads
+
+
+_cond_both_ways.defvjp(_cond_both_ways_fwd, _cond_both_ways_bwd)
+
+
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
@@ -498,9 +621,16 @@ class MoELayer(Layer):
       ``e_score_correction_bias``, weights the chosen scores, normalised
       (``normalize_gates``) and multiplied by ``routed_scaling``.  ``held_experts=(lo, hi)`` is this chip's share
       (None: all E); only those experts' weights exist here, and the
-      result is their part alone.  Experts have no bias.  The layer keeps
-      an int32 buffer ``expert_stats`` (kept pairs by held expert, pairs
-      assigned, tokens), fed through ``record_expert_stats``.
+      result is their part alone.  Experts have no bias.  A layer that
+      holds a share runs over a buffer of ``dropless_short_tiles`` tiles
+      (16,384 rows for 2 x 8192 tokens on 8 of 128 experts) while
+      ``tiles_used`` fits in it, and over the worst case (102,400 rows)
+      in the other branch of one ``lax.cond`` when it does not; where the
+      worst case is no longer than that (all E held) there is no branch.
+      The layer keeps an int32 buffer ``expert_stats`` (kept pairs by
+      held expert, pairs assigned, tokens, tokens that had a short
+      buffer to take, tokens that took it), fed through
+      ``record_expert_stats``.
     """
 
     def __init__(self, hidden_size: int, ffn_size: int, num_experts: int,
@@ -553,11 +683,34 @@ class MoELayer(Layer):
                 [num_experts], default_initializer=I.Constant(0.0))
             self.register_buffer(
                 EXPERT_STATS_BUFFER,
-                Tensor(jnp.zeros((hi - lo + 2,), jnp.int32)))
+                Tensor(jnp.zeros((hi - lo + 4,), jnp.int32)))
 
     # -- dropless formulation: this chip's experts, every token kept ---
-    def _fn_dropless(self, x, gate, score_bias, w_up, w_down):
+    def _experts_over(self, n_tiles, layout, tokens, weight, w_up, w_down):
+        """Dispatch, up product, activation, down product and combine
+        over the first `n_tiles` tiles of `layout`'s buffer (the used
+        tiles are a prefix of it).  Over the whole buffer the passes that
+        go by token are gathers, `top_k` of T rows each; over a shorter
+        one they go by its rows (one scatter-add of them: on the chip 1.5
+        ms where the six gathers take 4.7, PERF.md section 6, PR 34)."""
         from ..ops.grouped_matmul import grouped_matmul
+        rows = n_tiles * DROPLESS_TILE
+        by_rows = rows < layout["src_token"].shape[0]
+        tiles = (layout["tile_group"][:n_tiles], layout["tiles_used"])
+        src = {"dest_row": layout["dest_row"],
+               "src_pair": layout["src_pair"][:rows],
+               "src_token": layout["src_token"][:rows]}
+        dispatch, combine = (_dispatch_rows, _combine_rows) if by_rows \
+            else (_dispatch, _combine)
+        x_buf = dispatch(tokens, src["src_token"], src["dest_row"])
+        up = grouped_matmul(x_buf, w_up.astype(tokens.dtype), *tiles,
+                            tile_m=DROPLESS_TILE)
+        y_buf = grouped_matmul(self.experts.act(up),
+                               w_down.astype(tokens.dtype), *tiles,
+                               tile_m=DROPLESS_TILE)
+        return combine(y_buf, weight, src)
+
+    def _fn_dropless(self, x, gate, score_bias, w_up, w_down):
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
         lo, hi = self.held
@@ -571,28 +724,31 @@ class MoELayer(Layer):
                 logits, score_bias, self.top_k, self.normalize_gates,
                 self.routed_scaling)
             layout = dropless_layout(idx, lo, hi - lo, DROPLESS_TILE)
+        n_tiles = layout["tile_group"].shape[0]
+        short_tiles = dropless_short_tiles(
+            b * s, self.top_k, hi - lo, self.num_experts, DROPLESS_TILE)
+        operands = (tokens, weight, w_up, w_down)
         with jax.named_scope("expert_ffn"):
-            tiles = (layout["tile_group"], layout["tiles_used"])
-            x_buf = _dispatch(tokens, layout["src_token"],
-                              layout["dest_row"])
-            up = grouped_matmul(x_buf, w_up.astype(x.dtype), *tiles,
-                                tile_m=DROPLESS_TILE)
-            y_buf = grouped_matmul(self.experts.act(up),
-                                   w_down.astype(x.dtype), *tiles,
-                                   tile_m=DROPLESS_TILE)
-            y = _combine(y_buf, weight,
-                         {k: layout[k] for k in
-                          ("dest_row", "src_pair", "src_token")})
-        return (y.reshape(b, s, h), layout["load"], layout["assigned"])
+            full = functools.partial(self._experts_over, n_tiles)
+            if short_tiles >= n_tiles:      # the worst case is no longer
+                y = full(layout, *operands)
+                short = jnp.zeros((2,), jnp.int32)
+            else:
+                fits = layout["tiles_used"][0] <= short_tiles
+                y = _cond_both_ways(
+                    functools.partial(self._experts_over, short_tiles),
+                    full, fits, layout, operands)
+                short = jnp.stack([b * s, jnp.where(fits, b * s, 0)])
+        return y.reshape(b, s, h), layout["load"], layout["assigned"], short
 
     def _forward_dropless(self, x):
-        y, load, assigned = apply(
+        y, load, assigned, short = apply(
             self._fn_dropless, x, self.gate, self.e_score_correction_bias,
             self.experts.w_up, self.experts.w_down, name="moe_layer")
         arr = x.data if isinstance(x, Tensor) else jnp.asarray(x)
         record_expert_stats(
             load.data, assigned.data, tokens=arr.shape[0] * arr.shape[1],
-            into=self._buffers[EXPERT_STATS_BUFFER])
+            into=self._buffers[EXPERT_STATS_BUFFER], short=short.data)
         return y
 
     # -- dense/GSPMD formulation -------------------------------------

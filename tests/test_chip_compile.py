@@ -327,6 +327,70 @@ def test_decode_step_sorts_only_inside_a_branch(one_chip, monkeypatch):
             assert shape not in wide, line[:300]
 
 
+# ---------------------------------------------------------------------------
+# the dropless layer: the worst-case buffer only inside a branch
+# ---------------------------------------------------------------------------
+def test_dropless_layer_runs_its_worst_case_only_inside_a_branch(
+        one_chip, monkeypatch):
+    """One routed layer at the published widths (2 x 8192 tokens, top 6
+    of 128, experts 0-8 held), forward and backward under remat: a
+    ``conditional`` each (the remat's second forward is dead code), whose
+    one branch runs every grouped-matmul kernel over 32 tiles (16,384
+    rows: twice the 6,144 pairs to expect, and a part tile an expert) and
+    whose other over the 200 of the worst case; no kernel outside them."""
+    import re
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.func import functional_call
+    # the CPU process's dispatch would take the composite: the compile
+    # is for the chip, so say so here and not through an option
+    monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    layer = moe.MoELayer(2688, 1856, num_experts=128, top_k=6,
+                         capacity_factor=None, routed_scaling=2.5,
+                         held_experts=(0, 8), activation="relu2")
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=one_chip)
+    params = {n: struct(p.shape, f32) for n, p in layer.named_parameters()}
+    bufs = {n: struct(b.shape, i32) for n, b in layer.named_buffers()}
+    x = struct((2, 8192, 2688), bf16)
+
+    @jax.checkpoint
+    def forward(params, bufs, x):
+        with no_grad():
+            return functional_call(layer, params, bufs, x)
+
+    def loss(params, bufs, x):
+        y, bufs = forward(params, bufs, x)
+        return y.astype(f32).sum(), bufs
+
+    with persistent_cache_off():
+        text = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 2), has_aux=True)).lower(
+                params, bufs, x).compile().as_text()
+    bodies, calls = _computations(text)
+    kernel_tiles = lambda names: sorted(
+        int(n) for name in names for n in re.findall(
+            r'custom_call_target="tpu_custom_call", '
+            r'operand_layout_constraints=\{s32\[(\d+)\]', bodies[name]))
+    conds = [line for body in bodies.values()
+             for line in body.splitlines() if " conditional(" in line]
+    assert len(conds) == 2, [line[:200] for line in conds]
+    worst = 2 * 8192 * 6 // moe.DROPLESS_TILE + 8
+    short = moe.dropless_short_tiles(2 * 8192, 6, 8, 128,
+                                     moe.DROPLESS_TILE)
+    assert (short, worst) == (32, 200)
+    in_branches = set()
+    for line, kernels in zip(sorted(conds, key=len), (2, 6)):
+        branches = re.findall(r"%([\w.-]+)", re.search(
+            r"branch_computations=\{([^}]*)\}", line).group(1))
+        reached = [_reachable(calls, [name]) for name in branches]
+        # forward: up and down; backward: those again, dx and dw of each
+        assert sorted(kernel_tiles(r) for r in reached) == \
+            [[short] * kernels, [worst] * kernels]
+        in_branches |= reached[0] | reached[1]
+    assert not kernel_tiles(set(bodies) - in_branches)
+
+
 def test_mesh_decode_step_gains_no_collective(topo, monkeypatch):
     """The same step over a described 2x2 mesh (dp x tp, weights and
     cache laid out by the engine's own rules): the conditional's

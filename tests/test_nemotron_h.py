@@ -2,8 +2,9 @@
 plain reference (``benchmark/references/nemotron_h.py``) at a small size
 on the CPU: logits, loss and every gradient on seeded weights; the
 chunked scan against the sequential recurrence; the router; the shares
-of one MoE layer adding up to the uncut layer; the grouped matmul kernel
-against its composite; a step through ``SpmdTrainer``."""
+of one MoE layer adding up to the uncut layer, over the short dropless
+buffer and over the worst case; the grouped matmul kernel against its
+composite; a step through ``SpmdTrainer``."""
 import importlib
 
 import jax
@@ -376,6 +377,179 @@ def test_grouped_matmul_kernel_matches_its_composite(dtype):
     assert ops.kernel_paths.counts()["grouped_matmul"]["composite"] == 1
 
 
+# ---------------------------------------------------------------------------
+# the short dropless buffer and the worst case behind one `cond`
+# ---------------------------------------------------------------------------
+# 64 tokens x 3 choices, experts 4..8 of 16 held, tiles of 16 rows: the
+# worst case is 12 + 4 = 16 tiles, the load to expect 48 pairs, the short
+# buffer ceil(2 x 48 / 16) + 4 = 10 tiles.  Pairs on each held expert:
+LOADS = {"short": (12, 12, 12, 12),             # 4 tiles
+         "all_on_one": (48, 48, 48, 48),        # every pair held: 12 tiles
+         "at_the_boundary": (48, 48, 33, 16),   # 3 + 3 + 3 + 1 = 10 tiles
+         "one_past_it": (48, 48, 33, 17)}       # 3 + 3 + 3 + 2 = 11 tiles
+SHORT_TILES, BRANCH_TOKENS = 10, 64
+
+
+def routed_to(counts, seed=17):
+    """``x [1, 64, 128]`` and a router ``gate [128, 16]`` that reads the
+    first 16 features: token by token the top 3 are the experts this
+    builds, ``counts[e]`` tokens on held expert ``4 + e`` and the other
+    choices on absent ones."""
+    rng = np.random.default_rng(seed)
+    picks = [[] for _ in range(BRANCH_TOKENS)]
+    for e, n in enumerate(counts):
+        free = sorted(range(BRANCH_TOKENS),
+                      key=lambda t: (len(picks[t]), (t + 16 * e) % 64))
+        for t in free[:n]:
+            picks[t].append(4 + e)
+    absent = [e for e in range(16) if not 4 <= e < 8]
+    x = rng.normal(size=(1, BRANCH_TOKENS, 128))
+    for t, chosen in enumerate(picks):
+        assert len(chosen) <= 3
+        chosen = chosen + [absent[(t + j) % 12]
+                           for j in range(3 - len(chosen))]
+        x[0, t, :16] = rng.uniform(-0.3, 0.3, 16) - 1.5
+        x[0, t, chosen] += 3.0
+    gate = 0.01 * rng.normal(size=(128, 16))
+    gate[:16] += np.eye(16)
+    return jnp.asarray(x, jnp.float32), jnp.asarray(gate, jnp.float32)
+
+
+def branch_layer(held=(4, 8), seed=19):
+    """A layer of 16 experts, top 3, and seeded weights for its share."""
+    layer = moe.MoELayer(128, 64, num_experts=16, top_k=3,
+                         capacity_factor=None, routed_scaling=2.5,
+                         held_experts=held, activation="relu2")
+    rng = np.random.default_rng(seed)
+    n = layer.held[1] - layer.held[0]
+    params = {
+        "e_score_correction_bias": jnp.zeros((16,), jnp.float32),
+        "experts.w_up": jnp.asarray(
+            0.1 * rng.normal(size=(n, 128, 64)), jnp.float32),
+        "experts.w_down": jnp.asarray(
+            0.1 * rng.normal(size=(n, 64, 128)), jnp.float32)}
+    return layer, params
+
+
+def through(layer, params, bufs, x):
+    from paddle_tpu.core.autograd import no_grad
+    with no_grad():
+        return functional_call(layer, params, bufs, x)
+
+
+@pytest.fixture
+def tiles_of_16(monkeypatch):
+    monkeypatch.setattr(moe, "DROPLESS_TILE", 16)
+    assert moe.dropless_short_tiles(BRANCH_TOKENS, 3, 4, 16, 16) == \
+        SHORT_TILES < BRANCH_TOKENS * 3 // 16 + 4
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_either_branch_is_the_plain_loop(tiles_of_16, load):
+    """The layer's output and every gradient (x, gate, w_up, w_down)
+    against the reference's loop over the held experts, under a load
+    that takes the short buffer, one that takes the worst case, and the
+    two on either side of the boundary; the buffer's last slots say
+    which branch ran."""
+    counts = LOADS[load]
+    x, gate = routed_to(counts)
+    layer, params = branch_layer()
+    params["gate"] = gate
+    probe = jnp.asarray(np.random.default_rng(23).normal(size=x.shape),
+                        jnp.float32)
+    ref_kw, _ = small("E", held_experts=[4, 8], num_experts_per_tok=3)
+    c = R.cfg(ref_kw)
+
+    def program(params, x):
+        y, bufs = through(layer, params, buffers_of(layer), x)
+        return jnp.sum(y * probe), (y, bufs["expert_stats"])
+
+    def reference(params, x):
+        p = {"mixer.routed." + k: v for k, v in params.items()}
+        y = R.moe_routed(c, x[0], p, jnp.matmul, held=(4, 8))
+        return jnp.sum(y * probe[0]), y
+
+    (_, (y, stats)), got = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(params, x)
+    (_, want_y), want = jax.value_and_grad(
+        reference, argnums=(0, 1), has_aux=True)(params, x)
+    stats = np.asarray(stats)
+    np.testing.assert_array_equal(stats[:4], counts)    # nothing dropped
+    assert stats[4] == sum(counts) and stats[5] == BRANCH_TOKENS
+    tiles = sum(-(-n // 16) for n in counts)
+    assert stats[6] == BRANCH_TOKENS                # it had a branch
+    assert stats[7] == (BRANCH_TOKENS if tiles <= SHORT_TILES else 0)
+    np.testing.assert_allclose(y[0], want_y, atol=2e-5)
+    for name in ("gate", "experts.w_up", "experts.w_down"):
+        np.testing.assert_allclose(got[0][name], want[0][name], atol=2e-5,
+                                   err_msg=name)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-5)
+    assert float(jnp.max(jnp.abs(got[0]["gate"]))) > 1e-3
+
+
+@pytest.mark.parametrize("steps,share", [
+    (("short",), 1.0), (("all_on_one",), 0.0),
+    (("short", "one_past_it", "at_the_boundary"), 2 / 3)])
+def test_short_buffer_share_counts_the_branch_taken(tiles_of_16, steps,
+                                                    share):
+    """Over a sequence of steps, through ``publish_expert_totals``'
+    take-and-zero: the buffer starts again from zero, a second reading
+    of nothing leaves the share, and a further step moves it."""
+    layer, params = branch_layer()
+    bufs = buffers_of(layer)
+    step = jax.jit(lambda params, bufs, x: through(layer, params, bufs, x))
+    moe.reset_expert_totals()
+    for load in steps:
+        x, params["gate"] = routed_to(LOADS[load])
+        _, bufs = step(params, bufs, x)
+    held = {"layer.expert_stats": bufs["expert_stats"]}
+    totals = moe.publish_expert_totals(held)
+    assert totals["short_buffer_share"] == pytest.approx(share)
+    assert totals["pairs_dropped"] == 0
+    rec = totals["layers"]["layer.expert_stats"]
+    assert rec["tokens"] == rec["branch_tokens"] == \
+        BRANCH_TOKENS * len(steps)
+    assert rec["short_tokens"] == round(share * rec["tokens"])
+    assert int(np.asarray(held["layer.expert_stats"]).sum()) == 0
+    assert moe.publish_expert_totals(held)["short_buffer_share"] == \
+        pytest.approx(share)
+    x, params["gate"] = routed_to(LOADS["short"])
+    _, bufs = step(params, {"expert_stats": held["layer.expert_stats"]}, x)
+    again = moe.publish_expert_totals(
+        {"layer.expert_stats": bufs["expert_stats"]})
+    n = len(steps)
+    assert again["short_buffer_share"] == pytest.approx(
+        (share * n + 1) / (n + 1))
+    moe.reset_expert_totals()
+    assert moe.expert_totals()["short_buffer_share"] is None
+
+
+@pytest.mark.parametrize("held,branches", [(None, 0), ((4, 8), 1)])
+def test_a_layer_holding_every_expert_has_no_branch(tiles_of_16, held,
+                                                    branches):
+    """Its worst case is the load to expect, so its program carries no
+    ``cond``, its buffer's last two slots stay at zero and the share
+    reads None; a share of the experts carries one."""
+    x, gate = routed_to(LOADS["short"])
+    layer, params = branch_layer(held)
+    params["gate"] = gate
+    run = lambda params, x: through(layer, params, buffers_of(layer), x)
+    text = str(jax.make_jaxpr(run)(params, x))
+    assert text.count(" cond[") == branches, text[:2000]
+    _, bufs = run(params, x)
+    np.testing.assert_array_equal(bufs["expert_stats"][-2:],
+                                  [branches * BRANCH_TOKENS] * 2)
+    moe.reset_expert_totals()
+    totals = moe.publish_expert_totals({"layer.expert_stats":
+                                        bufs["expert_stats"]})
+    assert totals["short_buffer_share"] == (1.0 if branches else None)
+    # the three existing fields as before: every pair, or the 48 held
+    assert totals["local_pairs_per_token"] == (3.0 if held is None
+                                               else 48 / BRANCH_TOKENS)
+    assert totals["pairs_dropped"] == 0
+    moe.reset_expert_totals()
+
+
 def test_capacity_arguments_are_refused_on_the_wrong_path():
     with pytest.raises(ValueError, match="dropless"):
         moe.MoELayer(16, 32, 4, capacity_factor=1.25, held_experts=(0, 2))
@@ -383,14 +557,26 @@ def test_capacity_arguments_are_refused_on_the_wrong_path():
         moe.MoELayer(16, 32, 4, capacity_factor=None, held_experts=(2, 6))
 
 
-def test_train_step_through_spmd_trainer_never_recompiles():
+@pytest.mark.parametrize("flips", [False, True],
+                         ids=["one_buffer", "branch_flips"])
+def test_train_step_through_spmd_trainer_never_recompiles(monkeypatch,
+                                                          flips):
+    """With tiles of 512 the 64 tokens' worst case is as short as the
+    short buffer and the step has no branch; with tiles of 8 it has one
+    (52 tiles against 28), and a router pushed onto the held experts for
+    one step (4 x 64 pairs: 32 tiles) takes the worst case and comes
+    back, in the same executable."""
     from paddle_tpu.distributed import SpmdTrainer, create_mesh
+    if flips:
+        monkeypatch.setattr(moe, "DROPLESS_TILE", 8)
     from paddle_tpu.distributed.fleet import DistributedStrategy
     from paddle_tpu.optimizer import Adam
     from paddle_tpu.utils import compile_counter
     ref_kw, kw = small("MEM*E")
     model = NemotronHForCausalLM(NemotronHConfig(**kw, fused_ce=True))
     flat = seeded(ref_kw)
+    bias = {n: np.asarray(v) for n, v in flat.items()
+            if n.endswith("e_score_correction_bias")}
     for name, p in dict(model.named_parameters()).items():
         p.data = flat[name]
     crit = GPTPretrainingCriterion()
@@ -406,7 +592,13 @@ def test_train_step_through_spmd_trainer_never_recompiles():
     batches = [ids_of(2, 32, seed=s) for s in range(3)]
     first = float(trainer.train_step(*batches[0]))
     snap = compile_counter.snapshot()
-    losses = [float(trainer.train_step(*b)) for b in batches[1:]]
+    losses = []
+    for push, batch in zip((10.0 * flips, 0.0), batches[1:]):
+        for n, value in bias.items():
+            trainer.params[n] = jax.device_put(
+                value + push * (np.arange(16) < 4),
+                trainer.params[n].sharding)
+        losses.append(float(trainer.train_step(*batch)))
     assert snap.new_compiles == 0 and snap.new_traces == 0
     assert np.isfinite([first] + losses).all()
     assert abs(first - np.log(256)) < 0.1
@@ -416,8 +608,10 @@ def test_train_step_through_spmd_trainer_never_recompiles():
     assert totals["pairs_dropped"] == 0
     assert all(rec["tokens"] == 3 * 64 for rec in totals["layers"].values())
     # 6 of 16 experts a token, 4 held: 1.5 local pairs a token expected
-    # (the seeded correction bias moves it on 192 tokens)
-    assert 0.5 < totals["local_pairs_per_token"] < 2.5
+    # (the seeded correction bias moves it on 192 tokens), 4 in the step
+    # whose router was pushed onto the held experts
+    assert 0.5 < totals["local_pairs_per_token"] - flips * 2.5 / 3 < 2.5
+    assert totals["short_buffer_share"] == (2 / 3 if flips else None)
     assert totals["load_max_over_mean"] >= 1.0
     # a reading TAKES the counts: the buffers start again from zero (an
     # int32 holds the tokens between two readings, the totals are Python
