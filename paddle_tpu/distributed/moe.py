@@ -37,7 +37,10 @@ Gating, two routers inside the ONE layer class (``MoELayer``):
 - the DROPLESS path (``capacity_factor=None``): sigmoid scores over
   all E experts (the router's product in float32 at the highest
   precision), top-k chosen by score plus a correction bias, the chosen scores normalised and scaled, NO token dropped, no
-  auxiliary loss.  ``held_experts=(lo, hi)`` tells the layer which
+  auxiliary loss.  Its experts have no bias and are ``W_down act(x
+  W_up)`` for ``activation`` gelu, relu or relu2, or GATED for
+  ``"swiglu"``: ``W_down (silu(x W_gate) * (x W_up))``, a third stacked
+  weight ``w_gate`` and a third grouped product over the same rows.  ``held_experts=(lo, hi)`` tells the layer which
   experts live on this chip: it routes over all E and computes its own
   experts' part of the result (``None`` holds all E, and the parts of
   all shares add up to that).  The (token, expert) pairs that fall on
@@ -567,8 +570,12 @@ class ExpertParallelFFN(Layer):
     Parameters are the batched analogue of GPTMLP: w_up [E, H, F],
     w_down [E, F, H]; each expert e computes
     down(act(up(x_e))) on its capacity slice.  ``has_bias=False`` (the
-    dropless path's experts) leaves b_up and b_down out.
+    dropless path's experts) leaves b_up and b_down out.  A gated
+    ``activation`` ("swiglu") adds ``w_gate [E, H, F]``:
+    down(silu(gate(x_e)) * up(x_e)).
     """
+
+    GATED = ("swiglu",)
 
     def __init__(self, num_experts: int, hidden_size: int, ffn_size: int,
                  weight_attr=None, down_weight_attr=None,
@@ -583,6 +590,10 @@ class ExpertParallelFFN(Layer):
         self.w_up = self.create_parameter(
             [num_experts, hidden_size, ffn_size], attr=weight_attr,
             default_initializer=I.Normal(0.0, 0.02))
+        self.w_gate = self.create_parameter(
+            [num_experts, hidden_size, ffn_size], attr=weight_attr,
+            default_initializer=I.Normal(0.0, 0.02)) \
+            if activation in self.GATED else None
         self.b_up = self.create_parameter(
             [num_experts, ffn_size], is_bias=True) if has_bias else None
         self.w_down = self.create_parameter(
@@ -591,12 +602,17 @@ class ExpertParallelFFN(Layer):
             default_initializer=I.Normal(0.0, 0.02))
         self.b_down = self.create_parameter(
             [num_experts, hidden_size], is_bias=True) if has_bias else None
-        for p in (self.w_up, self.b_up, self.w_down, self.b_down):
+        for p in (self.w_up, self.w_gate, self.b_up, self.w_down,
+                  self.b_down):
             if p is not None:
                 mark_sharding(p, PartitionSpec(ep_axis,
                                                *([None] * (p.ndim - 1))))
 
-    def act(self, x):
+    def act(self, x, gate=None):
+        """The activation of the up product `x` (a gated one also takes
+        the gate product)."""
+        if self.activation == "swiglu":
+            return jax.nn.silu(gate) * x
         if self.activation == "gelu":
             return jax.nn.gelu(x, approximate=True)
         if self.activation == "relu":
@@ -621,7 +637,8 @@ class MoELayer(Layer):
       ``e_score_correction_bias``, weights the chosen scores, normalised
       (``normalize_gates``) and multiplied by ``routed_scaling``.  ``held_experts=(lo, hi)`` is this chip's share
       (None: all E); only those experts' weights exist here, and the
-      result is their part alone.  Experts have no bias.  A layer that
+      result is their part alone.  Experts have no bias; ``activation``
+      "swiglu" makes them gated (``experts.w_gate``).  A layer that
       holds a share runs over a buffer of ``dropless_short_tiles`` tiles
       (16,384 rows for 2 x 8192 tokens on 8 of 128 experts) while
       ``tiles_used`` fits in it, and over the worst case (102,400 rows)
@@ -645,10 +662,12 @@ class MoELayer(Layer):
         super().__init__()
         self.dropless = capacity_factor is None
         if not self.dropless and (held_experts is not None
-                                  or routed_scaling != 1.0):
+                                  or routed_scaling != 1.0
+                                  or activation in ExpertParallelFFN.GATED):
             raise ValueError(
-                "routed_scaling and held_experts belong to the dropless "
-                "path: pass capacity_factor=None with them")
+                "routed_scaling, held_experts and a gated activation "
+                "belong to the dropless path: pass capacity_factor=None "
+                "with them")
         lo, hi = held_experts if held_experts is not None \
             else (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -686,8 +705,10 @@ class MoELayer(Layer):
                 Tensor(jnp.zeros((hi - lo + 4,), jnp.int32)))
 
     # -- dropless formulation: this chip's experts, every token kept ---
-    def _experts_over(self, n_tiles, layout, tokens, weight, w_up, w_down):
-        """Dispatch, up product, activation, down product and combine
+    def _experts_over(self, n_tiles, layout, tokens, weight, w_up, w_down,
+                      w_gate=None):
+        """Dispatch, up product (and gate product, for gated experts),
+        activation, down product and combine
         over the first `n_tiles` tiles of `layout`'s buffer (the used
         tiles are a prefix of it).  Over the whole buffer the passes that
         go by token are gathers, `top_k` of T rows each; over a shorter
@@ -703,14 +724,14 @@ class MoELayer(Layer):
         dispatch, combine = (_dispatch_rows, _combine_rows) if by_rows \
             else (_dispatch, _combine)
         x_buf = dispatch(tokens, src["src_token"], src["dest_row"])
-        up = grouped_matmul(x_buf, w_up.astype(tokens.dtype), *tiles,
-                            tile_m=DROPLESS_TILE)
-        y_buf = grouped_matmul(self.experts.act(up),
-                               w_down.astype(tokens.dtype), *tiles,
-                               tile_m=DROPLESS_TILE)
+        product = lambda rows, w: grouped_matmul(
+            rows, w.astype(tokens.dtype), *tiles, tile_m=DROPLESS_TILE)
+        gate = None if w_gate is None else product(x_buf, w_gate)
+        y_buf = product(self.experts.act(product(x_buf, w_up), gate),
+                        w_down)
         return combine(y_buf, weight, src)
 
-    def _fn_dropless(self, x, gate, score_bias, w_up, w_down):
+    def _fn_dropless(self, x, gate, score_bias, w_up, w_down, *w_gate):
         b, s, h = x.shape
         tokens = x.reshape(b * s, h)
         lo, hi = self.held
@@ -727,7 +748,7 @@ class MoELayer(Layer):
         n_tiles = layout["tile_group"].shape[0]
         short_tiles = dropless_short_tiles(
             b * s, self.top_k, hi - lo, self.num_experts, DROPLESS_TILE)
-        operands = (tokens, weight, w_up, w_down)
+        operands = (tokens, weight, w_up, w_down) + w_gate
         with jax.named_scope("expert_ffn"):
             full = functools.partial(self._experts_over, n_tiles)
             if short_tiles >= n_tiles:      # the worst case is no longer
@@ -742,9 +763,11 @@ class MoELayer(Layer):
         return y.reshape(b, s, h), layout["load"], layout["assigned"], short
 
     def _forward_dropless(self, x):
+        gated = () if self.experts.w_gate is None else (self.experts.w_gate,)
         y, load, assigned, short = apply(
             self._fn_dropless, x, self.gate, self.e_score_correction_bias,
-            self.experts.w_up, self.experts.w_down, name="moe_layer")
+            self.experts.w_up, self.experts.w_down, *gated,
+            name="moe_layer")
         arr = x.data if isinstance(x, Tensor) else jnp.asarray(x)
         record_expert_stats(
             load.data, assigned.data, tokens=arr.shape[0] * arr.shape[1],

@@ -5,3 +5,5 @@ from .gpt import (  # noqa: F401
     StaticKVCache, gpt_configs)
 from .nemotron_h import (NemotronHConfig, NemotronHModel,  # noqa: F401
                          NemotronHForCausalLM)
+from .kimi_linear import (KimiLinearConfig, KimiLinearModel,  # noqa: F401
+                          KimiLinearForCausalLM)

@@ -21,6 +21,7 @@ from .fused_cross_entropy import (  # noqa: F401
 from .grouped_matmul import (  # noqa: F401
     grouped_matmul, grouped_matmul_available)
 from .ssd_scan import causal_conv1d, ssd_scan  # noqa: F401
+from .kda_scan import kda_scan  # noqa: F401
 from .quantized_matmul import (  # noqa: F401
     quantized_matmul, quantized_matmul_available, fake_quant_matmul,
     quantize_channel, quantize_kv, dequantize_kv, get_qmm_tiles)

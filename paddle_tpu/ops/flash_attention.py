@@ -25,7 +25,13 @@ An optional key-padding mask [B, S] (1 = attend, 0 = masked) covers the
 padded-batch pretraining case without an O(S²) bias tensor; arbitrary
 additive masks still fall back to the XLA composite.
 
-Layout contract: q [B, S, H, D], k/v [B, S, Hkv, D] with H % Hkv == 0.
+Layout contract: q [B, S, H, D], k [B, S, Hkv, D], v [B, S, Hkv, Dv] with
+H % Hkv == 0.  The scores' width D and the values' width Dv are two
+numbers: every kernel takes q and k at D (the softmax scale is
+``D ** -0.5``) and v, o and do at Dv, so latent attention's 192-wide
+scores over 128-wide values (128 + 64 channels, no padding to 256) run
+through the same three kernels as D == Dv.  Each width is 64, 192 or a
+multiple of 128.
 """
 from __future__ import annotations
 
@@ -86,10 +92,10 @@ def flash_attention_available() -> bool:
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *,
                 block_k: int, causal: bool, scale: float):
-    """One (bh, g, q_block) program. q_ref [bq,d]; k/v [S,d]; m_ref (1,S)
-    key mask; outputs o [bq,d] and lse (1,bq)."""
-    block_q, d = q_ref.shape
-    s = k_ref.shape[0]
+    """One (bh, g, q_block) program. q_ref [bq,d]; k [S,d]; v [S,dv];
+    m_ref (1,S) key mask; outputs o [bq,dv] and lse (1,bq)."""
+    block_q = q_ref.shape[0]
+    s, dv = v_ref.shape
     n_k = s // block_k
 
     # keep q/k/v in their storage dtype (bf16) for the MXU dots — f32
@@ -101,7 +107,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *,
 
     m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, dv), jnp.float32)
 
     def body(j, carry):
         m, l, acc = carry
@@ -198,9 +204,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, m_ref,
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, m_ref,
                     dk_ref, dv_ref, *, block_q: int, causal: bool,
                     scale: float, n_groups: int):
-    """One (bh, k_block, g): dk/dv for this k block, accumulated over the
-    GQA group grid dimension (g innermost; init at g == 0)."""
+    """One (bh, k_block, g): dk [bk,d] / dv [bk,dv] for this k block,
+    accumulated over the GQA group grid dimension (g innermost; init at
+    g == 0)."""
     block_k, d = k_ref.shape
+    dv = v_ref.shape[1]
     s = q_ref.shape[0]
     n_q = s // block_q
     kj = pl.program_id(1)
@@ -246,7 +254,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, m_ref,
     i0 = k_start // block_q if causal else 0
     dk, dv = jax.lax.fori_loop(
         i0, n_q, body, (jnp.zeros((block_k, d), jnp.float32),
-                        jnp.zeros((block_k, d), jnp.float32)))
+                        jnp.zeros((block_k, dv), jnp.float32)))
     # dk_j = scale * Σ ds_ij q_i (scale was folded into q before the
     # bf16-input rework; now applied once here)
     dk = dk * scale
@@ -265,7 +273,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, m_ref,
 
 # ---------------------------------------------------------------------------
 # pallas_call wrappers over the GQA layout
-#   q4 [BHkv, G, S, D], k3/v3 [BHkv, S, D], mask [B, 1, S]
+#   q4 [BHkv, G, S, D], k3 [BHkv, S, D], v3 [BHkv, S, Dv], mask [B, 1, S]
 # ---------------------------------------------------------------------------
 def _pick_block(s, want=256):
     while s % want:
@@ -543,8 +551,27 @@ def autotune_sweep(seq: int, head_dim: int, causal: bool, batch: int = 1,
     return best
 
 
+_SCOPED_VMEM = 16 * 2 ** 20     # what a kernel may use unless it asks
+
+
+def _strip_room(s: int, d: int, dv: int, itemsize: int) -> dict:
+    """Every kernel keeps two whole strips of the sequence in VMEM (k and
+    v, or q and do), double-buffered, each width rounded up to whole lane
+    tiles.  Up to 8192 x (128 + 128) in bf16 they fit the compiler's own
+    budget beside the blocks and the score tile, and the call is built as
+    it always was; wider (192 counts as 256) it asks for the strips and
+    that budget on top."""
+    lanes = lambda w: -(-w // 128) * 128
+    strips = 2 * s * (lanes(d) + lanes(dv)) * itemsize
+    if strips <= _SCOPED_VMEM // 2:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=strips + _SCOPED_VMEM)}
+
+
 def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
     bhkv, g, s, d = q4.shape
+    dv = v3.shape[2]
     hkv = bhkv // mask.shape[0]
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
@@ -559,21 +586,22 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
             pl.BlockSpec((None, None, block_q, d),
                          lambda b, gi, i: (b, gi, i, 0)),
             pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),
+            pl.BlockSpec((None, s, dv), lambda b, gi, i: (b, 0, 0)),
             pl.BlockSpec((None, 1, s),
                          lambda b, gi, i, hkv=hkv: (b // hkv, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
+            pl.BlockSpec((None, None, block_q, dv),
                          lambda b, gi, i: (b, gi, i, 0)),
             pl.BlockSpec((None, None, 1, block_q),
                          lambda b, gi, i: (b, gi, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bhkv, g, s, d), q4.dtype),
+            jax.ShapeDtypeStruct((bhkv, g, s, dv), q4.dtype),
             jax.ShapeDtypeStruct((bhkv, g, 1, s), jnp.float32),
         ],
         interpret=_INTERPRET,
+        **_strip_room(s, d, dv, q4.dtype.itemsize),
     )
     return run_kernel(q4.dtype, call, q4, k3, v3, mask)
 
@@ -581,6 +609,7 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
 def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
              block_q=512, block_k=512):
     bhkv, g, s, d = q4.shape
+    dv = v3.shape[2]
     hkv = bhkv // mask.shape[0]
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
@@ -598,8 +627,8 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
             pl.BlockSpec((None, None, block_q, d),
                          lambda b, gi, i: (b, gi, i, 0)),   # q
             pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),  # k
-            pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),  # v
-            pl.BlockSpec((None, None, block_q, d),
+            pl.BlockSpec((None, s, dv), lambda b, gi, i: (b, 0, 0)),  # v
+            pl.BlockSpec((None, None, block_q, dv),
                          lambda b, gi, i: (b, gi, i, 0)),   # do
             pl.BlockSpec((None, None, 1, block_q),
                          lambda b, gi, i: (b, gi, 0, i)),   # lse
@@ -612,6 +641,7 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
                                lambda b, gi, i: (b, gi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bhkv, g, s, d), q4.dtype),
         interpret=_INTERPRET,
+        **_strip_room(s, d, dv, q4.dtype.itemsize),
     )
     dq = run_kernel(q4.dtype, call, q4, k3, v3, do4, lse, delta, mask)
 
@@ -624,11 +654,11 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
         in_specs=[
             pl.BlockSpec((None, block_k, d),
                          lambda b, j, gi: (b, j, 0)),       # k
-            pl.BlockSpec((None, block_k, d),
+            pl.BlockSpec((None, block_k, dv),
                          lambda b, j, gi: (b, j, 0)),       # v
             pl.BlockSpec((None, None, s, d),
                          lambda b, j, gi: (b, gi, 0, 0)),   # q (one group)
-            pl.BlockSpec((None, None, s, d),
+            pl.BlockSpec((None, None, s, dv),
                          lambda b, j, gi: (b, gi, 0, 0)),   # do
             pl.BlockSpec((None, None, 1, s),
                          lambda b, j, gi: (b, gi, 0, 0)),   # lse
@@ -639,16 +669,17 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), lambda b, j, gi: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, j, gi: (b, j, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda b, j, gi: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
+            jax.ShapeDtypeStruct((bhkv, s, dv), jnp.float32),
         ],
         interpret=_INTERPRET,
+        **_strip_room(s, d, dv, q4.dtype.itemsize),
     )
-    dk, dv = run_kernel(q4.dtype, call, k3, v3, q4, do4, lse, delta, mask)
-    return dq, dk.astype(k3.dtype), dv.astype(v3.dtype)
+    dk, d_v = run_kernel(q4.dtype, call, k3, v3, q4, do4, lse, delta, mask)
+    return dq, dk.astype(k3.dtype), d_v.astype(v3.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -661,7 +692,7 @@ def _to_gqa(q, k, v):
     # q head index = hk * g + gi (repeat_interleave convention)
     q4 = jnp.swapaxes(q, 1, 2).reshape(b * hkv, g, s, d)
     k3 = jnp.swapaxes(k, 1, 2).reshape(b * hkv, s, d)
-    v3 = jnp.swapaxes(v, 1, 2).reshape(b * hkv, s, d)
+    v3 = jnp.swapaxes(v, 1, 2).reshape(b * hkv, s, v.shape[3])
     return q4, k3, v3
 
 
@@ -670,7 +701,8 @@ def _from_gqa_q(o4, b, s, h, d):
 
 
 def _composite(q, k, v, causal, kv_mask=None):
-    """XLA reference math on [B,S,H,D] (k/v may have fewer heads)."""
+    """XLA reference math on [B,S,H,D] (k/v may have fewer heads, v
+    another width)."""
     b, s, h, d = q.shape
     hkv = k.shape[2]
     if hkv != h:
@@ -712,7 +744,7 @@ def _flash_fwd_impl(q, k, v, mask, causal):
     q4, k3, v3 = _to_gqa(q, k, v)
     bq, bk = get_block_sizes(s, d, causal)
     o4, lse = _fwd_gqa(q4, k3, v3, mask, causal, block_q=bq, block_k=bk)
-    return _from_gqa_q(o4, b, s, h, d), (q, k, v, mask, o4, lse)
+    return _from_gqa_q(o4, b, s, h, v.shape[3]), (q, k, v, mask, o4, lse)
 
 
 def _flash_fwd(q, k, v, mask, causal):
@@ -722,29 +754,36 @@ def _flash_fwd(q, k, v, mask, causal):
 def _flash_bwd(causal, res, g_out):
     q, k, v, mask, o4, lse = res
     b, s, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[3]
     q4, k3, v3 = _to_gqa(q, k, v)
-    do4 = jnp.swapaxes(g_out, 1, 2).reshape(b * hkv, h // hkv, s, d)
+    do4 = jnp.swapaxes(g_out, 1, 2).reshape(b * hkv, h // hkv, s, dv)
     bq, bk = get_block_sizes(s, d, causal)
     dq4, dk3, dv3 = _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
                              block_q=bq, block_k=bk)
     dq = _from_gqa_q(dq4, b, s, h, d).astype(q.dtype)
     dk = jnp.swapaxes(dk3.reshape(b, hkv, s, d), 1, 2)
-    dv = jnp.swapaxes(dv3.reshape(b, hkv, s, d), 1, 2)
-    return dq, dk, dv, jnp.zeros_like(mask)
+    d_v = jnp.swapaxes(dv3.reshape(b, hkv, s, dv), 1, 2)
+    return dq, dk, d_v, jnp.zeros_like(mask)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _width_served(d: int) -> bool:
+    """A head width the kernels take: whole lane tiles, 64, or 192 (a
+    tile and a half: latent attention's 128 + 64 score channels)."""
+    return d % 128 == 0 or d in (64, 192)
+
+
 def flash_attention(q, k, v, causal=False, kv_mask=None):
-    """q [B,S,H,D]; k/v [B,S,Hkv,D] (GQA native — no head expansion);
-    kv_mask optional [B,S] (1 = key attended, 0 = padding). Pallas fused
-    fwd+bwd when shapes allow, XLA composite otherwise."""
+    """q [B,S,H,D]; k [B,S,Hkv,D]; v [B,S,Hkv,Dv] (GQA native — no head
+    expansion; Dv may differ from D, the output is [B,S,H,Dv]); kv_mask
+    optional [B,S] (1 = key attended, 0 = padding). Pallas fused fwd+bwd
+    when shapes allow, XLA composite otherwise."""
     b, s, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
-    supported = (s == sk and s % 128 == 0 and (d % 128 == 0 or d == 64)
-                 and h % hkv == 0)
+    supported = (s == sk and s % 128 == 0 and _width_served(d)
+                 and _width_served(v.shape[3]) and h % hkv == 0)
     if not supported or not flash_attention_available():
         kernel_paths.note_composite("flash_attention", supported)
         return _composite(q, k, v, causal, kv_mask)

@@ -151,8 +151,11 @@ def _conv_bwd(saved, dy):
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-def causal_conv1d(x, weight, bias):
+def causal_conv1d(x, weight, bias=None):
     """Depthwise causal convolution over the sequence: ``x [b, s, C]``,
-    ``weight [C, K]``, ``bias [C]``; position t reads t-K+1 .. t."""
+    ``weight [C, K]``, ``bias [C]`` (None: no bias); position t reads
+    t-K+1 .. t."""
+    if bias is None:
+        bias = jnp.zeros(weight.shape[:1], weight.dtype)
     with jax.named_scope("mamba_conv"):
         return _conv(x, weight, bias)

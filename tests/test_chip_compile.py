@@ -328,6 +328,57 @@ def test_decode_step_sorts_only_inside_a_branch(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the linear-attention / latent-attention stack's two mixers
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind,kernels", [("kda", 0), ("mla", 3)])
+def test_kimi_mixer_at_published_widths(one_chip, monkeypatch, kind,
+                                        kernels):
+    """One KDA mixer (32 heads of 128 x 128 state, conv 4, chunks of 64,
+    heads in rematerialised groups) and one latent-attention mixer (32
+    heads, 192-wide scores on 128-wide values through the flash kernels:
+    forward, dq and dk/dv at least; no composite, no padding to 256) at 2 x 8192 x 2304, forward and backward under remat:
+    the chip's compiler takes them, and the KDA mixer's temporaries stay
+    under a third of the chip."""
+    from paddle_tpu import ops
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.func import functional_call
+    from paddle_tpu.models import kimi_linear as K
+    # the CPU process's dispatch would take the composite: the compile
+    # is for the chip, so say so here and not through an option
+    for mod in (fa, ops):
+        monkeypatch.setattr(mod, "flash_attention_available", lambda: True)
+    cfg = K.KimiLinearConfig(vocab_size=20480, num_hidden_layers=5,
+                             held_experts=(0, 8))
+    layer = {"kda": K.KDAMixer, "mla": K.MLAttention}[kind](cfg)
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=one_chip)
+    params = {n: struct(p.shape, bf16) for n, p in layer.named_parameters()}
+    x = struct((2, 8192, 2304), bf16)
+
+    @jax.checkpoint
+    def forward(params, x):
+        with no_grad():
+            return functional_call(layer, params, {}, x)[0]
+
+    loss = lambda params, x: forward(params, x).astype(f32).sum()
+    ops.kernel_paths.reset()
+    with persistent_cache_off():
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile()
+    if kind == "mla":
+        assert compiled.as_text().count("tpu_custom_call") >= kernels
+        assert ops.kernel_paths.counts()["flash_attention"] == \
+            {"kernel": 1, "composite": 0}
+        assert "256]" not in "".join(
+            line for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line)
+    else:
+        assert compiled.as_text().count("tpu_custom_call") == kernels
+        assert "kda_scan" not in ops.kernel_paths.counts()
+        assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
 # the dropless layer: the worst-case buffer only inside a branch
 # ---------------------------------------------------------------------------
 def test_dropless_layer_runs_its_worst_case_only_inside_a_branch(
