@@ -3,8 +3,13 @@
 The reference's equivalent is the C++/CUDA operator library
 (/root/reference/paddle/fluid/operators/); here the op library is the XLA
 op set (paddle_tpu.tensor / nn.functional lowerings), and this package
-holds only the kernels XLA won't produce on its own — fused attention
-today, with room for fused optimizers / collectives-overlapped matmuls.
+holds only the kernels XLA won't produce on its own: fused attention
+(flash, decode, paged), the grouped product over held experts, the KDA
+scan's chunk kept in VMEM (``kda_scan`` dispatches to
+``kda_chunk_kernel`` on the chip), the blocked cross-entropy and the
+quantized products.  ``ssd_scan`` is XLA's chunked form alone (a kernel
+lost to it).  Every entry point with two paths records the one it traced
+in ``kernel_paths``.
 """
 from . import kernel_paths  # noqa: F401
 from .flash_attention import (  # noqa: F401

@@ -30,22 +30,43 @@ block's first row ``r``: ``k_i exp(Gamma_i - Gamma_r)`` and
 product a level of halving.  Inside a sub-block the ``[SUB, SUB, K]`` differences
 ``exp(Gamma_i - Gamma_j)`` are formed directly (every exponent at most
 0), in slices of the batch so that the block of differences stays small,
-each slice rematerialised in the backward.
+each slice rematerialised in the backward.  (The kernels halve on, down
+to single rows, and form no block of differences.)
 
 Precision: ``g``, ``Gamma``, every decay, the solve and the carried
 state are float32 whatever the inputs; the matrix products take their
 operands in the inputs' dtype (bf16 under AMP) and accumulate in
-float32.  The backward is this chunked form differentiated by JAX; the
-triangular solve brings its own rule.
+float32.
 
-Memory.  Differentiated whole, the form keeps some thirty ``[b, s, H,
-K]`` float32 intermediates for its backward: 0.15 GiB a (row, head) pair
-of 8192 positions and 128 channels, 8.9 GiB for 2 rows of 32 heads.
-Heads are independent, so a caller with many of them maps over groups of
-heads, each group rematerialised (``models/kimi_linear.py`` does).
+Two paths, one contract.  On the chip, for heads of whole lane tiles (K
+and V multiples of 128), ``ops/kda_chunk_kernel.py`` keeps a chunk in
+VMEM: Pallas kernels under one ``jax.custom_vjp``, forward and
+hand-derived backward, the state carried over the chunks in a VMEM
+scratch.  Everywhere else (no tpu, narrow heads) XLA's own operations
+run the form below, `_chunked`, whose backward is that form
+differentiated by JAX (the triangular solve brings its own rule); it is
+also the oracle of the kernels' tests.  ``ops.kernel_paths`` records the
+choice under ``kda_scan``.
 
-There is ONE implementation, in XLA's own operations, on the chip and
-off it, so there is no choice for ``ops.kernel_paths`` to record.
+Why the kernels (one head group, 2 x 8192 x 4 heads of 128, bf16, on a
+v5e; scratch timings of PR 37, PERF.md section 6): XLA's form writes
+every ``[C, K]`` intermediate of a chunk to HBM (11.7 GB forward and
+backward for 0.1 GB of inputs and outputs) and takes 5.24 ms forward and
+14.41 forward and backward; the kernels take 2.46 and 4.17.  Cut where
+the kernels' stages are, XLA's pair terms, solve, ``W`` and ``U`` take
+4.36 and 10.62 ms and its pass over the state with the outputs 2.10 and
+5.45; of the kernels' 2.46 and 4.17 the pass over the state with the
+outputs is 1.24 and 2.96.
+
+Memory of XLA's form.  Differentiated whole, it keeps some thirty ``[b,
+s, H, K]`` float32 intermediates for its backward: 0.15 GiB a (row,
+head) pair of 8192 positions and 128 channels, 8.9 GiB for 2 rows of 32
+heads.  Heads are independent, so a caller with many of them maps over
+groups of heads, each group rematerialised (``models/kimi_linear.py``
+does).  The kernels keep 112 KB a chunk and head under bf16 (the state
+entering in float32, the inverse and the two pair squares in bf16): 14
+MiB a (row, head) pair of 8192 positions, 112 MiB for a group of 4 heads
+of 2 rows, 0.875 GiB for 2 rows of 32 heads.
 """
 from __future__ import annotations
 
@@ -53,6 +74,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from . import kda_chunk_kernel, kernel_paths
 
 __all__ = ["kda_scan"]
 
@@ -77,8 +100,32 @@ def kda_scan(q, k, v, g, beta, chunk: int = 64):
         raise ValueError(f"chunk must be {SUB} times a power of two (the "
                          f"pair terms halve it down to {SUB} rows), got "
                          f"{chunk}")
+    supported = kda_chunk_kernel.serves(q.shape[-1], v.shape[-1], int(chunk))
     with jax.named_scope("kda_scan"):
-        return _chunked(q, k, v, g, beta, int(chunk))
+        if not supported or not kda_chunk_kernel.available():
+            kernel_paths.note_composite("kda_scan", supported)
+            return _chunked(q, k, v, g, beta, int(chunk))
+        kernel_paths.note("kda_scan", "kernel")
+        return _in_vmem(q, k, v, g, beta, int(chunk))
+
+
+def _whole_chunks(c, *arrays):
+    """``arrays [b, s, ..]`` with ``s`` padded to whole chunks of ``c``
+    by positions of zeros."""
+    pad = (-arrays[0].shape[1]) % c
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+                 for t in arrays)
+
+
+def _in_vmem(q, k, v, g, beta, c):
+    """The kernels' path: the same casts and padding as `_chunked`."""
+    s, cdt = q.shape[1], v.dtype
+    o = kda_chunk_kernel.scan(*_whole_chunks(
+        c, q.astype(cdt), k.astype(cdt), v, g.astype(_F32),
+        beta.astype(_F32)), c)
+    return o[:, :s]
 
 
 @jax.checkpoint
@@ -161,12 +208,8 @@ def _chunked(q, k, v, g, beta, c):
     bsz, s, n_heads, kdim = q.shape
     vdim = v.shape[-1]
     cdt = v.dtype
-    pad = (-s) % c
-    if pad:
-        widen = lambda t: jnp.pad(t, [(0, 0), (0, pad)] +
-                                  [(0, 0)] * (t.ndim - 2))
-        q, k, v, g, beta = map(widen, (q, k, v, g, beta))
-    nc = (s + pad) // c
+    q, k, v, g, beta = _whole_chunks(c, q, k, v, g, beta)
+    nc = q.shape[1] // c
     # [b, nc, H, C, .]
     heads = lambda t: jnp.moveaxis(
         t.reshape((bsz, nc, c, n_heads) + t.shape[3:]), 3, 2)
@@ -203,4 +246,4 @@ def _chunked(q, k, v, g, beta, c):
     o = _dot("...ck,...kv->...cv", q_plus, before) + \
         _dot("...ij,...jv->...iv", m_qk.astype(cdt), u_new)
     o = jnp.moveaxis(o, 2, 3).reshape(bsz, nc * c, n_heads, vdim)
-    return (o[:, :s] if pad else o).astype(cdt)
+    return o[:, :s].astype(cdt)

@@ -138,12 +138,25 @@ def test_kda_with_the_carried_state_cut_differs():
     assert float(jnp.max(jnp.abs(broken[:, 32:] - want[:, 32:]))) > 0.05
 
 
-def test_kda_scan_has_one_path_and_notes_no_fallback():
+@pytest.mark.parametrize("widths,reason", [
+    (dict(kdim=128, vdim=128), "backend is not tpu"),
+    (dict(kdim=16, vdim=8), "shape not served by the kernel"),
+    (dict(kdim=128, vdim=64), "shape not served by the kernel")])
+def test_kda_scan_notes_its_path(widths, reason):
+    """Off the chip the scan is XLA's form and says why: there is no tpu,
+    or (heads narrower than a lane tile) the kernel would not serve the
+    shape anywhere.  A chunk the pair terms cannot halve is refused
+    before either path."""
     ops.kernel_paths.reset()
-    ops.kda_scan(*scan_inputs(6, 1, 40), chunk=16)
-    assert "kda_scan" not in ops.kernel_paths.counts()
+    args = scan_inputs(6, 1, 40, **widths)
+    got = ops.kda_scan(*args, chunk=16)
+    assert ops.kernel_paths.counts()["kda_scan"] == \
+        {"kernel": 0, "composite": 1}
+    assert ops.kernel_paths.last_reason("kda_scan") == reason
+    np.testing.assert_allclose(got, recurrence(*args), atol=2e-6)
     with pytest.raises(ValueError, match="power of two"):
-        ops.kda_scan(*scan_inputs(6, 1, 40), chunk=48)
+        ops.kda_scan(*args, chunk=48)
+    assert ops.kernel_paths.counts()["kda_scan"]["composite"] == 1
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
