@@ -1599,9 +1599,9 @@ def _smoke_telemetry():
     doc = tr.chrome_trace()
     n_events = obs.validate_chrome_trace(doc)
     names = {e["name"] for e in doc["traceEvents"]}
-    if "dispatch" not in names:
+    if "train_step/launch" not in names:
         raise SystemExit(
-            f"bench --smoke: no 'dispatch' span in the trace "
+            f"bench --smoke: no 'train_step/launch' span in the trace "
             f"({n_events} events; names {sorted(names)[:12]})")
     with tempfile.TemporaryDirectory() as td:
         trace_path = os.path.join(td, "trace.json")

@@ -175,14 +175,17 @@ class StepResult(_Deferred):
     the caller requested them; they are never synced here.
     """
 
-    __slots__ = ("_raw", "_value", "_resolved", "_timings", "outputs")
+    __slots__ = ("_raw", "_value", "_resolved", "_timings", "outputs",
+                 "_step")
 
-    def __init__(self, loss, timings: Optional[dict] = None, outputs=None):
+    def __init__(self, loss, timings: Optional[dict] = None, outputs=None,
+                 step: Optional[int] = None):
         self._raw = loss
         self._value = None
         self._resolved = False
         self._timings = timings
         self.outputs = outputs
+        self._step = step                 # the train step that made it
 
     @property
     def loss(self):
@@ -202,12 +205,16 @@ class StepResult(_Deferred):
         return v
 
     def _compute(self):
+        # the host's read of the loss: the span may close long after the
+        # train_step that launched the work, and says which one that was
+        from ..observability.spans import span
         data = self._unwrap(self._raw)
-        try:
-            return float(data)
-        except (TypeError, ValueError):
-            import numpy as np
-            return float(np.asarray(data))
+        with span("train_step/read", "train", step=self._step):
+            try:
+                return float(data)
+            except (TypeError, ValueError):
+                import numpy as np
+                return float(np.asarray(data))
 
     def item(self):
         return self.resolve()
@@ -215,10 +222,12 @@ class StepResult(_Deferred):
     def block_until_ready(self):
         """Barrier: wait for the device to finish this step (counted as a
         sync point; no host transfer)."""
+        from ..observability.spans import span
         t0 = time.perf_counter()
         target = self._unwrap(self._raw)
         if hasattr(target, "block_until_ready"):
-            target.block_until_ready()
+            with span("train_step/read", "train", step=self._step):
+                target.block_until_ready()
         record_host_sync()
         if self._timings is not None:
             self._timings["sync_ms"] = (

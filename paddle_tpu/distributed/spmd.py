@@ -649,15 +649,13 @@ class SpmdTrainer:
             arr = jnp.asarray(arr)
             return jax.device_put(arr, self._batch_sharding(arr))
 
-        out = jax.tree_util.tree_map(
-            put, batch, is_leaf=lambda x: isinstance(x, Tensor))
+        with _spans.span("train_step/h2d", "train",
+                         step=self._step_count + 1):
+            out = jax.tree_util.tree_map(
+                put, batch, is_leaf=lambda x: isinstance(x, Tensor))
         dt = (time.perf_counter() - t0) * 1e3
         with self._timings_lock:
             self._timings["h2d_ms"] += dt
-        tr = _spans.tracer()
-        if tr.active:
-            now = tr.now_us()
-            tr.complete("h2d", now - dt * 1e3, dt * 1e3, cat="train")
         return out
 
     def _analyze_comm(self, key, args):
@@ -707,7 +705,9 @@ class SpmdTrainer:
                                   int(d.id) for d in
                                   np.asarray(self.mesh.devices).flat]}})
         t0 = time.perf_counter()
-        res = self._compiled[key](*args)
+        with _spans.span("train_step/launch", "train",
+                         step=self._step_count + 1):
+            res = self._compiled[key](*args)
         dt = (time.perf_counter() - t0) * 1e3
         if key in self._first_call_keys:
             self._timings["dispatch_ms"] += dt
@@ -719,11 +719,6 @@ class SpmdTrainer:
             self._timings["compile_ms_cold"] += dt
             _exec_registry.registry().note_compile(
                 self._exec_component, key, dt)
-        tr = _spans.tracer()
-        if tr.active:
-            now = tr.now_us()
-            tr.complete("dispatch", now - dt * 1e3, dt * 1e3, cat="train",
-                        args={"key": str(key)})
         return res
 
     # ------------------------------------------------------------------
@@ -774,18 +769,23 @@ class SpmdTrainer:
             return self._loss_and_buffers({**tp, **frozen_p}, buffers,
                                           inputs, labels, scale=scale)
 
-        (_, (new_buffers, outs, loss)), grads = jax.value_and_grad(
-            lfn, has_aux=True)(train_p)
+        # what a trace shows under fwd_bwd and under no scope of the
+        # model is the trainer's glue: AMP casts, the layer scan's
+        # stacking, gradient casts
+        with jax.named_scope("fwd_bwd"):
+            (_, (new_buffers, outs, loss)), grads = jax.value_and_grad(
+                lfn, has_aux=True)(train_p)
         grads = {n: grads.get(n, jnp.zeros_like(a))
                  for n, a in params.items()}
         return loss, new_buffers, grads, (outs if want_outputs else None)
 
     def _apply(self, params, opt_state, grads, lr, step_no):
-        new_train, new_state = self.optimizer.apply_gradients(
-            {n: a for n, a in params.items() if self._trainable[n]},
-            {n: g for n, g in grads.items() if self._trainable[n]},
-            {n: s for n, s in opt_state.items() if self._trainable[n]},
-            lr=lr, step=step_no)
+        with jax.named_scope("optimizer"):
+            new_train, new_state = self.optimizer.apply_gradients(
+                {n: a for n, a in params.items() if self._trainable[n]},
+                {n: g for n, g in grads.items() if self._trainable[n]},
+                {n: s for n, s in opt_state.items() if self._trainable[n]},
+                lr=lr, step=step_no)
         new_params = {n: new_train.get(n, a) for n, a in params.items()}
         new_opt = {n: new_state.get(n, s) for n, s in opt_state.items()}
         return new_params, new_opt
@@ -1131,14 +1131,6 @@ class SpmdTrainer:
 
         return jax.jit(fwd)
 
-    @staticmethod
-    def _span_sync(dt_ms: float):
-        tr = _spans.tracer()
-        if tr.active:
-            now = tr.now_us()
-            tr.complete("sync", now - dt_ms * 1e3, dt_ms * 1e3,
-                        cat="train")
-
     def _watchdog_beat(self):
         """Arm the stall watchdog on the first step when
         PADDLE_TPU_WATCHDOG_S is set, then heartbeat it: one monotonic
@@ -1331,6 +1323,12 @@ class SpmdTrainer:
             # PADDLE_TPU_PROFILE=start:stop — device capture windowed on
             # the step counter (observability.capture)
             self._profile.on_step(self._step_count)
+        n = self._step_count + 1
+        with _spans.step_span("train_step", "train", step_num=n, step=n):
+            return self._train_step(inputs, labels, return_outputs)
+
+    def _train_step(self, inputs, labels, return_outputs):
+        """train_step's body, inside its ``train_step`` span."""
         inputs = inputs if isinstance(inputs, (tuple, list)) else (inputs,)
         labels = labels if isinstance(labels, (tuple, list)) else (labels,)
         batch = self.shard_batch(tuple(inputs) + tuple(labels))
@@ -1377,25 +1375,29 @@ class SpmdTrainer:
             if self._anom_rollback:
                 # one host sync per step — the policy's documented price
                 t_sync = time.perf_counter()
-                self._handle_rollback(guard)
+                with _spans.span("train_step/read", "train",
+                                 step=self._step_count):
+                    self._handle_rollback(guard)
                 async_dispatch.record_host_sync()
                 dt_sync = (time.perf_counter() - t_sync) * 1e3
                 self._timings["sync_ms"] += dt_sync
-                self._span_sync(dt_sync)
             elif guard is not None:
                 t_sync = time.perf_counter()
-                self._raise_nonfinite(
-                    guard, names=["loss"] if self.fp16_scaling else None)
+                with _spans.span("train_step/read", "train",
+                                 step=self._step_count):
+                    self._raise_nonfinite(
+                        guard,
+                        names=["loss"] if self.fp16_scaling else None)
                 async_dispatch.record_host_sync()
                 dt_sync = (time.perf_counter() - t_sync) * 1e3
                 self._timings["sync_ms"] += dt_sync
-                self._span_sync(dt_sync)
             from ..testing import faults as _faults
             _faults.maybe_sigterm(self._step_count)
             _faults.maybe_hang(self._step_count)
             self._telemetry_step_end()
             self._membership_tick()
-            result = StepResult(loss, timings=self._timings, outputs=outs)
+            result = StepResult(loss, timings=self._timings, outputs=outs,
+                                step=self._step_count)
             return (result, outs) if return_outputs else result
         if return_outputs:
             raise NotImplementedError(
@@ -1442,7 +1444,8 @@ class SpmdTrainer:
         _faults.maybe_hang(self._step_count)
         self._telemetry_step_end()
         self._membership_tick()
-        return StepResult(loss, timings=self._timings)
+        return StepResult(loss, timings=self._timings,
+                          step=self._step_count)
 
     def eval_step(self, inputs):
         # an eval loop is progress too: heartbeat (never arm — an

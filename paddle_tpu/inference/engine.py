@@ -728,17 +728,21 @@ class InferenceEngine:
         (per-slot) top-p sampling. logits [N, V] f32.  The
         vocabulary-wide work runs only when a row of this call samples:
         the program branches on its own ``temps`` operand."""
-        logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            logits = logits.astype(jnp.float32)
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        def sampling():
-            s_logits, sort_idx = self._warp_sorted(logits, temps, top_ps)
-            choice = jax.random.categorical(key, s_logits, axis=-1)
-            sampled = jnp.take_along_axis(
-                sort_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
-            return jnp.where(temps > 0, sampled, greedy)
+            def sampling():
+                s_logits, sort_idx = self._warp_sorted(logits, temps,
+                                                       top_ps)
+                choice = jax.random.categorical(key, s_logits, axis=-1)
+                sampled = jnp.take_along_axis(
+                    sort_idx, choice[:, None],
+                    axis=-1)[:, 0].astype(jnp.int32)
+                return jnp.where(temps > 0, sampled, greedy)
 
-        return jax.lax.cond(jnp.any(temps > 0), sampling, lambda: greedy)
+            return jax.lax.cond(jnp.any(temps > 0), sampling,
+                                lambda: greedy)
 
     def _decode_fn(self, params, cache, tokens, active, key, temps,
                    top_ps):
@@ -746,7 +750,8 @@ class InferenceEngine:
             logits, cache = functional_apply(self.model, "decode_step",
                                              params, tokens, cache,
                                              active)
-        key, sub = jax.random.split(key)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
         nxt = self._sample_from_logits(logits, sub, temps, top_ps)
         return nxt, key, cache, _moe.fold_expert_stats(b)
 
@@ -757,7 +762,8 @@ class InferenceEngine:
                                              "decode_step_paged",
                                              params, tokens, cache,
                                              tables, lengths)
-        key, sub = jax.random.split(key)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
         nxt = self._sample_from_logits(logits, sub, temps, top_ps)
         return nxt, key, cache, _moe.fold_expert_stats(b)
 
@@ -1139,13 +1145,15 @@ class InferenceEngine:
         plen = prompt.size
         req.t_admit = time.perf_counter()
         self._timings["prefill_tokens"] += bucket
-        logits, cache, moe = self._timed_exec(
-            "prefill_ms", ("prefill", bucket), self._prefill_jit,
-            self.params, self.cache, jnp.asarray(ids),
-            np.int32(slot), np.int32(plen))
-        self.cache = cache
-        self._accum_moe(moe)
-        self._record_admission(req, slot, plen, logits)
+        with _spans.span("prefill", "serve", bucket=bucket,
+                         prompt_tokens=plen):
+            logits, cache, moe = self._timed_exec(
+                "prefill_ms", ("prefill", bucket), self._prefill_jit,
+                self.params, self.cache, jnp.asarray(ids),
+                np.int32(slot), np.int32(plen))
+            self.cache = cache
+            self._accum_moe(moe)
+            self._record_admission(req, slot, plen, logits)
 
     def _admit_paged(self, req: Request, slot: int) -> bool:
         """Paged admission: one in-engine prefill, then the same slot
@@ -1235,18 +1243,20 @@ class InferenceEngine:
         ids[0, :suffix.size] = suffix
         row = np.zeros(self.blocks_per_slot, np.int32)
         row[:len(blocks)] = blocks
-        if prefix_len == 0:
-            logits, cache, moe = self._timed_exec(
-                "prefill_ms", (key_prefix, bucket), cold_jit,
-                dom.params, dom.cache, jnp.asarray(ids),
-                jnp.asarray(row), np.int32(suffix.size),
-                mesh=dom.mesh)
-        else:
-            logits, cache, moe = self._timed_exec(
-                "prefill_ms", (key_prefix + "_ext", bucket), ext_jit,
-                dom.params, dom.cache, jnp.asarray(ids),
-                jnp.asarray(row), np.int32(prefix_len),
-                np.int32(suffix.size), mesh=dom.mesh)
+        with _spans.span("prefill", "serve", bucket=bucket,
+                         prompt_tokens=int(suffix.size)):
+            if prefix_len == 0:
+                logits, cache, moe = self._timed_exec(
+                    "prefill_ms", (key_prefix, bucket), cold_jit,
+                    dom.params, dom.cache, jnp.asarray(ids),
+                    jnp.asarray(row), np.int32(suffix.size),
+                    mesh=dom.mesh)
+            else:
+                logits, cache, moe = self._timed_exec(
+                    "prefill_ms", (key_prefix + "_ext", bucket), ext_jit,
+                    dom.params, dom.cache, jnp.asarray(ids),
+                    jnp.asarray(row), np.int32(prefix_len),
+                    np.int32(suffix.size), mesh=dom.mesh)
         dom.cache = cache
         self._accum_moe(moe)
 
@@ -1412,22 +1422,25 @@ class InferenceEngine:
             budget -= adv
         if not advance.any():
             return 0
-        self._timings["prefill_tokens"] += int(advance.sum())
-        if self.kv_layout == "paged":
-            logits, cache, moe = self._timed_exec(
-                "prefill_ms", ("prefill_chunk_paged", c),
-                self._prefill_chunk_paged_jit,
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self._tables),
-                jnp.asarray(self._slot_len.astype(np.int32)),
-                jnp.asarray(advance))
-        else:
-            logits, cache, moe = self._timed_exec(
-                "prefill_ms", ("prefill_chunk", c),
-                self._prefill_chunk_jit,
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self._slot_len.astype(np.int32)),
-                jnp.asarray(advance))
+        advanced = int(advance.sum())
+        self._timings["prefill_tokens"] += advanced
+        with _spans.span("prefill", "serve", bucket=c,
+                         prompt_tokens=advanced):
+            if self.kv_layout == "paged":
+                logits, cache, moe = self._timed_exec(
+                    "prefill_ms", ("prefill_chunk_paged", c),
+                    self._prefill_chunk_paged_jit,
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(self._tables),
+                    jnp.asarray(self._slot_len.astype(np.int32)),
+                    jnp.asarray(advance))
+            else:
+                logits, cache, moe = self._timed_exec(
+                    "prefill_ms", ("prefill_chunk", c),
+                    self._prefill_chunk_jit,
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(self._slot_len.astype(np.int32)),
+                    jnp.asarray(advance))
         self.cache = cache
         if moe is not None:
             # park the fold: np.asarray'ing it here would cost a host
@@ -1476,8 +1489,7 @@ class InferenceEngine:
         _flightrec.record(
             "chunk_tick",
             dur_ms=(time.perf_counter() - tick_wall0) * 1e3,
-            prefilling=len(pre), tokens=int(advance.sum()),
-            graduated=produced)
+            prefilling=len(pre), tokens=advanced, graduated=produced)
         return produced
 
     def _graduate(self, req: Request, slot: int, tok: int):
@@ -1707,16 +1719,84 @@ class InferenceEngine:
         """Admit queued requests into free slots, then decode one token
         for every active slot. Returns the number of tokens produced
         this step (admission prefills included)."""
-        produced = 0
         self._watchdog_beat()
         if self._retuner is not None:
             # runs a PENDING retune episode only on a quiesced replica
             # (no active slots, empty queue); O(1) otherwise
             self._retuner.on_tick()
-        tick_wall0 = time.perf_counter()
         if self._profile is not None:
             # PADDLE_TPU_PROFILE=start:stop over DECODE TICKS
             self._profile.on_step(self._timings["decode_steps"])
+        n = self._timings["decode_steps"] + 1
+        with _spans.step_span("tick", "serve", step_num=n, tick=n) as tick:
+            return self._tick(tick, n)
+
+    def _tick(self, tick, n: int) -> int:
+        """step()'s body inside its ``tick`` span; the four children
+        ``tick/admit``, ``tick/launch``, ``tick/read`` and
+        ``tick/commit`` tile it.  `n` numbers the decode tick that this
+        call launches, if it launches one."""
+        tick_wall0 = time.perf_counter()
+        with _spans.span("tick/admit", "serve", tick=n):
+            produced = self._admit_queued()
+        with _spans.span("tick/launch", "serve", tick=n):
+            launched = self._launch_decode(tick)
+        if launched is None:
+            self._watchdog_idle_if_empty()
+            return produced
+        if self._spec is not None:
+            produced += self._commit_spec(n, *launched)
+            self._watchdog_idle_if_empty()
+            return produced
+        n_active, sampled, nxt, moe = launched
+        # the ONE host sync of the decode step: the scheduler needs the
+        # sampled ids for EOS retirement and admission (the expert-load
+        # fold, when present, is a sibling output of the same executable
+        # — fetching it here rides the same sync)
+        with _spans.span("tick/read", "serve", tick=n):
+            t0 = time.perf_counter()
+            nxt_np = np.asarray(nxt)
+            self._flush_moe()    # parked chunk-tick folds ride this sync
+            self._accum_moe(moe)
+            async_dispatch.record_host_sync()
+            self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
+        with _spans.span("tick/commit", "serve", tick=n):
+            self._timings["decode_steps"] += 1
+            self._timings["sampled_ticks"] += sampled
+            self._m_ticks.inc()
+            self._m_tokens.inc(n_active)
+            commit_now = time.perf_counter()
+            for slot, req in enumerate(self._slots):
+                # prefilling rows were inactive this step: their sampled
+                # token and cache write are masked garbage, not a commit
+                if req is None or req.prefilling:
+                    continue
+                tok = int(nxt_np[slot])
+                self._slot_len[slot] += 1    # the token we just appended
+                req.generated.append(tok)
+                req.token_times.append(commit_now)
+                self._next_token[slot] = tok
+                produced += 1
+                self._timings["tokens_generated"] += 1
+                self._retire_if_done(req, tok)
+            # flight-recorder ring (host counters only — zero extra
+            # syncs) + deterministic stall injection for the watchdog
+            # tests
+            _flightrec.record(
+                "decode_tick",
+                dur_ms=(time.perf_counter() - tick_wall0) * 1e3,
+                tick=self._timings["decode_steps"], active=n_active,
+                tokens=produced)
+            from ..testing import faults as _faults
+            _faults.maybe_hang(self._timings["decode_steps"])
+            self._watchdog_idle_if_empty()
+        return produced
+
+    def _admit_queued(self) -> int:
+        """``tick/admit``: expire, admit queued requests into free slots
+        (running their prefills) and advance chunked prefills.  Returns
+        the first tokens produced."""
+        produced = 0
         self._m_queue.set(len(self._queue))
         self._retire_expired()
         stall_t0 = time.perf_counter()
@@ -1761,34 +1841,36 @@ class InferenceEngine:
             # NOT gated on _admitting: a draining engine must finish
             # the prompts already bound to slots
             produced += self._chunk_tick()
-        active_np = np.asarray(
+        return produced
+
+    def _active_mask(self):
+        return np.asarray(
             [1 if (r is not None and not r.prefilling) else 0
              for r in self._slots], np.int32)
+
+    def _launch_decode(self, tick):
+        """``tick/launch``: the active mask, the uploads and the dispatch
+        of the decode (or speculative) step.  None when no slot is
+        active; else what the read and the commit need."""
+        active_np = self._active_mask()
         if not active_np.any():
-            self._watchdog_idle_if_empty()
-            return produced
+            return None
         if self._spec is not None:
-            produced += self._step_spec()
-            self._watchdog_idle_if_empty()
-            return produced
+            return self._launch_spec(tick)
         if self.kv_layout == "paged":
             self._ensure_decode_room()
             # a preemption/memory-capped retirement may have emptied
             # slots; refresh the mask BEFORE accumulating occupancy so
             # the stats describe the decode step that actually runs
-            active_np = np.asarray(
-                [1 if (r is not None and not r.prefilling) else 0
-                 for r in self._slots], np.int32)
+            active_np = self._active_mask()
             if not active_np.any():
-                self._watchdog_idle_if_empty()
-                return produced
+                return None
             self._timings["block_occupancy_sum"] += \
                 self._alloc.num_in_use / self._alloc.capacity
-        self._timings["occupancy_sum"] += float(active_np.mean())
-        n_active = int(active_np.sum())
-        self._m_active.set(n_active)
-        tick_t0 = self._tracer.now_us() if self._tracer.active else 0.0
         sampled = int((self._temps > 0).any())
+        n_active = self._note_active(
+            tick, active_np, 1,
+            sampled_ticks=self._timings["sampled_ticks"] + sampled)
         if self.kv_layout == "paged":
             nxt, self._key, cache, moe = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_paged_jit,
@@ -1806,57 +1888,24 @@ class InferenceEngine:
                 jnp.asarray(active_np), self._key,
                 jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         self.cache = cache
-        # the ONE host sync of the decode step: the scheduler needs the
-        # sampled ids for EOS retirement and admission (the expert-load
-        # fold, when present, is a sibling output of the same executable
-        # — fetching it here rides the same sync)
-        t0 = time.perf_counter()
-        nxt_np = np.asarray(nxt)
-        self._flush_moe()        # parked chunk-tick folds ride this sync
-        self._accum_moe(moe)
-        async_dispatch.record_host_sync()
-        self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
-        self._timings["decode_steps"] += 1
-        self._timings["sampled_ticks"] += sampled
-        self._m_ticks.inc()
-        self._m_tokens.inc(n_active)
-        if self._tracer.active:
-            now_us = self._tracer.now_us()
-            self._tracer.complete(
-                "decode_tick", tick_t0, now_us - tick_t0, cat="serve",
-                args={"active": n_active,
-                      "sampled_ticks": self._timings["sampled_ticks"]})
-        commit_now = time.perf_counter()
-        for slot, req in enumerate(self._slots):
-            # prefilling rows were inactive this step: their sampled
-            # token and cache write are masked garbage, not a commit
-            if req is None or req.prefilling:
-                continue
-            tok = int(nxt_np[slot])
-            self._slot_len[slot] += 1        # the token we just appended
-            req.generated.append(tok)
-            req.token_times.append(commit_now)
-            self._next_token[slot] = tok
-            produced += 1
-            self._timings["tokens_generated"] += 1
-            self._retire_if_done(req, tok)
-        # flight-recorder ring (host counters only — zero extra syncs)
-        # + deterministic stall injection for the watchdog tests
-        _flightrec.record(
-            "decode_tick",
-            dur_ms=(time.perf_counter() - tick_wall0) * 1e3,
-            tick=self._timings["decode_steps"], active=n_active,
-            tokens=produced)
-        from ..testing import faults as _faults
-        _faults.maybe_hang(self._timings["decode_steps"])
-        self._watchdog_idle_if_empty()
-        return produced
+        return n_active, sampled, nxt, moe
 
-    def _step_spec(self) -> int:
-        """One speculative tick for every active slot: draft proposes
-        K, target verifies K+1 in one executable, the scheduler commits
-        the accepted prefix + bonus token.  Still exactly ONE host sync
-        — it just pays for ~K+1 tokens now."""
+    def _note_active(self, tick, active_np, window: int, **more) -> int:
+        """Occupancy counters of the tick about to launch, and on its
+        ``tick`` span the active slots and ``kv_positions``: the cache
+        positions its attention has to read, the active slots' lengths
+        with the `window` new tokens (host arithmetic, no sync)."""
+        self._timings["occupancy_sum"] += float(active_np.mean())
+        n_active = int(active_np.sum())
+        self._m_active.set(n_active)
+        tick.note(active=n_active, kv_positions=int(
+            np.dot(active_np, self._slot_len)) + window * n_active, **more)
+        return n_active
+
+    def _launch_spec(self, tick):
+        """The launch of one speculative tick for every active slot:
+        draft proposes K, target verifies K+1 in one executable.  Still
+        exactly ONE host sync — it just pays for ~K+1 tokens now."""
         k = self._spec.k
         # capacity: a slot without room for the whole K+1 window
         # retires now (the window writes at slot_len..slot_len+K).
@@ -1875,77 +1924,73 @@ class InferenceEngine:
         # still-prefilling slots (chunked mode) sit the tick out: the
         # verify window's garbage writes on their rows land above their
         # valid length and the next chunk scatters over them first
-        active_np = np.asarray(
-            [1 if (r is not None and not r.prefilling) else 0
-             for r in self._slots], np.int32)
+        active_np = self._active_mask()
         if not active_np.any():
-            return 0
+            return None
         if self.kv_layout == "paged":
             self._timings["block_occupancy_sum"] += \
                 self._alloc.num_in_use / self._alloc.capacity
-        self._timings["occupancy_sum"] += float(active_np.mean())
-        n_active = int(active_np.sum())
-        self._m_active.set(n_active)
-        tick_t0 = self._tracer.now_us() if self._tracer.active else 0.0
-        out = self._spec.tick(active_np)
+        n_active = self._note_active(tick, active_np, k + 1, k=k)
+        return n_active, self._spec.tick(active_np)
+
+    def _commit_spec(self, n: int, n_active: int, out) -> int:
+        """The read and the commit of a speculative tick: the scheduler
+        commits the accepted prefix + bonus token of every slot."""
+        k = self._spec.k
         # the ONE host sync of the tick: K+1 target-greedy tokens + the
         # committed count per slot, one int32 readback
-        t0 = time.perf_counter()
-        out_np = np.asarray(out)
-        self._flush_moe()        # parked chunk-tick folds ride this sync
-        async_dispatch.record_host_sync()
-        self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
-        self._timings["decode_steps"] += 1
-        self._timings["sampled_ticks"] += int((self._temps > 0).any())
-        self._timings["spec_ticks"] += 1
-        self._timings["spec_slot_ticks"] += int(active_np.sum())
-        produced = 0
-        commit_now = time.perf_counter()
-        for slot, req in enumerate(list(self._slots)):
-            if req is None or req.prefilling:
-                continue
-            n_emit = int(out_np[slot, k + 1])
-            toks = out_np[slot, :k + 1]
-            # host mirrors the in-graph length advance (dense) / owns
-            # it (paged); EOS/max-new truncation below RETIRES the
-            # slot, so the un-truncated advance never leaks into a
-            # later tick
-            self._slot_len[slot] += n_emit
-            emitted = []
-            retired = False
-            for i in range(n_emit):
-                tok = int(toks[i])
-                req.generated.append(tok)
-                req.token_times.append(commit_now)
-                emitted.append(tok)
-                produced += 1
-                self._timings["tokens_generated"] += 1
-                if tok == req.eos_id or \
-                        len(req.generated) >= req.max_new_tokens:
-                    retired = True
-                    self._retire(req)
-                    break
-            # count what actually reached the stream — an EOS/max-new
-            # truncation must not inflate accepted_tokens_per_tick
-            self._timings["spec_tokens_committed"] += len(emitted)
-            if not retired and emitted:
-                self._next_token[slot] = emitted[-1]
-                self._spec.after_commit(slot,
-                                        np.asarray(emitted, np.int32))
-        self._m_ticks.inc()
-        self._m_tokens.inc(produced)
-        if self._tracer.active:
-            # spec accept counts per tick, as the timeline args
-            now_us = self._tracer.now_us()
-            self._tracer.complete(
-                "spec_tick", tick_t0, now_us - tick_t0, cat="serve",
-                args={"active": n_active, "committed": produced,
-                      "k": k})
-        _flightrec.record("spec_tick",
-                          tick=self._timings["decode_steps"],
-                          active=n_active, committed=produced, k=k)
-        from ..testing import faults as _faults
-        _faults.maybe_hang(self._timings["decode_steps"])
+        with _spans.span("tick/read", "serve", tick=n):
+            t0 = time.perf_counter()
+            out_np = np.asarray(out)
+            self._flush_moe()    # parked chunk-tick folds ride this sync
+            async_dispatch.record_host_sync()
+            self._timings["sync_ms"] += (time.perf_counter() - t0) * 1e3
+        with _spans.span("tick/commit", "serve", tick=n) as commit:
+            self._timings["decode_steps"] += 1
+            self._timings["sampled_ticks"] += int((self._temps > 0).any())
+            self._timings["spec_ticks"] += 1
+            self._timings["spec_slot_ticks"] += n_active
+            produced = 0
+            commit_now = time.perf_counter()
+            for slot, req in enumerate(list(self._slots)):
+                if req is None or req.prefilling:
+                    continue
+                n_emit = int(out_np[slot, k + 1])
+                toks = out_np[slot, :k + 1]
+                # host mirrors the in-graph length advance (dense) / owns
+                # it (paged); EOS/max-new truncation below RETIRES the
+                # slot, so the un-truncated advance never leaks into a
+                # later tick
+                self._slot_len[slot] += n_emit
+                emitted = []
+                retired = False
+                for i in range(n_emit):
+                    tok = int(toks[i])
+                    req.generated.append(tok)
+                    req.token_times.append(commit_now)
+                    emitted.append(tok)
+                    produced += 1
+                    self._timings["tokens_generated"] += 1
+                    if tok == req.eos_id or \
+                            len(req.generated) >= req.max_new_tokens:
+                        retired = True
+                        self._retire(req)
+                        break
+                # count what actually reached the stream — an EOS/max-new
+                # truncation must not inflate accepted_tokens_per_tick
+                self._timings["spec_tokens_committed"] += len(emitted)
+                if not retired and emitted:
+                    self._next_token[slot] = emitted[-1]
+                    self._spec.after_commit(
+                        slot, np.asarray(emitted, np.int32))
+            self._m_ticks.inc()
+            self._m_tokens.inc(produced)
+            commit.note(committed=produced)     # the tick's accept count
+            _flightrec.record("spec_tick",
+                              tick=self._timings["decode_steps"],
+                              active=n_active, committed=produced, k=k)
+            from ..testing import faults as _faults
+            _faults.maybe_hang(self._timings["decode_steps"])
         return produced
 
     def step_or_raise(self) -> int:

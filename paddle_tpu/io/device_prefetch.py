@@ -103,36 +103,32 @@ class DevicePrefetcher:
 
     # -- consumer ------------------------------------------------------
     def __iter__(self):
+        from ..observability import spans as _spans
         self._ensure_started()
         try:
             while True:
                 t0 = time.perf_counter()
-                while True:
-                    try:
-                        kind, payload = self._q.get(timeout=0.5)
-                        break
-                    except queue.Empty:
-                        # a worker killed without posting its END/ERROR
-                        # frame must not hang the training loop.  The
-                        # producer may have posted its FINAL frame and
-                        # exited between our timeout and this check, so
-                        # drain once more before declaring it dead
-                        if not self.alive:
-                            try:
-                                kind, payload = self._q.get_nowait()
-                                break
-                            except queue.Empty:
-                                raise RuntimeError(
-                                    "device prefetch thread died without "
-                                    "delivering a batch")
+                with _spans.span("data_wait", "train"):
+                    while True:
+                        try:
+                            kind, payload = self._q.get(timeout=0.5)
+                            break
+                        except queue.Empty:
+                            # a worker killed without posting its END/ERROR
+                            # frame must not hang the training loop.  The
+                            # producer may have posted its FINAL frame and
+                            # exited between our timeout and this check, so
+                            # drain once more before declaring it dead
+                            if not self.alive:
+                                try:
+                                    kind, payload = self._q.get_nowait()
+                                    break
+                                except queue.Empty:
+                                    raise RuntimeError(
+                                        "device prefetch thread died without "
+                                        "delivering a batch")
                 dt = (time.perf_counter() - t0) * 1e3
                 self._timings["data_wait_ms"] += dt
-                from ..observability import spans as _spans
-                tr = _spans.tracer()
-                if tr.active:
-                    now = tr.now_us()
-                    tr.complete("data_wait", now - dt * 1e3, dt * 1e3,
-                                cat="train")
                 if kind == _END:
                     return
                 if kind == _ERROR:

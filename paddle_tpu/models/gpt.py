@@ -404,20 +404,23 @@ class GPTAttention(Layer):
         flow through the tape there)."""
         cfg = self.cfg
         b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x if isinstance(x, Tensor) else Tensor(x))
-        arr = qkv.data
-        h_dim = cfg.hidden_size
-        kv_dim = cfg.num_kv_heads * cfg.head_dim
-        q = arr[:, :, :h_dim].reshape(b, s, cfg.num_heads, cfg.head_dim)
-        k = arr[:, :, h_dim:h_dim + kv_dim].reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        v = arr[:, :, h_dim + kv_dim:].reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
+        with jax.named_scope("attn_proj"):
+            qkv = self.qkv_proj(x if isinstance(x, Tensor) else Tensor(x))
+            arr = qkv.data
+            h_dim = cfg.hidden_size
+            kv_dim = cfg.num_kv_heads * cfg.head_dim
+            q = arr[:, :, :h_dim].reshape(b, s, cfg.num_heads,
+                                          cfg.head_dim)
+            k = arr[:, :, h_dim:h_dim + kv_dim].reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim)
+            v = arr[:, :, h_dim + kv_dim:].reshape(
+                b, s, cfg.num_kv_heads, cfg.head_dim)
         return q, k, v
 
     def _proj_out(self, out_arr, b, s):
-        out = Tensor(out_arr.reshape(b, s, -1))
-        return self.dropout(self.out_proj(out))
+        with jax.named_scope("attn_proj"):
+            out = Tensor(out_arr.reshape(b, s, -1))
+            return self.dropout(self.out_proj(out))
 
     @staticmethod
     def _upgrade_cache(cache, b, hkv, d, cap, dtype):
@@ -457,26 +460,27 @@ class GPTAttention(Layer):
         ring/flash/composite routing as the no-cache forward, shared by
         forward_prefill and the fresh-cache legacy path.  Returns raw
         [b, s, H, D]."""
-        cfg = self.cfg
-        causal = s > 1
-        if cfg.sequence_parallel and self._sp_active(b, s):
-            from ..distributed.ring_attention import \
-                sequence_parallel_attention
-            out = sequence_parallel_attention(
-                Tensor(q), Tensor(k), Tensor(v), sp_axis=cfg.sp_axis,
-                causal=causal)
-            return out.data if isinstance(out, Tensor) else out
-        if cfg.use_flash_attention:
-            return F.flash_attention(Tensor(q), Tensor(k), Tensor(v),
-                                     causal=causal, training=False).data
-        kf, vf = k, v
-        if cfg.num_kv_heads != cfg.num_heads:
-            rep = cfg.num_heads // cfg.num_kv_heads
-            kf = jnp.repeat(kf, rep, axis=2)
-            vf = jnp.repeat(vf, rep, axis=2)
-        return F.scaled_dot_product_attention(
-            Tensor(q), Tensor(kf), Tensor(vf), is_causal=causal,
-            training=False).data
+        with jax.named_scope("attn_core"):
+            cfg = self.cfg
+            causal = s > 1
+            if cfg.sequence_parallel and self._sp_active(b, s):
+                from ..distributed.ring_attention import \
+                    sequence_parallel_attention
+                out = sequence_parallel_attention(
+                    Tensor(q), Tensor(k), Tensor(v), sp_axis=cfg.sp_axis,
+                    causal=causal)
+                return out.data if isinstance(out, Tensor) else out
+            if cfg.use_flash_attention:
+                return F.flash_attention(Tensor(q), Tensor(k), Tensor(v),
+                                         causal=causal, training=False).data
+            kf, vf = k, v
+            if cfg.num_kv_heads != cfg.num_heads:
+                rep = cfg.num_heads // cfg.num_kv_heads
+                kf = jnp.repeat(kf, rep, axis=2)
+                vf = jnp.repeat(vf, rep, axis=2)
+            return F.scaled_dot_product_attention(
+                Tensor(q), Tensor(kf), Tensor(vf), is_causal=causal,
+                training=False).data
 
     def _forward_with_cache(self, x, cache):
         """Fixed-capacity cached attention (the legacy ``cache=`` path,
@@ -554,9 +558,9 @@ class GPTAttention(Layer):
         own strips, so inside one jitted prefill XLA keeps one."""
         b, s = x.shape[0], x.shape[1]
         q, k, v = self._qkv_arrays(x)
-        out = self._attend_fresh(q, k, v, b, s)
-        return (self._proj_out(out, b, s), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2))
+        out = self._proj_out(self._attend_fresh(q, k, v, b, s), b, s)
+        with jax.named_scope("attn_core"):
+            return out, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
 
     def step(self, x, kv, lengths):
         """One serving step over one cache layer, for a decode tick
@@ -574,8 +578,10 @@ class GPTAttention(Layer):
         ``(out, kv)``."""
         b, w = x.shape[0], x.shape[1]
         q, k, v = self._qkv_arrays(x)
-        kv = kv.write(k, v, lengths)
-        out = kv.attend(q).astype(q.dtype)          # [b, w, H, D]
+        with jax.named_scope("kv_write"):
+            kv = kv.write(k, v, lengths)
+        with jax.named_scope("decode_attn"):
+            out = kv.attend(q).astype(q.dtype)      # [b, w, H, D]
         return self._proj_out(out, b, w), kv
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
@@ -633,16 +639,25 @@ class GPTAttention(Layer):
                     "attn_mask with a kv cache is not supported; pad "
                     "tokens are masked by the cache length instead")
             return self._forward_with_cache(x, cache)
-        qkv = self.qkv_proj(x)
-        h_dim = cfg.hidden_size
-        kv_dim = cfg.num_kv_heads * cfg.head_dim
-        q = qkv[:, :, :h_dim].reshape(
-            [b, s, cfg.num_heads, cfg.head_dim])
-        k = qkv[:, :, h_dim:h_dim + kv_dim].reshape(
-            [b, s, cfg.num_kv_heads, cfg.head_dim])
-        v = qkv[:, :, h_dim + kv_dim:].reshape(
-            [b, s, cfg.num_kv_heads, cfg.head_dim])
+        with jax.named_scope("attn_proj"):
+            qkv = self.qkv_proj(x)
+            h_dim = cfg.hidden_size
+            kv_dim = cfg.num_kv_heads * cfg.head_dim
+            q = qkv[:, :, :h_dim].reshape(
+                [b, s, cfg.num_heads, cfg.head_dim])
+            k = qkv[:, :, h_dim:h_dim + kv_dim].reshape(
+                [b, s, cfg.num_kv_heads, cfg.head_dim])
+            v = qkv[:, :, h_dim + kv_dim:].reshape(
+                [b, s, cfg.num_kv_heads, cfg.head_dim])
+        with jax.named_scope("attn_core"):
+            out = self._attend(q, k, v, attn_mask, b, s)
+        with jax.named_scope("attn_proj"):
+            return self.dropout(self.out_proj(out))
 
+    def _attend(self, q, k, v, attn_mask, b, s):
+        """The training forward's attention between its two projections:
+        ring, flash or composite; returns ``[b, s, H D]``."""
+        cfg = self.cfg
         causal = s > 1
         if (cfg.sequence_parallel and attn_mask is None
                 and self._sp_active(b, s)):
@@ -656,9 +671,7 @@ class GPTAttention(Layer):
                     "attn_dropout inside ring attention is not supported")
             out = sequence_parallel_attention(
                 q, k, v, sp_axis=cfg.sp_axis, causal=causal)
-            out = out.reshape([b, s, -1])
-            out = self.out_proj(out)
-            return self.dropout(out)
+            return out.reshape([b, s, -1])
 
         if cfg.use_flash_attention and attn_mask is None:
             # GQA goes in un-expanded: the Pallas kernel walks kv-head
@@ -675,9 +688,7 @@ class GPTAttention(Layer):
                 q, k, v, attn_mask=attn_mask,
                 dropout_p=cfg.attn_dropout, is_causal=causal,
                 training=self.training)
-        out = out.reshape([b, s, -1])
-        out = self.out_proj(out)
-        return self.dropout(out)
+        return out.reshape([b, s, -1])
 
 
 class GPTMLP(Layer):
@@ -733,35 +744,41 @@ class GPTBlock(Layer):
         else:
             self.mlp = GPTMLP(config)
 
+    # A block's two scopes each hold the pre-norm, the sublayer and the
+    # residual add, so that no operation falls between blocks.
+    def _mlp_block(self, x):
+        with jax.named_scope("mlp"):
+            return x + self.mlp(self.ln_2(x))
+
     def forward(self, x, attn_mask=None):
-        x = x + self.attn(self.ln_1(x), attn_mask=attn_mask)
-        x = x + self.mlp(self.ln_2(x))
-        return x
+        with jax.named_scope("attn"):
+            x = x + self.attn(self.ln_1(x), attn_mask=attn_mask)
+        return self._mlp_block(x)
 
     def forward_prefill(self, x):
         """Block forward that also surfaces this layer's k/v for the
         StaticKVCache write. Returns (x, k [B,Hkv,S,D], v)."""
-        a, k, v = self.attn.forward_prefill(self.ln_1(x))
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, k, v
+        with jax.named_scope("attn"):
+            a, k, v = self.attn.forward_prefill(self.ln_1(x))
+            x = x + a
+        return self._mlp_block(x), k, v
 
     def step(self, x, kv, lengths):
         """Block step over one cache layer's view (LN/MLP are
         position-wise, so only attention needs the window machinery).
         Returns ``(x, kv)``."""
-        a, kv = self.attn.step(self.ln_1(x), kv, lengths)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, kv
+        with jax.named_scope("attn"):
+            a, kv = self.attn.step(self.ln_1(x), kv, lengths)
+            x = x + a
+        return self._mlp_block(x), kv
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
         """Block prefill over one slot's gathered block buffer."""
-        a, k_buf, v_buf = self.attn.forward_prefill_paged(
-            self.ln_1(x), k_buf, v_buf, prefix_len)
-        x = x + a
-        x = x + self.mlp(self.ln_2(x))
-        return x, k_buf, v_buf
+        with jax.named_scope("attn"):
+            a, k_buf, v_buf = self.attn.forward_prefill_paged(
+                self.ln_1(x), k_buf, v_buf, prefix_len)
+            x = x + a
+        return self._mlp_block(x), k_buf, v_buf
 
 
 class GPTModel(Layer):
@@ -937,6 +954,12 @@ class GPTModel(Layer):
                      name="gpt_scan_layers_zero3" if z3_mesh is not None
                      else "gpt_scan_layers")
 
+    def _final_norm(self, x, scope: str):
+        """The final norm under the scope of what follows it: ``head_ce``
+        in training (head and loss), ``head`` in serving."""
+        with jax.named_scope(scope):
+            return self.ln_f(x)
+
     # ---- serving path: static KV cache --------------------------------
     def init_kv_cache(self, batch_slots: int, capacity: Optional[int] = None,
                       dtype=None, kv_dtype=None) -> StaticKVCache:
@@ -983,8 +1006,8 @@ class GPTModel(Layer):
             else jnp.asarray(input_ids)
         s = ids.shape[1]
         pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
-        x = self.wte(Tensor(ids)) + self.wpe(pos)
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.drop(self.wte(Tensor(ids)) + self.wpe(pos))
         slot = jnp.asarray(slot, jnp.int32)
         zero = jnp.asarray(0, jnp.int32)
 
@@ -1006,12 +1029,13 @@ class GPTModel(Layer):
                 v, v_s = quantize_kv(v, mode)
                 new = (k, v, k_s, v_s)
             kv = cache.layer(i)
-            cache = cache.with_layer(i, DenseKVLayer(*(
-                put(buf, rows) for buf, rows in
-                zip((kv.k, kv.v, kv.k_scale, kv.v_scale), new))))
+            with jax.named_scope("kv_write"):
+                cache = cache.with_layer(i, DenseKVLayer(*(
+                    put(buf, rows) for buf, rows in
+                    zip((kv.k, kv.v, kv.k_scale, kv.v_scale), new))))
         lengths = cache.lengths.at[slot].set(
             jnp.asarray(prompt_len, jnp.int32))
-        return self.ln_f(x), cache.with_lengths(lengths)
+        return self._final_norm(x, "head"), cache.with_lengths(lengths)
 
     def step(self, tokens, cache, lengths, advance=None, tables=None):
         """One serving step for every slot, W tokens a slot: the decode
@@ -1045,16 +1069,16 @@ class GPTModel(Layer):
         b = lens.shape[0]
         w = toks.size // b
         pos = jnp.minimum(window_positions(lens, w), cfg.max_seq_len - 1)
-        x = self.wte(Tensor(toks.reshape(b, w))) + \
-            self.wpe(Tensor(pos.reshape(b, w)))
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.drop(self.wte(Tensor(toks.reshape(b, w))) +
+                          self.wpe(Tensor(pos.reshape(b, w))))
         if advance is not None:
             cache = cache.with_lengths(jnp.minimum(
                 lens + jnp.asarray(advance, jnp.int32), cache.capacity))
         for i, blk in enumerate(self.blocks):
             x, kv = blk.step(x, cache.layer(i, tables), lens)
             cache = cache.with_layer(i, kv)
-        return self.ln_f(x), cache
+        return self._final_norm(x, "head"), cache
 
     # ---- serving path: paged KV cache ---------------------------------
     def forward_prefill_paged(self, input_ids, cache, table_row,
@@ -1080,8 +1104,9 @@ class GPTModel(Layer):
         off = jnp.asarray(prefix_len, jnp.int32)
         pos = jnp.minimum(off + jnp.arange(s, dtype=jnp.int32),
                           cfg.max_seq_len - 1)
-        x = self.wte(Tensor(ids)) + self.wpe(Tensor(pos[None, :]))
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.drop(self.wte(Tensor(ids)) +
+                          self.wpe(Tensor(pos[None, :])))
         table_row = jnp.asarray(table_row, jnp.int32)
         cache_k, cache_v = cache.k, cache.v
         k_sc, v_sc = cache.k_scale, cache.v_scale
@@ -1131,16 +1156,18 @@ class GPTModel(Layer):
                     rows_to_blocks(k_buf, bs))
                 cache_v = cache_v.at[i, table_row].set(
                     rows_to_blocks(v_buf, bs))
-        return self.ln_f(x), type(cache)(cache_k, cache_v, k_sc, v_sc)
+        return self._final_norm(x, "head"), \
+            type(cache)(cache_k, cache_v, k_sc, v_sc)
 
     def forward(self, input_ids, attn_mask=None):
         from ..distributed.recompute import recompute as _rc
         s = input_ids.shape[1]
         pos = Tensor(jnp.arange(s, dtype=jnp.int32)[None, :])
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.drop(self.wte(input_ids) + self.wpe(pos))
         if self._scan_ok(attn_mask):
-            return self.ln_f(self._forward_blocks_scanned(x))
+            return self._final_norm(self._forward_blocks_scanned(x),
+                                    "head_ce")
         for blk in self.blocks:
             if self._recompute and self.training:
                 # mask passed positionally so the checkpointed region
@@ -1150,7 +1177,7 @@ class GPTModel(Layer):
                     _rc(blk, x, attn_mask, policy=pol)
             else:
                 x = blk(x) if attn_mask is None else blk(x, attn_mask)
-        return self.ln_f(x)
+        return self._final_norm(x, "head_ce")
 
 
 class GPTForCausalLM(Layer):
@@ -1206,12 +1233,11 @@ class GPTForCausalLM(Layer):
             # slices would force GSPMD to all-gather the vocab-sharded
             # LM head every step, costing more than the logits save
             return x, self.gpt.wte.weight
-        if self.cfg.tie_word_embeddings:
-            w = self.gpt.wte.weight  # [V, H], vocab-sharded over tp
-            logits = matmul(x, w, transpose_y=True)
-        else:
-            logits = self.lm_head(x)
-        return logits
+        with jax.named_scope("head_ce"):
+            if self.cfg.tie_word_embeddings:
+                w = self.gpt.wte.weight  # [V, H], vocab-sharded over tp
+                return matmul(x, w, transpose_y=True)
+            return self.lm_head(x)
 
     # ---- serving path -------------------------------------------------
     def init_kv_cache(self, batch_slots: int, capacity: Optional[int] = None,
@@ -1221,9 +1247,11 @@ class GPTForCausalLM(Layer):
 
     def _head_logits(self, hidden):
         """hidden Tensor [..., H] -> logits Tensor [..., V]."""
-        if self.cfg.tie_word_embeddings:
-            return matmul(hidden, self.gpt.wte.weight, transpose_y=True)
-        return self.lm_head(hidden)
+        with jax.named_scope("head"):
+            if self.cfg.tie_word_embeddings:
+                return matmul(hidden, self.gpt.wte.weight,
+                              transpose_y=True)
+            return self.lm_head(hidden)
 
     def prefill(self, input_ids, cache: StaticKVCache, slot, prompt_len):
         """Prefill one slot; returns ``(logits [1, V], cache)`` — the
@@ -1407,19 +1435,20 @@ class GPTPretrainingCriterion(Layer):
         # the model hands over (hidden [B, S, H], lm weight [V, H])
         # instead and the loss runs blockwise over the vocab without
         # ever materializing the logits tensor.
-        flat_labels = labels.reshape([-1])
-        if isinstance(logits, (tuple, list)) and len(logits) == 2:
-            hidden, w = logits
-            h = hidden.shape[-1]
-            losses = F.fused_linear_cross_entropy(
-                hidden.reshape([-1, h]), w, flat_labels,
-                reduction="none", ignore_index=self.ignore_index)
-        else:
-            v = logits.shape[-1]
-            losses = F.cross_entropy(logits.reshape([-1, v]), flat_labels,
-                                     reduction="none",
-                                     ignore_index=self.ignore_index)
-        if loss_mask is not None:
-            m = loss_mask.reshape([-1]).astype("float32")
-            return (losses.reshape([-1]) * m).sum() / m.sum()
-        return losses.mean()
+        with jax.named_scope("head_ce"):
+            flat_labels = labels.reshape([-1])
+            if isinstance(logits, (tuple, list)) and len(logits) == 2:
+                hidden, w = logits
+                h = hidden.shape[-1]
+                losses = F.fused_linear_cross_entropy(
+                    hidden.reshape([-1, h]), w, flat_labels,
+                    reduction="none", ignore_index=self.ignore_index)
+            else:
+                v = logits.shape[-1]
+                losses = F.cross_entropy(logits.reshape([-1, v]), flat_labels,
+                                         reduction="none",
+                                         ignore_index=self.ignore_index)
+            if loss_mask is not None:
+                m = loss_mask.reshape([-1]).astype("float32")
+                return (losses.reshape([-1]) * m).sum() / m.sum()
+            return losses.mean()

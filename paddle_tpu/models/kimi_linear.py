@@ -216,27 +216,31 @@ class KDAMixer(Layer):
         # and one group's intermediates.  A group's rows of W_o are
         # applied inside its step and summed in float32, so that nothing
         # outside the steps needs the scan's output: the layer's own remat
-        # then has no scan to run again.
-        n = max(m for m in range(1, h + 1)
-                if h % m == 0 and m * bsz <= max(self.PAIRS_AT_ONCE, bsz))
-        # [.., h c] -> [h / n, .., n c]
-        by_group = lambda t, c: jnp.moveaxis(
-            t.reshape(t.shape[:-1] + (h // n, n * c)), -2, 0)
-        rows = lambda t: t.reshape(h // n, n * d, -1)   # [h d, .] by head
+        # then has no scan to run again.  What the scope kda_groups
+        # holds beside the finer scopes inside it is that loop's price:
+        # regrouping copies, slices and stacking.
+        with jax.named_scope("kda_groups"):
+            n = max(m for m in range(1, h + 1) if h % m == 0 and
+                    m * bsz <= max(self.PAIRS_AT_ONCE, bsz))
+            # [.., h c] -> [h / n, .., n c]
+            by_group = lambda t, c: jnp.moveaxis(
+                t.reshape(t.shape[:-1] + (h // n, n * c)), -2, 0)
+            # [h d, .] by head
+            rows = lambda t: t.reshape(h // n, n * d, -1)
 
-        def add_group(out, group):
-            *inputs, w_o_rows = group
-            y = self._mix_group(*inputs, norm_w)
-            with jax.named_scope("kda_proj"):
-                return out + jnp.matmul(y, w_o_rows,
-                                        preferred_element_type=_F32), None
+            def add_group(out, group):
+                *inputs, w_o_rows = group
+                y = self._mix_group(*inputs, norm_w)
+                with jax.named_scope("kda_proj"):
+                    return out + jnp.matmul(
+                        y, w_o_rows, preferred_element_type=_F32), None
 
-        out, _ = jax.lax.scan(
-            jax.checkpoint(add_group), jnp.zeros(x.shape, _F32),
-            (by_group(q, d), by_group(k, d), by_group(v, d), by_group(f, d),
-             by_group(b, 1), by_group(gate, d), rows(c_q), rows(c_k),
-             rows(c_v), a_log.reshape(h // n, n), by_group(dt_bias, d),
-             rows(w_o)))
+            out, _ = jax.lax.scan(
+                jax.checkpoint(add_group), jnp.zeros(x.shape, _F32),
+                (by_group(q, d), by_group(k, d), by_group(v, d),
+                 by_group(f, d), by_group(b, 1), by_group(gate, d),
+                 rows(c_q), rows(c_k), rows(c_v),
+                 a_log.reshape(h // n, n), by_group(dt_bias, d), rows(w_o)))
         return out.astype(x.dtype)
 
     def forward(self, x):
@@ -348,6 +352,11 @@ class KimiDecoderLayer(Layer):
         self.post_attention_layernorm = RMSNorm(cfg.hidden_size, epsilon=eps)
         self.mlp = KimiMLP(cfg, cfg.intermediate_size, "dense_mlp") \
             if cfg.ffn_kind(layer) == "dense" else KimiMoE(cfg)
+        # the two sub-layers' named scopes, each over its pre-norm, the
+        # sub-layer and the residual add
+        self.scopes = (cfg.mixer_kind(layer),
+                       "dense_mlp" if cfg.ffn_kind(layer) == "dense"
+                       else "moe")
 
     PARTS = ("mixer", "ffn")
 
@@ -355,9 +364,11 @@ class KimiDecoderLayer(Layer):
         """Both sub-layers, or the one `part` names (the model remats
         them one by one)."""
         if part != "ffn":
-            x = x + self.self_attn(self.input_layernorm(x))
+            with jax.named_scope(self.scopes[0]):
+                x = x + self.self_attn(self.input_layernorm(x))
         if part != "mixer":
-            x = x + self.mlp(self.post_attention_layernorm(x))
+            with jax.named_scope(self.scopes[1]):
+                x = x + self.mlp(self.post_attention_layernorm(x))
         return x
 
 
@@ -387,7 +398,8 @@ class KimiLinearModel(Layer):
 
     def forward(self, input_ids):
         from ..distributed.recompute import recompute
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         for layer in self.layers:
             if self._recompute and self.training:
                 for part in layer.PARTS:
@@ -395,7 +407,8 @@ class KimiLinearModel(Layer):
                                   part=part)
             else:
                 x = layer(x)
-        return self.norm(x)
+        with jax.named_scope("head_ce"):
+            return self.norm(x)
 
 
 class KimiLinearForCausalLM(Layer):
@@ -414,5 +427,6 @@ class KimiLinearForCausalLM(Layer):
         if self.cfg.fused_ce and self.training:
             # the criterion projects vocabulary block by block
             return x, self.lm_head.weight
-        return apply(lambda h, w: jnp.matmul(h, w.T), x,
-                     self.lm_head.weight, name="lm_head")
+        with jax.named_scope("head_ce"):
+            return apply(lambda h, w: jnp.matmul(h, w.T), x,
+                         self.lm_head.weight, name="lm_head")

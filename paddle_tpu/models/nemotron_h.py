@@ -136,25 +136,33 @@ class Mamba2Mixer(Layer):
             cfg.mamba_head_dim
         g, n = cfg.n_groups, cfg.ssm_state_size
         f32 = jnp.float32
-        zxbcdt = jnp.matmul(x, w_in)
-        z = zxbcdt[..., :d_in]
-        xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
-        dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
-        xbc = jax.nn.silu(causal_conv1d(xbc, conv_w, conv_b))
-        xs = xbc[..., :d_in].reshape(b, s, heads, p)
-        b_mat = xbc[..., d_in:d_in + g * n].reshape(b, s, g, n)
-        c_mat = xbc[..., d_in + g * n:].reshape(b, s, g, n)
-        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        with jax.named_scope("mamba_proj"):
+            zxbcdt = jnp.matmul(x, w_in)
+            z = zxbcdt[..., :d_in]
+            xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
+            dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
+        with jax.named_scope("mamba_conv"):
+            xbc = jax.nn.silu(causal_conv1d(xbc, conv_w, conv_b))
+            xs = xbc[..., :d_in].reshape(b, s, heads, p)
+            b_mat = xbc[..., d_in:d_in + g * n].reshape(b, s, g, n)
+            c_mat = xbc[..., d_in + g * n:].reshape(b, s, g, n)
+        with jax.named_scope("mamba_gate_norm"):
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
         y = ssd_scan(xs, dt, -jnp.exp(a_log.astype(f32)), b_mat, c_mat,
                      chunk=cfg.chunk_size)
-        y = y.astype(f32) + d_skip.astype(f32)[:, None] * xs.astype(f32)
-        # RMSNorm over groups of d_inner / n_groups channels of y silu(z)
-        y = y.reshape(b, s, d_in) * jax.nn.silu(z.astype(f32))
-        yg = y.reshape(b, s, g, d_in // g)
-        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) +
-                                cfg.layer_norm_epsilon)
-        y = (yg.reshape(b, s, d_in) * norm_w.astype(f32)).astype(x.dtype)
-        return jnp.matmul(y, w_out)
+        with jax.named_scope("mamba_gate_norm"):
+            y = y.astype(f32) + d_skip.astype(f32)[:, None] * \
+                xs.astype(f32)
+            # RMSNorm over groups of d_inner / n_groups channels of
+            # y silu(z)
+            y = y.reshape(b, s, d_in) * jax.nn.silu(z.astype(f32))
+            yg = y.reshape(b, s, g, d_in // g)
+            yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                                    + cfg.layer_norm_epsilon)
+            y = (yg.reshape(b, s, d_in) *
+                 norm_w.astype(f32)).astype(x.dtype)
+        with jax.named_scope("mamba_proj"):
+            return jnp.matmul(y, w_out)
 
     def forward(self, x):
         return apply(self._fn, x, self.in_proj.weight, self.conv1d.weight,
@@ -179,14 +187,18 @@ class NemotronHAttention(Layer):
         b, s = x.shape[0], x.shape[1]
         h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
-        q = self.q_proj(x).reshape([b, s, h, d])
-        k = self.k_proj(x).reshape([b, s, hkv, d])
-        v = self.v_proj(x).reshape([b, s, hkv, d])
+        with jax.named_scope("attn_proj"):
+            q = self.q_proj(x).reshape([b, s, h, d])
+            k = self.k_proj(x).reshape([b, s, hkv, d])
+            v = self.v_proj(x).reshape([b, s, hkv, d])
         # GQA goes in un-expanded: the kernel walks kv-head groups (off
         # the chip the entry point expands them for its composite)
-        out = F.flash_attention(q, k, v, causal=True,
-                                training=self.training)
-        return self.o_proj(out.reshape([b, s, h * d]))
+        with jax.named_scope("attn_core"):
+            out = F.flash_attention(q, k, v, causal=True,
+                                    training=self.training)
+            out = out.reshape([b, s, h * d])
+        with jax.named_scope("attn_proj"):
+            return self.o_proj(out)
 
 
 class NemotronHMLP(Layer):
@@ -227,6 +239,8 @@ class NemotronHMoE(Layer):
 
 
 _MIXERS = {"M": Mamba2Mixer, "*": NemotronHAttention, "E": NemotronHMoE}
+# a block's named scope: its pre-norm, its mixer and the residual add
+_BLOCK_SCOPES = {"M": "mamba", "*": "attn", "E": "moe"}
 
 
 class NemotronHBlock(Layer):
@@ -234,9 +248,11 @@ class NemotronHBlock(Layer):
         super().__init__()
         self.norm = RMSNorm(cfg.hidden_size, epsilon=cfg.layer_norm_epsilon)
         self.mixer = _MIXERS[kind](cfg)
+        self.scope = _BLOCK_SCOPES[kind]
 
     def forward(self, x):
-        return x + self.mixer(self.norm(x))
+        with jax.named_scope(self.scope):
+            return x + self.mixer(self.norm(x))
 
 
 class NemotronHModel(Layer):
@@ -263,13 +279,15 @@ class NemotronHModel(Layer):
 
     def forward(self, input_ids):
         from ..distributed.recompute import recompute
-        x = self.embeddings(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embeddings(input_ids)
         for layer in self.layers:
             if self._recompute and self.training:
                 x = recompute(layer, x, policy=self._recompute_policy)
             else:
                 x = layer(x)
-        return self.norm_f(x)
+        with jax.named_scope("head_ce"):
+            return self.norm_f(x)
 
 
 class _Head(Layer):
@@ -297,5 +315,6 @@ class NemotronHForCausalLM(Layer):
         if self.cfg.fused_ce and self.training:
             # the criterion projects vocabulary block by block
             return x, self.lm_head.weight
-        return apply(lambda h, w: jnp.matmul(h, w.T), x,
-                     self.lm_head.weight, name="lm_head")
+        with jax.named_scope("head_ce"):
+            return apply(lambda h, w: jnp.matmul(h, w.T), x,
+                         self.lm_head.weight, name="lm_head")

@@ -9,12 +9,18 @@ One sink, four capabilities, every entry point feeds it:
   step loops, the serving engine's decode tick, the paged allocator,
   the router, checkpoint save/restore, the compile/trace and host-sync
   counters, and the load harness.
-- **spans** — structured host spans (train step phases, per-request
-  serving lifecycle) exported as Chrome-trace/Perfetto JSON, nested
-  inside device captures via jax.profiler.TraceAnnotation.
+- **spans** — ONE primitive for a host span, ``span`` (a
+  ``jax.profiler.TraceAnnotation``; ``profiler.RecordEvent`` is the same
+  class): the trainer's and the engine's phases (``train_step`` >
+  ``/h2d`` ``/launch``, ``train_step/read``; ``tick`` > ``/admit``
+  ``/launch`` ``/read`` ``/commit``) land in the profiler's own trace,
+  on the device trace's clock, and, when the buffer is armed, in the
+  Chrome-trace/Perfetto export beside the per-request lifecycle.
 - **capture** — ``PADDLE_TPU_PROFILE=start:stop`` windows a
-  jax.profiler device trace over a step/tick range with zero
-  steady-state overhead.
+  jax.profiler trace over a step/tick range with zero steady-state
+  overhead: the operator's route to the picture the benchmark's
+  ``--trace 1`` reads (step markers, host phases, scope paths under
+  ``tf_op``).
 - **slo** — fleet aggregation over engine replicas + a rolling SLO
   monitor (threshold breaches, regression vs BENCH_rows.jsonl).
 - **flightrec** — always-on bounded black box: recent step/tick ring +
@@ -44,14 +50,15 @@ from .exec_registry import ExecRegistry, HBMLedger
 from .flightrec import FlightRecorder
 from .metrics import counter, gauge, histogram, parse_exposition, registry
 from .slo import FleetAggregator, SLOMonitor, load_bench_baseline
-from .spans import (export_chrome_trace, span, tracer,
+from .spans import (export_chrome_trace, span, step_span, tracer,
                     validate_chrome_trace)
 from .watchdog import Watchdog, detect_stragglers
 
 __all__ = [
     "metrics", "spans", "counter", "gauge", "histogram", "registry",
     "snapshot", "write_snapshot", "parse_exposition",
-    "span", "tracer", "export_chrome_trace", "validate_chrome_trace",
+    "span", "step_span", "tracer", "export_chrome_trace",
+    "validate_chrome_trace",
     "ProfileWindow", "parse_profile_spec",
     "FleetAggregator", "SLOMonitor", "load_bench_baseline",
     "flightrec", "FlightRecorder", "watchdog", "Watchdog",

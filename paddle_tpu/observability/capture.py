@@ -15,10 +15,11 @@ knob captures either.  When the env is unset ``from_env`` returns None
 and the entry points hold a literal None — the steady-state cost of the
 feature is one ``is not None`` check per step, no allocation, no call.
 
-Host spans recorded while a capture is active nest inside the device
-trace via the ``jax.profiler.TraceAnnotation`` half of RecordEvent; the
-chrome-trace export (observability.spans) is independent of captures and
-works with no device profiler at all.
+Host spans recorded while a capture is active land in its host plane
+(``observability.spans.span`` is a ``jax.profiler.TraceAnnotation``),
+beside the device's operations and on their clock; the chrome-trace
+export of the span buffer is independent of captures and works with no
+device profiler at all.
 """
 from __future__ import annotations
 

@@ -1,41 +1,60 @@
-"""Structured span tracing -> Chrome-trace / Perfetto JSON.
+"""Host spans: ONE primitive, on the profiler's clock.
 
-``profiler.RecordEvent`` (the reference ``platform/profiler.h:127`` RAII
-marker) annotates the DEVICE timeline via
-``jax.profiler.TraceAnnotation``; this module is its host-side twin: the
-same enter/exit pairs also land in a process-wide event buffer as
-structured spans, which export as Chrome-trace JSON (``chrome://tracing``
-/ Perfetto's ``ui.perfetto.dev`` open it directly — the reference
-``device_tracer.h:43`` CUPTI→chrome-trace path, minus CUPTI).
+``span`` (``profiler.RecordEvent`` is the same class under its reference
+name, ``platform/profiler.h:127``) IS a ``jax.profiler.TraceAnnotation``:
+entering it puts the span into the host plane of whatever profiler
+session is running, on the clock of the device trace, so the program's
+phases can be laid against the chip's busy intervals.  When the span
+buffer below is armed the same enter/exit pair is also appended to it,
+and exports as Chrome-trace JSON (``chrome://tracing`` / Perfetto's
+``ui.perfetto.dev`` open it directly; the flight recorder's
+``trace.json`` is built from it).  ``step_span`` is the same thing for
+the two parents (``train_step``, ``tick``): a
+``jax.profiler.StepTraceAnnotation``, which XProf groups by.
 
-Tracks (Chrome-trace pid/tid):
+The names the trainer and the engine record (PERF.md section 3 has the
+metric that reads each):
 
-- ``pid=1`` "host": named phase spans — train step phases
-  (data_wait/h2d/dispatch/sync), decode ticks, prefill calls.  ``tid``
-  is the emitting thread.
+- ``train_step`` (``step``) > ``train_step/h2d``, ``train_step/launch``;
+  ``train_step/read`` (``step``) is the host's read of the loss and may
+  close after its parent.
+- ``tick`` (``tick``, ``active``, ``kv_positions``) > ``tick/admit``
+  (> ``prefill`` with ``bucket``, ``prompt_tokens``), ``tick/launch``,
+  ``tick/read``, ``tick/commit``.
+
+Tracks of the buffer (Chrome-trace pid/tid):
+
+- ``pid=1`` "host": the spans above.  ``tid`` is the emitting thread.
 - ``pid=2`` "requests": one track PER REQUEST (``tid=rid``) holding its
-  lifecycle — ``queued`` → ``prefill`` → ``decode`` — plus instant
-  events for preemptions and per-tick speculative accept counts.
+  lifecycle, ``queued`` -> ``prefill`` -> ``decode``, plus instant
+  events for preemptions.  These are built afterwards from timestamps
+  the engine records anyway (``SpanTracer.complete``), not spans.
 
-The contract the overhead tests enforce: tracing costs nothing when
-off.  Every instrumentation site guards on ``tracer().active`` (one
-attribute read, no call, no allocation), and recording itself is
-timestamp arithmetic + ``list.append`` — no host syncs, no jax calls,
-so a traced decode loop stays zero-recompile and one-sync-per-tick.
+With no profiler session and the buffer off a span costs its
+annotation's enter and exit and nothing else: no clock read, no dict,
+no allocation beyond the object.  Recording is timestamp arithmetic and
+``list.append``: no host syncs, no jax calls, so a traced decode loop
+stays zero-recompile and one-sync-per-tick.
 
-Knobs: ``PADDLE_TPU_SPANS=1`` arms the tracer at import;
+Knobs: ``PADDLE_TPU_SPANS=1`` arms the buffer at import;
 ``PADDLE_TPU_SPANS=<path>.json`` also names the default export path.
+``PADDLE_TPU_PROFILE=<start>:<stop>`` (``capture.py``) runs a profiler
+session over those steps or ticks.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
-__all__ = ["SpanTracer", "tracer", "span", "export_chrome_trace",
-           "validate_chrome_trace", "PID_HOST", "PID_REQUESTS"]
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+__all__ = ["SpanTracer", "tracer", "span", "step_span",
+           "export_chrome_trace", "validate_chrome_trace", "PID_HOST",
+           "PID_REQUESTS"]
 
 PID_HOST = 1
 PID_REQUESTS = 2
@@ -155,31 +174,67 @@ def default_export_path() -> Optional[str]:
     return env if env not in ("", "0", "1") else None
 
 
-class span:
-    """Context manager recording one host span (when the tracer is
-    active).  For hot loops prefer guarding on ``tracer().active`` and
-    calling ``complete`` with timestamps you already have."""
+def _buffered(annotation, name: str, doc: str):
+    """`annotation` (a profiler annotation class) with the buffer as its
+    second sink.  nanobind classes take one base, so the two span
+    classes are made from this one body and not from a mixin."""
 
-    __slots__ = ("name", "cat", "args", "_t0")
+    class cls(annotation):
+        __slots__ = ("name", "cat", "args", "_t0")
 
-    def __init__(self, name: str, cat: str = "host",
-                 args: Optional[dict] = None):
-        self.name = name
-        self.cat = cat
-        self.args = args
-        self._t0 = 0.0
+        def __init__(self, name: str, cat: str = "host",
+                     args: Optional[dict] = None, **more):
+            if args:
+                more = {**args, **more}
+            super().__init__(name, **more)
+            self.name = name
+            self.cat = cat
+            self.args = more
+            self._t0 = None
 
-    def __enter__(self):
-        if _TRACER.active:
-            self._t0 = _TRACER.now_us()
-        return self
+        def __enter__(self):
+            super().__enter__()
+            if _TRACER.active:
+                self._t0 = _TRACER.now_us()
+            return self
 
-    def __exit__(self, *exc):
-        if _TRACER.active:
-            now = _TRACER.now_us()
-            _TRACER.complete(self.name, self._t0, now - self._t0,
-                             cat=self.cat, args=self.args)
-        return False
+        def __exit__(self, *exc):
+            super().__exit__(*exc)
+            if self._t0 is not None:
+                _TRACER.complete(self.name, self._t0,
+                                 _TRACER.now_us() - self._t0, cat=self.cat,
+                                 args=self.args or None)
+                self._t0 = None
+            return False
+
+        def note(self, **more):
+            """Arguments known only after the span was entered (a tick's
+            active slots): both sinks get them."""
+            self.set_metadata(**more)
+            self.args.update(more)
+
+        def __call__(self, fn):
+            """As a decorator: every call of `fn` is one such span."""
+            @functools.wraps(fn)
+            def wrapped(*a, **k):
+                with type(self)(self.name, self.cat, self.args):
+                    return fn(*a, **k)
+            return wrapped
+
+    cls.__name__ = cls.__qualname__ = name
+    cls.__doc__ = doc
+    return cls
+
+
+span = _buffered(
+    TraceAnnotation, "span",
+    """One host span: ``with span("tick/read", tick=n): ...``.  Keyword
+    arguments (or ``args``) travel with the span into the profiler's
+    trace and into the buffer.  Also a decorator.""")
+step_span = _buffered(
+    StepTraceAnnotation, "step_span",
+    """A ``span`` that the profiler's tools group by, for the parents
+    ``train_step`` and ``tick``: pass ``step_num``.""")
 
 
 def export_chrome_trace(path: Optional[str] = None) -> Optional[str]:

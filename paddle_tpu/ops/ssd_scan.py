@@ -157,5 +157,4 @@ def causal_conv1d(x, weight, bias=None):
     t-K+1 .. t."""
     if bias is None:
         bias = jnp.zeros(weight.shape[:1], weight.dtype)
-    with jax.named_scope("mamba_conv"):
-        return _conv(x, weight, bias)
+    return _conv(x, weight, bias)

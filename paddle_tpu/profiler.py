@@ -7,71 +7,32 @@ fluid/profiler.py:131,198,255 (profiler ctx manager, start/stop).
 
 TPU-native: XLA already timestamps every HLO on-device; what the
 framework owns is (1) host-side trace annotations that show up nested
-inside the device timeline (jax.profiler.TraceAnnotation ==
-RecordEvent), (2) capture control writing TensorBoard/Perfetto traces
-(start_trace/stop_trace == EnableProfiler -> chrome-trace file), and
+inside the device timeline (RecordEvent: observability.spans.span, a
+jax.profiler.TraceAnnotation), (2) capture control writing
+TensorBoard/Perfetto traces (start_trace/stop_trace == EnableProfiler ->
+chrome-trace file), and
 (3) cheap per-step wall timing for training loops (hapi logs
 `step_time_ms` through StepTimer) — the profiler.py summary-table role.
 """
 from __future__ import annotations
 
 import contextlib
-import functools
 import time
 from typing import Optional
 
 import jax
 
+# The reference's RAII marker (platform/profiler.h:127) is the program's
+# one span primitive under its reference name: a profiler annotation that
+# nests inside a device capture and, when the span buffer is armed, also
+# lands there for the Chrome-trace export.  Context manager or decorator;
+# with no capture and the buffer off it costs the annotation's enter/exit.
+from .observability.spans import span as RecordEvent
+
 __all__ = ["RecordEvent", "record_event", "profiler", "start_profiler",
            "stop_profiler", "StepTimer", "memory_stats", "cost_stats"]
 
 _active_trace_dir: Optional[str] = None
-
-
-class RecordEvent:
-    """Host-side trace annotation (reference platform/profiler.h:127).
-    Context manager or decorator; nests inside the device trace when a
-    capture is active, costs ~nothing when idle.
-
-    Two sinks per event (ISSUE 13): the jax TraceAnnotation shows the
-    span nested inside a device capture, and — when the structured span
-    tracer is armed (observability.spans) — the same enter/exit pair
-    lands in the process span buffer for Chrome-trace export, so one
-    RecordEvent instruments both the device timeline and the host
-    timeline."""
-
-    def __init__(self, name: str, args: Optional[dict] = None):
-        self.name = name
-        self.args = args
-        self._ann = None
-        self._t0_us = 0.0
-
-    def __enter__(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        from .observability import spans as _spans
-        tr = _spans.tracer()
-        if tr.active:
-            self._t0_us = tr.now_us()
-        return self
-
-    def __exit__(self, *exc):
-        self._ann.__exit__(*exc)
-        self._ann = None
-        from .observability import spans as _spans
-        tr = _spans.tracer()
-        if tr.active:
-            now = tr.now_us()
-            tr.complete(self.name, self._t0_us, now - self._t0_us,
-                        cat="record_event", args=self.args)
-        return False
-
-    def __call__(self, fn):
-        @functools.wraps(fn)
-        def wrapped(*a, **k):
-            with RecordEvent(self.name):
-                return fn(*a, **k)
-        return wrapped
 
 
 record_event = RecordEvent
