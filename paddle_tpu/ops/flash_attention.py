@@ -8,18 +8,19 @@ streams k/v blocks through the MXU with a running max/denominator
 the saved per-row logsumexp (the standard flash recompute strategy), so
 HBM traffic is O(S·D) instead of O(S²) for fwd and bwd alike.
 
-Backward = two kernels sharing the recompute:
-  - dq: per q-block, loop over k-blocks; dq_i = scale * Σ_j ds_ij k_j
-  - dk/dv: per k-block, loop over q-blocks (and GQA groups);
-    dv_j = Σ_i p_ij do_i, dk_j = scale * Σ_i ds_ij q_i
+Backward = ONE kernel per key block, looping over the query tiles (and,
+across grid steps, the GQA groups): the probabilities and ds are rebuilt
+once a tile and feed all three gradients, five products a tile
+  dv_j = Σ_i p_ij do_i, dk_j = scale * Σ_i ds_ij q_i,
+  dq_i = scale * Σ_j ds_ij k_j (summed over the key blocks in VMEM)
   with p_ij = exp(scale·q_i·k_j − lse_i), ds_ij = p_ij (do_i·v_j − δ_i),
-  δ_i = do_i·o_i (one cheap XLA rowsum before the kernels).
-All inner [block_q, block_k] tiles live in registers/VMEM only.
+  δ_i = do_i·o_i (one cheap XLA rowsum before the kernel).
+All inner [block_k, block_q] tiles live in registers/VMEM only.
 
 GQA is native: q is laid out [B·Hkv, G, S, D] and k/v [B·Hkv, S, D]; the
 grid walks (kv-head, group, block), so grouped-query models never
 materialize repeat_interleaved K/V (G enters as a grid dimension, and
-the dk/dv kernel accumulates over it in-place across grid steps).
+the backward kernel accumulates dk/dv over it in-place across grid steps).
 
 An optional key-padding mask [B, S] (1 = attend, 0 = masked) covers the
 padded-batch pretraining case without an O(S²) bias tensor; arbitrary
@@ -30,7 +31,7 @@ H % Hkv == 0.  The scores' width D and the values' width Dv are two
 numbers: every kernel takes q and k at D (the softmax scale is
 ``D ** -0.5``) and v, o and do at Dv, so latent attention's 192-wide
 scores over 128-wide values (128 + 64 channels, no padding to 256) run
-through the same three kernels as D == Dv.  Each width is 64, 192 or a
+through the same kernels as D == Dv.  Each width is 64, 192 or a
 multiple of 128.
 """
 from __future__ import annotations
@@ -88,160 +89,194 @@ def flash_attention_available() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# forward kernel
+# what the kernels wait for
+#
+# Timed alone on a v5e (PERF.md section 6, PR 39) the kernels are bound
+# by how many rows they push through the MXU, not by the vector unit: a
+# head of 64 fills half the array's depth (q.k, do.v) or half its width
+# (p.v and the three gradients), and knocking every mask out of the
+# loops bought 6%.  What pays is fewer products (the backward rebuilds
+# the scores and ds once, not once for dq and once for dk/dv), fewer
+# reductions across lanes (every tile is [bk, bq]: below), and fewer
+# elements visited (the tiles in _AUTOTUNE_TABLE).  Each body is still
+# built from what a call can observe, all of it static:
+#   - no key mask passed: no mask operand, no compare and select, and no
+#     guard for fully masked rows (under `causal` alone a row attends at
+#     least its own position, so exp(_NEG - m) is exactly 0);
+#   - `causal`: each loop runs twice, first over the tiles that lie
+#     wholly under the diagonal (no iota, compare or select), then over
+#     the tile or tiles the diagonal crosses; those above stay unvisited;
+#   - the scores stay as the MXU leaves them, q.k unscaled, and `scale`
+#     goes into the exponent's constant: exp2(s * scale * log2 e - ...).
+#     The running max is kept in the scores' own units; `lse` is stored
+#     in natural-log units of the scaled scores, as it always was.
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *,
-                block_k: int, causal: bool, scale: float):
+_LOG2E = math.log2(math.e)
+
+
+def _attends(shape, q_axis: int, q0, k0):
+    """Bool tile: the element's query (along ``q_axis``, counted from q0)
+    is at or past its key (along the other axis, counted from k0)."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    return ahead >= k0 - q0
+
+
+def _visit(tile, init, whole, crossed):
+    """``tile(j, carry, crossed)`` over the range of tiles the diagonal
+    leaves whole, then over the range it crosses (None: not causal).
+    Whole tiles first: a row's first tile then holds a key it attends,
+    so its running max is finite before a tile that masks all of it."""
+    carry = jax.lax.fori_loop(
+        *whole, functools.partial(tile, crossed=False), init)
+    if crossed is None:
+        return carry
+    return jax.lax.fori_loop(
+        *crossed, functools.partial(tile, crossed=True), carry)
+
+
+def _key_tiles(q_start, block_q: int, block_k: int, n_k: int, causal):
+    """(whole, crossed) ranges of the key tiles a query block visits."""
+    if not causal:
+        return (0, n_k), None
+    first_crossed = (q_start + 1) // block_k
+    end = jnp.minimum((q_start + block_q + block_k - 1) // block_k, n_k)
+    return (0, first_crossed), (first_crossed, end)
+
+
+def _query_tiles(k_start, block_q: int, block_k: int, n_q: int, causal):
+    """(whole, crossed) ranges of the query tiles a key block meets:
+    those it crosses come first in the sequence, the whole ones after."""
+    if not causal:
+        return (0, n_q), None
+    first_whole = jnp.minimum(
+        (k_start + block_k + block_q - 2) // block_q, n_q)
+    return (first_whole, n_q), (k_start // block_q, first_whole)
+
+
+# ---------------------------------------------------------------------------
+# the kernels.  Every tile is [bk, bq], keys down the sublanes and
+# queries along the lanes: what there is one of a query (the running max
+# and sum, lse, delta) is then a dense row of lanes and not a column
+# with one lane in use, and a reduction over the keys runs elementwise
+# down the tile and not across lanes.  The forward's accumulator is
+# turned too, [dv, bq] = vT.pT, so its rescale is a row against rows
+# and it is turned back once, on its way out (a third off the forward
+# at heads of 64); of the backward's products only dq contracts a tile
+# over its first dimension.
+# ---------------------------------------------------------------------------
+def _scores_t(k_blk, q_blk, kv_f, q0, k0):
+    """sT [bk, bq] = k.q, unscaled, masked to _NEG.  ``kv_f``: the key
+    mask's slice, or None; ``q0``: where the tile's queries start when
+    the diagonal crosses it (its keys start at k0), or None."""
+    st = jax.lax.dot_general(
+        k_blk, q_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [bk, bq]
+    if kv_f is not None:
+        st = jnp.where(kv_f[:, None] > 0, st, _NEG)
+    if q0 is not None:
+        st = jnp.where(_attends(st.shape, 1, q0, k0), st, _NEG)
+    return st
+
+
+def _probs_t(st, shift, expo: float, guard: bool):
+    """exp2(st * expo - shift) over a tile of masked scores; with a key
+    mask a row may attend nothing, and its masked scores then read 0
+    whatever the shift is."""
+    pT = jnp.exp2(st * expo - shift)
+    return jnp.where(st <= _NEG / 2, 0.0, pT) if guard else pT
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool,
+                scale: float):
     """One (bh, g, q_block) program. q_ref [bq,d]; k [S,d]; v [S,dv];
-    m_ref (1,S) key mask; outputs o [bq,dv] and lse (1,bq)."""
+    m_ref (1,S), the key mask, only when the caller passed one; outputs
+    o [bq,dv] and lse (1,bq)."""
+    *mask, o_ref, lse_ref = rest
+    m_ref = mask[0] if mask else None
     block_q = q_ref.shape[0]
     s, dv = v_ref.shape
-    n_k = s // block_k
+    expo = scale * _LOG2E
 
     # keep q/k/v in their storage dtype (bf16) for the MXU dots — f32
     # matmul inputs run at a fraction of the bf16 MXU rate; accumulation
     # stays f32 via preferred_element_type (the standard mixed scheme)
     q = q_ref[:]
-    qi = pl.program_id(2)
-    q_start = qi * block_q
+    q_start = pl.program_id(2) * block_q
 
-    m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, dv), jnp.float32)
+    m0 = jnp.full((1, block_q), _NEG, jnp.float32)
+    l0 = jnp.zeros((1, block_q), jnp.float32)
+    acc0 = jnp.zeros((dv, block_q), jnp.float32)
 
-    def body(j, carry):
+    def tile(j, carry, crossed):
         m, l, acc = carry
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        sblk = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [bq, bk] f32
-        # reshape the f32 mask BEFORE comparing: mosaic can't insert a
-        # minor dim on 1-bit vectors
-        kv_f = m_ref[0, pl.ds(j * block_k, block_k)]       # (bk,) f32
-        sblk = jnp.where(kv_f[None, :] > 0, sblk, _NEG)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            sblk = jnp.where(rows >= cols, sblk, _NEG)
-        m_new = jnp.maximum(m, jnp.max(sblk, axis=1, keepdims=True))
-        p = jnp.exp(sblk - m_new)
-        p = jnp.where(sblk <= _NEG / 2, 0.0, p)  # fully-masked rows
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        k_start = j * block_k
+        k_blk = k_ref[pl.ds(k_start, block_k), :]
+        v_blk = v_ref[pl.ds(k_start, block_k), :]
+        st = _scores_t(
+            k_blk, q,
+            None if m_ref is None else m_ref[0, pl.ds(k_start, block_k)],
+            q_start if crossed else None, k_start)
+        m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+        pT = _probs_t(st, m_new * expo, expo, m_ref is not None)
+        alpha = jnp.exp2((m - m_new) * expo)               # [1, bq]
+        l_new = l * alpha + jnp.sum(pT, axis=0, keepdims=True)
         acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v_blk, pT.astype(v_blk.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [dv, bq]
         return m_new, l_new, acc_new
 
-    if causal:
-        last = (q_start + block_q + block_k - 1) // block_k
-        n_iter = min(last, n_k) if isinstance(last, int) \
-            else jnp.minimum(last, n_k)
-    else:
-        n_iter = n_k
-    m, l, acc = jax.lax.fori_loop(0, n_iter, body, (m0, l0, acc0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0, :] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+    m, l, acc = _visit(tile, (m0, l0, acc0), *_key_tiles(
+        q_start, block_q, block_k, s // block_k, causal))
+    l = jnp.maximum(l, 1e-30)
+    o_ref[:] = (acc / l).T.astype(o_ref.dtype)
+    lse_ref[:] = m * scale + jnp.log(l)
 
 
-# ---------------------------------------------------------------------------
-# backward kernels (everything in [bk, bq] orientation: lse/delta live on
-# the lane axis, so no sublane broadcasts or transposes are emitted)
-# ---------------------------------------------------------------------------
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, m_ref,
-                   dq_ref, *, block_k: int, causal: bool, scale: float):
-    """One (bh, g, q_block): dq for this q block."""
-    block_q, d = q_ref.shape
-    s = k_ref.shape[0]
-    n_k = s // block_k
-    qi = pl.program_id(2)
-    q_start = qi * block_q
-
-    # bf16 MXU inputs, f32 accumulation (see _fwd_kernel note)
-    qs = q_ref[:]                                          # [bq, d]
-    do = do_ref[:]                                         # [bq, d]
-    lse = lse_ref[0, :]                                    # (bq,)
-    delta = dl_ref[0, :]                                   # (bq,)
-
-    def body(j, dq_acc):
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        st = jax.lax.dot_general(
-            k_blk, qs, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [bk, bq]
-        kv_f = m_ref[0, pl.ds(j * block_k, block_k)]       # (bk,) f32
-        st = jnp.where(kv_f[:, None] > 0, st, _NEG)
-        if causal:
-            krows = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            qcols = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            st = jnp.where(qcols >= krows, st, _NEG)
-        pT = jnp.exp(st - lse[None, :])                    # [bk, bq]
-        pT = jnp.where(st <= _NEG / 2, 0.0, pT)
-        dpT = jax.lax.dot_general(
-            v_blk, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, bq]
-        dsT = (pT * (dpT - delta[None, :])).astype(k_blk.dtype)
-        return dq_acc + jax.lax.dot_general(
-            dsT, k_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, d]
-
-    if causal:
-        last = (q_start + block_q + block_k - 1) // block_k
-        n_iter = min(last, n_k) if isinstance(last, int) \
-            else jnp.minimum(last, n_k)
-    else:
-        n_iter = n_k
-    dq = jax.lax.fori_loop(0, n_iter, body,
-                           jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, m_ref,
-                    dk_ref, dv_ref, *, block_q: int, causal: bool,
-                    scale: float, n_groups: int):
-    """One (bh, k_block, g): dk [bk,d] / dv [bk,dv] for this k block,
-    accumulated over the GQA group grid dimension (g innermost; init at
-    g == 0)."""
+def _bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, *rest,
+                block_q: int, causal: bool, scale: float, n_groups: int):
+    """One (bh, g, k_block): this key block against every query tile it
+    meets.  The probabilities and ds are rebuilt ONCE a tile and feed
+    all three gradients (five products a tile, where a dq kernel beside
+    a dk/dv kernel paid seven):
+      - dk [bk,d] / dv [bk,dv] are summed over the query tiles here and
+        over the GQA group across grid steps: with one group they are
+        this block's rows, written in k's dtype; with several the whole
+        sequence stays in VMEM in float32 (g is not the innermost grid
+        dimension) and the rows are added in place;
+      - dq is summed over the key blocks, the innermost grid dimension,
+        in the float32 scratch ``dq_acc`` [S,d], and written once, at
+        the last key block."""
+    *mask, dq_ref, dk_ref, dv_ref, dq_acc = rest
     block_k, d = k_ref.shape
     dv = v_ref.shape[1]
-    s = q_ref.shape[0]
-    n_q = s // block_q
-    kj = pl.program_id(1)
-    g = pl.program_id(2)
+    n_q = q_ref.shape[0] // block_q
+    g, kj = pl.program_id(1), pl.program_id(2)
     k_start = kj * block_k
 
     # bf16 MXU inputs, f32 accumulation (see _fwd_kernel note)
     k_blk = k_ref[:]
     v_blk = v_ref[:]
-    kv_f = m_ref[0, pl.ds(k_start, block_k)]               # (bk,) f32
+    kv_f = mask[0][0, pl.ds(k_start, block_k)] if mask else None  # (bk,)
 
-    def body(i, carry):
+    @pl.when(kj == 0)
+    def _first_key_block():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def tile(i, carry, crossed):
         dk_acc, dv_acc = carry
-        q_blk = q_ref[pl.ds(i * block_q, block_q), :]      # [bq, d]
-        do_blk = do_ref[pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)]      # (bq,)
-        delta = dl_ref[0, pl.ds(i * block_q, block_q)]
-        st = jax.lax.dot_general(
-            k_blk, q_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [bk, bq]
-        st = jnp.where(kv_f[:, None] > 0, st, _NEG)
-        if causal:
-            krows = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            qcols = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            st = jnp.where(qcols >= krows, st, _NEG)
-        pT = jnp.exp(st - lse[None, :])
-        pT = jnp.where(st <= _NEG / 2, 0.0, pT)
-        pT16 = pT.astype(do_blk.dtype)
+        q_rows = pl.ds(i * block_q, block_q)
+        q_blk = q_ref[q_rows, :]                           # [bq, d]
+        do_blk = do_ref[q_rows, :]
+        lse2 = lse_ref[0, q_rows] * _LOG2E                 # (bq,)
+        delta = dl_ref[0, q_rows]
+        pT = _probs_t(
+            _scores_t(k_blk, q_blk, kv_f,
+                      i * block_q if crossed else None, k_start),
+            lse2[None, :], scale * _LOG2E, kv_f is not None)
         dv_acc = dv_acc + jax.lax.dot_general(
-            pT16, do_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
+            pT.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bk, dv]
         dpT = jax.lax.dot_general(
             v_blk, do_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, bq]
@@ -249,26 +284,38 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dl_ref, m_ref,
         dk_acc = dk_acc + jax.lax.dot_general(
             dsT, q_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bk, d]
+        dq_acc[q_rows, :] += jax.lax.dot_general(
+            dsT, k_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [bq, d]
         return dk_acc, dv_acc
 
-    i0 = k_start // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(
-        i0, n_q, body, (jnp.zeros((block_k, d), jnp.float32),
-                        jnp.zeros((block_k, dv), jnp.float32)))
-    # dk_j = scale * Σ ds_ij q_i (scale was folded into q before the
-    # bf16-input rework; now applied once here)
+    init = (jnp.zeros((block_k, d), jnp.float32),
+            jnp.zeros((block_k, dv), jnp.float32))
+    dk, d_v = _visit(tile, init, *_query_tiles(
+        k_start, block_q, block_k, n_q, causal))
+    # dk_j = scale * Σ ds_ij q_i and dq_i = scale * Σ ds_ij k_j: the
+    # scale is applied once, to the sums
     dk = dk * scale
 
-    @pl.when(g == 0)
-    def _init():
+    if n_groups == 1:
         dk_ref[:] = dk.astype(dk_ref.dtype)
-        dv_ref[:] = dv.astype(dv_ref.dtype)
+        dv_ref[:] = d_v.astype(dv_ref.dtype)
+    else:
+        k_rows = pl.ds(k_start, block_k)
 
-    if n_groups > 1:
+        @pl.when(g == 0)
+        def _first_group():
+            dk_ref[k_rows, :] = dk
+            dv_ref[k_rows, :] = d_v
+
         @pl.when(g > 0)
-        def _accum():
-            dk_ref[:] = dk_ref[:] + dk.astype(dk_ref.dtype)
-            dv_ref[:] = dv_ref[:] + dv.astype(dv_ref.dtype)
+        def _later_group():
+            dk_ref[k_rows, :] += dk
+            dv_ref[k_rows, :] += d_v
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _last_key_block():
+        dq_ref[:] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -284,15 +331,18 @@ def _pick_block(s, want=256):
 # ---------------------------------------------------------------------------
 # block-size autotuning
 #
-# The fixed (512, 512) tiles the kernel shipped with are a safe middle
-# ground, not an optimum: the right tile trades VMEM footprint (the
-# [block_q, block_k] f32 score tile + the full k/v strips) against grid
-# overhead and MXU occupancy, and the balance shifts with sequence
-# length and head_dim. The table below carries per-shape defaults from
-# a one-shot fwd+bwd sweep on TPU v5 lite (bf16, GPT head shapes);
-# unknown shapes fall back to the nearest tabled sequence and finally
-# to the fixed defaults, and every choice is clamped by _pick_block so
-# a bad entry can never produce an invalid grid.
+# The right tile trades the elements a causal loop visits for nothing
+# (a tile the diagonal crosses is computed whole: block / S of the
+# causal half) against what every tile costs whatever its size (the
+# loop's turn, the running max and sum, the accumulator's rescale), and
+# the balance shifts with sequence length and head width.  The table
+# below holds ONLY what a chip measured: forward + backward of these
+# kernels timed alone on one TPU v5e over _SWEEP_CANDIDATES, bf16, at
+# the shapes the benchmark's cells run (PERF.md section 6, PR 39, has
+# every timing).  Other shapes take the nearest tabled sequence of
+# their (device, head_dim, causal) and finally the fixed defaults, and
+# every choice is clamped by _pick_block so a bad entry can never
+# produce an invalid grid.
 #
 # PADDLE_TPU_FLASH_AUTOTUNE: "1" (default) = table lookup,
 # "0" = fixed defaults, "sweep" = run a one-shot on-device sweep for
@@ -300,25 +350,22 @@ def _pick_block(s, want=256):
 # ---------------------------------------------------------------------------
 _DEFAULT_BLOCKS = (512, 512)
 
-# (device_kind, seq, head_dim, causal) -> (block_q, block_k)
+# (device_kind, seq, head_dim, causal) -> (block_q, block_k); head_dim
+# is the scores' width.  ms forward + backward at the entry | at the
+# runner-up | at the tiles the shape had before:
 _AUTOTUNE_TABLE = {
-    # v5 lite: 16 MB VMEM/core; d=64 leaves room for wide k blocks, and
-    # causal masking favors taller q blocks (fewer skipped k iterations
-    # per program)
-    ("v5e", 1024, 64, True): (512, 512),
-    ("v5e", 1024, 64, False): (512, 1024),
-    ("v5e", 2048, 64, True): (512, 1024),
-    ("v5e", 2048, 64, False): (512, 1024),
-    ("v5e", 4096, 64, True): (1024, 1024),
-    ("v5e", 4096, 64, False): (512, 1024),
-    ("v5e", 8192, 64, True): (1024, 1024),
-    # d=128 doubles every strip; halve the q tile to stay under budget
-    ("v5e", 1024, 128, True): (256, 512),
-    ("v5e", 2048, 128, True): (256, 512),
-    ("v5e", 4096, 128, True): (512, 512),
-    # v5p / v6e carry more VMEM bandwidth; same shapes, wider k
-    ("v5p", 2048, 64, True): (512, 1024),
-    ("v6e", 2048, 64, True): (512, 1024),
+    # [96,1,2048,64], gpt3-350m: 3.21 | (1024,1024) 3.48 | (512,1024) 3.68
+    ("v5e", 2048, 64, True): (512, 512),
+    # [4,16,8192,128], 32 heads on 2: 27.3 | (1024,512) 28.1 | (512,512) 28.3
+    ("v5e", 8192, 128, True): (1024, 1024),
+    # [64,1,8192,192] on values of 128: 40.2 | (1024,512) 41.0 | (512,512) 41.2
+    ("v5e", 8192, 192, True): (1024, 1024),
+    # the serving prefill's buckets, [16,1,s,128], forward alone: 0.071 |
+    # (1024,1024) 0.074 | (256,512) 0.119; under 1024 one tile wins
+    ("v5e", 1024, 128, True): (512, 512),
+    ("v5e", 512, 128, True): (512, 512),
+    ("v5e", 256, 128, True): (256, 256),
+    ("v5e", 128, 128, True): (128, 128),
 }
 
 _SWEEP_CACHE: dict = {}
@@ -513,7 +560,7 @@ def autotune_sweep(seq: int, head_dim: int, causal: bool, batch: int = 1,
                      .astype(np.float32) * 0.1, dtype=jnp.bfloat16)
     v3 = jnp.asarray(rng.randn(batch * heads, seq, head_dim)
                      .astype(np.float32) * 0.1, dtype=jnp.bfloat16)
-    mask = jnp.ones((batch, 1, seq), jnp.float32)
+    mask = None     # the body every cell runs: no key mask
 
     def step_time(bq, bk):
         fwd = jax.jit(functools.partial(
@@ -554,29 +601,46 @@ def autotune_sweep(seq: int, head_dim: int, causal: bool, batch: int = 1,
 _SCOPED_VMEM = 16 * 2 ** 20     # what a kernel may use unless it asks
 
 
-def _strip_room(s: int, d: int, dv: int, itemsize: int) -> dict:
-    """Every kernel keeps two whole strips of the sequence in VMEM (k and
-    v, or q and do), double-buffered, each width rounded up to whole lane
-    tiles.  Up to 8192 x (128 + 128) in bf16 they fit the compiler's own
-    budget beside the blocks and the score tile, and the call is built as
-    it always was; wider (192 counts as 256) it asks for the strips and
-    that budget on top."""
+def _strip_room(s: int, d: int, dv: int, itemsize: int,
+                backward_groups: int = 0) -> dict:
+    """Every kernel keeps whole strips of the sequence in VMEM, each
+    double-buffered and each width rounded up to whole lane tiles: the
+    forward k and v; the backward q and do, dq as it goes out and as its
+    float32 sum, and with several groups dk and dv in float32 too.  Up
+    to half the compiler's own budget they fit beside the blocks and the
+    score tile, and the call is built as it always was; past that it
+    asks for the strips and that budget on top."""
     lanes = lambda w: -(-w // 128) * 128
     strips = 2 * s * (lanes(d) + lanes(dv)) * itemsize
+    if backward_groups:
+        strips += s * lanes(d) * (2 * itemsize + 4)
+        if backward_groups > 1:
+            strips += 2 * s * (lanes(d) + lanes(dv)) * 4
     if strips <= _SCOPED_VMEM // 2:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=strips + _SCOPED_VMEM)}
 
 
+def _mask_spec(mask, bhkv: int, s: int):
+    """The key mask's operand and BlockSpec, for either kernel's grid
+    (its first index is the kv head's): a one-element list each, or
+    empty ones when the caller passed no mask."""
+    if mask is None:
+        return [], []
+    hkv = bhkv // mask.shape[0]
+    return [mask], [pl.BlockSpec((None, 1, s),
+                                 lambda b, i, j: (b // hkv, 0, 0))]
+
+
 def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
     bhkv, g, s, d = q4.shape
     dv = v3.shape[2]
-    hkv = bhkv // mask.shape[0]
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
     scale = 1.0 / math.sqrt(d)
     grid = (bhkv, g, s // block_q)
+    mask, mask_spec = _mask_spec(mask, bhkv, s)
     kernel = functools.partial(_fwd_kernel, block_k=block_k,
                                causal=causal, scale=scale)
     call = pl.pallas_call(
@@ -587,8 +651,7 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
                          lambda b, gi, i: (b, gi, i, 0)),
             pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),
             pl.BlockSpec((None, s, dv), lambda b, gi, i: (b, 0, 0)),
-            pl.BlockSpec((None, 1, s),
-                         lambda b, gi, i, hkv=hkv: (b // hkv, 0, 0)),
+            *mask_spec,
         ],
         out_specs=[
             pl.BlockSpec((None, None, block_q, dv),
@@ -603,82 +666,62 @@ def _fwd_gqa(q4, k3, v3, mask, causal, block_q=512, block_k=512):
         interpret=_INTERPRET,
         **_strip_room(s, d, dv, q4.dtype.itemsize),
     )
-    return run_kernel(q4.dtype, call, q4, k3, v3, mask)
+    return run_kernel(q4.dtype, call, q4, k3, v3, *mask)
 
 
 def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
              block_q=512, block_k=512):
     bhkv, g, s, d = q4.shape
     dv = v3.shape[2]
-    hkv = bhkv // mask.shape[0]
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
-    scale = 1.0 / math.sqrt(d)
+    mask, mask_spec = _mask_spec(mask, bhkv, s)
     # delta_i = do_i · o_i — one fused XLA rowsum, O(S·D)
     delta = jnp.sum(do4.astype(jnp.float32) * o4.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                # [BHkv,G,1,S]
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, block_k=block_k,
-                                  causal=causal, scale=scale)
+    kernel = functools.partial(
+        _bwd_kernel, block_q=block_q, causal=causal,
+        scale=1.0 / math.sqrt(d), n_groups=g)
+    strip = lambda w: pl.BlockSpec((None, None, s, w),
+                                   lambda b, gi, j: (b, gi, 0, 0))
+    row = pl.BlockSpec((None, None, 1, s), lambda b, gi, j: (b, gi, 0, 0))
+    # dk and dv: a key block's rows, or with several groups the whole
+    # sequence in float32, resident while the groups add into it
+    if g == 1:
+        summed = lambda w: pl.BlockSpec((None, block_k, w),
+                                        lambda b, gi, j: (b, j, 0))
+        summed_dtype = k3.dtype
+    else:
+        summed = lambda w: pl.BlockSpec((None, s, w),
+                                        lambda b, gi, j: (b, 0, 0))
+        summed_dtype = jnp.float32
     call = pl.pallas_call(
-        dq_kernel,
-        grid=(bhkv, g, s // block_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, gi, i: (b, gi, i, 0)),   # q
-            pl.BlockSpec((None, s, d), lambda b, gi, i: (b, 0, 0)),  # k
-            pl.BlockSpec((None, s, dv), lambda b, gi, i: (b, 0, 0)),  # v
-            pl.BlockSpec((None, None, block_q, dv),
-                         lambda b, gi, i: (b, gi, i, 0)),   # do
-            pl.BlockSpec((None, None, 1, block_q),
-                         lambda b, gi, i: (b, gi, 0, i)),   # lse
-            pl.BlockSpec((None, None, 1, block_q),
-                         lambda b, gi, i: (b, gi, 0, i)),   # delta
-            pl.BlockSpec((None, 1, s),
-                         lambda b, gi, i, hkv=hkv: (b // hkv, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, block_q, d),
-                               lambda b, gi, i: (b, gi, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bhkv, g, s, d), q4.dtype),
-        interpret=_INTERPRET,
-        **_strip_room(s, d, dv, q4.dtype.itemsize),
-    )
-    dq = run_kernel(q4.dtype, call, q4, k3, v3, do4, lse, delta, mask)
-
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                                   causal=causal, scale=scale,
-                                   n_groups=g)
-    call = pl.pallas_call(
-        dkv_kernel,
-        grid=(bhkv, s // block_k, g),   # g innermost: in-place accumulate
+        kernel,
+        grid=(bhkv, g, s // block_k),   # key blocks innermost: dq sums
         in_specs=[
             pl.BlockSpec((None, block_k, d),
-                         lambda b, j, gi: (b, j, 0)),       # k
+                         lambda b, gi, j: (b, j, 0)),       # k
             pl.BlockSpec((None, block_k, dv),
-                         lambda b, j, gi: (b, j, 0)),       # v
-            pl.BlockSpec((None, None, s, d),
-                         lambda b, j, gi: (b, gi, 0, 0)),   # q (one group)
-            pl.BlockSpec((None, None, s, dv),
-                         lambda b, j, gi: (b, gi, 0, 0)),   # do
-            pl.BlockSpec((None, None, 1, s),
-                         lambda b, j, gi: (b, gi, 0, 0)),   # lse
-            pl.BlockSpec((None, None, 1, s),
-                         lambda b, j, gi: (b, gi, 0, 0)),   # delta
-            pl.BlockSpec((None, 1, s),
-                         lambda b, j, gi, hkv=hkv: (b // hkv, 0, 0)),
+                         lambda b, gi, j: (b, j, 0)),       # v
+            strip(d),                                       # q (one group)
+            strip(dv),                                      # do
+            row,                                            # lse
+            row,                                            # delta
+            *mask_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda b, j, gi: (b, j, 0)),
-            pl.BlockSpec((None, block_k, dv), lambda b, j, gi: (b, j, 0)),
-        ],
+        out_specs=[strip(d), summed(d), summed(dv)],
         out_shape=[
-            jax.ShapeDtypeStruct((bhkv, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((bhkv, s, dv), jnp.float32),
+            jax.ShapeDtypeStruct((bhkv, g, s, d), q4.dtype),
+            jax.ShapeDtypeStruct((bhkv, s, d), summed_dtype),
+            jax.ShapeDtypeStruct((bhkv, s, dv), summed_dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
         interpret=_INTERPRET,
-        **_strip_room(s, d, dv, q4.dtype.itemsize),
+        **_strip_room(s, d, dv, q4.dtype.itemsize, backward_groups=g),
     )
-    dk, d_v = run_kernel(q4.dtype, call, k3, v3, q4, do4, lse, delta, mask)
+    dq, dk, d_v = run_kernel(q4.dtype, call, k3, v3, q4, do4, lse, delta,
+                             *mask)
     return dq, dk.astype(k3.dtype), d_v.astype(v3.dtype)
 
 
@@ -763,7 +806,7 @@ def _flash_bwd(causal, res, g_out):
     dq = _from_gqa_q(dq4, b, s, h, d).astype(q.dtype)
     dk = jnp.swapaxes(dk3.reshape(b, hkv, s, d), 1, 2)
     d_v = jnp.swapaxes(dv3.reshape(b, hkv, s, dv), 1, 2)
-    return dq, dk, d_v, jnp.zeros_like(mask)
+    return dq, dk, d_v, None if mask is None else jnp.zeros_like(mask)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -788,7 +831,10 @@ def flash_attention(q, k, v, causal=False, kv_mask=None):
         kernel_paths.note_composite("flash_attention", supported)
         return _composite(q, k, v, causal, kv_mask)
     kernel_paths.note("flash_attention", "kernel")
-    mask = jnp.ones((b, 1, s), jnp.float32) if kv_mask is None \
+    # which body was built: the key mask is a static choice, like causal
+    kernel_paths.note("flash_attention.key_mask" if kv_mask is not None
+                      else "flash_attention.no_key_mask", "kernel")
+    mask = None if kv_mask is None \
         else kv_mask.reshape(b, 1, s).astype(jnp.float32)
     part = _mesh_partition(b, h, hkv)
     if part is None:
@@ -796,9 +842,10 @@ def flash_attention(q, k, v, causal=False, kv_mask=None):
     where, qkv_spec, mask_spec = part
     from ..distributed.mesh import shard_map
     return shard_map(
-        lambda q, k, v, mask: _flash(q, k, v, mask, causal),
-        in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
-        out_specs=qkv_spec, check_vma=False, **where)(q, k, v, mask)
+        lambda q, k, v, *mask: _flash(q, k, v, *(mask or (None,)), causal),
+        in_specs=(qkv_spec,) * 3 + (mask_spec,) * (mask is not None),
+        out_specs=qkv_spec, check_vma=False, **where)(
+            q, k, v, *(() if mask is None else (mask,)))
 
 
 def _mesh_partition(b: int, h: int, hkv: int):

@@ -138,6 +138,126 @@ def test_kv_mask_forward_and_backward():
     assert np.allclose(np.asarray(gf[2])[:, 192:], 0.0)
 
 
+def _grads(fn, q, k, v):
+    """Value and all three gradients of a weighted sum of fn's output
+    (the weights keep every row's cotangent distinct)."""
+    def loss(q_, k_, v_):
+        out = fn(q_, k_, v_).astype(jnp.float32)
+        return (out * jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
+                              .reshape(out.shape))).sum(), out
+    (_, out), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return out, grads
+
+
+def _masked_head(b, s, n):
+    """[B, S] key mask with the first n keys padded out: under `causal`
+    the first n rows then attend nothing at all."""
+    mask = np.ones((b, s), np.float32)
+    mask[:, :n] = 0.0
+    return jnp.asarray(mask)
+
+
+# what the split loops can get wrong: (block_q, block_k), the shape, and
+# whether the call is causal and carries a key mask
+SPLIT_CASES = {
+    "tall_tiles_diagonal_crosses_four": dict(blocks=(512, 128), s=1024),
+    "wide_tiles_diagonal_crosses_each_once": dict(blocks=(128, 512), s=1024),
+    "tall_by_two": dict(blocks=(256, 128), s=512),
+    "wide_by_two": dict(blocks=(128, 256), s=512),
+    "one_tile": dict(blocks=(256, 256), s=256),
+    "one_tile_blocks_clamped": dict(blocks=(512, 1024), s=128),
+    "gqa_two_widths": dict(blocks=(256, 128), s=512, h=8, hkv=2, d=192,
+                           dv=128),
+    "gqa_two_widths_wide": dict(blocks=(128, 256), s=512, h=4, hkv=2,
+                                d=192, dv=128),
+    "not_causal_no_mask": dict(blocks=(128, 256), s=512, causal=False),
+    "not_causal_tall": dict(blocks=(256, 128), s=512, causal=False,
+                            h=4, hkv=2),
+    "key_mask_and_causal_tall": dict(blocks=(256, 128), s=512, masked=96),
+    "key_mask_and_causal_wide": dict(blocks=(128, 256), s=512, masked=160,
+                                     h=4, hkv=2),
+}
+
+
+@pytest.mark.parametrize("case", SPLIT_CASES)
+def test_split_loops_match_composite(case, monkeypatch):
+    """Forward and all three gradients against the composite, with the
+    tiles pinned so that the diagonal crosses what the case names."""
+    c = {**dict(h=2, hkv=None, d=64, dv=None, causal=True, masked=0),
+         **SPLIT_CASES[case]}
+    s, causal = c["s"], c["causal"]
+    monkeypatch.setattr(fa, "get_block_sizes", lambda *a, **k: c["blocks"])
+    q, k, v = make_qkv(b=1, s=s, h=c["h"], hkv=c["hkv"], d=c["d"], seed=11)
+    if c["dv"]:
+        v = v[..., :c["dv"]]
+    mask = _masked_head(1, s, c["masked"]) if c["masked"] else None
+
+    out, grads = _grads(lambda *a: fa.flash_attention(
+        *a, causal=causal, kv_mask=mask), q, k, v)
+    ref, ref_grads = _grads(lambda *a: fa._composite(
+        *a, causal, kv_mask=mask), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(grads, ref_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+    if mask is not None:
+        # rows that attend nothing read exact zeros and pass none back;
+        # padded keys receive nothing
+        n = c["masked"]
+        assert not np.asarray(out)[:, :n].any()
+        assert not np.asarray(grads[0])[:, :n].any()
+        assert not np.asarray(grads[1])[:, :n].any()
+        assert not np.asarray(grads[2])[:, :n].any()
+
+
+def _pallas_calls(jaxpr):
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("key_mask", [False, True])
+def test_key_mask_is_an_operand_only_when_passed(key_mask):
+    """The kernels are built without the mask operand when the caller
+    passed none, and kernel_paths tells the two bodies apart."""
+    from paddle_tpu.ops import kernel_paths
+    q, k, v = make_qkv(b=2, s=256, h=4, hkv=2)
+    mask = _masked_head(2, 256, 32) if key_mask else None
+    kernel_paths.reset()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: fa.flash_attention(*a, causal=True, kv_mask=mask).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    operands = sorted(len(e.invars) for e in _pallas_calls(jaxpr.jaxpr))
+    # forward q k v; backward k v q do lse delta; the mask on top
+    assert operands == [3 + key_mask, 6 + key_mask]
+    built, other = ("key_mask", "no_key_mask") if key_mask \
+        else ("no_key_mask", "key_mask")
+    counts = kernel_paths.counts()
+    assert counts["flash_attention"] == {"kernel": 1, "composite": 0}
+    assert counts["flash_attention." + built] == \
+        {"kernel": 1, "composite": 0}
+    assert "flash_attention." + other not in counts
+
+
+def test_a_fallback_names_no_body():
+    """A shape the kernels do not serve counts as the composite, as it
+    did, and as neither body."""
+    from paddle_tpu.ops import kernel_paths
+    q, k, v = make_qkv(b=1, s=100, h=2)
+    kernel_paths.reset()
+    fa.flash_attention(q, k, v, causal=True)
+    assert kernel_paths.counts() == \
+        {"flash_attention": {"kernel": 0, "composite": 1}}
+    assert kernel_paths.last_reason("flash_attention") == \
+        "shape not served by the kernel"
+
+
 def test_bf16_inputs():
     q, k, v = make_qkv(b=1, s=256, h=2)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))

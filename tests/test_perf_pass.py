@@ -143,21 +143,22 @@ def test_pick_vocab_block():
 # flash-attention block-size autotuner
 # ---------------------------------------------------------------------------
 def test_autotune_table_exact_hit():
-    assert get_block_sizes(2048, 64, True, device_kind="v5e") == (512, 1024)
+    assert get_block_sizes(2048, 64, True, device_kind="v5e") == (512, 512)
     # device_kind strings come from jax verbatim; aliases normalize
-    assert get_block_sizes(2048, 64, True, device_kind="TPU v5 lite") \
-        == (512, 1024)
+    assert get_block_sizes(8192, 128, True, device_kind="TPU v5 lite") \
+        == (1024, 1024)
 
 
 def test_autotune_nearest_seq_fallback():
-    # 16384 is not tabled for (v5e, d64, causal): nearest tabled seq
-    # (8192) supplies the tiles, clamped to divide the actual seq
-    assert get_block_sizes(16384, 64, True, device_kind="v5e") \
+    # 6144 is not tabled for (v5e, d128, causal): the nearest tabled seq
+    # (8192 is 2048 away, 1024 is 5120) supplies the tiles, clamped to
+    # divide the actual seq
+    assert get_block_sizes(6144, 128, True, device_kind="v5e") \
         == (1024, 1024)
 
 
 def test_autotune_unknown_kind_uses_defaults():
-    assert get_block_sizes(2048, 64, True, device_kind="gpu-h100") \
+    assert get_block_sizes(8192, 128, True, device_kind="gpu-h100") \
         == (512, 512)
 
 
@@ -168,15 +169,15 @@ def test_autotune_clamps_to_short_seq():
 
 def test_autotune_env_kill_switch(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", "0")
-    assert get_block_sizes(2048, 64, True, device_kind="v5e") == (512, 512)
+    assert get_block_sizes(8192, 128, True, device_kind="v5e") == (512, 512)
 
 
 def test_autotune_sweep_mode_foreign_kind_uses_table(monkeypatch):
     # sweep only tunes the local device; asking for another kind must
     # fall through to the table, not run (and rerun) a local sweep
     monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", "sweep")
-    assert get_block_sizes(2048, 64, True, device_kind="v5e") \
-        == (512, 1024)
+    assert get_block_sizes(8192, 128, True, device_kind="v5e") \
+        == (1024, 1024)
 
 
 @pytest.mark.slow
