@@ -134,6 +134,19 @@ def default_prefill_buckets(max_seq_len: int, lo: int = 16) -> List[int]:
     return [b for b in bks if b <= max_seq_len] or [max_seq_len]
 
 
+class _NotedLater:
+    """Stands in for the ``tick`` span of a decode tick launched ahead
+    of its own step() call: what _note_active would note is kept for the
+    span of the call that reads the tick."""
+
+    def __init__(self):
+        self.args = {}
+        self.occupancy = 0.0    # counted with the tick, by that call
+
+    def note(self, **more):
+        self.args.update(more)
+
+
 class Request:
     """One in-flight generation request (host-side bookkeeping)."""
 
@@ -266,6 +279,25 @@ class InferenceEngine:
             raise ValueError(f"prefill_chunk must be >= 0, got "
                              f"{self.prefill_chunk}")
         self._chunked = self.prefill_chunk > 0
+        from .spec_decode import SpecDecoder, resolve_spec_k
+        # False for a model that serves from a per-slot recurrent state
+        self._cache_has_rows = bool(getattr(model, "cache_has_rows", True))
+        if not self._cache_has_rows:
+            # a recurrent state is valid at one position: nothing to
+            # page, to share by prefix, to roll back or to extend a
+            # window over, no rows to quantize, no KV heads to shard
+            asked = {"kv_layout='paged'": self.kv_layout == "paged",
+                     "prefix_cache": bool(prefix_cache),
+                     "spec_k": resolve_spec_k(spec_k) > 0,
+                     "prefill_chunk": self._chunked,
+                     "kv_dtype": self.kv_dtype is not None,
+                     "mesh": mesh is not None}
+            for option, on in asked.items():
+                if on:
+                    raise ValueError(
+                        f"{type(model).__name__} serves from a per-slot "
+                        f"recurrent state with no rows of keys and "
+                        f"values: {option} is not supported for it")
 
         # persistent compile cache: a restarted server deserializes its
         # prefill/decode executables instead of recompiling them
@@ -345,7 +377,6 @@ class InferenceEngine:
         # non-speculative rollout); temperature>0 slots run the full
         # rejection-sampling residual (ISSUE 18 satellite), so sampled
         # traffic rides the spec path too.
-        from .spec_decode import SpecDecoder, resolve_spec_k
         sk = resolve_spec_k(spec_k)
         self._spec = None
         if sk > 0:
@@ -426,6 +457,9 @@ class InferenceEngine:
         self._draining = False
         self._guard = None
         self._guard_timeout: Optional[float] = None
+        # the decode tick launched one step ahead of its read, if any
+        # (see _tick): what _launch_decode returned for it
+        self._ahead = None
         self.undelivered: List[Request] = []
         self._first_call_keys: set = set()
         # executable key -> {kernel entry point: {"kernel": n,
@@ -499,6 +533,12 @@ class InferenceEngine:
         self._m_active = _metrics.gauge(
             "serve_active_slots", "occupied decode slots",
             labels=("engine",)).labels(**lbl)
+        if not self._cache_has_rows:
+            _metrics.gauge(
+                "serve_recurrent_state_bytes",
+                "per-slot recurrent state held, as laid out",
+                labels=("engine",)).labels(**lbl).set(
+                    _exec_registry.tree_bytes(self.cache))
         # flight recorder + stall watchdog (observability): crash hooks
         # once per process; the watchdog thread appears on the first
         # tick only when PADDLE_TPU_WATCHDOG_S arms it, and an engine
@@ -1735,12 +1775,29 @@ class InferenceEngine:
         """step()'s body inside its ``tick`` span; the four children
         ``tick/admit``, ``tick/launch``, ``tick/read`` and
         ``tick/commit`` tile it.  `n` numbers the decode tick that this
-        call launches, if it launches one."""
+        call commits, if it commits one.
+
+        The chip never waits for the host where the host can tell what
+        the next tick's inputs are WITHOUT reading this one's tokens:
+        when no active request can end at this tick (none has an EOS,
+        none reaches its budget or the cache's end) and this call
+        admitted none, tick n + 1 is launched on the device's own
+        tokens BEFORE tick n is read, and the next call finds it in
+        flight.  Every tick still gets the inputs the serial order
+        gives it, so the tokens are the same; a tick that may end a
+        request, and the admission that follows, run serially as
+        before."""
         tick_wall0 = time.perf_counter()
         with _spans.span("tick/admit", "serve", tick=n):
             produced = self._admit_queued()
-        with _spans.span("tick/launch", "serve", tick=n):
-            launched = self._launch_decode(tick)
+        launched, self._ahead = self._ahead, None
+        if launched is not None:
+            launched, later = launched
+            tick.note(**later.args)   # on the span of the call that reads it
+            self._timings["occupancy_sum"] += later.occupancy
+        else:
+            with _spans.span("tick/launch", "serve", tick=n):
+                launched = self._launch_decode(tick)
         if launched is None:
             self._watchdog_idle_if_empty()
             return produced
@@ -1748,7 +1805,12 @@ class InferenceEngine:
             produced += self._commit_spec(n, *launched)
             self._watchdog_idle_if_empty()
             return produced
-        n_active, sampled, nxt, moe = launched
+        n_active, sampled, nxt, moe, bound = launched
+        if not produced and self._may_run_ahead(bound):
+            with _spans.span("tick/launch", "serve", tick=n + 1):
+                later = _NotedLater()
+                self._ahead = (self._launch_decode(later, after=launched),
+                               later)
         # the ONE host sync of the decode step: the scheduler needs the
         # sampled ids for EOS retirement and admission (the expert-load
         # fold, when present, is a sibling output of the same executable
@@ -1766,10 +1828,12 @@ class InferenceEngine:
             self._m_ticks.inc()
             self._m_tokens.inc(n_active)
             commit_now = time.perf_counter()
-            for slot, req in enumerate(self._slots):
-                # prefilling rows were inactive this step: their sampled
-                # token and cache write are masked garbage, not a commit
-                if req is None or req.prefilling:
+            for slot, req in bound:
+                # the slots that were active when the tick was launched
+                # (a prefilling row's sampled token and cache write are
+                # masked garbage, not a commit); one retired since by a
+                # deadline or a drain has its tokens already
+                if req.done or self._slots[slot] is not req:
                     continue
                 tok = int(nxt_np[slot])
                 self._slot_len[slot] += 1    # the token we just appended
@@ -1791,6 +1855,26 @@ class InferenceEngine:
             _faults.maybe_hang(self._timings["decode_steps"])
             self._watchdog_idle_if_empty()
         return produced
+
+    def _may_run_ahead(self, bound) -> bool:
+        """Whether the tick after the one in flight may be launched
+        before that one is read: its inputs must not depend on the
+        tokens.  `bound` is the in-flight tick's (slot, request) pairs;
+        none of them may end at it, and they must still be all that is
+        active.  Dense single-device decoding only: the paged tick makes
+        room from the host's lengths, the speculative one commits a
+        count the host must read, a mesh commits its operands."""
+        if (self.kv_layout != "dense" or self._chunked
+                or self._spec is not None or self.mesh is not None
+                or not self._admitting):
+            return False
+        for slot, req in bound:
+            if (req.done or self._slots[slot] is not req
+                    or req.eos_id >= 0
+                    or len(req.generated) + 1 >= req.max_new_tokens
+                    or self._slot_len[slot] + 2 >= self.max_seq_len):
+                return False
+        return len(bound) == int(self._active_mask().sum())
 
     def _admit_queued(self) -> int:
         """``tick/admit``: expire, admit queued requests into free slots
@@ -1848,15 +1932,20 @@ class InferenceEngine:
             [1 if (r is not None and not r.prefilling) else 0
              for r in self._slots], np.int32)
 
-    def _launch_decode(self, tick):
+    def _launch_decode(self, tick, after=None):
         """``tick/launch``: the active mask, the uploads and the dispatch
         of the decode (or speculative) step.  None when no slot is
-        active; else what the read and the commit need."""
+        active; else what the read and the commit need.  `after` is a
+        launched tick not yet read (_tick): this one then takes the
+        device's own tokens and counts the slots one token longer."""
         active_np = self._active_mask()
         if not active_np.any():
             return None
         if self._spec is not None:
             return self._launch_spec(tick)
+        ahead = after is not None
+        sampled_in_flight, tokens = (after[1], after[2]) if ahead \
+            else (0, None)
         if self.kv_layout == "paged":
             self._ensure_decode_room()
             # a preemption/memory-capped retirement may have emptied
@@ -1869,8 +1958,9 @@ class InferenceEngine:
                 self._alloc.num_in_use / self._alloc.capacity
         sampled = int((self._temps > 0).any())
         n_active = self._note_active(
-            tick, active_np, 1,
-            sampled_ticks=self._timings["sampled_ticks"] + sampled)
+            tick, active_np, 1, ahead=ahead,
+            sampled_ticks=self._timings["sampled_ticks"] + sampled
+            + sampled_in_flight)
         if self.kv_layout == "paged":
             nxt, self._key, cache, moe = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_paged_jit,
@@ -1884,22 +1974,33 @@ class InferenceEngine:
             nxt, self._key, cache, moe = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_jit,
                 self.params, self.cache,
-                jnp.asarray(self._next_token),
+                tokens if ahead else jnp.asarray(self._next_token),
                 jnp.asarray(active_np), self._key,
                 jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         self.cache = cache
-        return n_active, sampled, nxt, moe
+        bound = [(slot, self._slots[slot])
+                 for slot in np.flatnonzero(active_np)]
+        return n_active, sampled, nxt, moe, bound
 
-    def _note_active(self, tick, active_np, window: int, **more) -> int:
+    def _note_active(self, tick, active_np, window: int,
+                     ahead: bool = False, **more) -> int:
         """Occupancy counters of the tick about to launch, and on its
-        ``tick`` span the active slots and ``kv_positions``: the cache
-        positions its attention has to read, the active slots' lengths
-        with the `window` new tokens (host arithmetic, no sync)."""
-        self._timings["occupancy_sum"] += float(active_np.mean())
+        ``tick`` span the active slots and what the cache says the tick
+        has to read (``tick_reads``: ``kv_positions``, the cache
+        positions its attention reads, the active slots' lengths with
+        the `window` new tokens; a recurrent state's ``state_bytes``).
+        Host arithmetic, no sync."""
+        if ahead:
+            tick.occupancy = float(active_np.mean())
+        else:
+            self._timings["occupancy_sum"] += float(active_np.mean())
         n_active = int(active_np.sum())
         self._m_active.set(n_active)
-        tick.note(active=n_active, kv_positions=int(
-            np.dot(active_np, self._slot_len)) + window * n_active, **more)
+        # a tick launched ahead finds every active slot one token longer
+        lens = self._slot_len + active_np if ahead else self._slot_len
+        tick.note(active=n_active,
+                  **self.cache.tick_reads(active_np, lens, window),
+                  **more)
         return n_active
 
     def _launch_spec(self, tick):
@@ -2164,6 +2265,15 @@ class InferenceEngine:
             jnp.zeros(self.batch_slots, jnp.int32),
             jnp.zeros(self.batch_slots, jnp.int32), self._key,
             jnp.asarray(self._temps), jnp.asarray(self._top_ps))
+        if self.mesh is None:
+            # and once on its own tokens, as a tick launched ahead takes
+            # them (_tick): jit's fast path keys on the operand's kind.
+            # The key chain stays where the first call left it.
+            _, _, cache, _ = self._timed_exec(
+                "decode_ms", ("decode", 0), self._decode_jit,
+                self.params, cache, nxt,
+                jnp.zeros(self.batch_slots, jnp.int32), self._key,
+                jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         # drop the warmup garbage: zero every slot's length (host-side
         # constant, so no extra executable rides the hot path).  On a
         # serving mesh the zeros are COMMITTED like the originals —
@@ -2342,20 +2452,11 @@ class InferenceEngine:
         if ep > 1 and self.model.cfg.moe_num_experts % ep == 0:
             ebytes //= ep
         pbytes += ebytes
-        cfg = self.model.cfg
-        # KV heads split over tp only when they divide evenly (the
-        # sharding helpers replicate otherwise — mirror that here)
-        hkv = cfg.num_kv_heads // tp if cfg.num_kv_heads % tp == 0 \
-            else cfg.num_kv_heads
-        kv_item = jnp.dtype(self.cache.dtype).itemsize
         if self.kv_layout == "paged":
             per_slot_pos = self.blocks_per_slot * self.block_size
         else:
             per_slot_pos = self.max_seq_len
-        kv = (2 * cfg.num_layers * per_slot_pos * hkv *
-              cfg.head_dim * kv_item)
-        if self.cache.quantized:
-            kv += 2 * cfg.num_layers * per_slot_pos * hkv * 4
+        kv = self.cache.step_bytes_per_slot(per_slot_pos, tp)
         return int(pbytes / self.batch_slots + kv)
 
     @property

@@ -49,7 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import KVLayerView
+from ..models.gpt import KVLayerView, kv_step_bytes, kv_tick_reads
 from ..observability import metrics as _metrics
 
 __all__ = ["PagedKVCache", "BlockAllocator", "init_paged_cache",
@@ -159,6 +159,13 @@ class PagedKVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    tick_reads = staticmethod(kv_tick_reads)
+
+    def step_bytes_per_slot(self, positions: int, tp: int = 1) -> int:
+        return kv_step_bytes(self.num_layers, self.k.shape[2],
+                             self.k.shape[4], self.dtype, self.quantized,
+                             positions, tp)
 
     def layer(self, i, tables) -> PagedKVLayer:
         """Layer ``i``'s pools behind the slots' block tables, as a

@@ -7,3 +7,7 @@ from .nemotron_h import (NemotronHConfig, NemotronHModel,  # noqa: F401
                          NemotronHForCausalLM)
 from .kimi_linear import (KimiLinearConfig, KimiLinearModel,  # noqa: F401
                           KimiLinearForCausalLM)
+from .recurrent_cache import (RecurrentStateCache,  # noqa: F401
+                              RetentionLayerView)
+from .brumby import (BrumbyConfig, BrumbyModel,  # noqa: F401
+                     BrumbyForCausalLM)

@@ -59,6 +59,27 @@ def window_positions(lengths, w: int):
     return lengths[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
 
 
+def kv_tick_reads(active, slot_len, window: int) -> dict:
+    """The ``tick`` span's arguments for a cache of rows:
+    ``kv_positions``, the cached positions the tick's attention has to
+    read: the active slots' lengths with the `window` new tokens."""
+    return {"kv_positions": int(np.dot(active, slot_len)) +
+            window * int(np.sum(active))}
+
+
+def kv_step_bytes(layers: int, kv_heads: int, head_dim: int, dtype,
+                  quantized: bool, positions: int, tp: int = 1) -> int:
+    """Bytes of k and v a decode step streams for one slot holding
+    `positions` rows: 8-bit codes count with their f32 scale planes.  KV
+    heads split over ``tp`` only when they divide evenly (the sharding
+    helpers replicate otherwise)."""
+    hkv = kv_heads // tp if kv_heads % tp == 0 else kv_heads
+    kv = 2 * layers * positions * hkv * head_dim * jnp.dtype(dtype).itemsize
+    if quantized:
+        kv += 2 * layers * positions * hkv * 4
+    return kv
+
+
 @dataclass
 class KVLayerView:
     """One layer of a serving KV cache, as a serving step sees it: the
@@ -209,6 +230,13 @@ class StaticKVCache:
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    tick_reads = staticmethod(kv_tick_reads)
+
+    def step_bytes_per_slot(self, positions: int, tp: int = 1) -> int:
+        return kv_step_bytes(self.num_layers, self.kv_heads,
+                             self.k[0].shape[3], self.dtype, self.quantized,
+                             positions, tp)
 
     def with_lengths(self, lengths) -> "StaticKVCache":
         """The same buffers under new per-slot lengths."""

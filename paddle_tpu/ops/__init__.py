@@ -6,7 +6,9 @@ op set (paddle_tpu.tensor / nn.functional lowerings), and this package
 holds only the kernels XLA won't produce on its own: fused attention
 (flash, decode, paged), the grouped product over held experts, the KDA
 scan's chunk kept in VMEM (``kda_scan`` dispatches to
-``kda_chunk_kernel`` on the chip), the blocked cross-entropy and the
+``kda_chunk_kernel`` on the chip), power retention's decode step over a
+state updated where it lies (``power_retention`` dispatches to
+``power_retention_kernel``), the blocked cross-entropy and the
 quantized products.  ``ssd_scan`` is XLA's chunked form alone (a kernel
 lost to it).  Every entry point with two paths records the one it traced
 in ``kernel_paths``.
@@ -27,6 +29,8 @@ from .grouped_matmul import (  # noqa: F401
     grouped_matmul, grouped_matmul_available)
 from .ssd_scan import causal_conv1d, ssd_scan  # noqa: F401
 from .kda_scan import kda_scan  # noqa: F401
+from .power_retention import (  # noqa: F401
+    power_retention_chunked, power_retention_step)
 from .quantized_matmul import (  # noqa: F401
     quantized_matmul, quantized_matmul_available, fake_quant_matmul,
     quantize_channel, quantize_kv, dequantize_kv, get_qmm_tiles)

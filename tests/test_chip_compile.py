@@ -333,6 +333,37 @@ def test_decode_step_sorts_only_inside_a_branch(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# power retention's decode step: one pass over a state updated in place
+# ---------------------------------------------------------------------------
+def test_retention_step_updates_its_state_where_it_lies(one_chip):
+    """The decode kernel at Brumby's published widths (40 query heads on
+    8 KV heads of 128) over 4 slots, the state donated: one custom call,
+    both halves of the state come out in the memory they went in by, and
+    no temporary the size of a slot's state stands beside them."""
+    pk = importlib.import_module("paddle_tpu.ops.power_retention_kernel")
+    pr = importlib.import_module("paddle_tpu.ops.power_retention")
+    slots, heads, kv_heads, d = 4, 40, 8, 128
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    state = pr.RetentionState(f32(slots, kv_heads, pr.state_rows(d), d),
+                              f32(slots, kv_heads, d, d))
+    assert pk.serves(f32(slots, heads, d), f32(slots, kv_heads, d),
+                     f32(slots, kv_heads, d), state)
+    with persistent_cache_off():
+        compiled = jax.jit(
+            lambda q, k, v, g, st: pk.step(q, k, v, g, st, 1e-6),
+            donate_argnums=(4,)).lower(
+                f32(slots, heads, d), f32(slots, kv_heads, d),
+                f32(slots, kv_heads, d), f32(slots, kv_heads),
+                state).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    held = (state.s.size + state.z.size) * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    assert memory.temp_size_in_bytes < held // slots
+
+
+# ---------------------------------------------------------------------------
 # the linear-attention / latent-attention stack's two mixers
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("kind,kernels", [("kda", 2), ("mla", 2)])

@@ -579,3 +579,109 @@ def test_serve_bench_long_sequence():
     assert sorted(res) == sorted(rids)
     assert all(len(res[r]) == 40 for r in rids)
     assert eng.stats["slot_occupancy"] > 0.5
+
+
+# -------- the tick launched ahead of its read (engine._tick) --------
+def _serve_churn(engine, temperature, eos_id=None):
+    """Eight requests of uneven length through three slots; the tokens
+    by request in the order sent, and how many calls left a tick in
+    flight."""
+    rng = np.random.RandomState(1)
+    rids = [engine.add_request(rng.randint(1, 97, rng.randint(2, 15)),
+                               max_new_tokens=n, eos_id=eos_id,
+                               temperature=temperature, deadline_s=600.0)
+            for n in (5, 9, 3, 7, 12, 4, 6, 40)]
+    ahead = 0
+    while engine.has_work:
+        engine.step_or_raise()
+        ahead += engine._ahead is not None
+        # a tick in flight is counted when it is read, with its step
+        assert engine._timings["occupancy_sum"] <= \
+            engine._timings["decode_steps"]
+    return [engine.results[r].tolist() for r in rids], ahead
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_tick_ahead_serves_the_serial_orders_tokens(model, temperature):
+    """Where no request can end at the tick in flight the next one is
+    launched before that one is read; every tick still gets the inputs
+    the serial order gives it, so the tokens are the same, sampled ones
+    too (one key chain), and nothing compiles or traces after warm-up."""
+    def engine():
+        return InferenceEngine(model, batch_slots=3, seed=3,
+                               prefill_buckets=[8, 16]).warmup([8, 16])
+    serial = engine()
+    serial._may_run_ahead = lambda bound: False
+    want, none = _serve_churn(serial, temperature)
+    eng = engine()
+    snap = compile_counter.snapshot()
+    got, ahead = _serve_churn(eng, temperature)
+    assert none == 0 and ahead >= 30
+    assert got == want
+    assert (snap.new_compiles, snap.new_traces) == (0, 0)
+    assert eng.stats["decode_steps"] == serial.stats["decode_steps"]
+    assert eng.stats["tokens_generated"] == serial.stats["tokens_generated"]
+    assert eng._timings["occupancy_sum"] == serial._timings["occupancy_sum"]
+
+
+def test_tick_ahead_waits_where_a_token_could_end_a_request(model):
+    """An EOS makes the next tick's inputs depend on this one's tokens:
+    such a request is never run ahead of; nor is a tick that may be a
+    request's last, nor a call that admitted."""
+    eng = InferenceEngine(model, batch_slots=2, prefill_buckets=[16])
+    _, ahead = _serve_churn(eng, 0.0, eos_id=96)
+    assert ahead == 0
+    rid = eng.add_request(np.arange(1, 6), max_new_tokens=4)
+    flights = []
+    while eng.has_work:
+        eng.step_or_raise()
+        flights.append(eng._ahead is not None)
+    # prefill + tick 1 (the call admitted), tick 2 with tick 3 behind
+    # it, tick 3: the last, nothing behind it
+    assert flights == [False, True, False]
+    assert len(eng.results[rid]) == 4
+
+
+def test_tick_in_flight_is_dropped_for_a_request_retired_meanwhile(model):
+    """A request retired between the launch and the read (a deadline, a
+    forced drain) has its tokens already; the tick in flight gives it
+    none, and none to the slot's next occupant."""
+    eng = InferenceEngine(model, batch_slots=1, prefill_buckets=[8])
+    first = eng.add_request(np.arange(1, 6), max_new_tokens=30)
+    for _ in range(3):
+        eng.step_or_raise()
+    assert eng._ahead is not None
+    req = eng._slots[0]
+    had = len(req.generated)
+    req.timed_out = True
+    eng._retire(req)
+    second = eng.add_request(np.arange(7, 12), max_new_tokens=5)
+    eng.run()
+    assert len(eng.results[first]) == had
+    fresh = InferenceEngine(model, batch_slots=1, prefill_buckets=[8])
+    alone = fresh.add_request(np.arange(7, 12), max_new_tokens=5)
+    np.testing.assert_array_equal(eng.results[second],
+                                  fresh.run()[alone])
+
+
+def test_every_read_tick_is_noted_on_one_tick_span(model):
+    """What the benchmark's readers count as launched ticks: a tick
+    launched ahead is noted on the span of the call that reads it, one
+    tick a span, with the positions it read."""
+    from paddle_tpu import observability as obs
+    eng = InferenceEngine(model, batch_slots=2, prefill_buckets=[8])
+    tr = obs.tracer()
+    tr.clear()
+    tr.start()
+    try:
+        eng.add_request(np.arange(1, 6), max_new_tokens=6)
+        eng.run()
+        noted = [e["args"] for e in tr.chrome_trace()["traceEvents"]
+                 if e["name"] == "tick" and "kv_positions" in e["args"]]
+    finally:
+        tr.stop()
+        tr.clear()
+    # five ticks after the prefill's token; tick i attends 5 + i positions
+    assert [a["kv_positions"] for a in noted] == [6, 7, 8, 9, 10]
+    assert eng.stats["decode_steps"] == 5
