@@ -97,6 +97,7 @@ from ..observability import metrics as _metrics
 from ..observability import spans as _spans
 from ..observability import watchdog as _watchdog
 from ..ops import kernel_paths as _kernel_paths
+from ..ops.decode_attention import positions_streamed as _positions_streamed
 from ..utils import compile_cache, compile_counter
 from .paged_kv import (BlockAllocator, blocks_for, blocks_to_extend,
                        init_paged_cache)
@@ -1988,8 +1989,9 @@ class InferenceEngine:
         ``tick`` span the active slots and what the cache says the tick
         has to read (``tick_reads``: ``kv_positions``, the cache
         positions its attention reads, the active slots' lengths with
-        the `window` new tokens; a recurrent state's ``state_bytes``).
-        Host arithmetic, no sync."""
+        the `window` new tokens; a recurrent state's ``state_bytes``),
+        and for a cache of rows ``kv_positions_read``, what the tick's
+        kernel streams.  Host arithmetic, no sync."""
         if ahead:
             tick.occupancy = float(active_np.mean())
         else:
@@ -1998,10 +2000,26 @@ class InferenceEngine:
         self._m_active.set(n_active)
         # a tick launched ahead finds every active slot one token longer
         lens = self._slot_len + active_np if ahead else self._slot_len
-        tick.note(active=n_active,
-                  **self.cache.tick_reads(active_np, lens, window),
-                  **more)
+        reads = self.cache.tick_reads(active_np, lens, window)
+        if self._cache_has_rows:
+            reads["kv_positions_read"] = self._kv_positions_read(
+                active_np, lens, window)
+        tick.note(active=n_active, **reads, **more)
         return n_active
+
+    def _kv_positions_read(self, active_np, lens, window: int) -> int:
+        """Cache positions the tick's attention STREAMS, beside the
+        ``kv_positions`` it has to read.  Where the decode executable
+        traced the dense kernel's length-bounded body
+        (``kernel_paths``), the active slots' lengths rounded up as the
+        op rounds them (a retired slot's stale in-graph length is not
+        the host's to know: what the kernel reads of it is left out);
+        on every other path every slot to its capacity."""
+        if window == 1 and "decode_attention.bounded" in \
+                self.kernel_paths.get(("decode", 0), ()):
+            return int(np.dot(active_np, _positions_streamed(
+                lens + window, self.max_seq_len)))
+        return self.batch_slots * self.max_seq_len
 
     def _launch_spec(self, tick):
         """The launch of one speculative tick for every active slot:

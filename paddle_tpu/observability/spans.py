@@ -18,8 +18,9 @@ metric that reads each):
 - ``train_step`` (``step``) > ``train_step/h2d``, ``train_step/launch``;
   ``train_step/read`` (``step``) is the host's read of the loss and may
   close after its parent.
-- ``tick`` (``tick``, ``active``, ``kv_positions``; ``state_bytes`` where the
-  cache holds a recurrent state) > ``tick/admit``
+- ``tick`` (``tick``, ``active``, ``kv_positions`` and, beside what the
+  tick has to read, ``kv_positions_read``: what its kernel streams;
+  ``state_bytes`` where the cache holds a recurrent state) > ``tick/admit``
   (> ``prefill`` with ``bucket``, ``prompt_tokens``), ``tick/launch``,
   ``tick/read``, ``tick/commit``.
 

@@ -5,22 +5,38 @@ step and attends it against a preallocated, fixed-capacity cache
 layer ``[batch_slots, kv_heads, max_seq, head_dim]`` whose per-slot
 occupancy is a ``lengths`` vector.  The layer is HEAD-MAJOR, like the
 paged pool: each (slot, kv head)'s ``[max_seq, head_dim]`` strip is the
-layer's two minor dimensions, so the kernels' ``[B·Hkv, S, D]`` view is
-a reshape of the buffer as it lies in HBM (no transpose, no copy) and
-the composites' einsums read it as it is.  Decode attention is memory-bound — the whole
-cost is streaming the KV cache through the chip once — so the fusion
-target is different from training flash attention: there is no softmax
-tiling problem (one query row), the win is reading each K/V block from
-HBM exactly once and never materializing the [B, H, S] score matrix or
-a repeat_interleaved K/V for GQA.
+layer's two minor dimensions, so the kernels take blocks of the buffer
+as it lies in HBM (no transpose, no copy) and the composites' einsums
+read it as it is.  Decode attention is memory-bound — the whole cost is
+streaming what the slots HOLD of the KV cache through the chip once —
+so the fusion target is different from training flash attention: there
+is no softmax tiling problem (one query row), the win is reading each
+K/V block from HBM exactly once and never materializing the [B, H, S]
+score matrix or a repeat_interleaved K/V for GQA.
 
-Kernel shape: grid ``(B·Hkv,)``; each program holds the slot's query
-group ``[G, D]`` (G = H/Hkv query heads sharing one KV head) in VMEM and
-streams the slot's ``[S, D]`` K/V strips block by block with a running
-online-softmax max/denominator, masking key positions ``>= lengths[b]``.
-Like ``flash_attention.py`` the mask rides in as an f32 ``[B, 1, S]``
-strip (1 = valid) — trivially cheap next to the cache itself and it
-keeps the kernel free of SMEM scalar plumbing.
+Kernel shape (the single-token kernel, ``_decode_gqa``): ``lengths``
+rides in as a scalar-prefetch operand and the grid is ``(slot, kv-head
+group, key-block step)``.  A step holds the query groups ``[hb, G, D]``
+of ``hb`` kv heads of one slot and ONE ``[hb, block_k, D]`` block of
+their k and of their v; the index map names the slot's blocks
+``0 .. (lengths[b]-1) // block_k`` on the LAST steps of the axis and
+clamps before them, so no block past a slot's length ever leaves HBM
+(an unchanged block index is not fetched again) and the body skips the
+steps that have none.  Validity inside the block the length crosses is
+``iota < lengths[b]``; the running max, denominator and accumulator
+live in VMEM scratch across the steps.  No host value enters a shape:
+one executable serves every ``lengths``.
+Sizing (PERF.md section 6, PR 42): a step costs about 0.35 us whatever
+it moves and a (slot, head) block of 512 keys is 0.3 us of copying, so
+one head a step buys nothing; a step takes as many of a slot's kv heads
+as keep its four blocks in flight inside 8 MiB (all 16 at 16 heads of
+128: a 4 MiB step, 24 x 4 steps a layer).  A slot's blocks run last so
+that the pipeline, which fetches one step ahead, copies the NEXT slot's
+first block under this slot's last products.
+An int8 cache goes through the same body (``quantized``).  The window
+kernels still run a grid ``(B·Hkv,)`` over whole ``[S, D]`` strips with
+an f32 ``[B, W, S]`` mask strip, and read every slot to its capacity
+(ROADMAP D12).
 
 The XLA composite (`_decode_composite`) is the CPU/fallback path and the
 ground truth for the kernel tests; both use f32 score accumulation.
@@ -152,141 +168,198 @@ def write_kv(buf, idx, new):
         unique_indices=not window)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, *, block_k: int,
-                   scale: float):
-    """One (b·hkv) program: q_ref [G, D] query group; k/v [S, D] cache
-    strips; m_ref (1, S) f32 validity; o_ref [G, D]."""
-    g, d = q_ref.shape
-    s = k_ref.shape[0]
-    n_k = s // block_k
-
-    # storage-dtype (bf16) MXU inputs, f32 accumulation — the same mixed
-    # scheme as the training flash kernel
-    q = q_ref[:]
-
-    m0 = jnp.full((g, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc0 = jnp.zeros((g, d), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        sblk = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [g, bk] f32
-        kv_f = m_ref[0, pl.ds(j * block_k, block_k)]        # (bk,) f32
-        sblk = jnp.where(kv_f[None, :] > 0, sblk, _NEG)
-        m_new = jnp.maximum(m, jnp.max(sblk, axis=1, keepdims=True))
-        p = jnp.exp(sblk - m_new)
-        p = jnp.where(sblk <= _NEG / 2, 0.0, p)  # fully-masked blocks
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
-    m, l, acc = jax.lax.fori_loop(0, n_k, body, (m0, l0, acc0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+def _key_blocks(lengths, block_k: int):
+    """How many blocks of ``block_k`` positions hold a slot's first
+    ``lengths`` positions (scalars, numpy or jax arrays alike).  The one
+    place the length bound is written: the dense kernel's index maps, its
+    body and ``positions_streamed`` all read it."""
+    return (lengths + (block_k - 1)) // block_k
 
 
-def _decode_gqa(q3, k3, v3, mask, block_k=512):
-    """q3 [B·Hkv, G, D]; k3/v3 [B·Hkv, S, D]; mask [B, 1, S] f32."""
-    bhkv, g, d = q3.shape
-    s = k3.shape[1]
-    hkv = bhkv // mask.shape[0]
-    block_k = _fa._pick_block(s, block_k)
-    scale = 1.0 / math.sqrt(d)
-    kernel = functools.partial(_decode_kernel, block_k=block_k,
-                               scale=scale)
-    call = pl.pallas_call(
-        kernel,
-        grid=(bhkv,),
-        in_specs=[
-            pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1, s),
-                         lambda b, hkv=hkv: (b // hkv, 0, 0)),
+# Read on the chip at [24, 16, 2048, 128] bf16 over twelve sampled ticks
+# of chat-shaped lengths (PERF.md section 6, PR 42): blocks of 512 keys
+# with all 16 heads a step 0.210 ms a layer, 8 heads 0.229, 4 heads
+# 0.250; blocks of 256 0.266 (a step's products cost nearly what 512
+# cost, so half the rounding does not pay).  512 is also the block the
+# kernel had before it was bounded, so its sums run in the same order.
+_DECODE_BLOCK_K = 512
+# k and v blocks, each double-buffered by the pipeline
+_DECODE_BLOCKS_VMEM = 8 * 2 ** 20
+
+
+def _decode_block_k(s: int) -> int:
+    """The dense decode kernel's key block: it divides the capacity."""
+    return _fa._pick_block(s, _DECODE_BLOCK_K)
+
+
+def _decode_tiling(hkv: int, s: int, d: int, itemsize: int):
+    """(kv heads a program, key block) of the dense decode kernel for a
+    ``[B, hkv, s, d]`` layer: the key block divides the capacity, and a
+    program takes as many of a slot's kv heads as keep the four blocks in
+    flight (k and v, two buffers each, minor dimension padded to the 128
+    lanes) inside ``_DECODE_BLOCKS_VMEM``."""
+    block_k = _decode_block_k(s)
+    strip = 4 * block_k * (-(-d // 128) * 128) * itemsize
+    heads = max(h for h in range(1, hkv + 1)
+                if hkv % h == 0 and (h * strip <= _DECODE_BLOCKS_VMEM
+                                     or h == 1))
+    return heads, block_k
+
+
+def positions_streamed(lengths, capacity: int):
+    """Cache positions the dense decode kernel streams for slots holding
+    ``lengths`` positions (the new token included) of ``capacity``: the
+    length rounded up to the kernel's key block, and one block for an
+    empty slot (the pipeline fetches the block its index map names
+    whether or not the body runs)."""
+    block_k = _decode_block_k(capacity)
+    return block_k * _key_blocks(lengths, block_k).clip(
+        1, capacity // block_k)
+
+
+def _first_step(length, block_k: int, steps: int):
+    """The step, of a grid axis of ``steps`` key-block steps, at which a
+    slot holding ``length`` positions starts: its blocks run on the LAST
+    steps, in ascending order, and the steps before them skip.  So the
+    step that computes a slot's last block is the one during which the
+    pipeline fetches the next slot's first (it prefetches a step ahead):
+    a copy is in flight under every block's products."""
+    return steps - jnp.clip(_key_blocks(length, block_k), 0, steps)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
+                   scale: float, quantized: bool):
+    """One (slot, kv-head group, key-block step) step.  len_ref [B] int32
+    in scalar memory; q_ref/o_ref [hb, G, D], the query groups of ``hb``
+    kv heads of slot b; k_ref/v_ref [hb, block_k, D], the key block the
+    index map named for this step: block ``max(j - first, 0)`` with
+    ``first`` from ``_first_step``, so nothing past the block that holds
+    position ``lengths[b] - 1`` is ever fetched (a block index that does
+    not change is not fetched again).  The body runs from step ``first``
+    on; the online-softmax state lives in VMEM scratch across the steps
+    and the output is written at the last.  Storage-dtype (bf16) MXU
+    inputs, f32 scores and accumulation — the same mixed scheme as the
+    training flash kernel.  ``quantized``: k/v arrive as int8 with
+    ``[hb, 1, block_k]`` f32 scale strips and are dequantized AFTER
+    leaving HBM, so the blocks stream at half the bytes."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
+    b, j = pl.program_id(0), pl.program_id(2)
+    n = len_ref[b]
+    heads = q_ref.shape[0]
+    first = _first_step(n, block_k, pl.num_programs(2))
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j >= first)
+    def _block():
+        pos = (j - first) * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        valid = pos < n                     # false only in the last block
+        for h in range(heads):
+            q, k_blk, v_blk = q_ref[h], k_ref[h], v_ref[h]
+            if quantized:
+                k_blk = (k_blk.astype(jnp.float32) *
+                         ks_ref[h, 0, :][:, None]).astype(q.dtype)
+                v_blk = (v_blk.astype(jnp.float32) *
+                         vs_ref[h, 0, :][:, None]).astype(q.dtype)
+            sblk = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [G, bk] f32
+            sblk = jnp.where(valid, sblk, _NEG)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
+            p = jnp.exp(sblk - m_new)
+            p = jnp.where(sblk <= _NEG / 2, 0.0, p)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[h, :, :1] * alpha + \
+                jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        # a slot at length 0 ran no block: zeros over the guard
+        o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:, :, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _decode_gqa(q4, k4, v4, lengths, k_scale=None, v_scale=None):
+    """q4 [B, Hkv, G, D]; k4/v4 [B, Hkv, S, D], the layer as it lies
+    (int8 beside its [B, Hkv, S] f32 scale planes when quantized);
+    lengths [B] int32."""
+    heads, block_k = _decode_tiling(k4.shape[1], k4.shape[2], k4.shape[3],
+                                    k4.dtype.itemsize)
+    return _decode_call(lengths.astype(jnp.int32), q4, k4, v4, k_scale,
+                        v_scale, heads=heads, block_k=block_k,
+                        interpret=_interpret())
+
+
+# A jit of its own, so that the layers of a step share ONE trace of the
+# body and one Mosaic module: traced layer by layer, the heads unrolled
+# in the body cost a 24-layer decode step 3 s of set-up (PERF.md section
+# 6, PR 42).  What the trace reads from outside is in the static
+# arguments.
+@functools.partial(jax.jit, static_argnames=("heads", "block_k", "interpret"))
+def _decode_call(lengths, q4, k4, v4, k_scale, v_scale, *, heads: int,
+                 block_k: int, interpret: bool):
+    """The kernel's call: ``lengths`` scalar-prefetched, grid (slot,
+    kv-head group, key-block step)."""
+    pltpu = _fa.pltpu
+    b, hkv, g, d = q4.shape
+    steps = k4.shape[2] // block_k
+
+    def kv_index(i, hg, j, lens):
+        first = _first_step(lens[i], block_k, steps)
+        return (i, hg, jnp.maximum(j - first, 0), 0)
+
+    io_spec = pl.BlockSpec((None, heads, g, d),
+                           lambda i, hg, j, lens: (i, hg, 0, 0))
+    kv_spec = pl.BlockSpec((None, heads, block_k, d), kv_index)
+    in_specs = [io_spec, kv_spec, kv_spec]
+    args = [q4, k4, v4]
+    if k_scale is not None:
+        # a unit axis spliced in: the strip's two minor dimensions are
+        # (1, block_k) however many heads a step takes
+        def scale_index(i, hg, j, lens):
+            slot, group, block, _ = kv_index(i, hg, j, lens)
+            return (slot, group, 0, block)
+
+        sc_spec = pl.BlockSpec((None, heads, 1, block_k), scale_index)
+        in_specs += [sc_spec, sc_spec]
+        args += [k_scale.astype(jnp.float32)[:, :, None, :],
+                 v_scale.astype(jnp.float32)[:, :, None, :]]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, hkv // heads, steps),
+        in_specs=in_specs,
+        out_specs=io_spec,
+        scratch_shapes=[
+            pltpu.VMEM((heads, g, 128), jnp.float32),   # running max
+            pltpu.VMEM((heads, g, 128), jnp.float32),   # running denominator
+            pltpu.VMEM((heads, g, d), jnp.float32),     # output accumulator
         ],
-        out_specs=pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
-        interpret=_interpret(),
     )
-    return _fa.run_kernel(q3.dtype, call, q3, k3, v3, mask)
-
-
-def _decode_kernel_q(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, o_ref,
-                     *, block_k: int, scale: float):
-    """Quantized-cache variant of _decode_kernel: k/v strips arrive in
-    int8 with per-position f32 scale strips ((1, S), like the mask) and
-    are dequantized block-by-block AFTER leaving HBM — the strips
-    stream at half the bytes, which is the whole point of the int8
-    cache on a bandwidth-bound kernel."""
-    g, d = q_ref.shape
-    s = k_ref.shape[0]
-    n_k = s // block_k
-
-    q = q_ref[:]
-    m0 = jnp.full((g, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc0 = jnp.zeros((g, d), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        ks = ks_ref[0, pl.ds(j * block_k, block_k)]         # (bk,) f32
-        vs = vs_ref[0, pl.ds(j * block_k, block_k)]
-        k_blk = (k_ref[pl.ds(j * block_k, block_k), :]
-                 .astype(jnp.float32) * ks[:, None]).astype(q.dtype)
-        v_blk = (v_ref[pl.ds(j * block_k, block_k), :]
-                 .astype(jnp.float32) * vs[:, None]).astype(q.dtype)
-        sblk = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [g, bk] f32
-        kv_f = m_ref[0, pl.ds(j * block_k, block_k)]        # (bk,) f32
-        sblk = jnp.where(kv_f[None, :] > 0, sblk, _NEG)
-        m_new = jnp.maximum(m, jnp.max(sblk, axis=1, keepdims=True))
-        p = jnp.exp(sblk - m_new)
-        p = jnp.where(sblk <= _NEG / 2, 0.0, p)  # fully-masked blocks
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
-    m, l, acc = jax.lax.fori_loop(0, n_k, body, (m0, l0, acc0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def _decode_gqa_q(q3, k3, v3, ks3, vs3, mask, block_k=512):
-    """Quantized wrapper: q3 [B·Hkv, G, D]; k3/v3 [B·Hkv, S, D] int8;
-    ks3/vs3 [B·Hkv, 1, S] f32 scales; mask [B, 1, S] f32."""
-    bhkv, g, d = q3.shape
-    s = k3.shape[1]
-    hkv = bhkv // mask.shape[0]
-    block_k = _fa._pick_block(s, block_k)
-    scale = 1.0 / math.sqrt(d)
-    kernel = functools.partial(_decode_kernel_q, block_k=block_k,
-                               scale=scale)
     call = pl.pallas_call(
-        kernel,
-        grid=(bhkv,),
-        in_specs=[
-            pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, s, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1, s), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1, s), lambda b: (b, 0, 0)),
-            pl.BlockSpec((None, 1, s),
-                         lambda b, hkv=hkv: (b // hkv, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, g, d), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bhkv, g, d), q3.dtype),
-        interpret=_interpret(),
+        functools.partial(_decode_kernel, block_k=block_k,
+                          scale=1.0 / math.sqrt(d),
+                          quantized=k_scale is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
     )
-    return _fa.run_kernel(q3.dtype, call, q3, k3, v3, ks3, vs3, mask)
+    return _fa.run_kernel(q4.dtype, call, lengths, *args)
 
 
 def _dequant_cache(cache, scale, dtype):
@@ -363,24 +436,15 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
 def _decode_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
                         v_scale=None):
     """The dense kernel dispatch AFTER the support gate — also the
-    shard_map body under tp (per-shard head ranges, same code).  The
-    head-major layer reshapes to the kernel's [B·Hkv, S, D] strips in
-    place: no whole-layer copy stands between the cache and the
-    kernel."""
+    shard_map body under tp (per-shard head ranges, same code;
+    ``lengths`` replicated).  The kernel takes the head-major layer as
+    it lies: no whole-layer copy, and no mask, stands between the cache
+    and the kernel."""
     b, h, d = q.shape
-    hkv, s = k_cache.shape[1], k_cache.shape[2]
-    mask = (jnp.arange(s)[None, :] <
-            lengths.astype(jnp.int32)[:, None]).astype(jnp.float32)
-    q3 = q.reshape(b, hkv, h // hkv, d).reshape(b * hkv, h // hkv, d)
-    k3 = k_cache.reshape(b * hkv, s, d)
-    v3 = v_cache.reshape(b * hkv, s, d)
-    if k_scale is not None:
-        ks3 = k_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
-        vs3 = v_scale.astype(jnp.float32).reshape(b * hkv, 1, s)
-        o3 = _decode_gqa_q(q3, k3, v3, ks3, vs3, mask.reshape(b, 1, s))
-    else:
-        o3 = _decode_gqa(q3, k3, v3, mask.reshape(b, 1, s))
-    return o3.reshape(b, hkv, h // hkv, d).reshape(b, h, d)
+    hkv = k_cache.shape[1]
+    kernel_paths.note("decode_attention.bounded", "kernel")
+    return _decode_gqa(q.reshape(b, hkv, h // hkv, d), k_cache, v_cache,
+                       lengths, k_scale, v_scale).reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------

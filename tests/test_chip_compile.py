@@ -115,6 +115,27 @@ def test_dense_decode(compile_for_chip, quantized):
     assert_kernel(compile_for_chip(da._decode_kernel_path, *specs))
 
 
+@pytest.mark.parametrize("slots,heads,kv_heads,d", [
+    (24, 16, 16, 128), (SLOTS, 16, 4, 128), (SLOTS, 12, 12, 64)],
+    ids=["closed_cell", "gqa_4", "heads_of_64"])
+def test_dense_decode_bounded_by_lengths(compile_for_chip, slots, heads,
+                                         kv_heads, d):
+    """The length-bounded kernel through its entry's dispatch: Mosaic
+    takes ``lengths`` as a scalar-prefetch operand and a block of
+    several kv heads x 512 keys, at the closed cell's shape (24 slots x
+    16 heads of 128 on 16 kv heads, all 16 a program), with a query
+    group of 4 and at heads of 64; nothing the size of a mask strip is
+    built beside it."""
+    cache = ((slots, kv_heads, SEQ, d), bf16)
+    assert da._decode_tiling(kv_heads, SEQ, d, 2) == (kv_heads, 512)
+    text = compile_for_chip(da._decode_kernel_path,
+                            ((slots, heads, d), bf16), cache, cache,
+                            ((slots,), i32))
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[{slots},{SEQ}]" not in text
+    assert f"f32[{slots},1,{SEQ}]" not in text
+
+
 def test_dense_window(compile_for_chip):
     heads, d, w = 16, 128, 8
     cache = ((SLOTS, heads, SEQ, d), bf16)
