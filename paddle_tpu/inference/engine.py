@@ -370,6 +370,7 @@ class InferenceEngine:
         self._prefill_chunk_paged_jit = jax.jit(
             self._prefill_chunk_paged_fn, donate_argnums=dargs)
         self._sample_jit = jax.jit(self._sample_from_logits)
+        self._place_jit = jax.jit(self._place_token_fn)
 
         # speculative decoding (inference.spec_decode): a draft model +
         # K>0 replace the single-token decode step with a propose/verify
@@ -438,6 +439,11 @@ class InferenceEngine:
             "prefill_stall_ms": 0.0,
             "compile_ms_cold": 0.0, "prefills": 0, "prefill_tokens": 0,
             "decode_steps": 0, "tokens_generated": 0,
+            # how often the chip was spared the host (_tick): decode
+            # ticks dispatched while an earlier executable was unread
+            # (ahead of a tick, or behind a prefill), and admissions
+            # whose first token was read after the next tick's dispatch
+            "ticks_launched_unread": 0, "admissions_read_late": 0,
             # decode ticks in which some slot had temperature > 0: the
             # sampler's own predicate (a retired slot's temps is 0)
             "sampled_ticks": 0,
@@ -461,6 +467,9 @@ class InferenceEngine:
         # the decode tick launched one step ahead of its read, if any
         # (see _tick): what _launch_decode returned for it
         self._ahead = None
+        # admissions of this call bound to their slots whose first token
+        # is still on the device: (request, slot, token [1])
+        self._fresh: List[tuple] = []
         self.undelivered: List[Request] = []
         self._first_call_keys: set = set()
         # executable key -> {kernel entry point: {"kernel": n,
@@ -785,6 +794,12 @@ class InferenceEngine:
             return jax.lax.cond(jnp.any(temps > 0), sampling,
                                 lambda: greedy)
 
+    def _place_token_fn(self, tokens, tok, slot):
+        """The tick's token vector with a fresh slot's entry taken from
+        its admission's sampler, device to device (_launch_decode)."""
+        with jax.named_scope("sample"):
+            return tokens.at[slot].set(tok[0])
+
     def _decode_fn(self, params, cache, tokens, active, key, temps,
                    top_ps):
         with _moe.collect_expert_stats() as b:
@@ -838,7 +853,8 @@ class InferenceEngine:
                   "prefill_chunk": "prefill",
                   "prefill_chunk_paged": "prefill",
                   "decode": "decode", "spec_tick": "spec_verify",
-                  "sample": "sample", "handoff_gather": "handoff",
+                  "sample": "sample", "place_token": "sample",
+                  "handoff_gather": "handoff",
                   "handoff_scatter": "handoff"}
 
     def _register_exec(self, key, jitfn, args, mesh=None):
@@ -1128,8 +1144,14 @@ class InferenceEngine:
 
     def _record_admission(self, req: Request, slot: int, plen: int,
                           logits):
-        """Shared tail of both admission paths: sample the first token
-        from the prefill logits, bind the request to its slot."""
+        """Shared tail of both admission paths, in two halves.  This
+        one is what the host knows when it dispatches: the first token's
+        sampler goes behind the prefill and the request is bound to its
+        slot.  What the host learns from the token is
+        ``_finish_admission``: where the host can tell that the token
+        ends nothing (``_reads_can_wait``), that half is left to
+        ``_tick``, which launches the decode tick behind the prefill
+        first; everywhere else it follows at once."""
         self._key, sub = jax.random.split(self._key)
         # np (not list) literals: a python-float list would lower an
         # extra convert_element_type executable on the admission path
@@ -1138,17 +1160,36 @@ class InferenceEngine:
             logits, sub,
             np.asarray([req.temperature], np.float32),
             np.asarray([req.top_p], np.float32))
+        req.queued_s += req.t_admit - req.t_queue_since
+        self._timings["prefills"] += 1
+        self._m_prefills.inc()
+        req.slot = slot
+        req.admit_seq = next(self._admit_counter)
+        self._slots[slot] = req
+        self._slot_len[slot] = plen
+        self._temps[slot] = req.temperature
+        self._top_ps[slot] = req.top_p
+        if self._reads_can_wait([(slot, req)]):
+            self._fresh.append((req, slot, tok))
+        else:
+            self._finish_admission(req, slot, tok)
+
+    def _finish_admission(self, req: Request, slot: int, tok):
+        """The half of an admission that needs the first token's VALUE
+        on the host: the read, the request's first timestamps, the
+        token into the stream and into the next tick's host vector."""
         tok = int(np.asarray(tok)[0])
         async_dispatch.record_host_sync()
+        if req.done or self._slots[slot] is not req:
+            # retired since the dispatch (a deadline, a drain): it has
+            # what it gets, and the slot may be another's already
+            return
         now = time.perf_counter()
         if req.t_first is None:
             req.t_first = now
             self._m_ttft.observe((now - req.t_enqueue) * 1e3)
         req.t_live = now
         req.token_times.append(now)
-        req.queued_s += req.t_admit - req.t_queue_since
-        self._timings["prefills"] += 1
-        self._m_prefills.inc()
         if self._tracer.active:
             # request-lifecycle timeline: close the queued span, record
             # the prefill span (host timestamps already on hand — no
@@ -1164,12 +1205,6 @@ class InferenceEngine:
             tr.complete("prefill", t_adm, tr.to_us(now) - t_adm,
                         pid=_spans.PID_REQUESTS, tid=req.rid,
                         cat="request", args={"slot": slot})
-        req.slot = slot
-        req.admit_seq = next(self._admit_counter)
-        self._slots[slot] = req
-        self._slot_len[slot] = plen
-        self._temps[slot] = req.temperature
-        self._top_ps[slot] = req.top_p
         req.generated.append(tok)
         self._next_token[slot] = tok
         self._retire_if_done(req, tok)
@@ -1779,26 +1814,42 @@ class InferenceEngine:
         call commits, if it commits one.
 
         The chip never waits for the host where the host can tell what
-        the next tick's inputs are WITHOUT reading this one's tokens:
-        when no active request can end at this tick (none has an EOS,
-        none reaches its budget or the cache's end) and this call
-        admitted none, tick n + 1 is launched on the device's own
-        tokens BEFORE tick n is read, and the next call finds it in
-        flight.  Every tick still gets the inputs the serial order
-        gives it, so the tokens are the same; a tick that may end a
-        request, and the admission that follows, run serially as
-        before."""
+        the next executable's inputs are WITHOUT reading a token
+        (``_reads_can_wait``).  When no active request can end at this
+        tick (none has an EOS, none reaches its budget or the cache's
+        end), tick n + 1 is launched on the device's own tokens BEFORE
+        tick n is read, and the next call finds it in flight.  When a
+        fresh request cannot end at its first token, the tick behind
+        its prefill is launched with that token taken from the sampler
+        on the device, and the host reads it while the tick runs.
+        Every tick still gets the inputs the serial order gives it, so
+        the tokens are the same; a token that may end a request is read
+        before anything is launched behind it, as before."""
         tick_wall0 = time.perf_counter()
         with _spans.span("tick/admit", "serve", tick=n):
             produced = self._admit_queued()
+        fresh, self._fresh = self._fresh, []
         launched, self._ahead = self._ahead, None
         if launched is not None:
             launched, later = launched
             tick.note(**later.args)   # on the span of the call that reads it
             self._timings["occupancy_sum"] += later.occupancy
+            if fresh and self._may_run_ahead(
+                    launched[4] + [(slot, req) for req, slot, _ in fresh]):
+                self._launch_ahead(n, launched, fresh)
         else:
             with _spans.span("tick/launch", "serve", tick=n):
-                launched = self._launch_decode(tick)
+                launched = self._launch_decode(tick, fresh=fresh)
+        if fresh:
+            # returns when the prefills end, the tick behind them running
+            with _spans.span("tick/read", "serve", tick=n):
+                t0 = time.perf_counter()
+                froze = self.num_active > len(fresh)
+                for admission in fresh:
+                    self._finish_admission(*admission)
+                if froze:
+                    self._timings["prefill_stall_ms"] += \
+                        (time.perf_counter() - t0) * 1e3
         if launched is None:
             self._watchdog_idle_if_empty()
             return produced
@@ -1807,11 +1858,8 @@ class InferenceEngine:
             self._watchdog_idle_if_empty()
             return produced
         n_active, sampled, nxt, moe, bound = launched
-        if not produced and self._may_run_ahead(bound):
-            with _spans.span("tick/launch", "serve", tick=n + 1):
-                later = _NotedLater()
-                self._ahead = (self._launch_decode(later, after=launched),
-                               later)
+        if self._ahead is None and self._may_run_ahead(bound):
+            self._launch_ahead(n, launched)
         # the ONE host sync of the decode step: the scheduler needs the
         # sampled ids for EOS retirement and admission (the expert-load
         # fold, when present, is a sibling output of the same executable
@@ -1857,14 +1905,23 @@ class InferenceEngine:
             self._watchdog_idle_if_empty()
         return produced
 
-    def _may_run_ahead(self, bound) -> bool:
-        """Whether the tick after the one in flight may be launched
-        before that one is read: its inputs must not depend on the
-        tokens.  `bound` is the in-flight tick's (slot, request) pairs;
-        none of them may end at it, and they must still be all that is
-        active.  Dense single-device decoding only: the paged tick makes
-        room from the host's lengths, the speculative one commits a
-        count the host must read, a mesh commits its operands."""
+    def _launch_ahead(self, n: int, launched, fresh=()):
+        """Tick n + 1 behind `launched`, tick n, which is not read yet;
+        the next call finds it in ``_ahead`` and notes it on its span."""
+        with _spans.span("tick/launch", "serve", tick=n + 1):
+            later = _NotedLater()
+            self._ahead = (self._launch_decode(later, after=launched,
+                                               fresh=fresh), later)
+
+    def _reads_can_wait(self, bound) -> bool:
+        """Whether the next executable may be dispatched before the host
+        reads the token that each of `bound`'s (slot, request) pairs is
+        about to get: its inputs must not depend on the tokens, so none
+        of them may end at that token (no EOS, budget and cache room
+        for one more).  Dense single-device decoding only: the paged
+        tick makes room from the host's lengths, the speculative one
+        commits a count the host must read and seeds its draft with the
+        first token, a mesh commits its operands."""
         if (self.kv_layout != "dense" or self._chunked
                 or self._spec is not None or self.mesh is not None
                 or not self._admitting):
@@ -1875,7 +1932,14 @@ class InferenceEngine:
                     or len(req.generated) + 1 >= req.max_new_tokens
                     or self._slot_len[slot] + 2 >= self.max_seq_len):
                 return False
-        return len(bound) == int(self._active_mask().sum())
+        return True
+
+    def _may_run_ahead(self, bound) -> bool:
+        """Whether the tick after the one that serves `bound` may be
+        launched before the host has read `bound`'s tokens: they can
+        wait, and `bound` is still all that is active."""
+        return self._reads_can_wait(bound) and \
+            len(bound) == int(self._active_mask().sum())
 
     def _admit_queued(self) -> int:
         """``tick/admit``: expire, admit queued requests into free slots
@@ -1933,20 +1997,25 @@ class InferenceEngine:
             [1 if (r is not None and not r.prefilling) else 0
              for r in self._slots], np.int32)
 
-    def _launch_decode(self, tick, after=None):
+    def _launch_decode(self, tick, after=None, fresh=()):
         """``tick/launch``: the active mask, the uploads and the dispatch
         of the decode (or speculative) step.  None when no slot is
         active; else what the read and the commit need.  `after` is a
         launched tick not yet read (_tick): this one then takes the
-        device's own tokens and counts the slots one token longer."""
+        device's own tokens and counts that tick's slots one token
+        longer.  `fresh` are admissions whose first token is not read
+        yet: each slot's entry of the token vector is placed from its
+        sampler's output, device to device."""
         active_np = self._active_mask()
         if not active_np.any():
             return None
         if self._spec is not None:
             return self._launch_spec(tick)
-        ahead = after is not None
-        sampled_in_flight, tokens = (after[1], after[2]) if ahead \
-            else (0, None)
+        sampled_in_flight, tokens, in_flight = 0, None, None
+        if after is not None:
+            sampled_in_flight, tokens = after[1], after[2]
+            in_flight = np.zeros_like(active_np)
+            in_flight[[slot for slot, _ in after[4]]] = 1
         if self.kv_layout == "paged":
             self._ensure_decode_room()
             # a preemption/memory-capped retirement may have emptied
@@ -1959,7 +2028,7 @@ class InferenceEngine:
                 self._alloc.num_in_use / self._alloc.capacity
         sampled = int((self._temps > 0).any())
         n_active = self._note_active(
-            tick, active_np, 1, ahead=ahead,
+            tick, active_np, 1, in_flight=in_flight,
             sampled_ticks=self._timings["sampled_ticks"] + sampled
             + sampled_in_flight)
         if self.kv_layout == "paged":
@@ -1972,10 +2041,18 @@ class InferenceEngine:
                 self._key, jnp.asarray(self._temps),
                 jnp.asarray(self._top_ps))
         else:
+            if tokens is None:
+                tokens = jnp.asarray(self._next_token)
+            for _, slot, tok in fresh:
+                tokens = self._timed_exec(
+                    "prefill_ms", ("place_token", 0), self._place_jit,
+                    tokens, tok, np.int32(slot))
+            if after is not None or fresh:
+                self._timings["ticks_launched_unread"] += 1
+                self._timings["admissions_read_late"] += len(fresh)
             nxt, self._key, cache, moe = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_jit,
-                self.params, self.cache,
-                tokens if ahead else jnp.asarray(self._next_token),
+                self.params, self.cache, tokens,
                 jnp.asarray(active_np), self._key,
                 jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         self.cache = cache
@@ -1984,22 +2061,26 @@ class InferenceEngine:
         return n_active, sampled, nxt, moe, bound
 
     def _note_active(self, tick, active_np, window: int,
-                     ahead: bool = False, **more) -> int:
+                     in_flight=None, **more) -> int:
         """Occupancy counters of the tick about to launch, and on its
         ``tick`` span the active slots and what the cache says the tick
         has to read (``tick_reads``: ``kv_positions``, the cache
         positions its attention reads, the active slots' lengths with
         the `window` new tokens; a recurrent state's ``state_bytes``),
         and for a cache of rows ``kv_positions_read``, what the tick's
-        kernel streams.  Host arithmetic, no sync."""
-        if ahead:
+        kernel streams.  `in_flight` marks the slots of a launched tick
+        not yet read, behind which this one goes.  Host arithmetic, no
+        sync."""
+        lens = self._slot_len
+        if in_flight is not None:
+            # noted, with its occupancy, by the call that reads the tick;
+            # it finds every slot of the tick in flight one token longer
             tick.occupancy = float(active_np.mean())
+            lens = lens + in_flight
         else:
             self._timings["occupancy_sum"] += float(active_np.mean())
         n_active = int(active_np.sum())
         self._m_active.set(n_active)
-        # a tick launched ahead finds every active slot one token longer
-        lens = self._slot_len + active_np if ahead else self._slot_len
         reads = self.cache.tick_reads(active_np, lens, window)
         if self._cache_has_rows:
             reads["kv_positions_read"] = self._kv_positions_read(
@@ -2274,9 +2355,10 @@ class InferenceEngine:
         # numpy operands, exactly as _record_admission passes them: jit's
         # fast path keys on the operand kind, so a jax.Array here would
         # leave the tick's first sample call to re-trace
-        self._timed_exec("prefill_ms", ("sample", 1), self._sample_jit,
-                         logits, sub, np.zeros((1,), np.float32),
-                         np.ones((1,), np.float32))
+        tok = self._timed_exec(
+            "prefill_ms", ("sample", 1), self._sample_jit,
+            logits, sub, np.zeros((1,), np.float32),
+            np.ones((1,), np.float32))
         nxt, self._key, cache, _ = self._timed_exec(
             "decode_ms", ("decode", 0), self._decode_jit,
             self.params, self.cache,
@@ -2284,12 +2366,20 @@ class InferenceEngine:
             jnp.zeros(self.batch_slots, jnp.int32), self._key,
             jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         if self.mesh is None:
-            # and once on its own tokens, as a tick launched ahead takes
-            # them (_tick): jit's fast path keys on the operand's kind.
+            # and once on the tokens a tick takes where the host's read
+            # can wait (_tick): the last tick's own, with a fresh slot's
+            # entry placed from its sampler's output, over the host's
+            # vector too.  jit's fast path keys on the operand's kind.
             # The key chain stays where the first call left it.
+            tokens = nxt
+            if self._spec is None:
+                for base in (jnp.asarray(self._next_token), nxt):
+                    tokens = self._timed_exec(
+                        "prefill_ms", ("place_token", 0), self._place_jit,
+                        base, tok, np.int32(0))
             _, _, cache, _ = self._timed_exec(
                 "decode_ms", ("decode", 0), self._decode_jit,
-                self.params, cache, nxt,
+                self.params, cache, tokens,
                 jnp.zeros(self.batch_slots, jnp.int32), self._key,
                 jnp.asarray(self._temps), jnp.asarray(self._top_ps))
         # drop the warmup garbage: zero every slot's length (host-side

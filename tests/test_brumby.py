@@ -273,30 +273,50 @@ def test_slots_at_different_lengths_do_not_leak():
         assert float(deficits(flat, prompt, outs[rid]).max()) <= 1e-4
 
 
+def _serve_five_through_two_slots(engine):
+    """The tokens by request, and how many calls left a tick in
+    flight."""
+    prompts = [ids_of(n, seed=40 + n) for n in (5, 17, 32, 9, 3)]
+    rids = [engine.add_request(p, max_new_tokens=n, eos_id=None)
+            for p, n in zip(prompts, (10, 30, 18, 6, 12))]
+    ahead = 0
+    while engine.has_work:
+        engine.step_or_raise()
+        ahead += engine._ahead is not None
+    return [engine.results[r].tolist() for r in rids], ahead
+
+
 def test_a_tick_launched_ahead_serves_the_serial_orders_tokens():
     """The engine launches the next tick before it reads the one in
     flight where no request can end there: over a state that is valid at
     one position only the tokens are those of the serial order, a slot
     reused in between included."""
     flat = seeded()
-    prompts = [ids_of(n, seed=40 + n) for n in (5, 17, 32, 9, 3)]
-    news = (10, 30, 18, 6, 12)
-
-    def serve(engine):
-        rids = [engine.add_request(p, max_new_tokens=n, eos_id=None)
-                for p, n in zip(prompts, news)]
-        ahead = 0
-        while engine.has_work:
-            engine.step_or_raise()
-            ahead += engine._ahead is not None
-        return [engine.results[r].tolist() for r in rids], ahead
-
     serial = engine_of(model_of(flat), batch_slots=2)
     serial._may_run_ahead = lambda bound: False
-    want, none = serve(serial)
-    got, ahead = serve(engine_of(model_of(flat), batch_slots=2))
+    want, none = _serve_five_through_two_slots(serial)
+    got, ahead = _serve_five_through_two_slots(
+        engine_of(model_of(flat), batch_slots=2))
     assert none == 0 and ahead >= 20
     assert got == want
+
+
+def test_a_tick_behind_an_unread_prefill_serves_the_serial_orders_tokens():
+    """A fresh slot's first token goes from the prefill's sampler to
+    the tick behind it on the device, the prefill having just written
+    that slot's state: the tokens are those of an engine that reads
+    every token before it launches anything."""
+    flat = seeded()
+    serial = engine_of(model_of(flat), batch_slots=2)
+    serial._reads_can_wait = lambda bound: False
+    want, none = _serve_five_through_two_slots(serial)
+    eng = engine_of(model_of(flat), batch_slots=2)
+    got, _ = _serve_five_through_two_slots(eng)
+    assert got == want
+    assert none == 0 and serial.stats["ticks_launched_unread"] == 0
+    assert eng.stats["admissions_read_late"] == eng.stats["prefills"] == 5
+    for key in ("decode_steps", "tokens_generated"):
+        assert eng.stats[key] == serial.stats[key]
 
 
 def test_cache_size_ignores_max_seq_len():

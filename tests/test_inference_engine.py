@@ -628,7 +628,7 @@ def test_tick_ahead_serves_the_serial_orders_tokens(model, temperature):
 def test_tick_ahead_waits_where_a_token_could_end_a_request(model):
     """An EOS makes the next tick's inputs depend on this one's tokens:
     such a request is never run ahead of; nor is a tick that may be a
-    request's last, nor a call that admitted."""
+    request's last.  A call that admitted runs ahead like any other."""
     eng = InferenceEngine(model, batch_slots=2, prefill_buckets=[16])
     _, ahead = _serve_churn(eng, 0.0, eos_id=96)
     assert ahead == 0
@@ -637,9 +637,9 @@ def test_tick_ahead_waits_where_a_token_could_end_a_request(model):
     while eng.has_work:
         eng.step_or_raise()
         flights.append(eng._ahead is not None)
-    # prefill + tick 1 (the call admitted), tick 2 with tick 3 behind
-    # it, tick 3: the last, nothing behind it
-    assert flights == [False, True, False]
+    # prefill + tick 1 behind it, with tick 2 behind that; tick 2 with
+    # tick 3 behind it; tick 3: the last, nothing behind it
+    assert flights == [True, True, False]
     assert len(eng.results[rid]) == 4
 
 
@@ -685,3 +685,191 @@ def test_every_read_tick_is_noted_on_one_tick_span(model):
     # five ticks after the prefill's token; tick i attends 5 + i positions
     assert [a["kv_positions"] for a in noted] == [6, 7, 8, 9, 10]
     assert eng.stats["decode_steps"] == 5
+
+
+# -------- the admission read late: the tick behind a prefill --------
+def _all_serial(engine):
+    """`engine` with every read where the parent of both mechanisms had
+    it: no tick ahead of a read, no tick behind an unread prefill."""
+    engine._reads_can_wait = lambda bound: False
+    return engine
+
+
+def _late_counters(engine):
+    stats = engine.stats
+    return stats["admissions_read_late"], stats["ticks_launched_unread"]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_admission_read_late_serves_the_serial_orders_tokens(model,
+                                                             temperature):
+    """Eight requests through three slots, admitted as slots come free:
+    where a fresh request cannot end at its first token the tick behind
+    its prefill takes that token from the sampler on the device, and
+    the host reads it afterwards.  The tokens are those of an engine
+    that reads everything at once, sampled ones too (one key chain),
+    with the same counts, and nothing compiles or traces after
+    warm-up."""
+    def engine():
+        return InferenceEngine(model, batch_slots=3, seed=3,
+                               prefill_buckets=[8, 16]).warmup([8, 16])
+    serial = _all_serial(engine())
+    want, none = _serve_churn(serial, temperature)
+    eng = engine()
+    snap = compile_counter.snapshot()
+    got, ahead = _serve_churn(eng, temperature)
+    assert (snap.new_compiles, snap.new_traces) == (0, 0)
+    assert got == want
+    assert none == 0 and _late_counters(serial) == (0, 0)
+    for key in ("decode_steps", "tokens_generated", "prefills",
+                "sampled_ticks"):
+        assert eng.stats[key] == serial.stats[key], key
+    assert eng._timings["occupancy_sum"] == serial._timings["occupancy_sum"]
+    late, unread = _late_counters(eng)
+    # every request can be seen through; a tick goes unread behind each
+    # call's prefills and ahead of every tick that ends no request
+    assert late == eng.stats["prefills"] == 8
+    assert unread >= ahead + 3 and unread <= eng.stats["decode_steps"]
+
+
+def test_admission_behind_a_tick_in_flight_takes_its_tokens(model):
+    """A request that arrives while a tick is in flight: the tick
+    behind its prefill takes the in-flight tick's tokens on the device
+    with the fresh slot's entry placed among them."""
+    def serve(eng):
+        rng = np.random.RandomState(7)
+        rids = [eng.add_request(rng.randint(1, 97, 6), max_new_tokens=30)]
+        marks = []
+        for i in range(12):
+            if i in (3, 6):
+                rids.append(eng.add_request(rng.randint(1, 97, 4 + i),
+                                            max_new_tokens=12))
+            flying = eng._ahead is not None
+            before = eng._timings["admissions_read_late"]
+            eng.step_or_raise()
+            marks.append((flying,
+                          eng._timings["admissions_read_late"] - before,
+                          eng._ahead is not None))
+        eng.run()
+        return [eng.results[r].tolist() for r in rids], marks
+
+    def engine():
+        return InferenceEngine(model, batch_slots=3,
+                               prefill_buckets=[16]).warmup([16])
+    want, _ = serve(_all_serial(engine()))
+    eng = engine()
+    snap = compile_counter.snapshot()
+    got, marks = serve(eng)
+    assert (snap.new_compiles, snap.new_traces) == (0, 0)
+    assert got == want
+    # both arrivals met a tick in flight, were read late, and left the
+    # tick that holds them in flight
+    assert marks[3] == (True, 1, True) and marks[6] == (True, 1, True)
+    assert _late_counters(eng)[0] == 3
+
+
+def test_two_slots_freed_in_one_call_both_reach_the_tick_behind(model):
+    """Two requests end at one tick and two take their slots in the
+    next call: both first tokens are placed on the device for the one
+    tick behind the two prefills."""
+    def serve(eng):
+        rng = np.random.RandomState(11)
+        rids = [eng.add_request(rng.randint(1, 97, n), max_new_tokens=m)
+                for n, m in ((5, 4), (7, 4), (6, 20), (9, 6), (3, 7))]
+        jumps = []
+        while eng.has_work:
+            before = eng._timings["admissions_read_late"]
+            eng.step_or_raise()
+            jumps.append(eng._timings["admissions_read_late"] - before)
+        return [eng.results[r].tolist() for r in rids], jumps
+
+    def engine():
+        return InferenceEngine(model, batch_slots=3, prefill_buckets=[16])
+    want, _ = serve(_all_serial(engine()))
+    got, jumps = serve(engine())
+    assert got == want
+    assert jumps[0] == 3 and 2 in jumps[1:]
+    assert [len(tokens) for tokens in got] == [4, 4, 20, 6, 7]
+
+
+@pytest.mark.parametrize("case", ["eos", "one_token", "cache_end"])
+def test_admission_that_may_end_at_its_first_token_is_read_at_once(case):
+    """A request with an EOS, one of a single token, and one whose
+    prompt ends two short of the cache's end may be over with their
+    first token: the host reads it before anything goes behind it, and
+    the tokens are the full forward's."""
+    m = tiny_model()
+    eng = InferenceEngine(m, batch_slots=2, prefill_buckets=[8, 64])
+    rng = np.random.RandomState(13)
+    prompt = rng.randint(1, 97, 62 if case == "cache_end" else 6)
+    kw = {"eos": dict(max_new_tokens=5, eos_id=96),
+          "one_token": dict(max_new_tokens=1),
+          "cache_end": dict(max_new_tokens=9)}[case]
+    rid = eng.add_request(prompt, **kw)
+    eng.step_or_raise()
+    assert _late_counters(eng)[0] == 0 and eng.stats["prefills"] == 1
+    out = eng.run()[rid].tolist()
+    want = naive_greedy(m, prompt, {"eos": 5, "one_token": 1,
+                                    "cache_end": 2}[case])
+    if 96 in want:
+        want = want[:want.index(96) + 1]
+    assert out == want
+    assert _late_counters(eng)[0] == 0
+    # the same engine sees through the next one
+    rid = eng.add_request(prompt[:5], max_new_tokens=3)
+    assert eng.run()[rid].tolist() == naive_greedy(m, prompt[:5], 3)
+    assert _late_counters(eng)[0] == 1
+
+
+@pytest.mark.parametrize("how", ["deadline", "drain"])
+def test_fresh_request_retired_before_its_first_read_leaves_no_token(
+        model, how):
+    """A fresh request retired between the launch of the tick behind
+    its prefill and the read of its first token (a deadline, a forced
+    drain) gets no token, and neither the read nor the tick in flight
+    gives one to the slot's next occupant."""
+    eng = InferenceEngine(model, batch_slots=1, prefill_buckets=[8])
+    launch = eng._launch_decode
+
+    def launch_then_retire(tick, after=None, fresh=()):
+        out = launch(tick, after=after, fresh=fresh)
+        if any(req.rid == first for req, _, _ in fresh):
+            if how == "deadline":
+                eng._slots[0].deadline = 0.0
+                eng._retire_expired()
+            else:
+                eng.drain(timeout_s=0.0)
+        return out
+    eng._launch_decode = launch_then_retire
+    first = eng.add_request(np.arange(1, 6), max_new_tokens=30)
+    eng.step_or_raise()
+    assert _late_counters(eng) == (1, 1)
+    assert eng.results[first].size == 0
+    assert eng.request_stats[first]["timed_out"]
+    assert eng.num_active == 0 and eng._ahead is None
+    second = eng.add_request(np.arange(7, 12), max_new_tokens=5)
+    eng.run()
+    alone = InferenceEngine(model, batch_slots=1, prefill_buckets=[8])
+    rid = alone.add_request(np.arange(7, 12), max_new_tokens=5)
+    np.testing.assert_array_equal(eng.results[second], alone.run()[rid])
+
+
+@pytest.mark.parametrize("kind", ["paged", "speculative", "chunked"])
+def test_engines_the_host_cannot_see_through_admit_serially(model, kind):
+    """The paged tick makes room from the host's lengths, the
+    speculative one seeds its draft with the first token, the chunked
+    one has no prefill to go behind: their admissions are read at once,
+    as before, and serve the full forward's tokens."""
+    kw = {"paged": dict(kv_layout="paged", kv_block_size=8),
+          "speculative": dict(spec_k=2, draft_model=model),
+          "chunked": dict(prefill_chunk=4)}[kind]
+    eng = InferenceEngine(model, batch_slots=2, prefill_buckets=[8], **kw)
+    rng = np.random.RandomState(17)
+    prompts = [rng.randint(1, 97, n) for n in (5, 7, 3)]
+    rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+    out = eng.run()
+    for rid, prompt in zip(rids, prompts):
+        assert out[rid].tolist() == naive_greedy(model, prompt, 6)
+    assert _late_counters(eng) == (0, 0)
+    assert eng.stats["prefills"] == 3

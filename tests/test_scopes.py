@@ -265,14 +265,25 @@ def test_three_ticks_leave_their_phases_nested(buffer):
     assert launched[0]["args"]["active"] == 2
     assert launched[0]["args"]["kv_positions"] == 9 + 6 + 2
     assert launched[1]["args"]["kv_positions"] == 9 + 6 + 4
+    # the first call launches tick 1 behind the two prefills, reads their
+    # first tokens (the first tick/read), launches tick 2 ahead (inside
+    # this call's span, numbered 2) and reads tick 1; the second call
+    # finds tick 2 in flight
+    by_number = {
+        1: ["tick/admit", "tick/launch", "tick/read", "tick/read",
+            "tick/commit"],
+        2: ["tick/admit", "tick/read", "tick/commit"]}
     for t in launched:
         mine = [e for e in events if e["name"] in children
                 and e["args"]["tick"] == t["args"]["tick"]
                 and _inside(e, t)]
         assert [e["name"] for e in sorted(mine, key=lambda e: e["ts"])] \
-            == list(children)
+            == by_number[t["args"]["tick"]]
         # the children tile the parent: what lies between them is small
         assert sum(e["dur"] for e in mine) <= t["dur"]
+    ahead = [e for e in events if e["name"] == "tick/launch"
+             and e["args"]["tick"] == 2]
+    assert len(ahead) == 1 and _inside(ahead[0], launched[0])
     prefills = [e for e in events if e["name"] == "prefill"]
     admits = [e for e in events if e["name"] == "tick/admit"]
     assert [p["args"] for p in prefills] == [
