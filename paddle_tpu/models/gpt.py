@@ -83,7 +83,9 @@ def kv_step_bytes(layers: int, kv_heads: int, head_dim: int, dtype,
 @dataclass
 class KVLayerView:
     """One layer of a serving KV cache, as a serving step sees it: the
-    layer's buffers behind two operations.
+    layer's buffers behind ONE operation, ``write_attend(q, k, v,
+    lengths)``: write W tokens, then attend their queries.  Its default
+    is the two halves one after the other.
 
     ``write(k, v, lengths)`` stores W new tokens' k and v
     ``[B, W, Hkv, D]`` for every slot at positions ``lengths[b] ..
@@ -109,6 +111,14 @@ class KVLayerView:
     v_scale: Optional[jax.Array] = None
     # (lengths, address) of the last write, for its queries' attend
     window: Optional[tuple] = None
+
+    def write_attend(self, q, k, v, lengths):
+        """``(out [B, W, H, D], the written view)``; the write under the
+        scope ``kv_write``, the attention under ``decode_attn``."""
+        with jax.named_scope("kv_write"):
+            kv = self.write(k, v, lengths)
+        with jax.named_scope("decode_attn"):
+            return kv.attend(q), kv
 
     def write(self, k, v, lengths) -> "KVLayerView":
         w = k.shape[1]
@@ -155,6 +165,24 @@ class DenseKVLayer(KVLayerView):
     def _put(self, buf, idx, new):
         from ..ops import write_kv
         return write_kv(buf, idx, new)
+
+    def write_attend(self, q, k, v, lengths):
+        """One token a slot into a cache without scale planes, where the
+        decode kernel runs: the kernel stores the token's k and v itself
+        and the step holds no scatter.  Everything else (a window, 8-bit
+        codes, off the chip) writes and then attends."""
+        from ..ops import decode_attention_writes, write_decode_attention
+        if k.shape[1] != 1 or self.k_scale is not None or \
+                not decode_attention_writes(q[:, 0], self.k):
+            return super().write_attend(q, k, v, lengths)
+        lens = lengths.astype(jnp.int32)
+        at = self._locate(lens)
+        with jax.named_scope("decode_attn"):
+            out, k_buf, v_buf = write_decode_attention(
+                q[:, 0].astype(self.k.dtype), k[:, 0], v[:, 0], self.k,
+                self.v, at)
+        return out[:, None], replace(self, k=k_buf, v=v_buf,
+                                     window=(lens, at))
 
     def _attend_token(self, q, lens, idx):
         from ..ops import decode_attention
@@ -606,11 +634,8 @@ class GPTAttention(Layer):
         ``(out, kv)``."""
         b, w = x.shape[0], x.shape[1]
         q, k, v = self._qkv_arrays(x)
-        with jax.named_scope("kv_write"):
-            kv = kv.write(k, v, lengths)
-        with jax.named_scope("decode_attn"):
-            out = kv.attend(q).astype(q.dtype)      # [b, w, H, D]
-        return self._proj_out(out, b, w), kv
+        out, kv = kv.write_attend(q, k, v, lengths)     # [b, w, H, D]
+        return self._proj_out(out.astype(q.dtype), b, w), kv
 
     def forward_prefill_paged(self, x, k_buf, v_buf, prefix_len):
         """Prefill attention over ONE slot's gathered block buffer:

@@ -20,6 +20,7 @@ from .flash_attention import (  # noqa: F401
 from .decode_attention import (  # noqa: F401
     chunk_prefill_attention, decode_attention,
     decode_attention_available, decode_attention_window,
+    decode_attention_writes, write_decode_attention,
     paged_chunk_prefill_attention, paged_decode_attention,
     paged_decode_attention_available, paged_decode_attention_window,
     write_kv)

@@ -33,7 +33,17 @@ as keep its four blocks in flight inside 8 MiB (all 16 at 16 heads of
 128: a 4 MiB step, 24 x 4 steps a layer).  A slot's blocks run last so
 that the pipeline, which fetches one step ahead, copies the NEXT slot's
 first block under this slot's last products.
-An int8 cache goes through the same body (``quantized``).  The window
+An int8 cache goes through the same body (``quantized``).
+The decode tick's call is ``write_decode_attention`` (PERF.md section 6,
+PR 46): the same kernel takes the token's k and v as two more operands
+and returns the two buffers, aliased to their operands.  A slot's last
+step holds the block with position ``lengths[b] - 1`` in VMEM; the body
+puts the new row there (a tile of 16 rows cut out, selected into and
+stored back) and sends the same tile out through a block aliased to the
+cache, so the tick holds no scatter and the sums run over the bytes, and
+in the order, a ``write_kv`` before the call would have given them.
+8-bit codes keep ``write_kv``: their scale planes lie position-minor,
+another tile.  The window
 kernels still run a grid ``(B·Hkv,)`` over whole ``[S, D]`` strips with
 an f32 ``[B, W, S]`` mask strip, and read every slot to its capacity
 (ROADMAP D12).
@@ -82,6 +92,7 @@ from . import kernel_paths
 _fa = importlib.import_module(__package__ + ".flash_attention")
 
 __all__ = ["decode_attention", "decode_attention_available", "write_kv",
+           "write_decode_attention", "decode_attention_writes",
            "paged_decode_attention", "paged_decode_attention_available",
            "decode_attention_window", "paged_decode_attention_window",
            "chunk_prefill_attention", "paged_chunk_prefill_attention",
@@ -227,8 +238,22 @@ def _first_step(length, block_k: int, steps: int):
     return steps - jnp.clip(_key_blocks(length, block_k), 0, steps)
 
 
+def _write_rows(dtype) -> int:
+    """Rows of the tile through which a write-then-attend call stores
+    its token: the storage dtype's sublane tile, 16 of bf16, 8 of
+    float32 (the least the chip's compiler lets a block or a copy cut
+    out of a buffer)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _new_position(length):
+    """Where the token a write-then-attend call stores lies in a slot
+    that holds ``length`` positions with it: the last of them."""
+    return jnp.maximum(length - 1, 0)
+
+
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
-                   scale: float, quantized: bool):
+                   scale: float, quantized: bool, writes: bool):
     """One (slot, kv-head group, key-block step) step.  len_ref [B] int32
     in scalar memory; q_ref/o_ref [hb, G, D], the query groups of ``hb``
     kv heads of slot b; k_ref/v_ref [hb, block_k, D], the key block the
@@ -241,9 +266,22 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
     inputs, f32 scores and accumulation — the same mixed scheme as the
     training flash kernel.  ``quantized``: k/v arrive as int8 with
     ``[hb, 1, block_k]`` f32 scale strips and are dequantized AFTER
-    leaving HBM, so the blocks stream at half the bytes."""
+    leaving HBM, so the blocks stream at half the bytes.
+    ``writes``: the call is the whole write-then-attend of the token at
+    position ``lengths[b] - 1``.  kn_ref/vn_ref [hb, 1, D] hold its k
+    and v; ko_ref/vo_ref [hb, T, D] are the tile of T rows (the storage
+    dtype's sublane tile) of the cache buffers, aliased to k and v,
+    that holds the position.  A slot's last step has the block with
+    that position in VMEM: the body cuts the tile out of it, selects the
+    new row in and stores the tile twice, to the out-ref (the pipeline
+    writes it to HBM when the slot's axis ends, and nothing of this slot
+    is fetched after) and back into the block, so that the products
+    below meet what a write before the call would have left there, in
+    the same order."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    elif writes:
+        kn_ref, vn_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b, j = pl.program_id(0), pl.program_id(2)
@@ -256,6 +294,21 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
         m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    if writes:
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _write():
+            tile = ko_ref.shape[1]
+            at = _new_position(n)
+            rows = pl.ds(pl.multiple_of(at % block_k // tile * tile, tile),
+                         tile)
+            is_new = jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile, 1), 1) == at % tile
+            for new_ref, blk_ref, out_ref in ((kn_ref, k_ref, ko_ref),
+                                              (vn_ref, v_ref, vo_ref)):
+                written = jnp.where(is_new, new_ref[:], blk_ref[:, rows, :])
+                blk_ref[:, rows, :] = written
+                out_ref[:] = written
 
     @pl.when(j >= first)
     def _block():
@@ -293,14 +346,17 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
                     ).astype(o_ref.dtype)
 
 
-def _decode_gqa(q4, k4, v4, lengths, k_scale=None, v_scale=None):
+def _decode_gqa(q4, k4, v4, lengths, k_scale=None, v_scale=None,
+                k_new=None, v_new=None):
     """q4 [B, Hkv, G, D]; k4/v4 [B, Hkv, S, D], the layer as it lies
     (int8 beside its [B, Hkv, S] f32 scale planes when quantized);
-    lengths [B] int32."""
+    lengths [B] int32.  With ``k_new``/``v_new`` [B, Hkv, 1, D] the call
+    also stores them at position ``lengths - 1`` and returns ``(out, k4,
+    v4)``."""
     heads, block_k = _decode_tiling(k4.shape[1], k4.shape[2], k4.shape[3],
                                     k4.dtype.itemsize)
     return _decode_call(lengths.astype(jnp.int32), q4, k4, v4, k_scale,
-                        v_scale, heads=heads, block_k=block_k,
+                        v_scale, k_new, v_new, heads=heads, block_k=block_k,
                         interpret=_interpret())
 
 
@@ -310,13 +366,16 @@ def _decode_gqa(q4, k4, v4, lengths, k_scale=None, v_scale=None):
 # 6, PR 42).  What the trace reads from outside is in the static
 # arguments.
 @functools.partial(jax.jit, static_argnames=("heads", "block_k", "interpret"))
-def _decode_call(lengths, q4, k4, v4, k_scale, v_scale, *, heads: int,
-                 block_k: int, interpret: bool):
+def _decode_call(lengths, q4, k4, v4, k_scale, v_scale, k_new, v_new, *,
+                 heads: int, block_k: int, interpret: bool):
     """The kernel's call: ``lengths`` scalar-prefetched, grid (slot,
-    kv-head group, key-block step)."""
+    kv-head group, key-block step).  With ``k_new``/``v_new`` it returns
+    the cache buffers beside the output, each aliased to its input: of
+    either, the call writes one tile of rows a (slot, kv-head group)."""
     pltpu = _fa.pltpu
     b, hkv, g, d = q4.shape
     steps = k4.shape[2] // block_k
+    writes = k_new is not None
 
     def kv_index(i, hg, j, lens):
         first = _first_step(lens[i], block_k, steps)
@@ -338,11 +397,27 @@ def _decode_call(lengths, q4, k4, v4, k_scale, v_scale, *, heads: int,
         in_specs += [sc_spec, sc_spec]
         args += [k_scale.astype(jnp.float32)[:, :, None, :],
                  v_scale.astype(jnp.float32)[:, :, None, :]]
+    out_specs, out_shape, aliases = io_spec, \
+        jax.ShapeDtypeStruct(q4.shape, q4.dtype), {}
+    if writes:
+        tile = _write_rows(k4.dtype)
+        new_spec = pl.BlockSpec((None, heads, 1, d),
+                                lambda i, hg, j, lens: (i, hg, 0, 0))
+        tile_spec = pl.BlockSpec(
+            (None, heads, tile, d), lambda i, hg, j, lens:
+            (i, hg, _new_position(lens[i]) // tile, 0))
+        in_specs += [new_spec, new_spec]
+        args += [k_new, v_new]
+        out_specs = [io_spec, tile_spec, tile_spec]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(k4.shape, k4.dtype),
+                     jax.ShapeDtypeStruct(v4.shape, v4.dtype)]
+        # operands count from the scalar-prefetched lengths
+        aliases = {2: 1, 3: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hkv // heads, steps),
         in_specs=in_specs,
-        out_specs=io_spec,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((heads, g, 128), jnp.float32),   # running max
             pltpu.VMEM((heads, g, 128), jnp.float32),   # running denominator
@@ -352,9 +427,10 @@ def _decode_call(lengths, q4, k4, v4, k_scale, v_scale, *, heads: int,
     call = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=block_k,
                           scale=1.0 / math.sqrt(d),
-                          quantized=k_scale is not None),
+                          quantized=k_scale is not None, writes=writes),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -393,6 +469,15 @@ def _decode_composite(q, k_cache, v_cache, lengths, k_scale=None,
     return out.reshape(b, h, d).astype(q.dtype)
 
 
+def _dense_kernels_serve(h: int, d: int, k_cache, quantized: bool) -> bool:
+    """The shapes the dense kernels serve: a capacity of whole lane
+    tiles, D 64 or a multiple of 128, whole query groups, int8 when
+    quantized (fp8 rides the composites)."""
+    hkv, s = k_cache.shape[1], k_cache.shape[2]
+    return (s % 128 == 0 and (d % 128 == 0 or d == 64) and h % hkv == 0
+            and (not quantized or k_cache.dtype == jnp.int8))
+
+
 def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
                      v_scale=None):
     """Single-token attention over a static, length-masked KV cache.
@@ -407,12 +492,10 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
     grouped ``h = hk·G + g`` like flash_attention).  Pallas fused
     kernel when shapes allow, XLA composite otherwise.
     """
-    b, h, d = q.shape
-    hkv, s = k_cache.shape[1], k_cache.shape[2]
+    h, d = q.shape[1:]
+    hkv = k_cache.shape[1]
     quantized = k_scale is not None
-    supported = (s % 128 == 0 and (d % 128 == 0 or d == 64)
-                 and h % hkv == 0
-                 and (not quantized or k_cache.dtype == jnp.int8))
+    supported = _dense_kernels_serve(h, d, k_cache, quantized)
     if not supported or not decode_attention_available():
         kernel_paths.note_composite("decode_attention", supported)
         return _decode_composite(q, k_cache, v_cache, lengths,
@@ -433,6 +516,44 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
                                v_scale)
 
 
+def decode_attention_writes(q, k_cache) -> bool:
+    """Whether ``write_decode_attention`` serves these shapes here:
+    ``decode_attention``'s own gate, for a cache without scale planes."""
+    return _dense_kernels_serve(q.shape[1], q.shape[2], k_cache, False) \
+        and decode_attention_available()
+
+
+def write_decode_attention(q, k_new, v_new, k_cache, v_cache, idx):
+    """``write_kv`` of one token a slot and ``decode_attention`` over
+    what it leaves, in ONE kernel call: the decode tick's
+    write-then-attend of a cache without scale planes.
+
+    q ``[B, H, D]``; k_new/v_new ``[B, Hkv, D]``, the token's k and v;
+    k_cache/v_cache ``[B, Hkv, S, D]``; idx ``[B]`` int32, the token's
+    position in its slot (inside the buffer), which holds ``idx + 1``
+    positions with it.  Returns ``(out [B, H, D], k_cache, v_cache)``,
+    the buffers with row ``idx[b]`` of every head of slot b written and
+    nothing else touched, each aliased to its operand: donated, it is
+    written where it lies and the tick holds no scatter.  The kernel
+    attends the new row from VMEM, in the place and the order in which a
+    write before the call would have had it read: the output is
+    ``decode_attention``'s bit for bit.  There is no composite here:
+    callers ask ``decode_attention_writes`` first and keep ``write_kv``
+    + ``decode_attention`` for everything it refuses."""
+    h, hkv = q.shape[1], k_cache.shape[1]
+    kernel_paths.note("decode_attention", "kernel")
+    args = [q, k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype),
+            k_cache, v_cache, idx.astype(jnp.int32) + 1]
+    mesh, _tp = _tp_mesh(hkv, h)
+    if mesh is None:
+        return _decode_write_kernel_path(*args)
+    from jax.sharding import PartitionSpec as P
+    row, layer = P(None, "tp", None), P(None, "tp", None, None)
+    return _shard_over_tp(_decode_write_kernel_path, mesh,
+                          [row, row, row, layer, layer, P(None)],
+                          (row, layer, layer), args)
+
+
 def _decode_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
                         v_scale=None):
     """The dense kernel dispatch AFTER the support gate — also the
@@ -445,6 +566,21 @@ def _decode_kernel_path(q, k_cache, v_cache, lengths, k_scale=None,
     kernel_paths.note("decode_attention.bounded", "kernel")
     return _decode_gqa(q.reshape(b, hkv, h // hkv, d), k_cache, v_cache,
                        lengths, k_scale, v_scale).reshape(b, h, d)
+
+
+def _decode_write_kernel_path(q, k_new, v_new, k_cache, v_cache, lengths):
+    """``write_decode_attention``'s dispatch after its gate, and its
+    shard_map body under tp: the same kernel with the new rows as
+    operands and the buffers as outputs.  ``lengths`` count the new
+    token."""
+    b, h, d = q.shape
+    hkv = k_cache.shape[1]
+    kernel_paths.note("decode_attention.bounded", "kernel")
+    kernel_paths.note("decode_attention.fused_write", "kernel")
+    out, k_cache, v_cache = _decode_gqa(
+        q.reshape(b, hkv, h // hkv, d), k_cache, v_cache, lengths,
+        k_new=k_new[:, :, None], v_new=v_new[:, :, None])
+    return out.reshape(b, h, d), k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------
@@ -812,12 +948,10 @@ def decode_attention_window(q, k_cache, v_cache, lengths, k_scale=None,
     guarantee speculative decoding rests on.  ``W=1`` reduces to
     ``decode_attention`` with lengths+1.  Quantized caches pass their
     ``[B, Hkv, S]`` f32 scale planes.  Returns ``[B, W, H, D]``."""
-    b, w, h, d = q.shape
-    hkv, s = k_cache.shape[1], k_cache.shape[2]
+    h, d = q.shape[2:]
+    hkv = k_cache.shape[1]
     quantized = k_scale is not None
-    supported = (s % 128 == 0 and (d % 128 == 0 or d == 64)
-                 and h % hkv == 0
-                 and (not quantized or k_cache.dtype == jnp.int8))
+    supported = _dense_kernels_serve(h, d, k_cache, quantized)
     if not supported or not decode_attention_available():
         kernel_paths.note_composite("decode_attention_window", supported)
         return _window_composite(q, k_cache, v_cache, lengths,

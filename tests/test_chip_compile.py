@@ -115,25 +115,34 @@ def test_dense_decode(compile_for_chip, quantized):
     assert_kernel(compile_for_chip(da._decode_kernel_path, *specs))
 
 
+@pytest.mark.parametrize("writes", [False, True], ids=["reads", "writes"])
 @pytest.mark.parametrize("slots,heads,kv_heads,d", [
     (24, 16, 16, 128), (SLOTS, 16, 4, 128), (SLOTS, 12, 12, 64)],
     ids=["closed_cell", "gqa_4", "heads_of_64"])
 def test_dense_decode_bounded_by_lengths(compile_for_chip, slots, heads,
-                                         kv_heads, d):
+                                         kv_heads, d, writes):
     """The length-bounded kernel through its entry's dispatch: Mosaic
     takes ``lengths`` as a scalar-prefetch operand and a block of
     several kv heads x 512 keys, at the closed cell's shape (24 slots x
     16 heads of 128 on 16 kv heads, all 16 a program), with a query
     group of 4 and at heads of 64; nothing the size of a mask strip is
-    built beside it."""
+    built beside it.  ``writes``: the same kernel as the decode tick
+    calls it, with the token's k and v as operands: Mosaic takes the
+    16-row tile cut out of a block at a dynamic offset, stored back into
+    the block and out through a block aliased to the cache, and the
+    program holds one kernel and no scatter."""
     cache = ((slots, kv_heads, SEQ, d), bf16)
     assert da._decode_tiling(kv_heads, SEQ, d, 2) == (kv_heads, 512)
-    text = compile_for_chip(da._decode_kernel_path,
-                            ((slots, heads, d), bf16), cache, cache,
-                            ((slots,), i32))
+    specs = [((slots, heads, d), bf16), cache, cache, ((slots,), i32)]
+    path = da._decode_kernel_path
+    if writes:
+        path = da._decode_write_kernel_path
+        specs[1:1] = [((slots, kv_heads, d), bf16)] * 2
+    text = compile_for_chip(path, *specs)
     assert text.count("tpu_custom_call") == 1
     assert f"f32[{slots},{SEQ}]" not in text
     assert f"f32[{slots},1,{SEQ}]" not in text
+    assert " scatter(" not in text
 
 
 def test_dense_window(compile_for_chip):
@@ -267,13 +276,19 @@ def test_decode_step_never_copies_the_cache(one_chip, monkeypatch,
     temporary nor as a ``copy``.  (At the stacked seq-major layout of
     PR 23 this program needed 194.5 MiB of temporaries in bf16 and
     101.8 MiB in int8: a layer sliced out, transposed for the kernel and
-    written back.)"""
+    written back.)  Since PR 46 the bf16 step holds NO scatter: the
+    attention kernel stores the tick's token itself, through outputs
+    aliased to the buffers.  The int8 step keeps its four a layer (codes
+    and scale planes of k and v): its scale planes lie position-minor
+    and take ``write_kv``."""
     import re
     eng = _decode_engine(monkeypatch, kv_dtype)
     layers = eng.cache.num_layers
     compiled = _compile_on_one_chip(eng, one_chip)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= layers
+    scatters = len(re.findall(r" scatter\(", text))
+    assert scatters == (4 * layers if kv_dtype else 0)
     # k and v per layer (and their scale planes), and the lengths
     leaves = jax.tree_util.tree_leaves(eng.cache)
     assert len(leaves) == layers * (4 if kv_dtype else 2) + 1

@@ -126,6 +126,102 @@ def test_the_cells_block_at_the_cells_capacity(kernel, monkeypatch, heads):
     assert not got[-1].any()
 
 
+def _write_then_attend(q, k_new, v_new, k, v, idx, attend):
+    """The two calls the fused one stands for: ``write_kv`` of both
+    buffers, then `attend` over what they leave."""
+    k, v = da.write_kv(k, idx, k_new), da.write_kv(v, idx, v_new)
+    return attend(q, k, v, idx + 1), k, v
+
+
+def _bits(a):
+    return np.asarray(a).view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+# positions of the new token, a slot each, at a key block of `blk` and a
+# capacity of `cap`: a slot's first token, the last row of a block and
+# the first of the next, the last position (where a slot at capacity is
+# clamped to), the last row of a 16-row tile and the first of the next,
+# and a slot nothing is served from, at the stale length it was left at
+_WRITE_AT = {"first_token": lambda blk, cap: 0,
+             "block_last_row": lambda blk, cap: blk - 1,
+             "next_block_first_row": lambda blk, cap: blk,
+             "capacity": lambda blk, cap: cap - 1,
+             "tile_last_row": lambda blk, cap: blk + 15,
+             "inactive": lambda blk, cap: 2 * blk + 37}
+
+
+def _fused_case(rng, n, hkv, g, cap, d, dtype):
+    """(q, k_new, v_new, k, v) of `n` slots."""
+    draw = lambda *shape: jnp.asarray(rng.randn(*shape) * 0.5, dtype)
+    return (draw(n, hkv * g, d), draw(n, hkv, d), draw(n, hkv, d),
+            draw(n, hkv, cap, d), draw(n, hkv, cap, d))
+
+
+def _assert_fused_is_its_two_calls(q, k_new, v_new, k, v, idx, atol):
+    """``write_decode_attention`` against ``write_kv`` + the unfused
+    kernel (output and BOTH buffers, whole, the same bits) and against
+    ``write_kv`` + the composite (the tolerance the kernel has).
+    Returns what the fused call gave."""
+    idx = jnp.asarray(idx)
+    assert da.decode_attention_writes(q, k)
+    got = da.write_decode_attention(q, k_new, v_new, k, v, idx)
+    want = _write_then_attend(q, k_new, v_new, k, v, idx,
+                              da.decode_attention)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    ref = _write_then_attend(q, k_new, v_new, k, v, idx,
+                             da._decode_composite)[0]
+    np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                               np.asarray(ref, np.float32), rtol=0,
+                               atol=atol)
+    return got
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads", ["one_head_a_step", "all_heads_a_step"])
+@pytest.mark.parametrize("g,d", [(1, 128), (4, 128), (1, 64), (2, 64)],
+                         ids=["mha_128", "gqa4_128", "mha_64", "gqa2_64"])
+def test_fused_write_is_write_kv_then_the_kernel_bit_for_bit(
+        kernel, block_128, monkeypatch, g, d, heads, dtype):
+    """One slot at each position of ``_WRITE_AT`` in one call; of either
+    buffer no row but ``(b, :, idx[b], :)`` differs from what went in."""
+    cap, hkv = 512, 4
+    idx = np.array([at(block_128, cap) for at in _WRITE_AT.values()],
+                   np.int32)
+    if heads == "one_head_a_step":
+        monkeypatch.setattr(da, "_decode_tiling",
+                            lambda *shape: (1, block_128))
+    n = len(idx)
+    q, k_new, v_new, k, v = _fused_case(np.random.RandomState(3), n, hkv,
+                                        g, cap, d, dtype)
+    got = _assert_fused_is_its_two_calls(
+        q, k_new, v_new, k, v, idx, 4e-3 if dtype == jnp.bfloat16 else 2e-5)
+    written = np.zeros((n, cap), bool)
+    written[np.arange(n), idx] = True
+    for buf, before, new in ((got[1], k, k_new), (got[2], v, v_new)):
+        changed = (_bits(buf) != _bits(before)).any(axis=(1, 3))  # [B, S]
+        assert not (changed & ~written).any()
+        np.testing.assert_array_equal(
+            _bits(buf)[np.arange(n), :, idx], _bits(new))
+
+
+@pytest.mark.parametrize("at", list(_WRITE_AT))
+def test_fused_write_at_the_cells_block_and_capacity(kernel, at):
+    """The block the 1.3B cell runs (512 of 2048, 16 kv heads of 128 a
+    step, bf16): every slot of the call at one position of
+    ``_WRITE_AT`` (511 / 512 among them) but the last, which stays an
+    active slot mid-buffer."""
+    cap, hkv, d, n = 2048, 16, 128, 3
+    assert da._decode_tiling(hkv, cap, d, 2) == (16, 512)
+    idx = np.full(n, _WRITE_AT[at](512, cap), np.int32)
+    idx[-1] = 700
+    _assert_fused_is_its_two_calls(
+        *_fused_case(np.random.RandomState(5), n, hkv, 1, cap, d,
+                     jnp.bfloat16), idx, 4e-3)
+
+
 def test_one_trace_serves_every_lengths(kernel, block_128):
     """``lengths`` is an operand: no host value enters the call's shape,
     so a second array of lengths neither traces nor compiles again."""
@@ -239,6 +335,9 @@ def test_tick_span_counts_what_the_kernel_streams(churn):
     assert decode["decode_attention.bounded"] == {"kernel": 2,
                                                   "composite": 0}
     assert decode["decode_attention"] == {"kernel": 2, "composite": 0}
+    # and the kernel wrote the tick's token itself
+    assert decode["decode_attention.fused_write"] == {"kernel": 2,
+                                                      "composite": 0}
     fallbacks = lambda e: host.kernel_fallbacks(
         {"kind": "serve", "kernel_paths": e.kernel_paths},
         {"ops": ["flash_attention", "decode_attention"]})
@@ -246,6 +345,6 @@ def test_tick_span_counts_what_the_kernel_streams(churn):
     # the composite reads every slot whole, whatever it holds
     assert [t["kv_positions"] for t in composite_ticks] == need
     assert {t["kv_positions_read"] for t in composite_ticks} == {384}
-    assert "decode_attention.bounded" not in \
-        composite.kernel_paths[("decode", 0)]
+    assert not {"decode_attention.bounded", "decode_attention.fused_write"} \
+        & set(composite.kernel_paths[("decode", 0)])
     assert fallbacks(composite) == 2.0

@@ -119,6 +119,100 @@ def test_write_kv_stores_each_slot_at_its_own_position(window):
     np.testing.assert_array_equal(np.asarray(got_s), want_s)
 
 
+def _fused_writes():
+    return da.kernel_paths.counts().get(
+        "decode_attention.fused_write", {}).get("kernel", 0)
+
+
+def _view_case(rng, hkv, g, d, w, kv_dtype=None):
+    """(a DenseKVLayer of 5 slots x 256 positions, q, k, v of a window of
+    `w` tokens, the lengths before it): a slot at 0, round a key block's
+    edge, mid-buffer, and one at capacity, whose token is clamped to the
+    last position."""
+    from paddle_tpu.models.gpt import DenseKVLayer
+    cap = 256
+    lengths = np.array([0, 127, 128, 40, cap], np.int32)
+    n = len(lengths)
+    k_buf = jnp.asarray(rng.randn(n, hkv, cap, d) * 0.5, jnp.bfloat16)
+    v_buf = jnp.asarray(rng.randn(n, hkv, cap, d) * 0.5, jnp.bfloat16)
+    scales = ()
+    if kv_dtype:
+        k_buf, ks = qm.quantize_kv(k_buf.astype(jnp.float32))
+        v_buf, vs = qm.quantize_kv(v_buf.astype(jnp.float32))
+        scales = (ks, vs)
+    q = jnp.asarray(rng.randn(n, w, hkv * g, d) * 0.5, jnp.bfloat16)
+    k = jnp.asarray(rng.randn(n, w, hkv, d) * 0.5, jnp.bfloat16)
+    v = jnp.asarray(rng.randn(n, w, hkv, d) * 0.5, jnp.bfloat16)
+    return DenseKVLayer(k_buf, v_buf, *scales), q, k, v, \
+        jnp.asarray(lengths)
+
+
+def _assert_same_view(got, want):
+    (out, kv), (out_w, kv_w) = got, want
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(out_w, np.float32))
+    for name in ("k", "v", "k_scale", "v_scale"):
+        a, b = getattr(kv, name), getattr(kv_w, name)
+        assert (a is None) == (b is None)
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("hkv,g,d", [(4, 1, 128), (2, 2, 128), (4, 1, 64),
+                                     (2, 4, 64)],
+                         ids=["mha_128", "gqa2_128", "mha_64", "gqa4_64"])
+def test_dense_view_write_attend_is_its_two_halves(hkv, g, d):
+    """``DenseKVLayer.write_attend`` of one token a slot where the
+    kernel runs: ONE call that also writes (``kernel_paths`` notes it),
+    and the output and both buffers are what ``write`` then ``attend``
+    give, bit for bit, a slot at length 0 and a slot at capacity among
+    them."""
+    from paddle_tpu.models.gpt import KVLayerView
+    layer, q, k, v, lengths = _view_case(np.random.RandomState(7), hkv, g,
+                                         d, 1)
+    da.set_interpret_mode(True)
+    try:
+        before = _fused_writes()
+        got = layer.write_attend(q, k, v, lengths)
+        fused = _fused_writes() - before
+        want = KVLayerView.write_attend(layer, q, k, v, lengths)
+        unfused = _fused_writes() - before - fused
+    finally:
+        da.set_interpret_mode(None)
+    assert (fused, unfused) == (1, 0)
+    assert got[0].shape == q.shape
+    _assert_same_view(got, want)
+    at = np.minimum(np.asarray(lengths), layer.k.shape[2] - 1)
+    for buf, new in ((got[1].k, k), (got[1].v, v)):
+        np.testing.assert_array_equal(
+            np.asarray(buf, np.float32)[np.arange(len(at)), :, at],
+            np.asarray(new[:, 0], np.float32))
+
+
+@pytest.mark.parametrize("case", ["window", "int8", "off_the_chip"])
+def test_dense_view_keeps_write_then_attend_for_everything_else(case):
+    """A window of several tokens, a cache of 8-bit codes beside scale
+    planes, and a process without the kernel take the view's default,
+    ``write`` then ``attend``: the fused call is noted by none of them
+    and the results are the default's own."""
+    from paddle_tpu.models.gpt import KVLayerView
+    layer, q, k, v, lengths = _view_case(
+        np.random.RandomState(8), 2, 2, 64, 3 if case == "window" else 1,
+        "int8" if case == "int8" else None)
+    if case == "window":
+        lengths = jnp.minimum(lengths, layer.k.shape[2] - 3)
+    da.set_interpret_mode(None if case == "off_the_chip" else True)
+    try:
+        before = _fused_writes()
+        got = layer.write_attend(q, k, v, lengths)
+        want = KVLayerView.write_attend(layer, q, k, v, lengths)
+        assert _fused_writes() == before
+    finally:
+        da.set_interpret_mode(None)
+    _assert_same_view(got, want)
+
+
 def test_cache_is_one_head_major_buffer_per_layer():
     cfg = GPTConfig(vocab_size=97, hidden_size=64, num_layers=3,
                     num_heads=4, num_kv_heads=2, max_seq_len=32,
