@@ -82,7 +82,6 @@ _LAZY_MODULES = (
     "distributed", "vision", "text", "hapi", "callbacks", "profiler",
     "framework", "regularizer", "linalg", "distribution", "incubate",
     "utils", "models", "autograd", "extension", "onnx", "observability",
-    "autotune",
 )
 
 

@@ -1,6 +1,6 @@
 """Async dispatch plumbing: lazy step results + host-sync accounting.
 
-The dispatch-bound regime (BENCH_r05: 35% MFU with kernels that should
+The dispatch-bound regime (35% MFU with kernels that should
 do better) comes from the HOST side of the step loop: calling
 ``float(loss)`` after every compiled step serializes dispatch against
 device completion, so the host can never run ahead and queue work.  JAX's
@@ -11,8 +11,8 @@ This module is the read-back discipline:
 - :class:`StepResult` wraps the device scalar a compiled step returns.
   It *is not* the number — it becomes the number (one blocking host
   transfer) only when somebody calls ``float()`` / formats / compares
-  it.  ``hapi.Model.fit`` and ``bench.py`` force results only every
-  ``log_freq`` steps, so the steps in between are pure dispatch.
+  it.  ``hapi.Model.fit`` forces results only every ``log_freq``
+  steps, so the steps in between are pure dispatch.
 - :class:`LazyValue` defers an arbitrary zero-arg computation (metric
   ``accumulate()``) the same way.
 - a process-wide **sync counter**: every forced read-back increments it,

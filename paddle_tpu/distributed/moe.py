@@ -894,8 +894,8 @@ class MoELayer(Layer):
         """a2a chunk count for the serving dispatch: an explicit
         a2a_chunks must divide the per-device capacity slice c_loc (the
         chunks partition it); None resolves from the overlap knob
-        (PADDLE_TPU_MOE_A2A_CHUNKS / tuning-table op 'moe_a2a_chunks')
-        and clamps DOWN to the nearest divisor."""
+        (PADDLE_TPU_MOE_A2A_CHUNKS) and clamps DOWN to the nearest
+        divisor."""
         if self.a2a_chunks is not None:
             k = int(self.a2a_chunks)
             if k < 1 or c_loc % k:

@@ -61,49 +61,11 @@ from .mesh import (Mesh, NamedSharding, PartitionSpec, default_mesh,
                    compile_mesh_guard)
 
 __all__ = ["SpmdTrainer", "dp_train_step", "zero_sharding_spec",
-           "build_param_specs", "StepResult", "tuned_remat_policy",
-           "remat_policy_key"]
+           "build_param_specs", "StepResult"]
 
 
 def _is_floating(a) -> bool:
     return jnp.issubdtype(a.dtype, jnp.floating)
-
-
-def remat_policy_key(cfg):
-    """Tuning-table key for the measured remat-policy choice: the model
-    shape dims that move the save-dots-vs-full trade-off.  None when the
-    model carries no recognizable config."""
-    h = getattr(cfg, "hidden_size", None)
-    if not h:
-        return None
-    from ..utils import tuning as _tuning
-    return (_tuning.device_kind(), int(h),
-            int(getattr(cfg, "num_layers", 0) or 0),
-            int(getattr(cfg, "max_seq_len", 0) or 0))
-
-
-def tuned_remat_policy(model):
-    """The unified tuning table's measured remat policy (op
-    "remat_policy": 'dots_no_batch' / 'dots' / 'full', recorded by
-    bench.py's sweep winner) for this device + model shape — exact key
-    first, then the nearest tabled shape.  Entries recorded as
-    'off'/'none' mean the sweep's winner ran WITHOUT remat; a trainer
-    that was asked for remat ignores them (returns None).  None when
-    nothing applicable is tabled."""
-    cfg = getattr(model, "cfg", None)
-    key = remat_policy_key(cfg) if cfg is not None else None
-    if key is None:
-        return None
-    from ..utils import tuning as _tuning
-    # bounded nearest (each shape dim within ~2× overall): a policy
-    # measured on a 125m model must NOT silently drive remat for a
-    # multi-billion-param config — dots-saveable retains activations a
-    # bigger model may not have memory for
-    val = _tuning.lookup_nearest("remat_policy", key, match_idx=(0,),
-                                 near_idx=(1, 2, 3), max_dist=2.1)
-    if not isinstance(val, str) or val.lower() in ("off", "none", ""):
-        return None
-    return val
 
 
 def zero_sharding_spec(shape, base_spec: PartitionSpec, dp_axis: str,
@@ -200,7 +162,7 @@ class SpmdTrainer:
         from ..utils.compile_cache import ensure_compile_cache
         ensure_compile_cache()
 
-        # step-time breakdown (trainer.stats / bench JSON): where did the
+        # step-time breakdown (trainer.stats): where did the
         # wall clock go — waiting for data, placing it, dispatching the
         # compiled step, or blocked on a host sync.  compile_ms_cold is
         # the first-call cost per executable in THIS process (trace +
@@ -240,17 +202,11 @@ class SpmdTrainer:
         _flightrec.install()
         self.watchdog: Optional[_watchdog.Watchdog] = None
         self._wd_checked = False
-        # live autotune tier (PADDLE_TPU_AUTOTUNE=live) — ADVISORY on a
-        # trainer: train knobs retrace, so a sustained step-time
-        # regression ships doctor verdicts (flightrec event) instead of
-        # mutating config mid-run.  None when unarmed.
-        from ..autotune.live import arm_trainer as _arm_autotune
-        self._retuner = _arm_autotune(self)
 
         # collective breakdown (comm_ms/comm_fraction in trainer.stats):
         # opt-in — measuring it AOT-compiles each step executable a
         # second time, which the tight test/CI budgets cannot afford by
-        # default (bench/dryrun turn it on)
+        # default
         self._comm_enabled = bool(
             comm_stats if comm_stats is not None
             else os.environ.get("PADDLE_TPU_COMM_STATS") == "1")
@@ -371,17 +327,14 @@ class SpmdTrainer:
                     "enable_recompute(); wrap blocks with "
                     "paddle_tpu.distributed.recompute(...) instead")
             # honor recompute_configs['policy'] (selective save-dots etc.)
-            # defaulting to the unified tuning table's measured winner
-            # for this (device, model shape) when one exists (bench.py
-            # records the sweep's best remat policy there), then 'full'
-            # — full-segment remat, matching the reference's
-            # recompute_optimizer; models that predate the policy kwarg
+            # defaulting to 'full' — full-segment remat, matching the
+            # reference's recompute_optimizer; models that predate the policy kwarg
             # keep working (signature-checked, so a TypeError raised
             # INSIDE enable_recompute still propagates)
             import inspect
             pol = st.recompute_configs.get("policy")
             if pol is None:
-                pol = tuned_remat_policy(model) or "full"
+                pol = "full"
             sig = inspect.signature(model.enable_recompute)
             if "policy" in sig.parameters:
                 model.enable_recompute(policy=pol)
@@ -553,8 +506,7 @@ class SpmdTrainer:
         # component label (see _timed_call), and the resident training
         # state — params, optimizer state, buffers, grad-merge buffer —
         # is tracked in the ledger (host-side shape math; weakref'd so
-        # a torn-down bench candidate releases its accounting with its
-        # HBM)
+        # a torn-down trainer releases its accounting with its HBM)
         self.telemetry_label = f"s{next(_TRAINER_IDS)}"
         self._exec_component = f"trainer:{self.telemetry_label}"
         _exec_registry.track_bytes(
@@ -1157,8 +1109,6 @@ class SpmdTrainer:
             self._m_step_hist.observe(last)
         _flightrec.record("train_step", dur_ms=last,
                           step=self._step_count)
-        if self._retuner is not None:
-            self._retuner.on_step(last)
 
     # ---- multi-slice membership / in-memory elasticity ---------------
     def attach_membership(self, membership, guard=None):
@@ -1574,7 +1524,7 @@ class SpmdTrainer:
 
     @property
     def stats(self) -> dict:
-        """Resilience counters + step-time breakdown for logging/bench.
+        """Resilience counters + step-time breakdown for logging.
 
         Anomaly half: the active policy plus how many updates it
         discarded (skip: on-device counter; fp16: steps whose
@@ -1689,7 +1639,7 @@ class SpmdTrainer:
             if (self._comm and mean_step > 0) else None
         # executable observatory (ISSUE 15): per-kind roofline digest
         # for this trainer's executables — populated once the deferred
-        # analyses ran (bench, report CLI, exec_registry.analyze_all).
+        # analyses ran (report CLI, exec_registry.analyze_all).
         # Reading stats never compiles.
         s["exec_profile"] = _exec_registry.profile(self._exec_component)
         s["hbm"] = _exec_registry.ledger().snapshot()

@@ -22,8 +22,8 @@ moves 2x less gradient data.
 Numerics are untouched: the gather reconstructs the exact replicated
 weights, every per-token op inside the block is batch-local, and
 reduce-scatter + sharded-Adam-update is elementwise-equal to
-all-reduce + full-Adam-update on the same shard.  The parity tests and
-the multichip dryrun assert this against the synchronous stage-3 path.
+all-reduce + full-Adam-update on the same shard.  The parity tests
+assert this against the synchronous stage-3 path.
 """
 from __future__ import annotations
 

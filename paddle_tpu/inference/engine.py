@@ -10,8 +10,9 @@ same discipline for inference, built from three papers:
   executable appending a single token per slot and running the fused
   single-token attention kernel (``ops.decode_attention``) over the
   cache.  Nothing in the decode loop ever changes shape, so generating N
-  tokens costs ZERO new XLA compiles (the contract ``bench.py --serve
-  --smoke`` and the engine tests assert via utils.compile_counter).
+  tokens costs ZERO new XLA compiles (the contract the engine tests
+  assert via utils.compile_counter, and ``benchmark/run.py`` on every
+  serving run as ``compiles_in_window``).
 - Yu et al., *Orca*: **continuous batching** — the decode batch is a set
   of fixed ``batch_slots``; new requests are admitted into free slots
   BETWEEN decode steps, and finished requests retire their slot
@@ -108,30 +109,17 @@ __all__ = ["InferenceEngine", "Request", "default_prefill_buckets"]
 
 def default_prefill_buckets(max_seq_len: int, lo: int = 16) -> List[int]:
     """Powers of two in [lo, max_seq_len], always including max_seq_len.
-    ``PADDLE_TPU_PREFILL_BUCKETS="64,256,1024"`` overrides; between the
-    env and the powers-of-two default sits the unified tuning table
-    (utils.tuning, op "prefill_buckets", key (device_kind, max_seq_len))
-    so a bucket list tuned for a traffic mix persists across restarts."""
+    ``PADDLE_TPU_PREFILL_BUCKETS="64,256,1024"`` overrides."""
     env = os.environ.get("PADDLE_TPU_PREFILL_BUCKETS", "").strip()
     if env:
         bks = sorted({int(x) for x in env.split(",") if x.strip()})
     else:
-        bks = None
-        try:
-            from ..utils import tuning as _tuning
-            tuned = _tuning.lookup("prefill_buckets",
-                                   (_tuning.device_kind(), max_seq_len))
-            if tuned:
-                bks = sorted({int(x) for x in tuned})
-        except (ValueError, TypeError):
-            pass
-        if not bks:
-            bks = []
-            b = lo
-            while b < max_seq_len:
-                bks.append(b)
-                b *= 2
-            bks.append(max_seq_len)
+        bks = []
+        b = lo
+        while b < max_seq_len:
+            bks.append(b)
+            b *= 2
+        bks.append(max_seq_len)
     return [b for b in bks if b <= max_seq_len] or [max_seq_len]
 
 
@@ -556,11 +544,6 @@ class InferenceEngine:
         _flightrec.install()
         self.watchdog: Optional[_watchdog.Watchdog] = None
         self._wd_checked = False
-        # live autotune tier (PADDLE_TPU_AUTOTUNE=live): SLO-triggered,
-        # quiesce-gated prefill-bucket retuner — None when unarmed, and
-        # the tick hook below is a single attribute check
-        from ..autotune.live import arm_engine as _arm_autotune
-        self._retuner = _arm_autotune(self)
 
     # ---- paged layout setup -------------------------------------------
     def _init_paged(self, cache_dtype, kv_block_size, kv_num_blocks,
@@ -1796,10 +1779,6 @@ class InferenceEngine:
         for every active slot. Returns the number of tokens produced
         this step (admission prefills included)."""
         self._watchdog_beat()
-        if self._retuner is not None:
-            # runs a PENDING retune episode only on a quiesced replica
-            # (no active slots, empty queue); O(1) otherwise
-            self._retuner.on_tick()
         if self._profile is not None:
             # PADDLE_TPU_PROFILE=start:stop over DECODE TICKS
             self._profile.on_step(self._timings["decode_steps"])
@@ -2287,15 +2266,13 @@ class InferenceEngine:
         return released
 
     def set_prefill_chunk(self, chunk: int) -> bool:
-        """Hot-apply the chunked-prefill budget (autotune axis
-        ``prefill_chunk``, ISSUE 20).  The scheduler reads
+        """Hot-apply the chunked-prefill budget.  The scheduler reads
         ``self._chunked`` / ``self.prefill_chunk`` fresh every tick,
         so this is a host-side flag flip — no restart.  A chunk width
         never run before costs one executable compile, paid here when
-        the replica is quiesced (live-retune episodes always are) and
-        lazily at the next chunk tick otherwise.  Slots currently
-        mid-prefill pin the switch: returns False without changing
-        anything — retry after they graduate."""
+        the replica is quiesced and lazily at the next chunk tick
+        otherwise.  Slots currently mid-prefill pin the switch: returns
+        False without changing anything — retry after they graduate."""
         chunk = int(chunk)
         if chunk < 0:
             raise ValueError(f"prefill_chunk must be >= 0, got {chunk}")
@@ -2593,13 +2570,12 @@ class InferenceEngine:
         s["kv_layout"] = self.kv_layout
         s["kv_dtype"] = self.kv_dtype or "dense"
         # chunked prefill (ISSUE 20): mode + chunk size ride every
-        # snapshot (bench rows, loadgen reports, the doctor's
-        # 'prefill-stall' rule gates itself off when chunking is on)
+        # snapshot (loadgen reports, the doctor's 'prefill-stall'
+        # rule gates itself off when chunking is on)
         s["chunked_prefill"] = self._chunked
         s["prefill_chunk"] = self.prefill_chunk
         # pod-scale serving (ISSUE 18): tp degree + mesh layout ride
-        # every stats snapshot (and through it, bench rows + loadgen
-        # reports)
+        # every stats snapshot (and through it, loadgen reports)
         s["tp"] = self.tp_degree
         s["ep"] = self.ep_degree
         if self.mesh is not None:
@@ -2692,8 +2668,8 @@ class InferenceEngine:
             s["itl_ms_p99"] = round(float(p99), 3)
         # executable observatory (ISSUE 15): the per-kind roofline
         # digest for THIS engine's executables — populated once
-        # something ran the deferred analyses (bench legs, the report
-        # CLI, exec_registry.analyze_all); None until then.  Reading
+        # something ran the deferred analyses (the report CLI,
+        # exec_registry.analyze_all); None until then.  Reading
         # stats never compiles and never syncs.
         s["exec_profile"] = _exec_registry.profile(self._exec_component)
         s["hbm"] = _exec_registry.ledger().snapshot()

@@ -1,8 +1,8 @@
 """Poisson-arrival serving load harness.
 
-``bench.py --serve`` measures the engine under a CLOSED loop: every
-request is enqueued up front, so the queue is always full and the only
-number that comes out is peak throughput.  Real traffic is OPEN-loop —
+A CLOSED loop (``engine.run()`` over a full queue) measures the engine
+with every request enqueued up front, so the queue is always full and
+the only number that comes out is peak throughput.  Real traffic is OPEN-loop —
 requests arrive on their own clock whether or not the server keeps up —
 and the metrics that matter are the ones a user feels: time-to-first-
 token at the tail (p99), sustained tokens/sec, and how close the
@@ -91,8 +91,7 @@ def run_loadtest(engine, num_requests: int, rate_rps: float,
     workload = workload or SharedPrefixWorkload(
         getattr(engine.model.cfg, "vocab_size", 1 << 15), seed=seed)
     # cumulative engine counters are engine-LIFETIME; snapshot so the
-    # report describes THIS window even on a reused engine (the same
-    # snapshot-and-subtract bench.py uses for compile counters)
+    # report describes THIS window even on a reused engine
     t_snap = dict(engine._timings)
     _load0 = getattr(engine, "_moe_load", None)
     moe_load_snap = None if _load0 is None else _load0.copy()
@@ -200,8 +199,8 @@ def run_loadtest(engine, num_requests: int, rate_rps: float,
         "kv_layout": st["kv_layout"],
     }
     # SLO verdict over THIS window's corrected TTFTs (threshold from
-    # PADDLE_TPU_SLO_TTFT_P99_MS / the monitor, regression vs the bench
-    # history): the observability tentpole's rolling watch, reported —
+    # PADDLE_TPU_SLO_TTFT_P99_MS / the monitor, regression vs the
+    # monitor's baseline): the observability tentpole's rolling watch, reported —
     # never asserted — by the harness
     mon = slo_monitor or SLOMonitor()
     for t in ttfts:

@@ -362,7 +362,7 @@ class GPTConfig:
             if self.moe_num_experts > 0:
                 # the expert FFNs are raw einsums (distributed.moe), not
                 # parallel linears — they would silently stay full
-                # precision while bench reported quantize='int8'
+                # precision while the config said quantize='int8'
                 raise NotImplementedError(
                     f"quantize={self.quantize!r} COMPUTE with MoE is "
                     f"not supported: expert FFN matmuls (the dominant "
@@ -393,8 +393,8 @@ class GPTConfig:
         return int(total)
 
     def flops_per_token(self, seq_len=None):
-        """Model FLOPs per token (fwd+bwd, 6N + attention quadratic term)
-        — the MFU formula used by bench.py."""
+        """Model FLOPs per token (fwd+bwd, 6N + attention quadratic
+        term)."""
         s = seq_len or self.max_seq_len
         n = self.num_params(include_embeddings=False)
         return 6 * n + 12 * self.num_layers * self.hidden_size * s

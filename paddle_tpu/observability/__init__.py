@@ -22,7 +22,7 @@ One sink, four capabilities, every entry point feeds it:
   ``--trace 1`` reads (step markers, host phases, scope paths under
   ``tf_op``).
 - **slo** — fleet aggregation over engine replicas + a rolling SLO
-  monitor (threshold breaches, regression vs BENCH_rows.jsonl).
+  monitor (threshold breaches, regression vs the caller's baseline).
 - **flightrec** — always-on bounded black box: recent step/tick ring +
   event log dumped as an atomic post-mortem bundle (JSON + Chrome
   trace) on unhandled exception, SIGTERM, rollback, fault kill, stall.
@@ -32,7 +32,7 @@ One sink, four capabilities, every entry point feeds it:
 - **doctor** — rule-based bottleneck attribution over the stats the
   entry points already emit: ranked ``[{bottleneck, evidence, knob}]``
   verdicts in ``trainer.stats['doctor']`` / ``engine.stats['doctor']``
-  / bench rows / loadgen reports.
+  / loadgen reports.
 
 Invariants (proven in tests/test_telemetry.py): telemetry-on adds zero
 host syncs per decode tick and keeps the decode loop zero-recompile;
@@ -49,7 +49,7 @@ from .doctor import diagnose
 from .exec_registry import ExecRegistry, HBMLedger
 from .flightrec import FlightRecorder
 from .metrics import counter, gauge, histogram, parse_exposition, registry
-from .slo import FleetAggregator, SLOMonitor, load_bench_baseline
+from .slo import FleetAggregator, SLOMonitor
 from .spans import (export_chrome_trace, span, step_span, tracer,
                     validate_chrome_trace)
 from .watchdog import Watchdog, detect_stragglers
@@ -60,7 +60,7 @@ __all__ = [
     "span", "step_span", "tracer", "export_chrome_trace",
     "validate_chrome_trace",
     "ProfileWindow", "parse_profile_spec",
-    "FleetAggregator", "SLOMonitor", "load_bench_baseline",
+    "FleetAggregator", "SLOMonitor",
     "flightrec", "FlightRecorder", "watchdog", "Watchdog",
     "detect_stragglers", "doctor", "diagnose",
     "exec_registry", "ExecRegistry", "HBMLedger",

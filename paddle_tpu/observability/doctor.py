@@ -5,8 +5,8 @@ ROADMAP item 1 ends every hardware run the same way: a human stares at
 to turn next.  Every signal in that triage already exists in the stats
 surfaces PRs 3-13 built — this module is the triage itself, encoded:
 ``diagnose(stats)`` runs a fixed rule table over the numbers a trainer
-/ engine / bench row / loadgen report already carries and emits a
-RANKED verdict list::
+/ engine / loadgen report already carries and emits a RANKED verdict
+list::
 
     [{"bottleneck": "comm-bound",
       "evidence": {"comm_fraction": 0.41, "top_op": "all-reduce"},
@@ -18,12 +18,13 @@ Rules fire only on evidence present in the dict (a missing or None
 signal skips the rule — the doctor never invents a bottleneck), scores
 normalize each signal into [0, 1]-ish "fraction of the step this
 costs" so verdicts rank across rules, and the output is JSON-safe so
-it rides ``trainer.stats['doctor']``, ``engine.stats['doctor']``,
-every bench row and the loadgen report unchanged.
+it rides ``trainer.stats['doctor']``, ``engine.stats['doctor']`` and
+the loadgen report unchanged.
 
-This is attribution, not enforcement: the doctor REPORTS.  The bench
-smoke asserts only on deliberately-injected fixtures (a sync-heavy
-loop must read host-sync-bound; a clean one must read clean).
+This is attribution, not enforcement: the doctor REPORTS.  Its tests
+(tests/test_flightrec.py) assert only on deliberately-injected
+fixtures (a sync-heavy loop must read host-sync-bound; a clean one
+must read clean).
 """
 from __future__ import annotations
 
@@ -82,11 +83,11 @@ class Rule:
     dict ``{"op", "param", "env", "candidates"}`` — or a callable
     ``(stats, evidence) -> dict`` when the advice depends on the
     evidence (e.g. spec_k candidates below the CURRENT k).  ``op`` is
-    the tuning-table namespace a winner commits under (None for advice
-    with no table entry), ``param`` the config axis an autotune
-    controller mutates (None for purely behavioral advice), ``env`` the
-    equivalent environment knob, ``candidates`` the suggested trial
-    values ([] defers to the controller's own axis defaults)."""
+    the kernel or program decision the advice is about (None for advice
+    about none), ``param`` the config axis to change (None for purely
+    behavioral advice), ``env`` the equivalent environment knob (None
+    where there is none), ``candidates`` the suggested trial values
+    ([] where the rule has none to suggest)."""
 
     def __init__(self, bottleneck: str, kinds: tuple, knob: str,
                  check: Callable[[dict], Optional[tuple]],
@@ -156,7 +157,7 @@ def _h2d_bound(s: dict):
 
 def _host_sync_bound(s: dict):
     # preferred evidence: a measured sync count over a step window
-    # (bench rows / the smoke fixture carry host_syncs_measured+steps);
+    # (a caller that counted them passes host_syncs_measured+steps);
     # fallback: the trainer's cumulative sync wall-time share
     syncs = _num(s, "host_syncs_measured")
     steps = _num(s, "steps") or _num(s, "steps_timed")
@@ -180,8 +181,8 @@ def _host_sync_bound(s: dict):
 
 def _recompile_churn(s: dict):
     # only the POST-WARMUP delta is evidence (engine-lifetime compile
-    # counts legitimately include warmup); bench rows and the smokes
-    # carry it as xla_compiles_measured
+    # counts legitimately include warmup); a caller that counted it
+    # passes it as xla_compiles_measured
     n = _num(s, "xla_compiles_measured")
     if n is None or n <= 0:
         return None
@@ -395,7 +396,7 @@ def _mfu_action(s: dict, ev: dict) -> dict:
     recompute less (remat policy A/B) so the bytes drop."""
     if ev.get("bound") == "compute":
         return {"op": "qmm_tiles", "param": "quantize",
-                "env": "BENCH_QUANTIZE", "candidates": ["int8"]}
+                "env": None, "candidates": ["int8"]}
     return {"op": "remat_policy", "param": "remat_policy", "env": None,
             "candidates": ["off", "dots_no_batch", "dots", "full"]}
 
@@ -512,7 +513,7 @@ RULES: List[Rule] = [
          "in-memory mesh reform (lost-slice reshard); tune "
          "PADDLE_TPU_SLICE_HB_TIMEOUT_S for the detection window",
          _slice_unhealthy,
-         # behavioral/operational: no tuning-table axis moves this
+         # behavioral/operational: no config axis moves this
          action={"op": None, "param": None,
                  "env": "PADDLE_TPU_SLICE_HB_TIMEOUT_S",
                  "candidates": []}),
@@ -603,7 +604,7 @@ RULES: List[Rule] = [
          "(PADDLE_TPU_SPEC_K) to amortize the streamed bytes",
          _hbm_heavy_decode, action=_decode_bw_action),
     Rule("mfu-below-target", ("train",),
-         "compute-bound: quantize=int8 (BENCH_QUANTIZE) / flash "
+         "compute-bound: GPTConfig(quantize='int8') / flash "
          "attention / remat off; bandwidth-bound: larger batch / "
          "fused_ce / scan_layers — see exec_profile gap_share for the "
          "executable owning the gap",

@@ -11,7 +11,7 @@ executable, and this registry is also the scouting party for ROADMAP
 item 5's unified ``Executable`` abstraction: every entry point that
 compiles something (SpmdTrainer fused step, GPipeTrainer tick, engine
 prefill buckets, dense/paged decode, spec verify tick, disagg prefill
-worker, bench candidates) registers it here.
+worker) registers it here.
 
 Three pieces:
 
@@ -28,12 +28,11 @@ Three pieces:
   XLA ``cost_analysis`` / ``memory_analysis`` are EXPLICITLY deferred:
   ``analyze()`` AOT re-lowers the executable from the stored shape
   structs (a compile that the persistent cache serves as a deserialize)
-  — bench legs, the report CLI and tests arm it; the decode loop never
+  — the report CLI and tests arm it; the decode loop never
   pays it and never recompiles after warmup.  Owners are held by
   WEAKREF: a dead engine's entries degrade to timing-only instead of
-  pinning its params in HBM (bench candidate teardown relies on that).
-- **Roofline** — per-device-kind peak FLOP/s and HBM GB/s tables (the
-  bench.py device-kind lookup, extended with bandwidth).  A device
+  pinning its params in HBM.
+- **Roofline** — per-device-kind peak FLOP/s and HBM GB/s tables.  A device
   kind that is not in the tables has NO peak: asking for one raises
   UnknownDevicePeak, and a snapshot taken there carries timings and XLA
   figures without roofline fractions.  Each analyzed entry
@@ -83,8 +82,8 @@ OOM_HEADROOM_MIN = 0.08    # headroom fraction below which = oom risk
 
 # peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
 # NB: v5e's headline 394 TFLOPS is the INT8 number; bf16 peak is 197.
-# This is the authoritative copy of the table bench.py grew for MFU —
-# bench.peak_flops delegates here now.
+# (benchmark/peaks.json is the benchmark's own copy; this one serves the
+# package's roofline digests.)
 PEAK_FLOPS_BF16 = {
     "v5 lite": 197e12, "v5e": 197e12,
     "v5p": 459e12, "v5": 459e12,
@@ -399,8 +398,7 @@ class ExecRegistry:
                           compiled.as_text().count("tpu_custom_call")}
         # pod-scale serving (ISSUE 18): an entry that compiled against a
         # multi-device (sub)mesh folds in its collective traffic, split
-        # per MESH AXIS — the tp/dp attribution bench --serve rows and
-        # the doctor read.  Diagnostics only: any failure leaves the
+        # per MESH AXIS — the tp/dp attribution the doctor reads.  Diagnostics only: any failure leaves the
         # cost/memory analysis intact and counts in the failure metric.
         shape = ((e.meta or {}).get("submesh") or {}).get("shape") or {}
         if any(int(n) > 1 for n in shape.values()):
@@ -623,7 +621,7 @@ class ExecRegistry:
     def profile(self, component: str) -> Optional[dict]:
         """Per-kind roofline digest for one component — what
         ``trainer.stats['exec_profile']`` / ``engine.stats
-        ['exec_profile']`` / bench rows carry.  Pure dict math over
+        ['exec_profile']`` carry.  Pure dict math over
         ALREADY-analyzed entries (None when nothing is analyzed yet):
         reading stats never compiles."""
         if not any(e.analysis is not None

@@ -25,8 +25,8 @@ Python host process:
   Keep label cardinality small and monotone ids short-lived processes
   only.
 - Exposition: Prometheus text format (``exposition()``) plus a
-  round-trip parser (``parse_exposition``) so the bench smoke can PROVE
-  the output scrapes, and an atomic JSONL snapshot writer riding
+  round-trip parser (``parse_exposition``) so tests/test_telemetry.py
+  can PROVE the output scrapes, and an atomic JSONL snapshot writer riding
   ``framework.fs.open_for_write`` (fsync + tmp + rename — a crashed
   snapshot never truncates the history file).
 
@@ -422,7 +422,7 @@ def write_snapshot(path: Optional[str] = None,
 
 
 # ---------------------------------------------------------------------------
-# exposition parser (the bench smoke's round-trip proof)
+# exposition parser (the round-trip proof of tests/test_telemetry.py)
 # ---------------------------------------------------------------------------
 def _parse_labels(text: str) -> Dict[str, str]:
     out: Dict[str, str] = {}

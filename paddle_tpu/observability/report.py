@@ -4,15 +4,14 @@
 registry + HBM ledger + doctor verdicts as text tables — from a LIVE
 process is pointless (the process would have to be this one), so the
 CLI is an OFFLINE reader: point it at a snapshot JSONL file
-(``observability.write_snapshot``), a flight-recorder bundle dir, or a
-``BENCH_rows.jsonl``; with no arguments it tries the
-``PADDLE_TPU_METRICS`` path and then the newest flightrec bundle.  No
-accelerator is required — everything renders from the JSON.
+(``observability.write_snapshot``) or a flight-recorder bundle dir;
+with no arguments it tries the ``PADDLE_TPU_METRICS`` path and then the
+newest flightrec bundle.  No accelerator is required — everything
+renders from the JSON.
 
     python -m paddle_tpu.observability.report --snapshot metrics.jsonl
     python -m paddle_tpu.observability.report --bundle \
         /tmp/paddle_tpu_flightrec/flightrec-123-001-stall
-    python -m paddle_tpu.observability.report --rows BENCH_rows.jsonl
 
 Exit codes: 0 rendered something, 2 nothing to render.
 """
@@ -25,8 +24,7 @@ import sys
 from typing import List, Optional
 
 __all__ = ["render_executables", "render_hbm", "render_doctor",
-           "render_tuning", "render_snapshot", "load_snapshot_file",
-           "main"]
+           "render_snapshot", "load_snapshot_file", "main"]
 
 
 def _fmt_bytes(n) -> str:
@@ -163,31 +161,7 @@ def render_doctor(verdicts) -> str:
         ["bottleneck", "score", "evidence", "knob", "action"], rows)
 
 
-def render_tuning() -> str:
-    """The unified tuning table with provenance (ISSUE 16): every op's
-    entries from utils.tuning plus who committed each one (source /
-    run / measured improvement) — winners are auditable."""
-    from ..utils import tuning as _tuning
-    ops = _tuning.all_entries()
-    rows = []
-    for op in sorted(ops):
-        for key in sorted(ops[op]):
-            meta = _tuning.provenance(op, key) or {}
-            imp = meta.get("improvement")
-            rows.append([
-                op, "|".join(key), json.dumps(ops[op][key])[:40],
-                meta.get("source", "-"), meta.get("run", "-"),
-                f"+{imp * 100:.2f}%" if isinstance(imp, (int, float))
-                else "-"])
-    if not rows:
-        return (f"tuning table: empty "
-                f"({_tuning.tuning_path() or 'persistence off'})")
-    return (f"tuning table ({_tuning.tuning_path() or 'in-process'})\n"
-            + _table(["op", "key", "value", "source", "run",
-                      "improvement"], rows))
-
-
-def render_snapshot(rec: dict, doctor_rows: Optional[list] = None) -> str:
+def render_snapshot(rec: dict) -> str:
     """Render one full snapshot record ({'metrics', 'executables',
     'hbm', ...}) — the function the tests round-trip through."""
     from . import doctor as _doctor
@@ -206,9 +180,6 @@ def render_snapshot(rec: dict, doctor_rows: Optional[list] = None) -> str:
              if k in ("decode", "spec_verify")),
             default=0)
     parts += ["", render_doctor(_doctor.diagnose(stats))]
-    if doctor_rows:
-        parts += ["", "latest bench-row doctor:",
-                  render_doctor(doctor_rows)]
     ts = rec.get("ts")
     if ts:
         parts.insert(0, f"snapshot ts={ts}")
@@ -242,44 +213,16 @@ def _load_bundle(path: str) -> Optional[dict]:
         return None
 
 
-def _latest_rows_doctor(path: str) -> Optional[list]:
-    last = None
-    try:
-        with open(path, errors="replace") as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if isinstance(rec, dict) and isinstance(
-                        rec.get("doctor"), list):
-                    last = rec["doctor"]
-    except OSError:
-        return None
-    return last
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.observability.report",
         description="Render the executable observatory (registry + HBM "
-                    "ledger + doctor) from a snapshot file, flightrec "
-                    "bundle, or bench rows file — offline, no device.")
+                    "ledger + doctor) from a snapshot file or a "
+                    "flightrec bundle — offline, no device.")
     ap.add_argument("--snapshot", help="snapshot JSONL "
                     "(observability.write_snapshot output)")
     ap.add_argument("--bundle", help="flight-recorder bundle directory")
-    ap.add_argument("--rows", help="BENCH_rows.jsonl (renders the "
-                    "latest row's doctor verdicts alongside)")
-    ap.add_argument("--tuning", action="store_true",
-                    help="print the unified tuning table with "
-                         "provenance (source/run/improvement)")
     args = ap.parse_args(argv)
-
-    if args.tuning:
-        print("== paddle_tpu tuning table ==")
-        print(render_tuning())
-        if not (args.snapshot or args.bundle or args.rows):
-            return 0
 
     rec = None
     source = None
@@ -308,24 +251,15 @@ def main(argv=None) -> int:
             if bundles:
                 rec = _load_bundle(bundles[-1])
                 source = bundles[-1]
-    doctor_rows = _latest_rows_doctor(args.rows) if args.rows else None
-    if rec is None and doctor_rows is not None:
-        # --rows alone: render the latest bench row's doctor verdicts
-        # (the rows file carries no registry snapshot, so that is the
-        # whole report — still a report, not an error)
-        print(f"== paddle_tpu observatory report ({args.rows}) ==")
-        print("latest bench-row doctor:")
-        print(render_doctor(doctor_rows))
-        return 0
     if rec is None:
-        print("report: nothing to render — pass --snapshot/--bundle/"
-              "--rows (see --help)", file=sys.stderr)
+        print("report: nothing to render — pass --snapshot or --bundle "
+              "(see --help)", file=sys.stderr)
         return 2
 
     print(f"== paddle_tpu observatory report ({source}) ==")
     if rec.get("reason"):
         print(f"flightrec reason: {rec['reason']}")
-    print(render_snapshot(rec, doctor_rows=doctor_rows))
+    print(render_snapshot(rec))
     return 0
 
 

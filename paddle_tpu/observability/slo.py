@@ -10,16 +10,15 @@ The Router places requests; this module answers "is the fleet healthy":
   whole fleet.
 - :class:`SLOMonitor` keeps a rolling window of per-request TTFTs and
   flags (a) threshold breaches (p99 over the target) and (b)
-  REGRESSIONS against the bench history: ``BENCH_rows.jsonl`` rows are
-  the measured record of what this host could do — a live p99 far above
-  the best measured row means the deployment degraded, not the load.
+  REGRESSIONS against a baseline p99 the caller measured on this
+  deployment: a live p99 far above it means the deployment degraded,
+  not the load.
 
 Everything here is host-side dict reading — no device state, no syncs —
 so a monitor tick is safe inside a serving loop.
 """
 from __future__ import annotations
 
-import json
 import os
 from collections import deque
 from typing import Dict, List, Optional, Sequence
@@ -28,7 +27,7 @@ import numpy as np
 
 from . import metrics
 
-__all__ = ["FleetAggregator", "SLOMonitor", "load_bench_baseline"]
+__all__ = ["FleetAggregator", "SLOMonitor"]
 
 
 class FleetAggregator:
@@ -126,44 +125,9 @@ class FleetAggregator:
                 "straggler": detect_stragglers(tick_ms)}
 
 
-def load_bench_baseline(rows_path: Optional[str] = None,
-                        kind: str = "loadtest",
-                        field: str = "ttft_ms_p99") -> Optional[float]:
-    """Best (lowest) measured `field` among non-smoke `kind` rows in the
-    bench history file (default: BENCH_rows.jsonl next to bench.py —
-    i.e. the repo root).  None when no usable row exists."""
-    if rows_path is None:
-        rows_path = os.environ.get("BENCH_ROWS_FILE", "").strip() or \
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-                "BENCH_rows.jsonl")
-    best = None
-    # a missing, empty, unreadable, or CORRUPT history file all mean
-    # the same thing: no baseline.  Binary garbage raises
-    # UnicodeDecodeError during line iteration (not json.loads), and a
-    # monitor constructed inside a serving loop must never die on it.
-    try:
-        with open(rows_path, errors="replace") as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                if not isinstance(rec, dict) or rec.get("kind") != kind:
-                    continue
-                if "smoke" in str(rec.get("metric", "")):
-                    continue            # smoke rows are not a perf record
-                v = rec.get(field)
-                if isinstance(v, (int, float)) and \
-                        not isinstance(v, bool) and v > 0:
-                    best = v if best is None else min(best, v)
-    except (OSError, ValueError):
-        return None
-    return best
-
-
 class SLOMonitor:
-    """Rolling TTFT watch: threshold breaches + bench-history regression.
+    """Rolling TTFT watch: threshold breaches + regression against the
+    caller's baseline.
 
     observe() per finished request (FleetAggregator feeds it); check()
     computes the window p50/p99 and returns breach flags.  Cheap enough
@@ -172,23 +136,16 @@ class SLOMonitor:
     def __init__(self, ttft_p99_ms: Optional[float] = None,
                  window: int = 512,
                  regression_factor: float = 2.0,
-                 baseline_ttft_p99_ms: Optional[float] = None,
-                 rows_path: Optional[str] = None):
+                 baseline_ttft_p99_ms: Optional[float] = None):
         env = os.environ.get("PADDLE_TPU_SLO_TTFT_P99_MS", "").strip()
         if ttft_p99_ms is None and env:
             ttft_p99_ms = float(env)
         self.ttft_p99_ms = ttft_p99_ms
         self.regression_factor = float(regression_factor)
-        if baseline_ttft_p99_ms is None:
-            baseline_ttft_p99_ms = load_bench_baseline(rows_path)
         self.baseline_ttft_p99_ms = baseline_ttft_p99_ms
         self._window: deque = deque(maxlen=int(window))
         self.breaches = 0
         self.regressions = 0
-        # verdict listeners (ISSUE 16): every check() verdict is pushed
-        # to subscribers — the live autotune retuner's signal feed.  A
-        # listener exception must never take the serving loop down.
-        self._listeners: List = []
         self._g_p99 = metrics.gauge("slo_ttft_ms_p99",
                                     "rolling-window TTFT p99")
         self._g_p50 = metrics.gauge("slo_ttft_ms_p50",
@@ -226,15 +183,4 @@ class SLOMonitor:
             out["regressed"] = True
             self.regressions += 1
             self._c_breach.labels(kind="regression").inc()
-        for cb in self._listeners:
-            try:
-                cb(out)
-            except Exception:
-                pass
         return out
-
-    def add_listener(self, cb) -> "SLOMonitor":
-        """Subscribe ``cb(verdict_dict)`` to every check() result (e.g.
-        a LiveRetuner's ``notify_slo``). Returns self for chaining."""
-        self._listeners.append(cb)
-        return self

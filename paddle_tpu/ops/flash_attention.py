@@ -37,10 +37,7 @@ multiple of 128.
 from __future__ import annotations
 
 import functools
-import json
 import math
-import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -329,7 +326,7 @@ def _pick_block(s, want=256):
 
 
 # ---------------------------------------------------------------------------
-# block-size autotuning
+# block sizes
 #
 # The right tile trades the elements a causal loop visits for nothing
 # (a tile the diagonal crosses is computed whole: block / S of the
@@ -337,16 +334,12 @@ def _pick_block(s, want=256):
 # loop's turn, the running max and sum, the accumulator's rescale), and
 # the balance shifts with sequence length and head width.  The table
 # below holds ONLY what a chip measured: forward + backward of these
-# kernels timed alone on one TPU v5e over _SWEEP_CANDIDATES, bf16, at
+# kernels timed alone on one TPU v5e over tiles of 128 to 1024, bf16, at
 # the shapes the benchmark's cells run (PERF.md section 6, PR 39, has
 # every timing).  Other shapes take the nearest tabled sequence of
 # their (device, head_dim, causal) and finally the fixed defaults, and
 # every choice is clamped by _pick_block so a bad entry can never
 # produce an invalid grid.
-#
-# PADDLE_TPU_FLASH_AUTOTUNE: "1" (default) = table lookup,
-# "0" = fixed defaults, "sweep" = run a one-shot on-device sweep for
-# each new shape and cache it for the process (TPU only).
 # ---------------------------------------------------------------------------
 _DEFAULT_BLOCKS = (512, 512)
 
@@ -368,234 +361,48 @@ _AUTOTUNE_TABLE = {
     ("v5e", 128, 128, True): (128, 128),
 }
 
-_SWEEP_CACHE: dict = {}
-_SWEEP_CANDIDATES = (128, 256, 512, 1024)
-
-# On-disk persistence of the sweep table: an on-device sweep costs tens
-# of seconds of compile+measure per shape, so PADDLE_TPU_FLASH_AUTOTUNE=
-# sweep pays once per (device_kind, seq, head_dim, causal) ACROSS
-# processes, not once per run.  PADDLE_TPU_FLASH_AUTOTUNE_CACHE names the
-# legacy JSON file ("0"/"off"/unset: no file — the tables in the code
-# decide).  Sweep winners ALSO land in
-# the unified tuning table (utils.tuning, op "flash_blocks") — the
-# generalization of this cache that serves quantized-matmul tiles, MoE
-# a2a chunks and prefill buckets too; get_block_sizes consults it even
-# outside sweep mode, so a tuned shape from any prior process wins over
-# the built-in table.
-_SWEEP_STORE_STATE = {"loaded": False}
-
-
-def _sweep_store_path():
-    p = os.environ.get("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", "").strip()
-    if p.lower() in ("0", "off", "false", "none"):
-        return None
-    return os.path.expanduser(p) if p else None
-
-
-def _unified_table_enabled() -> bool:
-    """Mirror flash winners into (and serve lookups from) the unified
-    tuning table ONLY when the legacy env var is unset: an explicit
-    PADDLE_TPU_FLASH_AUTOTUNE_CACHE pins flash entries to exactly that
-    file (the documented pre-unification contract, and what keeps the
-    legacy round-trip tests hermetic)."""
-    return os.environ.get("PADDLE_TPU_FLASH_AUTOTUNE_CACHE") is None
-
-
-def _sweep_key_str(key) -> str:
-    kind, seq, d, causal = key
-    return f"{kind}|{seq}|{d}|{int(causal)}"
-
-
-def _load_sweep_store():
-    """Merge the on-disk sweep tables into the process cache (once);
-    entries this process already swept win over stale disk entries.
-    Reads the legacy flash_autotune.json first (it predates the unified
-    table, so existing deployments keep their winners), then the
-    unified tuning table's "flash_blocks" entries."""
-    if _SWEEP_STORE_STATE["loaded"]:
-        return
-    _SWEEP_STORE_STATE["loaded"] = True
-    path = _sweep_store_path()
-    if path:
-        try:
-            with open(path) as f:
-                data = json.load(f)
-            if isinstance(data, dict):
-                for k, v in data.items():
-                    parts = str(k).split("|")
-                    if len(parts) != 4:
-                        continue
-                    key = (parts[0], int(parts[1]), int(parts[2]),
-                           bool(int(parts[3])))
-                    _SWEEP_CACHE.setdefault(key, (int(v[0]), int(v[1])))
-        except (OSError, ValueError, TypeError, IndexError, KeyError):
-            pass  # corrupt/unreadable table: sweep again, rewrite it
-    if not _unified_table_enabled():
-        return
-    try:
-        from ..utils import tuning as _tuning
-        for parts, v in _tuning.entries("flash_blocks").items():
-            if len(parts) != 4:
-                continue
-            key = (parts[0], int(parts[1]), int(parts[2]),
-                   bool(int(parts[3])))
-            _SWEEP_CACHE.setdefault(key, (int(v[0]), int(v[1])))
-    except (ValueError, TypeError, IndexError, ImportError):
-        pass
-
-
-def _persist_sweep_entry(key, val):
-    """Atomic read-modify-write of the sweep table via
-    framework.fs.open_for_write (fsync before rename: a crash can never
-    commit a truncated table that silently re-costs the sweep);
-    best-effort.  Winners are mirrored into the unified tuning table so
-    every tuning consumer shares one store going forward."""
-    if _unified_table_enabled():
-        try:
-            from ..utils import tuning as _tuning
-            _tuning.record("flash_blocks", key, list(val))
-        except Exception:
-            pass
-    path = _sweep_store_path()
-    if not path:
-        return
-    try:
-        data = {}
-        try:
-            with open(path) as f:
-                loaded = json.load(f)
-            if isinstance(loaded, dict):
-                data = loaded
-        except (OSError, ValueError):
-            pass
-        data[_sweep_key_str(key)] = list(val)
-        from ..framework.fs import open_for_write
-        with open_for_write(path, "w") as f:
-            json.dump(data, f, indent=0, sort_keys=True)
-    except OSError:
-        pass
+# (what a device's kind holds, the table's name for it).  The table is
+# keyed by the short names; a chip gives the long one ("TPU v5 lite"),
+# and a miss here sends every tabled shape to _DEFAULT_BLOCKS without a
+# word.
+_KIND_ALIASES = (("v5 lite", "v5e"), ("v5litepod", "v5e"),
+                 ("v5e", "v5e"), ("v5p", "v5p"),
+                 ("v6 lite", "v6e"), ("v6e", "v6e"),
+                 ("v4", "v4"), ("v3", "v3"), ("v2", "v2"))
 
 
 def _normalize_kind(kind: str) -> str:
-    from ..utils import tuning as _tuning
-    return _tuning.normalize_kind(kind)
+    """Canonical short device kind ('TPU v5 lite' -> 'v5e', ...)."""
+    k = (kind or "").lower()
+    for alias, canon in _KIND_ALIASES:
+        if alias in k:
+            return canon
+    return k
 
 
 def _device_kind() -> str:
-    from ..utils import tuning as _tuning
-    return _tuning.device_kind()
+    """Normalized kind of the local default device ('' when unknown)."""
+    try:
+        return _normalize_kind(getattr(jax.devices()[0], "device_kind", ""))
+    except Exception:  # pragma: no cover
+        return ""
 
 
 def get_block_sizes(seq: int, head_dim: int, causal: bool,
                     device_kind: str | None = None):
-    """(block_q, block_k) for this shape: sweep cache > env override >
-    table (exact, then nearest tabled seq) > fixed defaults. Always
-    clamped to divide seq."""
+    """(block_q, block_k) for this shape: the table's entry for the
+    nearest tabled seq (the shape's own, where it is tabled) of the same
+    kind, width and causality, else the fixed defaults. Always clamped
+    to divide seq."""
     kind = _normalize_kind(device_kind) if device_kind is not None \
         else _device_kind()
-    key = (kind, seq, head_dim, bool(causal))
-    mode = os.environ.get("PADDLE_TPU_FLASH_AUTOTUNE", "1")
-    if mode == "0":
-        bq, bk = _DEFAULT_BLOCKS
-        return _pick_block(seq, bq), _pick_block(seq, bk)
-    if key in _SWEEP_CACHE:
-        return _SWEEP_CACHE[key]
-    # unified tuning table (utils.tuning): a shape swept by ANY prior
-    # process serves here without re-arming sweep mode
-    if _unified_table_enabled():
-        try:
-            from ..utils import tuning as _tuning
-            tuned = _tuning.lookup("flash_blocks", key)
-            if tuned is not None:
-                bq, bk = int(tuned[0]), int(tuned[1])
-                return _pick_block(seq, bq), _pick_block(seq, bk)
-        except (ValueError, TypeError, IndexError):
-            pass
-    # sweep only tunes THIS process's device: an explicit foreign
-    # device_kind would re-run the sweep forever (the cache is keyed by
-    # the local kind) and return tiles tuned for the wrong chip
-    if (mode == "sweep" and kind == _device_kind()
-            and kind.startswith(("v2", "v3", "v4", "v5", "v6"))):
-        # a previous process may have paid for this sweep already
-        _load_sweep_store()
-        if key in _SWEEP_CACHE:
-            return _SWEEP_CACHE[key]
-        try:
-            return autotune_sweep(seq, head_dim, causal)
-        except Exception:  # sweep is best-effort; fall through to table
-            pass
-    if key in _AUTOTUNE_TABLE:
-        bq, bk = _AUTOTUNE_TABLE[key]
-        return _pick_block(seq, bq), _pick_block(seq, bk)
-    # nearest tabled sequence for the same (kind, head_dim, causal) —
-    # SWEPT entries (process cache / legacy file / unified tuning
-    # table, all merged by _load_sweep_store) count alongside the
-    # built-ins, so a sweep at seq 2048 serves seq 1920 too instead of
-    # dropping to the fixed defaults; swept entries come first so they
-    # win distance ties against the shipped table
-    _load_sweep_store()
-    near = [(s, v) for (k, s, d, c), v in _SWEEP_CACHE.items()
+    near = [(s, v) for (k, s, d, c), v in _AUTOTUNE_TABLE.items()
             if k == kind and d == head_dim and c == bool(causal)]
-    near += [(s, v) for (k, s, d, c), v in _AUTOTUNE_TABLE.items()
-             if k == kind and d == head_dim and c == bool(causal)]
     if near:
         _, (bq, bk) = min(near, key=lambda sv: abs(sv[0] - seq))
     else:
         bq, bk = _DEFAULT_BLOCKS
     return _pick_block(seq, bq), _pick_block(seq, bk)
-
-
-def autotune_sweep(seq: int, head_dim: int, causal: bool, batch: int = 1,
-                   heads: int = 4, iters: int = 5):
-    """One-shot on-device sweep: time fwd+bwd for each candidate tile on
-    a representative bf16 problem, cache the winner for the process.
-    Called on TPU only (interpret-mode timings are meaningless)."""
-    import numpy as np
-    kind = _device_kind()
-    key = (kind, seq, head_dim, bool(causal))
-    rng = np.random.RandomState(0)
-    q4 = jnp.asarray(rng.randn(batch * heads, 1, seq, head_dim)
-                     .astype(np.float32) * 0.1, dtype=jnp.bfloat16)
-    k3 = jnp.asarray(rng.randn(batch * heads, seq, head_dim)
-                     .astype(np.float32) * 0.1, dtype=jnp.bfloat16)
-    v3 = jnp.asarray(rng.randn(batch * heads, seq, head_dim)
-                     .astype(np.float32) * 0.1, dtype=jnp.bfloat16)
-    mask = None     # the body every cell runs: no key mask
-
-    def step_time(bq, bk):
-        fwd = jax.jit(functools.partial(
-            _fwd_gqa, causal=causal, block_q=bq, block_k=bk))
-        bwd = jax.jit(functools.partial(
-            _bwd_gqa, causal=causal, block_q=bq, block_k=bk))
-        o4, lse = fwd(q4, k3, v3, mask)
-        outs = bwd(q4, k3, v3, mask, o4, lse, o4)
-        jax.block_until_ready(outs)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            o4, lse = fwd(q4, k3, v3, mask)
-            outs = bwd(q4, k3, v3, mask, o4, lse, o4)
-        jax.block_until_ready(outs)
-        return (time.perf_counter() - t0) / iters
-
-    best, best_t = _DEFAULT_BLOCKS, None
-    for bq in _SWEEP_CANDIDATES:
-        for bk in _SWEEP_CANDIDATES:
-            if bq > seq or bk > seq or seq % bq or seq % bk:
-                continue
-            # [bq, bk] f32 score tile + k/v strips must fit VMEM (~16MB)
-            vmem = 4 * bq * bk * 3 + 2 * seq * head_dim * 4
-            if vmem > 12 * 2**20:
-                continue
-            try:
-                t = step_time(bq, bk)
-            except Exception:
-                continue  # tile rejected by the compiler: skip
-            if best_t is None or t < best_t:
-                best, best_t = (bq, bk), t
-    best = (_pick_block(seq, best[0]), _pick_block(seq, best[1]))
-    _SWEEP_CACHE[key] = best
-    _persist_sweep_entry(key, best)
-    return best
 
 
 _SCOPED_VMEM = 16 * 2 ** 20     # what a kernel may use unless it asks

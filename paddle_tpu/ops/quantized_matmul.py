@@ -1,6 +1,6 @@
 """AQT-style int8 (fp8-ready) quantized matmul + KV-cache quantization.
 
-The bench trajectory stalled at ~35% MFU with the step time dominated by
+Training stalled at ~35% MFU with the step time dominated by
 bf16 matmul FLOPs and, on the serving side, by KV bytes streamed from
 HBM.  Both halve under 8-bit arithmetic — the v5e MXU runs int8 at 2×
 the bf16 rate, and an int8 KV cache moves half the bytes per decode
@@ -13,11 +13,10 @@ lives in ops/decode_attention.py + the cache classes):
   ``quantize_kv`` scales per (position, head) over the trailing
   head_dim axis — the granularity the decode kernels dequantize at.
 - :func:`quantized_matmul` — y ≈ (q_x · q_w) · s_x · s_w.  A Pallas TPU
-  kernel (int8 MXU dots, int32 accumulation, f32 rescale; tile sizes
-  from the unified tuning table) with an XLA ``dot_general`` composite
-  fallback that is the CPU parity oracle: the int8 path accumulates in
-  int32 (exact — f32 would lose bits past 2^24), the fp8 path in f32
-  via ``preferred_element_type``.
+  kernel (int8 MXU dots, int32 accumulation, f32 rescale) with an XLA
+  ``dot_general`` composite fallback that is the CPU parity oracle: the
+  int8 path accumulates in int32 (exact — f32 would lose bits past
+  2^24), the fp8 path in f32 via ``preferred_element_type``.
 - :func:`fake_quant_matmul` — the AQT-style training op: forward runs
   the quantized matmul, backward is the straight-through estimator
   (grads flow through the DEQUANTIZED operands as if quantization were
@@ -51,7 +50,7 @@ __all__ = ["quantized_matmul", "quantized_matmul_available",
            "fake_quant_matmul", "quantize_channel", "quantize_kv",
            "dequantize_kv", "kv_storage_dtype", "kv_quant_supported",
            "kv_quant_mode", "resolve_kv_quant", "get_qmm_tiles",
-           "autotune_qmm_sweep", "QUANT_DTYPES"]
+           "QUANT_DTYPES"]
 
 QUANT_DTYPES = ("int8", "fp8")
 _EPS = 1e-8
@@ -194,54 +193,19 @@ def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, *, block_k: int):
         .astype(o_ref.dtype)
 
 
-def get_qmm_tiles(m: int, n: int, k: int, dtype: str = "int8"):
+def get_qmm_tiles(m: int, n: int, k: int):
     """(block_m, block_n, block_k) for the quantized-matmul kernel:
-    unified tuning table first (op "qmm_tiles", keyed by the shape
-    bucket), then — with PADDLE_TPU_TUNING=sweep on a real TPU — a
-    one-shot on-device sweep recorded back into the table, then
-    defaults clamped to divide the problem.  The m key is bucketed to
-    its power of two so one tuned entry serves every batch in its size
-    class."""
-    from ..utils import tuning as _tuning
-    m_bucket = 1
-    while m_bucket * 2 <= m:
-        m_bucket *= 2
-    key = (_tuning.device_kind(), m_bucket, n, k, dtype)
-    tuned = _tuning.lookup("qmm_tiles", key)
-    if tuned is None and dtype == "int8" and _tuning.sweep_enabled() \
-            and not _fa._INTERPRET:
-        try:
-            import jax as _jax
-            if _jax.default_backend() == "tpu":
-                tuned = autotune_qmm_sweep(m_bucket, n, k)
-        except Exception:   # sweep is best-effort; fall through
-            tuned = None
-    if tuned is None:
-        # nearest tabled shape for the same (device, dtype) — a sweep
-        # at one (m, n, k) should serve its size class, not leave every
-        # off-by-a-bucket shape on hard defaults (the flash autotuner's
-        # nearest-seq behaviour); _pick_block clamps whatever comes
-        # back, so a mismatched entry can never yield an invalid grid
-        tuned = _tuning.lookup_nearest("qmm_tiles", key,
-                                       match_idx=(0, 4),
-                                       near_idx=(1, 2, 3))
-    if tuned is not None:
-        try:
-            bm, bn, bk = (int(tuned[0]), int(tuned[1]), int(tuned[2]))
-            return (_fa._pick_block(m, bm), _fa._pick_block(n, bn),
-                    _fa._pick_block(k, bk))
-        except (ValueError, TypeError, IndexError):
-            pass
+    defaults clamped to divide the problem."""
     # defaults sized for the MXU: [bm, K]+[K, bn] int8 strips + the
     # [bm, bn] int32 accumulator stay well under VMEM at K ≤ 8192
     return (_fa._pick_block(m, 256), _fa._pick_block(n, 256),
             _fa._pick_block(k, 512))
 
 
-def _qmm_pallas(qx, qw, sx, sw, out_dtype, dtype, tiles=None):
+def _qmm_pallas(qx, qw, sx, sw, out_dtype):
     m, k = qx.shape
     n = qw.shape[1]
-    bm, bn, bk = tiles or get_qmm_tiles(m, n, k, dtype)
+    bm, bn, bk = get_qmm_tiles(m, n, k)
     kernel = functools.partial(_qmm_kernel, block_k=bk)
     call = pl.pallas_call(
         kernel,
@@ -278,7 +242,7 @@ def _qmm_forward(x, w, dtype, out_dtype):
                  and k % 128 == 0)
     if supported and quantized_matmul_available():
         kernel_paths.note("quantized_matmul", "kernel")
-        y = _qmm_pallas(qx, qw, sx, sw, out_dtype, dtype)
+        y = _qmm_pallas(qx, qw, sx, sw, out_dtype)
     else:
         kernel_paths.note_composite("quantized_matmul", supported)
         y = _qmm_composite(qx, qw, sx, sw, out_dtype)
@@ -293,52 +257,6 @@ def quantized_matmul(x, w, dtype: str = "int8", out_dtype=None):
     the composite is the parity oracle the kernel is tested against."""
     y, *_ = _qmm_forward(x, w, dtype, out_dtype or x.dtype)
     return y
-
-
-def autotune_qmm_sweep(m: int, n: int, k: int, iters: int = 5):
-    """One-shot on-device sweep over candidate int8 tiles for this
-    shape; the winner lands in the unified tuning table (op
-    "qmm_tiles") so every later process skips the sweep.  TPU only —
-    interpret-mode timings are meaningless."""
-    import time
-
-    import numpy as np
-
-    from ..utils import tuning as _tuning
-    key = (_tuning.device_kind(), m, n, k, "int8")
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(m, k).astype(np.float32) * 0.1)
-    w = jnp.asarray(rng.randn(k, n).astype(np.float32) * 0.1)
-    qx, sx = quantize_channel(x, axis=1)
-    qw, sw = quantize_channel(w, axis=0)
-
-    best, best_t = None, None
-    for bm in (64, 128, 256, 512):
-        for bn in (128, 256, 512):
-            for bk in (128, 256, 512, 1024):
-                if m % bm or n % bn or k % bk or bm > m or bn > n \
-                        or bk > k:
-                    continue
-                # int8 x/w strips + the int32 accumulator must fit VMEM
-                if bm * k + k * bn + 4 * bm * bn > 12 * 2**20:
-                    continue
-                try:
-                    fn = jax.jit(functools.partial(
-                        _qmm_pallas, out_dtype=jnp.float32,
-                        dtype="int8", tiles=(bm, bn, bk)))
-                    jax.block_until_ready(fn(qx, qw, sx, sw))
-                    t0 = time.perf_counter()
-                    for _ in range(iters):
-                        out = fn(qx, qw, sx, sw)
-                    jax.block_until_ready(out)
-                    t = (time.perf_counter() - t0) / iters
-                except Exception:
-                    continue            # tile rejected by the compiler
-                if best_t is None or t < best_t:
-                    best, best_t = (bm, bn, bk), t
-    if best is not None:
-        _tuning.record("qmm_tiles", key, list(best))
-    return best
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 Cold compile of a trainer or a serving engine is tens of seconds to
 minutes; JAX's persistent compilation cache (``jax_compilation_cache_dir``)
 lets the next process deserialize instead.  This module turns it on for
-paddle_tpu trainers, engines, the bench and the test suite:
+paddle_tpu trainers, engines, the benchmark and the test suite:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set (or ``jax_compilation_cache_dir``
   already configured): the cache is there and nothing here sets another;

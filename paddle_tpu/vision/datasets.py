@@ -3,7 +3,7 @@ FashionMNIST, Cifar10/100, Flowers, ImageFolder/DatasetFolder).
 
 Zero-egress environments (this one) can't download; each dataset reads
 the standard local file formats when present and otherwise raises with a
-clear message. `SyntheticMNIST`-style deterministic data for tests/bench
+clear message. `SyntheticMNIST`-style deterministic data for tests
 is available via `mode='synthetic'` or FakeData."""
 from __future__ import annotations
 

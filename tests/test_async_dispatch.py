@@ -11,14 +11,8 @@ The pipelined step loop's contracts:
 - Model.fit performs at most ONE blocking host sync per log_freq window
   (counted, not eyeballed);
 - the persistent XLA compile cache serves a warm second compile on the
-  CPU backend;
-- the flash autotune sweep table persists across (simulated) processes;
-- `python bench.py --smoke` holds the whole contract end to end.
+  CPU backend.
 """
-import json
-import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -33,7 +27,6 @@ from paddle_tpu.distributed.async_dispatch import LazyValue, StepResult
 from paddle_tpu.io import DataLoader
 from paddle_tpu.io.device_prefetch import DevicePrefetcher
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def make_mlp(seed):
@@ -394,60 +387,3 @@ def test_accuracy_update_stays_on_device():
     ref_pre = ref.compute(Tensor(np.asarray(logits)), labels)
     ref.update(np.asarray(ref_pre.data))
     assert m.accumulate() == pytest.approx(ref.accumulate())
-
-
-# ---------------------------------------------------------------------------
-# flash autotune sweep table persistence
-# ---------------------------------------------------------------------------
-def _flash_mod():
-    # paddle_tpu.ops re-exports flash_attention the FUNCTION; fetch the
-    # module itself
-    import importlib
-    return importlib.import_module("paddle_tpu.ops.flash_attention")
-
-
-def test_autotune_sweep_table_roundtrip(tmp_path, monkeypatch):
-    fa = _flash_mod()
-    path = tmp_path / "flash_autotune.json"
-    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", str(path))
-    key = ("v5e", 2048, 64, True)
-    fa._persist_sweep_entry(key, (256, 512))
-    assert json.loads(path.read_text()) == {"v5e|2048|64|1": [256, 512]}
-
-    # a "new process": empty in-memory cache, unloaded store
-    monkeypatch.setattr(fa, "_SWEEP_STORE_STATE", {"loaded": False})
-    monkeypatch.setattr(fa, "_SWEEP_CACHE", {})
-    fa._load_sweep_store()
-    assert fa._SWEEP_CACHE[key] == (256, 512)
-
-    # corrupt table: ignored, never raises
-    path.write_text("{not json")
-    monkeypatch.setattr(fa, "_SWEEP_STORE_STATE", {"loaded": False})
-    monkeypatch.setattr(fa, "_SWEEP_CACHE", {})
-    fa._load_sweep_store()
-    assert fa._SWEEP_CACHE == {}
-
-
-def test_autotune_cache_env_off(monkeypatch):
-    fa = _flash_mod()
-    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", "off")
-    assert fa._sweep_store_path() is None
-    fa._persist_sweep_entry(("v5e", 1024, 64, True), (128, 128))  # no-op
-
-
-# ---------------------------------------------------------------------------
-# bench --smoke: the dispatch-path contract, end to end
-# ---------------------------------------------------------------------------
-@pytest.mark.slow  # tier-1 wall budget: heaviest in file
-def test_bench_smoke_contract():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.run([sys.executable, "bench.py", "--smoke"], cwd=REPO,
-                       capture_output=True, text=True, timeout=580,
-                       env=env)
-    assert p.returncode == 0, p.stderr[-2000:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "bench_smoke" and out["ok"]
-    for k in ("data_wait_ms", "h2d_ms", "dispatch_ms", "sync_ms",
-              "compile_ms_cold", "compile_ms_warm"):
-        assert k in out, k
-    assert out["host_syncs_measured"] <= 1

@@ -219,7 +219,7 @@ def test_int8_matmul(compile_for_chip):
     m, k, n = 2048, 2048, 8192
 
     def run(qx, qw, sx, sw):
-        return qm._qmm_pallas(qx, qw, sx, sw, bf16, "int8")
+        return qm._qmm_pallas(qx, qw, sx, sw, bf16)
 
     assert_kernel(compile_for_chip(
         run, ((m, k), i8), ((k, n), i8), ((m, 1), f32), ((1, n), f32)))

@@ -353,15 +353,10 @@ def test_hol_blocked_head_not_reprobed(target, chunk):
 # ---- hot-apply + observability ------------------------------------------
 
 def test_set_prefill_chunk_hot_apply(target, prompts, reference):
-    """The autotune axis's hot-apply: flipping a warmed monolithic
-    engine into chunked mode is a host-side switch whose one-time chunk
-    compile lands at apply time — the traffic window after it stays
-    compile-free and token-identical."""
-    from paddle_tpu.autotune.knobs import axis_for
-    ax = axis_for("prefill_chunk")
-    assert ax is not None and ax.hot_apply
-    assert ax.env == "PADDLE_TPU_CHUNKED_PREFILL"
-
+    """Flipping a warmed monolithic engine into chunked mode is a
+    host-side switch whose one-time chunk compile lands at apply time —
+    the traffic window after it stays compile-free and
+    token-identical."""
     eng = InferenceEngine(target, batch_slots=2, prefill_buckets=[16])
     eng.warmup(buckets=eng.buckets)
     assert eng.set_prefill_chunk(4)

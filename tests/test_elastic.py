@@ -17,8 +17,7 @@ Done criteria exercised here:
   clamps the devices create_mesh sees, PADDLE_FAULT_FS_DELAY_MS injects
   write jitter;
 - kill-and-resume onto a SHRUNK mesh reproduces the uninterrupted loss
-  curve end to end (subprocess tests; the dp variant also rides
-  `bench.py --multichip-smoke`'s elastic phase);
+  curve end to end (subprocess tests);
 - CheckpointManager surfaces background commit failures (on_error /
   wait timeout), and the InferenceEngine drains gracefully and enforces
   per-request deadlines.

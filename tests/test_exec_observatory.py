@@ -401,18 +401,12 @@ def test_report_cli_exit_codes(tmp_path, capsys):
     assert report.main(["--snapshot", str(garbage)]) == 2
 
 
-def test_report_cli_rows_only_renders_doctor(tmp_path, capsys):
-    # the documented `--rows BENCH_rows.jsonl` standalone invocation
-    rows = tmp_path / "rows.jsonl"
-    rows.write_text(json.dumps({
-        "kind": "train", "mfu": 0.35,
-        "doctor": [{"bottleneck": "comm-bound",
-                    "evidence": {"comm_fraction": 0.4},
-                    "knob": "PADDLE_TPU_OVERLAP=1", "score": 0.4}],
-    }) + "\n")
-    assert report.main(["--rows", str(rows)]) == 0
-    out = capsys.readouterr().out
-    assert "comm-bound" in out and "PADDLE_TPU_OVERLAP" in out
+def test_report_cli_with_nothing_to_render(tmp_path, capsys, monkeypatch):
+    # no arguments, no PADDLE_TPU_METRICS file, no flightrec bundle
+    monkeypatch.delenv("PADDLE_TPU_METRICS", raising=False)
+    monkeypatch.setenv("PADDLE_TPU_FLIGHTREC_DIR", str(tmp_path / "none"))
+    assert report.main([]) == 2
+    assert "nothing to render" in capsys.readouterr().err
 
 
 def test_ledger_oom_flag_agrees_with_doctor_threshold(monkeypatch):

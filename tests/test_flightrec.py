@@ -445,7 +445,7 @@ def test_doctor_field_rides_trainer_and_engine_stats():
                           prefill_buckets=[16])
     eng.warmup(buckets=[16])
     assert isinstance(eng.stats["doctor"], list)
-    # JSON-safe: the stats consumer (bench row persist) dumps it
+    # JSON-safe: a stats consumer dumps it
     json.dumps(tr.stats["doctor"])
     json.dumps(eng.stats["doctor"])
 
@@ -481,6 +481,43 @@ def test_doctor_and_straggler_in_loadgen_reports():
     assert len(frep["straggler"]["per_replica_ms"]) == 2
     json.dumps(frep["doctor"])
     json.dumps(frep["straggler"])
+
+
+# ---- a verdict's action: plain data beside the knob's sentence ---------
+
+def test_every_rule_carries_an_action():
+    for rule in doctor.RULES:
+        assert rule.action is not None, rule.bottleneck
+
+
+def test_doctor_verdicts_carry_structured_actions():
+    v = doctor.diagnose({"comm_fraction": 0.4}, "train")
+    assert v and v[0]["bottleneck"] == "comm-bound"
+    a = v[0]["action"]
+    assert a == {"op": "moe_a2a_chunks", "param": "moe_a2a_chunks",
+                 "env": "PADDLE_TPU_MOE_A2A_CHUNKS",
+                 "candidates": [1, 2, 4, 8]}
+
+
+def test_spec_k_action_candidates_halve_below_current():
+    v = doctor.diagnose({"spec_acceptance_rate": 0.1, "spec_k": 8},
+                        "serve")
+    top = [x for x in v if x["bottleneck"] == "low-spec-acceptance"][0]
+    assert top["action"]["candidates"] == [4, 2, 1]
+
+
+def test_behavioral_action_has_no_param():
+    v = doctor.diagnose({"host_syncs_measured": 40, "steps": 10},
+                        "train")
+    top = [x for x in v if x["bottleneck"] == "host-sync-bound"][0]
+    assert top["action"]["param"] is None
+
+
+def test_render_doctor_shows_action_column():
+    from paddle_tpu.observability.report import render_doctor
+    out = render_doctor(doctor.diagnose({"comm_fraction": 0.4}, "train"))
+    assert "action" in out
+    assert "moe_a2a_chunks in [1,2,4,8] ->moe_a2a_chunks" in out
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
 """PR-1 step-time performance pass: blocked cross-entropy parity,
-flash-attention autotuner lookup, scan-over-layers parity.
+flash-attention block-size lookup, scan-over-layers parity.
 
 The contract under test (ISSUE 1): the fused LM loss must match
 `cross_entropy` values AND gradients without ever materializing the
-[N, V] logits tensor; the autotuner must return tabled tiles with a
-safe fallback; the scanned block stack must be numerically identical
+[N, V] logits tensor; the block-size lookup must return tabled tiles
+with a safe fallback, whatever files and switches the machine holds; the scanned block stack must be numerically identical
 to the unrolled loop (loss + grads) both standalone and through
 SpmdTrainer's recompute_configs={'scan_layers': True} knob.
 """
@@ -140,7 +140,7 @@ def test_pick_vocab_block():
 
 
 # ---------------------------------------------------------------------------
-# flash-attention block-size autotuner
+# flash-attention block sizes
 # ---------------------------------------------------------------------------
 def test_autotune_table_exact_hit():
     assert get_block_sizes(2048, 64, True, device_kind="v5e") == (512, 512)
@@ -167,30 +167,92 @@ def test_autotune_clamps_to_short_seq():
     assert bq <= 128 and bk <= 128 and 128 % bq == 0 and 128 % bk == 0
 
 
-def test_autotune_env_kill_switch(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", "0")
-    assert get_block_sizes(8192, 128, True, device_kind="v5e") == (512, 512)
+# The tables decide, whatever the machine holds.  Before PR 47 a JSON
+# store (PADDLE_TPU_TUNING_CACHE, by its docstring ~/.cache/paddle_tpu/
+# tuning.json) and two switches stood in front of every table below.
+_POISON = (64, 64)       # divides every tabled sequence, is no entry
 
 
-def test_autotune_sweep_mode_foreign_kind_uses_table(monkeypatch):
-    # sweep only tunes the local device; asking for another kind must
-    # fall through to the table, not run (and rerun) a local sweep
-    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", "sweep")
-    assert get_block_sizes(8192, 128, True, device_kind="v5e") \
-        == (1024, 1024)
+def _flash_table():
+    import importlib
+    return importlib.import_module(
+        "paddle_tpu.ops.flash_attention")._AUTOTUNE_TABLE
 
 
-@pytest.mark.slow
-def test_autotune_sweep_on_device():
-    """One-shot on-device sweep (TPU only): must return valid tiles and
-    cache them for the process."""
-    if jax.devices()[0].platform == "cpu":
-        pytest.skip("sweep timings are meaningless off-TPU")
-    from paddle_tpu.ops import flash_attention as fa
-    bq, bk = fa.autotune_sweep(1024, 64, True, iters=2)
-    assert 1024 % bq == 0 and 1024 % bk == 0
-    key = (fa._device_kind(), 1024, 64, True)
-    assert fa._SWEEP_CACHE[key] == (bq, bk)
+@pytest.fixture
+def poisoned_machine(tmp_path, monkeypatch):
+    """HOME and every variable that once named a store point at files
+    that hold other answers for every key asked below."""
+    import json
+    home = tmp_path / "home"
+    store = home / ".cache" / "paddle_tpu" / "tuning.json"
+    store.parent.mkdir(parents=True)
+    entries = {f"flash_blocks|{k}|{s}|{d}|{int(c)}": list(_POISON)
+               for (k, s, d, c) in _flash_table()}
+    entries.update({
+        "qmm_tiles|cpu|64|256|512|int8": [32, 128, 128],
+        "prefill_buckets|cpu|1024": [48, 1024],
+        "moe_a2a_chunks|cpu|64": 8,
+        "remat_policy|cpu|128|2|256": "dots",
+    })
+    store.write_text(json.dumps(entries))
+    legacy = tmp_path / "flash_autotune.json"
+    legacy.write_text(json.dumps(
+        {f"{k}|{s}|{d}|{int(c)}": list(_POISON)
+         for (k, s, d, c) in _flash_table()}))
+    monkeypatch.setenv("HOME", str(home))
+    monkeypatch.setenv("PADDLE_TPU_TUNING_CACHE", str(store))
+    monkeypatch.setenv("PADDLE_TPU_TUNING", "sweep")
+    return legacy
+
+
+@pytest.mark.parametrize("mode", [None, "0", "sweep"])
+@pytest.mark.parametrize("key", sorted(_flash_table()),
+                         ids=lambda k: f"s{k[1]}d{k[2]}")
+def test_flash_table_decides_on_a_poisoned_machine(
+        poisoned_machine, monkeypatch, key, mode):
+    _, seq, d, causal = key
+    if mode is not None:
+        monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE", mode)
+    # under the chip's own name: the alias is what finds the table
+    assert get_block_sizes(seq, d, causal, device_kind="TPU v5 lite") \
+        == _flash_table()[key]
+    monkeypatch.setenv("PADDLE_TPU_FLASH_AUTOTUNE_CACHE",
+                       str(poisoned_machine))
+    assert get_block_sizes(seq, d, causal, device_kind="TPU v5 lite") \
+        == _flash_table()[key]
+
+
+def test_qmm_tiles_decided_on_a_poisoned_machine(poisoned_machine):
+    from paddle_tpu.ops import get_qmm_tiles
+    assert get_qmm_tiles(64, 256, 512) == (64, 256, 512)
+
+
+def test_prefill_buckets_decided_on_a_poisoned_machine(poisoned_machine):
+    from paddle_tpu.inference.engine import default_prefill_buckets
+    assert default_prefill_buckets(1024) == [16, 32, 64, 128, 256, 512,
+                                             1024]
+
+
+def test_moe_a2a_chunks_decided_on_a_poisoned_machine(poisoned_machine):
+    from paddle_tpu.distributed.overlap import moe_a2a_chunks
+    assert moe_a2a_chunks(64) == 2
+
+
+def test_remat_policy_decided_on_a_poisoned_machine(poisoned_machine):
+    from paddle_tpu.distributed import SpmdTrainer, create_mesh
+    from paddle_tpu.distributed.fleet import DistributedStrategy
+    from paddle_tpu.models import GPTForCausalLM
+
+    m = GPTForCausalLM(_tiny_cfg())         # hidden 128, 2 layers, 256
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=m.parameters())
+    st = DistributedStrategy()
+    st.recompute = True
+    st.recompute_configs = {"policy": None}     # none named
+    mesh = create_mesh({"dp": 1}, devices=jax.devices()[:1])
+    SpmdTrainer(m, opt, lambda o, l: o.sum(), mesh=mesh, strategy=st)
+    assert m.gpt._recompute_policy == "full"
 
 
 # ---------------------------------------------------------------------------
@@ -234,8 +296,8 @@ def test_scan_layers_matches_unrolled():
 
 
 def test_scan_layers_with_fused_ce_and_remat():
-    """The bench path: scan + per-iteration jax.checkpoint + blocked CE
-    — still bit-comparable to the plain unrolled full-logits run."""
+    """The training cells' path: scan + per-iteration jax.checkpoint +
+    blocked CE — still bit-comparable to the plain unrolled full-logits run."""
     cfg = _tiny_cfg()
     rng = np.random.RandomState(1)
     ids = rng.randint(0, cfg.vocab_size, (2, 32)).astype(np.int32)

@@ -1,5 +1,5 @@
 """Quantized compute path tests (ISSUE 7): int8/fp8 matmul + fake-quant
-VJP, quantized KV caches, unified tuning table.
+VJP, quantized KV caches, the kernel's tiles.
 
 The contracts under test:
 - ops.quantized_matmul: the Pallas int8 kernel reproduces the XLA
@@ -13,13 +13,9 @@ The contracts under test:
 - int8 KV decode stays within tolerance of the dense decode on BOTH
   cache layouts (static and paged, GQA included), and a warmed int8
   engine churns admissions/retirements with ZERO recompiles;
-- utils.tuning round-trips through its JSON store, shrugs off a
-  corrupt file, and serves flash blocks / prefill buckets / MoE a2a
-  chunk counts.
+- the kernel's tiles are its defaults clamped to divide the problem.
 """
 import importlib
-import json
-import os
 
 import numpy as np
 import pytest
@@ -31,7 +27,7 @@ import paddle_tpu as paddle
 from paddle_tpu.inference import InferenceEngine
 from paddle_tpu.models import (GPTConfig, GPTForCausalLM,
                                GPTPretrainingCriterion)
-from paddle_tpu.utils import compile_counter, tuning
+from paddle_tpu.utils import compile_counter
 
 qm = importlib.import_module("paddle_tpu.ops.quantized_matmul")
 da = importlib.import_module("paddle_tpu.ops.decode_attention")
@@ -409,87 +405,12 @@ def test_decode_hbm_bytes_per_tok_int8_smaller(model):
 
 
 # ---------------------------------------------------------------------------
-# unified tuning table
+# the kernel's tiles
 # ---------------------------------------------------------------------------
-@pytest.fixture()
-def tuning_tmp(tmp_path, monkeypatch):
-    """Point the unified table at a tmp file and reset the process
-    cache on both sides of the test."""
-    path = tmp_path / "tuning.json"
-    monkeypatch.setenv("PADDLE_TPU_TUNING_CACHE", str(path))
-    tuning.reset_for_tests()
-    yield path
-    tuning.reset_for_tests()
-
-
-def test_tuning_table_roundtrip_and_corrupt_fallback(tuning_tmp):
-    key = ("v5e", 2048, 64, True)
-    tuning.record("flash_blocks", key, [256, 512])
-    data = json.loads(tuning_tmp.read_text())
-    assert data["flash_blocks|v5e|2048|64|1"] == [256, 512]
-
-    # "new process": cache dropped, reload from disk
-    tuning.reset_for_tests()
-    assert tuning.lookup("flash_blocks", key) == [256, 512]
-    assert tuning.entries("flash_blocks") == {
-        ("v5e", "2048", "64", "1"): [256, 512]}
-
-    # corrupt table: lookups degrade to None, record() rewrites it
-    tuning_tmp.write_text("{not json")
-    tuning.reset_for_tests()
-    assert tuning.lookup("flash_blocks", key) is None
-    tuning.record("qmm_tiles", ("v5e", 256, 512, 512, "int8"),
-                  [256, 256, 512])
-    assert json.loads(tuning_tmp.read_text())  # valid JSON again
-    tuning.reset_for_tests()
-    assert tuning.lookup("qmm_tiles",
-                         ("v5e", 256, 512, 512, "int8")) == [256, 256, 512]
-
-
-def test_tuning_serves_flash_blocks(tuning_tmp, monkeypatch):
-    """get_block_sizes consults the unified table (outside sweep mode)
-    when the legacy flash env var is unset."""
-    monkeypatch.delenv("PADDLE_TPU_FLASH_AUTOTUNE_CACHE", raising=False)
-    fa = importlib.import_module("paddle_tpu.ops.flash_attention")
-    tuning.record("flash_blocks", ("v9z", 2048, 64, True), [128, 256])
-    from paddle_tpu.ops import get_block_sizes
-    assert get_block_sizes(2048, 64, True, device_kind="v9z") == (128, 256)
-    # clamped through _pick_block like every other source
-    assert get_block_sizes(2048, 64, True, device_kind="v9z") \
-        == (fa._pick_block(2048, 128), fa._pick_block(2048, 256))
-
-
-def test_tuning_serves_prefill_buckets_and_a2a_chunks(tuning_tmp,
-                                                      monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_PREFILL_BUCKETS", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_MOE_A2A_CHUNKS", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_OVERLAP", raising=False)
-    kind = tuning.device_kind()
-    from paddle_tpu.inference.engine import default_prefill_buckets
-    tuning.record("prefill_buckets", (kind, 64), [8, 32, 64])
-    assert default_prefill_buckets(64) == [8, 32, 64]
-    # entries past max_seq are filtered like the env path's
-    tuning.record("prefill_buckets", (kind, 32), [8, 64])
-    assert default_prefill_buckets(32) == [8]
-
-    from paddle_tpu.distributed.overlap import moe_a2a_chunks
-    tuning.record("moe_a2a_chunks", (kind, 8), 4)
-    assert moe_a2a_chunks(8) == 4
-    # NEARBY token counts inherit the tuned value (bounded nearest —
-    # the sweep measures at the bench shape, MoE resolves at b×capacity
-    # which rarely matches exactly), clamped to a divisor: 4 -> 3 for 6
-    assert moe_a2a_chunks(6) == 3
-    # FAR counts (outside the ~4× nearest bound) keep the default
-    assert moe_a2a_chunks(96) == 2
-    monkeypatch.setenv("PADDLE_TPU_OVERLAP", "0")
-    assert moe_a2a_chunks(8) == 1            # kill switch still wins
-
-
-def test_qmm_tiles_consult_table(tuning_tmp):
-    kind = tuning.device_kind()
-    tuning.record("qmm_tiles", (kind, 16, 128, 256, "int8"),
-                  [8, 128, 128])
-    assert qm.get_qmm_tiles(16, 128, 256) == (8, 128, 128)
-    # untuned shape: defaults clamped to divide the problem
-    bm, bn, bk = qm.get_qmm_tiles(64, 256, 512)
-    assert 64 % bm == 0 and 256 % bn == 0 and 512 % bk == 0
+@pytest.mark.parametrize("m,n,k,want", [
+    (64, 256, 512, (64, 256, 512)),          # smaller than the defaults
+    (1024, 1024, 4096, (256, 256, 512)),     # the MXU-sized defaults
+    (96, 384, 640, (32, 128, 128)),          # clamped to divide
+])
+def test_qmm_tiles_divide_the_problem(m, n, k, want):
+    assert qm.get_qmm_tiles(m, n, k) == want

@@ -275,24 +275,6 @@ def test_doctor_expert_imbalance():
     assert verdicts(dict(over, moe_assigned_tokens=8.0)) == []
 
 
-def test_tier1_budget_unit(tmp_path):
-    """The wall-budget guard bench --smoke runs: pure decision fn +
-    record/load round trip, exemptions by basename."""
-    from paddle_tpu.testing import tier1_budget as tb
-
-    assert tb.files_over_budget({"a.py": 10.0, "b.py": 70.0},
-                                budget_s=60, exempt=[]) == [("b.py", 70.0)]
-    assert tb.files_over_budget({"t/b.py": 70.0}, budget_s=60,
-                                exempt=["b.py"]) == []
-
-    p = str(tmp_path / ".tier1_durations.json")
-    assert tb.check_recorded_durations(p) is None
-    tb.record_durations({"x.py": 12.0, "y.py": 99.9}, p)
-    v = tb.check_recorded_durations(p)
-    assert v is not None and v["files"] == 2
-    assert [f for f, _ in v["over_budget"]] == ["y.py"]
-
-
 @pytest.mark.slow
 def test_loadgen_moe_columns(model):
     """Loadgen reports grow the expert-balance window columns: the

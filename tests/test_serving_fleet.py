@@ -2,12 +2,10 @@
 prefill/decode, and the fleet load harness (ISSUE 12 tentpole pieces 2
 and 3 + the summary() satellite).
 
-The heavyweight end-to-end fleet comparison (prefix routing beats
-round-robin on hit rate and p99 TTFT at calibrated load) lives in the
-bench fleet smoke (`bench.py --serve --loadtest --smoke`, exercised by
-test_paged_kv.test_bench_loadtest_smoke_contract); this file covers the
-mechanisms deterministically — summary/fingerprint scoring equals the
-real radix match, routing policy decisions, handoff block accounting,
+No end-to-end fleet comparison is made here (whether prefix routing
+beats round-robin on hit rate and p99 TTFT is a timing, and a chip's
+to measure); this file covers the mechanisms deterministically —
+summary/fingerprint scoring equals the real radix match, routing policy decisions, handoff block accounting,
 and decode-path purity under disaggregation.
 """
 import numpy as np

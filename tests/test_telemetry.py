@@ -10,7 +10,7 @@ The two invariants that make telemetry shippable on a serving hot path:
    buffers nothing, and a disabled registry (PADDLE_TPU_METRICS=0)
    hands every caller the same shared no-op child.
 
-Plus the export contracts the bench smoke rides: Prometheus exposition
+Plus the export contracts: Prometheus exposition
 round-trips through the parser, Chrome-trace JSON validates and holds
 the per-request lifecycle, snapshot files land atomically.
 """
@@ -31,8 +31,7 @@ from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.observability.capture import (ProfileWindow,
                                               parse_profile_spec)
 from paddle_tpu.observability.metrics import Registry
-from paddle_tpu.observability.slo import (FleetAggregator, SLOMonitor,
-                                          load_bench_baseline)
+from paddle_tpu.observability.slo import FleetAggregator, SLOMonitor
 from paddle_tpu.utils import compile_counter
 
 
@@ -142,36 +141,15 @@ def test_exposition_escapes_hostile_label_values_and_help():
         assert hist["h_ms_count"] == 1
 
 
-def test_load_bench_baseline_missing_empty_corrupt(tmp_path):
-    """ISSUE 14 satellite: a missing, empty, or corrupt (binary
-    garbage) BENCH_rows.jsonl yields a clean no-baseline verdict —
-    never an exception out of a serving loop."""
-    # missing
-    assert load_bench_baseline(str(tmp_path / "nope.jsonl")) is None
-    # empty
-    empty = tmp_path / "empty.jsonl"
-    empty.write_text("")
-    assert load_bench_baseline(str(empty)) is None
-    # corrupt: binary garbage raises UnicodeDecodeError during line
-    # iteration without the hardening
-    corrupt = tmp_path / "corrupt.jsonl"
-    corrupt.write_bytes(b"\xff\xfe\x00garbage\x80\x81\nmore\xff\n")
-    assert load_bench_baseline(str(corrupt)) is None
-    # half-corrupt: the valid row is still found past garbage lines
-    mixed = tmp_path / "mixed.jsonl"
-    mixed.write_bytes(
-        b"\x80bad\n" +
-        json.dumps({"kind": "loadtest", "metric": "gpt_serve_loadtest",
-                    "ttft_ms_p99": 12.5}).encode() + b"\n{half")
-    assert load_bench_baseline(str(mixed)) == 12.5
-    # SLOMonitor built over each of them: clean "no baseline" verdict
-    for path in (tmp_path / "nope.jsonl", empty, corrupt):
-        mon = SLOMonitor(rows_path=str(path))
-        assert mon.baseline_ttft_p99_ms is None
-        mon.observe(50.0)
-        v = mon.check()
-        assert v["regressed"] is False
-        assert v["baseline_ttft_p99_ms"] is None
+def test_slo_monitor_without_a_baseline_reads_no_regression():
+    """The baseline is the caller's to pass; a monitor built without one
+    gives a clean no-baseline verdict however slow the window is."""
+    mon = SLOMonitor()
+    assert mon.baseline_ttft_p99_ms is None
+    mon.observe(5000.0)
+    v = mon.check()
+    assert v["regressed"] is False
+    assert v["baseline_ttft_p99_ms"] is None
 
 
 def test_snapshot_jsonl_is_atomic(tmp_path):
@@ -559,15 +537,7 @@ def test_fleet_aggregator_scrapes_new_records_once():
                    ("replica", "0"))]["value"] >= 1
 
 
-def test_slo_monitor_threshold_and_regression(tmp_path):
-    rows = tmp_path / "rows.jsonl"
-    rows.write_text(
-        json.dumps({"kind": "loadtest", "metric": "gpt_serve_loadtest",
-                    "ttft_ms_p99": 20.0}) + "\n" +
-        json.dumps({"kind": "loadtest", "metric": "loadtest_smoke",
-                    "ttft_ms_p99": 1.0}) + "\n")
-    # smoke rows are excluded from the baseline
-    assert load_bench_baseline(str(rows)) == 20.0
+def test_slo_monitor_threshold_and_regression():
     mon = SLOMonitor(ttft_p99_ms=50.0, baseline_ttft_p99_ms=20.0,
                      regression_factor=2.0)
     for _ in range(20):
