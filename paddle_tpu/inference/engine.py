@@ -269,9 +269,14 @@ class InferenceEngine:
                              f"{self.prefill_chunk}")
         self._chunked = self.prefill_chunk > 0
         from .spec_decode import SpecDecoder, resolve_spec_k
-        # False for a model that serves from a per-slot recurrent state
-        self._cache_has_rows = bool(getattr(model, "cache_has_rows", True))
-        if not self._cache_has_rows:
+        # what the model's cache holds, asked of the cache itself (its
+        # shapes alone: nothing is allocated): rows of keys and values,
+        # a per-slot recurrent state, or layers of both
+        held = jax.eval_shape(lambda: model.init_kv_cache(
+            self.batch_slots, self.max_seq_len))
+        self._cache_has_rows = bool(getattr(held, "has_rows", True))
+        self._cache_holds_state = bool(getattr(held, "holds_state", False))
+        if self._cache_holds_state:
             # a recurrent state is valid at one position: nothing to
             # page, to share by prefix, to roll back or to extend a
             # window over, no rows to quantize, no KV heads to shard
@@ -285,8 +290,10 @@ class InferenceEngine:
                 if on:
                     raise ValueError(
                         f"{type(model).__name__} serves from a per-slot "
-                        f"recurrent state with no rows of keys and "
-                        f"values: {option} is not supported for it")
+                        f"recurrent state"
+                        f"{' beside its' if self._cache_has_rows else ' with no'}"
+                        f" rows of keys and values: {option} is not "
+                        f"supported for it")
 
         # persistent compile cache: a restarted server deserializes its
         # prefill/decode executables instead of recompiling them
@@ -531,12 +538,12 @@ class InferenceEngine:
         self._m_active = _metrics.gauge(
             "serve_active_slots", "occupied decode slots",
             labels=("engine",)).labels(**lbl)
-        if not self._cache_has_rows:
+        if self._cache_holds_state:
             _metrics.gauge(
                 "serve_recurrent_state_bytes",
                 "per-slot recurrent state held, as laid out",
                 labels=("engine",)).labels(**lbl).set(
-                    _exec_registry.tree_bytes(self.cache))
+                    self.cache.held_state_bytes)
         # flight recorder + stall watchdog (observability): crash hooks
         # once per process; the watchdog thread appears on the first
         # tick only when PADDLE_TPU_WATCHDOG_S arms it, and an engine

@@ -235,10 +235,6 @@ class BrumbyModel(Layer):
 
 
 class BrumbyForCausalLM(Layer):
-    # what the serving cache holds: a state a slot, no rows of keys and
-    # values (the engine refuses the options that need rows)
-    cache_has_rows = False
-
     def __init__(self, config: BrumbyConfig):
         super().__init__()
         self.cfg = config

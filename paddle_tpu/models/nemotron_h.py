@@ -111,6 +111,75 @@ class _Conv1dParams(Layer):
         self.bias = self.create_parameter([channels], is_bias=True)
 
 
+@dataclass(frozen=True)
+class Mamba2Sizes:
+    """What the Mamba-2 mixer's mathematics is sized by."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    chunk: int
+    eps: float
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+
+def _gated(y, z):
+    return y * jax.nn.silu(z)
+
+
+def mamba2_mixer(x, w_in, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w,
+                 w_out, sz: Mamba2Sizes, view=None, real=None):
+    """The Mamba-2 mixer on arrays, ``x [b, s, hidden]``.  ``view`` None:
+    whole sequences from a zero state (training, a plain forward);
+    returns the output.  With a serving cache's view of this layer
+    (``recurrent_cache.MambaLayerView``) it is one serving step, the s
+    positions continuing the view's window and state and ``real [b]`` of
+    them real (None: all); returns ``(output, the view after them)``."""
+    from ..ops.ssd_scan import causal_conv1d, ssd_scan
+    b, s, _ = x.shape
+    d_in, heads, p = sz.inner, sz.heads, sz.head_dim
+    g, n = sz.groups, sz.state
+    f32 = jnp.float32
+    with jax.named_scope("mamba_proj"):
+        zxbcdt = jnp.matmul(x, w_in)
+        z = zxbcdt[..., :d_in]
+        xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
+        dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
+    with jax.named_scope("mamba_conv"):
+        if view is None:
+            xbc = causal_conv1d(xbc, conv_w, conv_b)
+        else:
+            xbc, view = view.convolve(xbc, conv_w, conv_b, real)
+        xbc = jax.nn.silu(xbc)
+        xs = xbc[..., :d_in].reshape(b, s, heads, p)
+        b_mat = xbc[..., d_in:d_in + g * n].reshape(b, s, g, n)
+        c_mat = xbc[..., d_in + g * n:].reshape(b, s, g, n)
+    with jax.named_scope("mamba_gate_norm"):
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    a_neg = -jnp.exp(a_log.astype(f32))
+    if view is None:
+        y = ssd_scan(xs, dt, a_neg, b_mat, c_mat, chunk=sz.chunk)
+    else:
+        y, view = view.absorb(xs, dt, a_neg, b_mat, c_mat, real).read()
+    with jax.named_scope("mamba_gate_norm"):
+        y = y.astype(f32) + d_skip.astype(f32)[:, None] * \
+            xs.astype(f32)
+        # RMSNorm over groups of d_inner / n_groups channels of
+        # y silu(z)
+        y = _gated(y.reshape(b, s, d_in), z.astype(f32))
+        yg = y.reshape(b, s, g, d_in // g)
+        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                                + sz.eps)
+        y = (yg.reshape(b, s, d_in) *
+             norm_w.astype(f32)).astype(x.dtype)
+    with jax.named_scope("mamba_proj"):
+        out = jnp.matmul(y, w_out)
+    return out if view is None else (out, view)
+
+
 class Mamba2Mixer(Layer):
     def __init__(self, cfg: NemotronHConfig):
         super().__init__()
@@ -127,42 +196,11 @@ class Mamba2Mixer(Layer):
         self.norm = RMSNorm(d_in, epsilon=cfg.layer_norm_epsilon)
         self.out_proj = _linear(cfg, d_in, cfg.hidden_size)
 
-    def _fn(self, x, w_in, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w,
-            w_out):
-        from ..ops.ssd_scan import causal_conv1d, ssd_scan
+    def _fn(self, x, *weights):
         cfg = self.cfg
-        b, s, _ = x.shape
-        d_in, heads, p = cfg.mamba_inner, cfg.mamba_num_heads, \
-            cfg.mamba_head_dim
-        g, n = cfg.n_groups, cfg.ssm_state_size
-        f32 = jnp.float32
-        with jax.named_scope("mamba_proj"):
-            zxbcdt = jnp.matmul(x, w_in)
-            z = zxbcdt[..., :d_in]
-            xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
-            dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
-        with jax.named_scope("mamba_conv"):
-            xbc = jax.nn.silu(causal_conv1d(xbc, conv_w, conv_b))
-            xs = xbc[..., :d_in].reshape(b, s, heads, p)
-            b_mat = xbc[..., d_in:d_in + g * n].reshape(b, s, g, n)
-            c_mat = xbc[..., d_in + g * n:].reshape(b, s, g, n)
-        with jax.named_scope("mamba_gate_norm"):
-            dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-        y = ssd_scan(xs, dt, -jnp.exp(a_log.astype(f32)), b_mat, c_mat,
-                     chunk=cfg.chunk_size)
-        with jax.named_scope("mamba_gate_norm"):
-            y = y.astype(f32) + d_skip.astype(f32)[:, None] * \
-                xs.astype(f32)
-            # RMSNorm over groups of d_inner / n_groups channels of
-            # y silu(z)
-            y = y.reshape(b, s, d_in) * jax.nn.silu(z.astype(f32))
-            yg = y.reshape(b, s, g, d_in // g)
-            yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
-                                    + cfg.layer_norm_epsilon)
-            y = (yg.reshape(b, s, d_in) *
-                 norm_w.astype(f32)).astype(x.dtype)
-        with jax.named_scope("mamba_proj"):
-            return jnp.matmul(y, w_out)
+        return mamba2_mixer(x, *weights, Mamba2Sizes(
+            cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+            cfg.ssm_state_size, cfg.chunk_size, cfg.layer_norm_epsilon))
 
     def forward(self, x):
         return apply(self._fn, x, self.in_proj.weight, self.conv1d.weight,

@@ -10,7 +10,8 @@ scan's chunk kept in VMEM (``kda_scan`` dispatches to
 state updated where it lies (``power_retention`` dispatches to
 ``power_retention_kernel``), the blocked cross-entropy and the
 quantized products.  ``ssd_scan`` is XLA's chunked form alone (a kernel
-lost to it).  Every entry point with two paths records the one it traced
+lost to it), and so is its single-token form ``ssd_step`` (a kernel lost
+to that too).  Every entry point with two paths records the one it traced
 in ``kernel_paths``.
 """
 from . import kernel_paths  # noqa: F401
@@ -28,7 +29,9 @@ from .fused_cross_entropy import (  # noqa: F401
     fused_linear_cross_entropy, pick_vocab_block)
 from .grouped_matmul import (  # noqa: F401
     grouped_matmul, grouped_matmul_available)
-from .ssd_scan import causal_conv1d, ssd_scan  # noqa: F401
+from .ssd_scan import (  # noqa: F401
+    causal_conv1d, causal_conv1d_step, conv_window, ssd_scan,
+    ssd_scan_with_state, ssd_step)
 from .kda_scan import kda_scan  # noqa: F401
 from .power_retention import (  # noqa: F401
     power_retention_chunked, power_retention_step)

@@ -19,10 +19,10 @@ exponentials) and the carried states stay float32 whatever the inputs;
 the matrix products take their operands in the inputs' dtype (bf16 under
 AMP) and accumulate in float32.
 
-There is ONE implementation, in XLA's own operations, on the chip and off
-it, so there is no choice for ``ops.kernel_paths`` to record (a count
-there means a kernel was passed over; an entry point that gains a kernel
-notes its choice from then on).  A Pallas kernel for the chunk's product (a chunk's
+The chunked form has ONE implementation, in XLA's own operations, on the
+chip and off it, so ``ssd_scan`` has no choice for ``ops.kernel_paths``
+to record; the single-token step below has one too.  A Pallas kernel for
+the chunk's product (a chunk's
 group of 8 heads a grid step, ``C B^T`` once, then mask, decay and a
 ``[128, 128] x [128, 64]`` product a head, the backward as the same
 kernel three more times with the operands' roles exchanged) was written,
@@ -30,13 +30,30 @@ agreed with this file to rounding, and LOST on the chip: 173.6 ms a step
 against 96.1 for these einsums at 2 x 8192 positions (PERF.md, PR 33): a
 head's products are too small to fill the MXU from inside one grid step,
 and XLA batches them over all heads and chunks.  It was deleted.
+
+Serving reads the same recurrence through three more entries:
+``ssd_scan_with_state`` (the chunked form from an initial state, handing
+back the state after the last position: a prefill), ``ssd_step`` (one
+token a slot over a state ``[slots, H, P, N]`` float32 updated where it
+lies: the decode tick), and the convolution's two serving forms, ``causal_conv1d_step`` over a
+window of the last ``K - 1`` inputs a slot and ``conv_window``, which
+cuts that window out of a prefill's inputs.  The step is XLA's own: ONE
+fusion reads a donated state, decays it, adds the token's outer product,
+stores it over itself and contracts it with C (0.413 ms a layer at 64
+slots of ``[64, 64, 128]``, 79% of the bytes' roofline).  A Pallas kernel
+for it (a grid step a slot, the state in VMEM, a head at a time, the
+decays from SMEM) was written, agreed to rounding, and LOST on the chip:
+0.486 ms a layer in the same probe, 65% inside the served tick (PERF.md,
+PR 48): a head's 64 row sums over 128 lanes cost it as much as the
+update.  It was deleted.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ssd_scan", "causal_conv1d"]
+__all__ = ["ssd_scan", "ssd_scan_with_state", "ssd_step", "causal_conv1d",
+           "causal_conv1d_step", "conv_window"]
 
 _F32 = jnp.float32
 
@@ -55,6 +72,42 @@ def ssd_scan(x, dt, a_neg, b_mat, c_mat, chunk: int = 128):
         return _chunked(x, dt, a_neg, b_mat, c_mat, int(chunk))
 
 
+def ssd_scan_with_state(x, dt, a_neg, b_mat, c_mat, chunk: int = 128,
+                        state=None):
+    """``ssd_scan`` from ``state [b, G, r, P, N]`` float32 (None: zeros),
+    also handing back the state after the last position: ``(y, state)``.
+    A position with ``dt = 0`` neither decays nor writes, so a caller
+    that zeroes ``dt`` past a row's real tokens gets the state after its
+    last real one (and garbage ``y`` past it)."""
+    with jax.named_scope("ssd_scan"):
+        return _chunked_from(x, dt, a_neg, b_mat, c_mat, int(chunk), state)
+
+
+def ssd_step(x, dt, a_neg, b_mat, c_mat, state, active=None):
+    """One token a slot: ``x [B, H, P]``, ``dt [B, H]`` (after its
+    softplus), ``a_neg [H]``, ``b_mat``/``c_mat [B, G, N]``, ``state
+    [B, H, P, N]`` float32.  ``S <- exp(dt A) S + dt x (x) B``, ``y = S
+    C``: returns ``(y [B, H, P] float32, state)``, without the ``D x``
+    skip, in ONE pass over the state (one fusion with two results),
+    which a donated buffer takes where it lies.  A slot with ``active ==
+    0`` neither decays nor writes."""
+    if active is not None:
+        dt = jnp.where((jnp.asarray(active) > 0)[:, None], dt, 0)
+    with jax.named_scope("ssm_step"):
+        bsz, n_heads, p = x.shape
+        n_groups, n = b_mat.shape[1:]
+        r = n_heads // n_groups
+        dt = dt.astype(_F32)
+        decay = jnp.exp(dt * a_neg.astype(_F32))                # [B, H]
+        xdt = x.astype(_F32) * dt[..., None]                    # [B, H, P]
+        s5 = state.reshape(bsz, n_groups, r, p, n)
+        s5 = s5 * decay.reshape(bsz, n_groups, r, 1, 1) + \
+            xdt.reshape(bsz, n_groups, r, p, 1) * \
+            b_mat.astype(_F32)[:, :, None, None, :]
+        y = jnp.sum(s5 * c_mat.astype(_F32)[:, :, None, None, :], axis=-1)
+        return y.reshape(bsz, n_heads, p), s5.reshape(state.shape)
+
+
 def _intra_chunk(cc, bc, xdt, cum):
     """A chunk's own product ((C B^T) o L) (dt x): ``cc``/``bc [b, nc, Q,
     G, N]``, ``xdt [b, nc, Q, G, r, P]``, ``cum [b, nc, G, r, Q]``."""
@@ -68,6 +121,14 @@ def _intra_chunk(cc, bc, xdt, cum):
 
 
 def _chunked(x, dt, a_neg, b_mat, c_mat, q):
+    """``y`` alone, from a zero state: ``ssd_scan``'s body (the
+    benchmark's planted faults wrap this name)."""
+    return _chunked_from(x, dt, a_neg, b_mat, c_mat, q, None)[0]
+
+
+def _chunked_from(x, dt, a_neg, b_mat, c_mat, q, state):
+    """``(y, the state after the last position [b, G, r, P, N])`` from
+    ``state`` (None: zeros)."""
     bsz, s, n_heads, p = x.shape
     n_groups, n = b_mat.shape[2], b_mat.shape[3]
     r = n_heads // n_groups
@@ -101,14 +162,16 @@ def _chunked(x, dt, a_neg, b_mat, c_mat, q):
         s_c, d_c = inp
         return h * d_c[..., None, None] + s_c, h
 
-    _, before = jax.lax.scan(
-        carry_on, jnp.zeros((bsz, n_groups, r, p, n), _F32),
+    if state is None:
+        state = jnp.zeros((bsz, n_groups, r, p, n), _F32)
+    last, before = jax.lax.scan(
+        carry_on, state,
         (jnp.moveaxis(own, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
     before = jnp.moveaxis(before, 0, 1).astype(cdt)         # state entering
     from_start = jnp.moveaxis(jnp.exp(cum), -1, 2)[..., None]
     y = y + _dot("bcign,bcgrpn->bcigrp", cc, before) * from_start
     y = y.reshape(bsz, nc * q, n_heads, p)
-    return (y[:, :s] if pad else y).astype(cdt)
+    return (y[:, :s] if pad else y).astype(cdt), last
 
 
 def _taps(xp, w, s):
@@ -158,3 +221,25 @@ def causal_conv1d(x, weight, bias=None):
     if bias is None:
         bias = jnp.zeros(weight.shape[:1], weight.dtype)
     return _conv(x, weight, bias)
+
+
+def causal_conv1d_step(window, x_new, weight, bias=None):
+    """``causal_conv1d`` for ONE new position a slot: ``window [B, K-1,
+    C]`` the slot's last ``K - 1`` inputs (oldest first), ``x_new [B,
+    C]``.  Returns ``(y [B, C], the window moved on by one)``, y what
+    ``causal_conv1d`` gives at that position, tap for tap."""
+    full = jnp.concatenate([window, x_new[:, None].astype(window.dtype)], 1)
+    if bias is None:
+        bias = jnp.zeros(weight.shape[:1], weight.dtype)
+    y = bias.astype(full.dtype) + _taps(full, weight.astype(full.dtype), 1)
+    return y[:, 0], full[:, 1:]
+
+
+def conv_window(x, real, k: int):
+    """The window a prefill leaves: the inputs ``x [B, S, C]`` at the
+    last ``k - 1`` real positions of each row (``real [B]`` of them are
+    real), zeros where a row is shorter than that."""
+    xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
+    at = jnp.asarray(real, jnp.int32)[:, None] + \
+        jnp.arange(k - 1, dtype=jnp.int32)[None, :]
+    return jnp.take_along_axis(xp, at[..., None], axis=1)

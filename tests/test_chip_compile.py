@@ -400,6 +400,69 @@ def test_retention_step_updates_its_state_where_it_lies(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# the state-space decode step, and the step of a stack of two kinds of cache
+# ---------------------------------------------------------------------------
+def test_ssd_step_updates_its_state_where_it_lies(one_chip):
+    """The Mamba-2 decode step (XLA's own form) at Granite 4.0-H's
+    published widths (64 heads of 64 on a state of 128, one B/C group)
+    over 8 slots, the state donated: the state comes out in the memory it
+    went in by, no temporary the size of a slot's state stands beside
+    it, and ONE fusion holds the update and the read-out."""
+    import re
+    ss = importlib.import_module("paddle_tpu.ops.ssd_scan")
+    slots, heads, p, n = 8, 64, 64, 128
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    with persistent_cache_off():
+        compiled = jax.jit(ss.ssd_step, donate_argnums=(5,)).lower(
+            f32(slots, heads, p), f32(slots, heads), f32(heads),
+            f32(slots, 1, n), f32(slots, 1, n),
+            f32(slots, heads, p, n)).compile()
+    held = slots * heads * p * n * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= held
+    assert memory.temp_size_in_bytes < held // slots
+    state = r"f32\[%d,%d,%d,%d\]" % (slots, heads, p, n)
+    readers = [line for line in compiled.as_text().splitlines()
+               if " fusion(" in line and re.search(state, line)]
+    assert len(readers) == 1, [line[:160] for line in readers]
+
+
+def test_hybrid_decode_step_never_copies_a_state_or_a_row(one_chip,
+                                                          monkeypatch):
+    """The engine's jitted decode step over a Mamba-2 layer and an
+    attention layer at Granite 4.0-H's published widths, 8 slots x 1024,
+    the cache donated: the attention kernel is in the program, the step
+    holds no scatter, and every leaf of the cache (state, window, k, v, lengths)
+    comes out in the memory it went in by, with no copy of a state or of
+    a layer's rows beside it."""
+    import re
+    from paddle_tpu.inference import InferenceEngine
+    from paddle_tpu.models import (GraniteHybridConfig,
+                                   GraniteHybridForCausalLM)
+    monkeypatch.setattr(da, "decode_attention_available", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention_available", lambda: True)
+    model = GraniteHybridForCausalLM(GraniteHybridConfig(
+        num_hidden_layers=2, layer_types=("mamba", "attention"),
+        vocab_size=8192, max_seq_len=1024))
+    model.eval()
+    eng = InferenceEngine(model, batch_slots=SLOTS, max_seq_len=1024,
+                          cache_dtype=bf16, prefill_buckets=[128])
+    compiled = _compile_on_one_chip(eng, one_chip)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.findall(r" scatter\(", text)
+    leaves = jax.tree_util.tree_leaves(eng.cache)
+    assert len(leaves) == 2 + 2 + 1
+    aliased = re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)",
+                         text.split("\n", 1)[0])
+    assert len(aliased) == len(leaves), text.split("\n", 1)[0][:400]
+    assert eng.cache.layers[1].k.shape == (8, 4, 1024, 128)  # two heads a row
+    for shape in ("8,64,64,128", "8,4,1024,128", "8,8,1024,64"):
+        assert not re.search(r"\[%s\]\S* copy\(" % shape, text), shape
+
+
+# ---------------------------------------------------------------------------
 # the linear-attention / latent-attention stack's two mixers
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("kind,kernels", [("kda", 2), ("mla", 2)])
