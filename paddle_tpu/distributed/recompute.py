@@ -32,17 +32,35 @@ _POLICIES = {
     "nothing": "nothing_saveable",
     "everything": "everything_saveable",
 }
+# the policies that keep the products' outputs and recompute the cheap
+# element-wise work between them
+_KEEP_PRODUCTS = ("dots", "dots_no_batch")
 
 
 def checkpoint_policy(name: Optional[str]):
     """Map strategy.recompute_configs['policy'] names onto
-    jax.checkpoint_policies."""
+    jax.checkpoint_policies.
+
+    `dots` and `dots_no_batch` keep the outputs of the products: XLA's
+    `dot_general`s and, under the names its forward rule gives them
+    (`ops.flash_attention.RESIDUAL_NAMES`), the flash attention
+    kernel's output and log-sum-exp. The kernel IS two products a tile,
+    and to a policy that looks for `dot_general` a Pallas custom call
+    is not one: without the names the backward would run the kernel's
+    forward a second time. `full` (None) recomputes everything,
+    `nothing` and `everything` and a raw `jax.checkpoint_policies`
+    attribute name mean what jax says."""
     if name is None or name == "full":
         return None
     attr = _POLICIES.get(name, name)
     pol = getattr(jax.checkpoint_policies, attr, None)
     if pol is None:
         raise ValueError(f"unknown recompute policy {name!r}")
+    if name in _KEEP_PRODUCTS:
+        from ..ops.flash_attention import RESIDUAL_NAMES
+        pol = jax.checkpoint_policies.save_from_both_policies(
+            pol, jax.checkpoint_policies.save_only_these_names(
+                *RESIDUAL_NAMES))
     return pol
 
 

@@ -862,10 +862,12 @@ class GPTModel(Layer):
         forward() (not by re-wrapping sublayers) so parameter names —
         and therefore state dicts/checkpoints — are unchanged.
 
-        policy: jax.checkpoint_policies name ('dots', 'dots_no_batch',
-        ...) — selective save policies keep matmul outputs and only
-        recompute the cheap elementwise ops, recovering most of the remat
-        FLOPs vs full recompute (None)."""
+        policy: a `distributed.recompute.checkpoint_policy` name.
+        'dots' and 'dots_no_batch' keep the products' outputs (the
+        matmuls' and, under the names its forward rule gives them, the
+        flash attention kernel's output and log-sum-exp) and recompute
+        only the cheap elementwise ops between them: no product and no
+        kernel runs twice.  None or 'full' recomputes everything."""
         self._recompute = True
         self._recompute_policy = policy
         return self

@@ -26,6 +26,16 @@ An optional key-padding mask [B, S] (1 = attend, 0 = masked) covers the
 padded-batch pretraining case without an O(S²) bias tensor; arbitrary
 additive masks still fall back to the XLA composite.
 
+What a remat policy can keep: the forward rule of the differentiated
+call (`_flash_fwd`) passes the two things the backward needs from the
+kernel through `jax.ad_checkpoint.checkpoint_name`: the output, as the
+caller gets it, under `flash_out` and the per-row log-sum-exp under
+`flash_lse` (RESIDUAL_NAMES).  A `jax.checkpoint` policy that saves those
+names (`distributed.recompute.checkpoint_policy("dots" |
+"dots_no_batch")` does) spares the backward a second run of the forward
+kernel; under any other policy a name is an identity.  The
+undifferentiated call (`_flash`: serving's prefills) carries no name.
+
 Layout contract: q [B, S, H, D], k [B, S, Hkv, D], v [B, S, Hkv, Dv] with
 H % Hkv == 0.  The scores' width D and the values' width Dv are two
 numbers: every kernel takes q and k at D (the softmax scale is
@@ -41,6 +51,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -48,6 +59,9 @@ from jax.experimental.pallas import tpu as pltpu
 from . import kernel_paths
 
 _INTERPRET = False  # set True in tests to run the kernel on CPU
+# what the differentiated call names for a remat policy to keep: the
+# kernel's output and its per-row log-sum-exp
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 _NEG = -1e30
 
 
@@ -535,12 +549,18 @@ def _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
 # ---------------------------------------------------------------------------
 # layout shuffles [B,S,H,D] <-> GQA grid layout
 # ---------------------------------------------------------------------------
-def _to_gqa(q, k, v):
-    b, s, h, d = q.shape
-    hkv = k.shape[2]
-    g = h // hkv
+def _to_gqa_q(x, hkv):
+    """[B, S, H, W] -> [B*Hkv, G, S, W]: q, and what has q's heads (the
+    output, its gradient)."""
+    b, s, h, w = x.shape
     # q head index = hk * g + gi (repeat_interleave convention)
-    q4 = jnp.swapaxes(q, 1, 2).reshape(b * hkv, g, s, d)
+    return jnp.swapaxes(x, 1, 2).reshape(b * hkv, h // hkv, s, w)
+
+
+def _to_gqa(q, k, v):
+    b, s, _, d = q.shape
+    hkv = k.shape[2]
+    q4 = _to_gqa_q(q, hkv)
     k3 = jnp.swapaxes(k, 1, 2).reshape(b * hkv, s, d)
     v3 = jnp.swapaxes(v, 1, 2).reshape(b * hkv, s, v.shape[3])
     return q4, k3, v3
@@ -585,28 +605,35 @@ def _composite(q, k, v, causal, kv_mask=None):
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _flash(q, k, v, mask, causal):
-    o, _ = _flash_fwd_impl(q, k, v, mask, causal)
-    return o
+    return _flash_fwd_impl(q, k, v, mask, causal)[0]
 
 
 def _flash_fwd_impl(q, k, v, mask, causal):
+    """(the output [B, S, H, Dv], the kernel's lse [B*Hkv, G, 1, S])."""
     b, s, h, d = q.shape
     q4, k3, v3 = _to_gqa(q, k, v)
     bq, bk = get_block_sizes(s, d, causal)
     o4, lse = _fwd_gqa(q4, k3, v3, mask, causal, block_q=bq, block_k=bk)
-    return _from_gqa_q(o4, b, s, h, v.shape[3]), (q, k, v, mask, o4, lse)
+    return _from_gqa_q(o4, b, s, h, v.shape[3]), lse
 
 
 def _flash_fwd(q, k, v, mask, causal):
-    return _flash_fwd_impl(q, k, v, mask, causal)
+    # the forward rule alone names what the backward needs from the
+    # kernel, so that a remat policy can keep it (RESIDUAL_NAMES); the
+    # output is kept as the caller gets it, [B, S, H, Dv]: stacked by a
+    # layer scan in the kernel's own [B*Hkv, G, S, 64] it pays a relayout
+    o, lse = _flash_fwd_impl(q, k, v, mask, causal)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return o, (q, k, v, mask, o, lse)
 
 
 def _flash_bwd(causal, res, g_out):
-    q, k, v, mask, o4, lse = res
+    q, k, v, mask, o, lse = res
     b, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[3]
     q4, k3, v3 = _to_gqa(q, k, v)
-    do4 = jnp.swapaxes(g_out, 1, 2).reshape(b * hkv, h // hkv, s, dv)
+    o4, do4 = _to_gqa_q(o, hkv), _to_gqa_q(g_out, hkv)
     bq, bk = get_block_sizes(s, d, causal)
     dq4, dk3, dv3 = _bwd_gqa(q4, k3, v3, mask, o4, lse, do4, causal,
                              block_q=bq, block_k=bk)

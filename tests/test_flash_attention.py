@@ -245,6 +245,64 @@ def test_key_mask_is_an_operand_only_when_passed(key_mask):
     assert "flash_attention." + other not in counts
 
 
+_NAMED_CASES = {
+    # (b, s, h, hkv, d, dv, key mask)
+    "mha": (2, 256, 4, 4, 64, 64, False),
+    "gqa": (2, 256, 8, 2, 64, 64, False),
+    "two_widths": (1, 128, 2, 2, 192, 128, False),
+    "gqa_key_mask": (2, 256, 4, 2, 64, 64, True),
+}
+
+
+def _named_case(case):
+    b, s, h, hkv, d, dv, key_mask = _NAMED_CASES[case]
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32) * 0.3)
+    k = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32) * 0.3)
+    v = jnp.asarray(rng.randn(b, s, hkv, dv).astype(np.float32) * 0.3)
+    mask = _masked_head(b, s, 32) if key_mask else None
+    loss = lambda *a: (fa.flash_attention(
+        *a, causal=True, kv_mask=mask) ** 2).sum()
+    return (q, k, v), loss
+
+
+@pytest.mark.parametrize("case", sorted(_NAMED_CASES))
+def test_forward_rule_names_what_the_backward_needs(case):
+    """The differentiated call names the output as the caller gets it,
+    [B, S, H, Dv], and the log-sum-exp as the kernel writes it; the
+    call itself names nothing."""
+    b, s, h, hkv, d, dv, _ = _NAMED_CASES[case]
+    qkv, loss = _named_case(case)
+
+    def named(jaxpr):
+        return {e.params["name"]: e.outvars[0].aval.shape
+                for e in jaxpr.eqns if e.primitive.name == "name"}
+
+    assert named(jax.make_jaxpr(jax.grad(loss))(*qkv).jaxpr) == {
+        "flash_out": (b, s, h, dv),
+        "flash_lse": (b * hkv, h // hkv, 1, s)}
+    assert named(jax.make_jaxpr(loss)(*qkv).jaxpr) == {}
+
+
+@pytest.mark.parametrize("case", sorted(_NAMED_CASES))
+def test_backward_from_kept_names_is_the_backward(case):
+    """Under a remat that keeps the two names the backward kernel reads
+    the kept output (turned back into the kernel's layout) and lse: the
+    gradients are those of the plain call bit for bit, and no second
+    forward kernel is traced; a remat that keeps nothing traces two."""
+    qkv, loss = _named_case(case)
+    plain = jax.grad(loss, argnums=(0, 1, 2))(*qkv)
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *fa.RESIDUAL_NAMES)
+    for policy, forwards in ((keep, 1), (None, 2)):
+        grad = jax.grad(jax.checkpoint(loss, policy=policy),
+                        argnums=(0, 1, 2))
+        calls = list(_pallas_calls(jax.make_jaxpr(grad)(*qkv).jaxpr))
+        assert len(calls) == forwards + 1
+        for a, b in zip(grad(*qkv), plain):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 def test_a_fallback_names_no_body():
     """A shape the kernels do not serve counts as the composite, as it
     did, and as neither body."""
